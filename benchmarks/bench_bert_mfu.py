@@ -51,11 +51,9 @@ def main() -> int:
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument("--warmup", type=int, default=3)
     parser.add_argument("--quick", action="store_true")
-    parser.add_argument("--probe-budget", type=float, default=300.0)
     args = parser.parse_args()
     return B.run_mfu_sweep("bert-base", sweep_configs(args.quick),
-                           steps=args.steps, warmup=args.warmup,
-                           probe_budget=args.probe_budget)
+                           steps=args.steps, warmup=args.warmup)
 
 
 if __name__ == "__main__":

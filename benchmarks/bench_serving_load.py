@@ -3271,7 +3271,6 @@ def main() -> int:
     parser.add_argument("--short-clients", type=int, default=12)
     parser.add_argument("--long-clients", type=int, default=4)
     parser.add_argument("--requests", type=int, default=6)
-    parser.add_argument("--probe-budget", type=float, default=300.0)
     parser.add_argument("--sanitize", action="store_true",
                         help="Run the load A/B with the lock-order "
                              "sanitizer wrapping the serving locks "
@@ -3282,8 +3281,7 @@ def main() -> int:
     parser.add_argument("--cpu", action="store_true")
     args = parser.parse_args()
 
-    jax, backend, fallback = B.init_backend(
-        args.cpu, probe_budget=args.probe_budget)
+    jax, backend = B.init_backend(args.cpu)
     model = args.model or ("gpt2-medium" if backend == "tpu"
                            else "gpt2-mini")
     r = bench_serving_load(jax, model, backend,
@@ -3300,9 +3298,8 @@ def main() -> int:
               "a correctness check, not a perf baseline",
               file=sys.stderr)
     # A mode that errored out is missing from load[]/load_sampled[]/
-    # load_spec[]: mark the row partial so resume_sweep's leg
-    # attribution (non-partial rows only) retries the leg instead of
-    # stamping it done without the headline A/B measurements.
+    # load_spec[]: mark the row partial, so that it is never read as
+    # a complete one without the headline A/B measurements.
     if len(r.get("load", [])) < 3 or len(r.get("load_sampled", [])) < 3 \
             or len(r.get("load_spec", [])) < 3 \
             or "telemetry_overhead" not in r \
@@ -3345,8 +3342,7 @@ def main() -> int:
         ov = sub.get("overhead_pct")
         if ov is None:
             # The leg errored out (row already marked partial
-            # above) — fail the run so resume_sweep retries it, but
-            # say what actually happened: the overhead was never
+            # above) — fail the run, but say what actually happened: the overhead was never
             # MEASURED, which is not the same as exceeding the
             # contract.  Explicit raise, not assert: python -O must
             # not strip the contract check.

@@ -37,8 +37,9 @@ sys.path.insert(0, REPO)
 FAIL_LR = 1.0  # trials above this injected-failure threshold exit 1
 
 # Real training child: tiny CIFAR-shaped ConvNet on the CPU backend.
-# The compilation cache is shared across trials (JAX_COMPILATION_CACHE_DIR
-# exported below) so only the first trial at each step-count pays XLA.
+# The compilation cache is shared across trials (train.py places it:
+# config.enable_compilation_cache) so only the first trial at each
+# step-count pays XLA.
 CHILD_CODE = textwrap.dedent(f"""
     import sys
     import jax
@@ -130,9 +131,8 @@ def main() -> int:
                         help="asha: configs sampled at rung 0")
     args = parser.parse_args()
 
-    # Children inherit: forced-CPU jax + a shared compilation cache.
-    cache_dir = os.path.join(tempfile.gettempdir(), "ptpu-sweep-xla-cache")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    # Children inherit forced-CPU jax; they share the compilation cache
+    # that train.py places itself (config.enable_compilation_cache).
     home = tempfile.mkdtemp(prefix="ptpu-sweep-")
     os.environ["POLYAXON_TPU_HOME"] = home
 

@@ -1,7 +1,7 @@
 """Render benchmarks/results.jsonl as a compact evidence table.
 
 results.jsonl is append-only and heterogeneous (headline rows, MFU
-sweeps, decode A/Bs, serving load, offline rooflines, partial wedge
+sweeps, decode A/Bs, serving load, offline rooflines, partial
 checkpoints...).  This prints the CURRENT evidence state: for every
 (bench, model, variant, batch, regime) key, the newest row wins;
 superseded and ``partial`` rows are dropped when a newer complete row
@@ -57,8 +57,8 @@ def current_state(rows):
         if prev is None:
             best[k] = r
             continue
-        # Completeness first (partial rows are wedge salvage), then
-        # recency.
+        # Completeness first (partial rows are salvage from a cut
+        # run), then recency.
         rank = (not r.get("partial"), r.get("ts", 0))
         prev_rank = (not prev.get("partial"), prev.get("ts", 0))
         if rank >= prev_rank:

@@ -310,6 +310,9 @@ def generate(model_name, prompt, max_new_tokens, temperature, top_k,
 
     if cpu:
         jax.config.update("jax_platforms", "cpu")
+    from polyaxon_tpu.config import enable_compilation_cache
+
+    enable_compilation_cache()
     from polyaxon_tpu.models import generate as G
     from polyaxon_tpu.models.registry import get_model
 
@@ -726,6 +729,9 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
 
     if cpu:
         jax.config.update("jax_platforms", "cpu")
+    from polyaxon_tpu.config import enable_compilation_cache
+
+    enable_compilation_cache()
     from polyaxon_tpu.serving import (ModelServer,
                                       PrefixFetchPolicy,
                                       make_server)

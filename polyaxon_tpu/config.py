@@ -47,6 +47,36 @@ def home_dir() -> str:
     return default_home()
 
 
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Every entry point that compiles (``train``, ``ptpu serve``, ``ptpu
+    generate``, ``bench.py``) calls this before its first compile, so
+    tuner trials, gang restarts and a server's next start re-read the
+    programs the last process compiled.
+
+    The directory is placed from OUTSIDE.  With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX has read it itself and
+    nothing is set in code (its other cache knobs are the outside's
+    too).  Otherwise it is ``<checkout>/.jax_cache`` — never a path
+    built from a temp name, a pid, the time or ``POLYAXON_TPU_HOME``:
+    a directory that moves never hits.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_dir:
+        return cache_dir
+    import jax
+
+    cache_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # Persist even sub-second compiles: tiny sweep trials are exactly
+    # the repeated-compile workload.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
+
+
 def _config_path() -> str:
     return os.path.join(home_dir(), "config.json")
 

@@ -18,6 +18,7 @@ the right shape for TPU decode.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -38,6 +39,23 @@ def _params(variables):
     untouched.
     """
     return dequantize_params(variables["params"])
+
+
+def jit_over(weights, fn, **jit_kw):
+    """``jax.jit(fn)`` with ``weights`` as the program's FIRST ARGUMENT,
+    bound here: ``jit_over(w, lambda w, toks: ...)(toks)``.
+
+    A jitted function that CLOSES over arrays gets them baked into the
+    program as constants.  For model weights that is a whole copy of
+    the model inside every compiled program — in the lowered module,
+    in the compiler's host memory, in the cached executable and on the
+    device.  It goes unnoticed at test sizes; at gpt2-medium width
+    ``ptpu serve`` ran a 40 GiB host out of memory on its first
+    request (v5e, PR 22), and gpt2-small's decode window lowered to
+    995 MB of text.  Passed as an argument, the one resident copy
+    serves every program, under whatever sharding it was placed with.
+    """
+    return functools.partial(jax.jit(fn, **jit_kw), weights)
 
 
 def init_cache(model, batch_size: int):

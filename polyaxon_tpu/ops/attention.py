@@ -12,10 +12,11 @@ selected automatically on TPU for supported shapes.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import threading
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,30 @@ import jax.numpy as jnp
 BIG_NEG = -1e30
 
 logger = logging.getLogger(__name__)
+
+# Which local route each attention call took, counted when the call is
+# TRACED (a jitted program traces once per shape).  The drop from the
+# Pallas kernel to the fused-XLA path is a routing decision, not an
+# error, so nothing else would show it: train.py reports the counts
+# after its compile and the server's /info carries them.
+_ROUTES: "collections.Counter[str]" = collections.Counter()
+_ROUTES_LOCK = threading.Lock()
+
+
+def route_counts() -> Dict[str, int]:
+    """``{"flash": n, "xla": n}``: local attention calls traced so far
+    in this process, by the path they took."""
+    with _ROUTES_LOCK:
+        return {"flash": _ROUTES["flash"], "xla": _ROUTES["xla"]}
+
+
+def _note_route(route: str, q, k, mask, causal, window) -> None:
+    with _ROUTES_LOCK:
+        _ROUTES[route] += 1
+    logger.info("attention route=%s q=%s k=%s mask=%s causal=%s window=%s",
+                route, q.shape, k.shape, getattr(mask, "shape", None),
+                causal, window)
+
 
 # Active sequence-parallel context: when set (mesh with sp>1 + mode),
 # dot_product_attention routes through ring/Ulysses shard_map attention —
@@ -147,6 +172,42 @@ def _xla_attention(q, k, v, mask, causal, scale, window=None,
     return out.astype(orig_dtype)
 
 
+def _mesh_flash(mesh, q, k, v, mask, causal, scale, window):
+    """The flash kernel under a train step's mesh.
+
+    GSPMD refuses a Mosaic kernel ("cannot be automatically
+    partitioned"), so a step jitted over more than one device has to
+    hand the kernel its shard itself: batch over the data axes, heads
+    over ``tp`` — the layout the models' ``constrain`` calls already
+    give q/k/v.  Rows and heads never interact in attention, so each
+    device's call is the whole computation for its shard."""
+    import math
+
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import active_batch_axes
+    from .flash import flash_attention, narrow_kv_mask
+
+    batch = active_batch_axes(mesh)
+    if batch and q.shape[0] % math.prod(mesh.shape[a] for a in batch):
+        batch = None
+    tp = mesh.shape.get("tp", 1)
+    heads = "tp" if tp > 1 and q.shape[2] % tp == 0 else None
+    qkv = P(batch, None, heads, None)
+
+    def local(q, k, v, kv_mask=None):
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               kv_mask=kv_mask, window=window)
+
+    operands, specs = (q, k, v), (qkv, qkv, qkv)
+    if mask is not None:
+        operands += (narrow_kv_mask(mask, q.shape[0], k.shape[1]),)
+        specs += (P(batch, None),)
+    return shard_map(local, mesh=mesh, in_specs=specs, out_specs=qkv,
+                     check_vma=False)(*operands)
+
+
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -189,6 +250,7 @@ def dot_product_attention(
                 "sequence_parallel: additive attention bias is not "
                 "supported by the ring/Ulysses schedules; falling back "
                 "to local attention for this call")
+        _note_route("xla", q, k, mask, causal, window)
         return _xla_attention(q, k, v, mask, causal, scale,
                               window=window, bias=bias)
     route = _sp_route(q, k, v, mask, causal, scale)
@@ -210,7 +272,17 @@ def dot_product_attention(
     # or interpret-mode, lane/MXU alignment, key-padding-mask-only —
     # denser masks use the fused-XLA path).
     if flash_eligible(q.shape[1], k.shape[1], q.shape[-1], mask):
+        _note_route("flash", q, k, mask, causal, window)
+        from ..parallel.constraints import current_mesh, \
+            in_manual_context
+
+        mesh = current_mesh()
+        if mesh is not None and mesh.size > 1 \
+                and not in_manual_context():
+            return _mesh_flash(mesh, q, k, v, mask, causal, scale,
+                               window)
         kv_mask = None if mask is None else mask[:, 0, 0, :]
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                kv_mask=kv_mask, window=window)
+    _note_route("xla", q, k, mask, causal, window)
     return _xla_attention(q, k, v, mask, causal, scale, window=window)

@@ -42,10 +42,9 @@ BLOCK_Q = int(os.environ.get("POLYAXON_TPU_FLASH_BLOCK_Q", 1024))
 BLOCK_KV = int(os.environ.get("POLYAXON_TPU_FLASH_BLOCK_KV", 1024))
 # The backward kernels hold more live operands per program (q/k/v/o/do
 # + two output accumulators), so their VMEM sweet spot can sit below
-# the forward's — tunable independently for the on-chip A/B
-# (benchmarks/tpu_sweep.sh bwd-block legs).  None = follow the LIVE
-# forward caps at call time, so tests that monkeypatch BLOCK_Q/
-# BLOCK_KV keep shrinking the backward tiling too.
+# the forward's — tunable independently for an on-chip A/B.  None =
+# follow the LIVE forward caps at call time, so tests that monkeypatch
+# BLOCK_Q/BLOCK_KV keep shrinking the backward tiling too.
 _env_q_bwd = os.environ.get("POLYAXON_TPU_FLASH_BLOCK_Q_BWD")
 _env_kv_bwd = os.environ.get("POLYAXON_TPU_FLASH_BLOCK_KV_BWD")
 BLOCK_Q_BWD = int(_env_q_bwd) if _env_q_bwd else None
@@ -171,8 +170,8 @@ def _block_ids(iq, ikv, block_q, block_kv, q_shift):
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
                 block_q: int, block_kv: int, q_shift: int,
                 padded: bool = False, window=None, n_kv_total=None):
-    # Optional key-padding mask rides as a 4th input ref ([1, block_kv,
-    # 128] f32; column 0 = 1.0 for valid keys).
+    # Optional key-padding mask rides as a 4th input ref ([1, 8,
+    # block_kv] f32; row 0 = 1.0 for valid keys).
     if padded:
         kvm_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -216,7 +215,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
                 scores = jnp.where(q_ids - k_ids <= window, scores,
                                    NEG_INF)
         if padded:
-            valid = kvm_ref[0][:, 0][None, :] > 0.0  # [1, block_kv]
+            valid = kvm_ref[0][:1, :] > 0.0  # [1, block_kv]
             scores = jnp.where(valid, scores, NEG_INF)
 
         m_prev = m_ref[:, :1]
@@ -248,17 +247,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
 
 
 def _pack_kv_mask(kv_mask, sk):
-    """[B, Sk] bool -> [B, Sk, 128] f32 (column 0 carries the value; the
-    128-lane minor dim keeps the mosaic tiling happy)."""
-    m = kv_mask.astype(jnp.float32)[:, :, None]
-    return jnp.broadcast_to(m, (kv_mask.shape[0], sk, 128))
+    """[B, Sk] bool -> [B, 8, Sk] f32: keys along the LANES, the way the
+    kernels' [block_q, block_kv] score tiles hold them, so the mask row
+    broadcasts over a tile as it is (row 0 carries the value; 8 rows
+    make one f32 sublane tile).  Keys along the sublanes had to be
+    turned into a lane vector inside the kernel, which this toolchain's
+    Mosaic lowers to spills: the v5e compile of a 256-key block ran out
+    of VMEM and a 512-key block did not finish (tests/
+    test_chip_compile.py holds the bert-base shape to this)."""
+    m = kv_mask.astype(jnp.float32)[:, None, :]
+    return jnp.broadcast_to(m, (kv_mask.shape[0], 8, sk))
 
 
 def _flash_forward(q, k, v, kvm, causal: bool, scale: float,
                    window=None):
     """q/k/v: [B, H, S, D] -> (out, lse[B, H, Sq, 128]).
 
-    ``kvm``: None or packed key-padding mask [B, Sk, 128] f32."""
+    ``kvm``: None or packed key-padding mask [B, 8, Sk] f32."""
     batch, heads, sq, d = q.shape
     sk = k.shape[2]
     if sq % 128 or sk % 128:
@@ -303,9 +308,9 @@ def _flash_forward(q, k, v, kvm, causal: bool, scale: float,
     ]
     inputs = [q, k, v]
     if padded:
-        in_specs.append(pl.BlockSpec((1, block_kv, 128),
-                                     lambda b, h, i, j: (b, kv_block(i, j),
-                                                         0)))
+        in_specs.append(pl.BlockSpec((1, 8, block_kv),
+                                     lambda b, h, i, j: (b, 0,
+                                                         kv_block(i, j))))
         inputs.append(kvm)
     out, lse = pl.pallas_call(
         kernel,
@@ -389,7 +394,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if padded:
             # Select (not multiply) so a fully-masked row's inf p terms
             # (lse == NEG_INF) cannot produce NaN.
-            valid = kvm_ref[0][:, 0][None, :] > 0.0
+            valid = kvm_ref[0][:1, :] > 0.0
             p = jnp.where(valid, p, 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -448,7 +453,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if window is not None:
                 p = jnp.where(q_ids - k_ids <= window, p, 0.0)
         if padded:
-            valid = kvm_ref[0][:, 0][None, :] > 0.0  # this kv block
+            valid = kvm_ref[0][:1, :] > 0.0  # this kv block
             p = jnp.where(valid, p, 0.0)
         # dV += P^T dO
         dv_acc[:] += jax.lax.dot_general(
@@ -520,8 +525,8 @@ def _flash_backward(q, k, v, kvm, o, lse, do, causal: bool, scale: float,
     dq_inputs = [q, k, v, do, lse, delta]
     if padded:
         dq_in_specs.append(pl.BlockSpec(
-            (1, block_kv, 128),
-            lambda b, h, i, j: (b, kv_block(i, j), 0)))
+            (1, 8, block_kv),
+            lambda b, h, i, j: (b, 0, kv_block(i, j))))
         dq_inputs.append(kvm)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
@@ -553,8 +558,8 @@ def _flash_backward(q, k, v, kvm, o, lse, do, causal: bool, scale: float,
                     rowspec_t]
     dkv_inputs = [q, k, v, do, lse, delta]
     if padded:
-        dkv_in_specs.append(pl.BlockSpec((1, block_kv, 128),
-                                         lambda b, h, i, j: (b, i, 0)))
+        dkv_in_specs.append(pl.BlockSpec((1, 8, block_kv),
+                                         lambda b, h, i, j: (b, 0, i)))
         dkv_inputs.append(kvm)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
@@ -601,7 +606,7 @@ def _flash_bwd(causal, scale, window, res, g):
         from .attention import _xla_attention
 
         mask = None if kvm is None else \
-            (kvm[:, None, None, :, 0] > 0.0)
+            (kvm[:, None, None, 0, :] > 0.0)
 
         def ref(q, k, v):
             out = _xla_attention(q.transpose(0, 2, 1, 3),

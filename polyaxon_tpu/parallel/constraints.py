@@ -87,6 +87,15 @@ def current_exact_mesh():
     return _EXACT_MESH.get()
 
 
+def in_manual_context() -> bool:
+    """Is this trace inside a ``shard_map`` (some mesh axis Manual)?"""
+    import jax
+
+    abstract = jax.sharding.get_abstract_mesh()
+    return abstract is not None and any(
+        "Manual" in str(t) for t in getattr(abstract, "axis_types", ()))
+
+
 def constrain(x, *axes: AxisName):
     """``with_sharding_constraint`` against the ambient mesh.
 
@@ -124,29 +133,9 @@ def constrain(x, *axes: AxisName):
     # Inside shard_map the mesh axes are Manual and per-axis constraints
     # are illegal (and meaningless — the caller already laid data out);
     # models run under both jit (constrain) and shard_map (no-op), e.g.
-    # blocks executing inside the pp pipeline.  Older jax (0.4.x) has
-    # no get_abstract_mesh; there the probe is the bound named-axis
-    # env — inside shard_map the mesh axes are bound, and the
-    # resulting sharding error would surface at LOWERING, outside the
-    # ValueError catch below, so it must be caught at trace time.
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        abstract = jax.sharding.get_abstract_mesh()
-        if abstract is not None and any(
-                "Manual" in str(t)
-                for t in getattr(abstract, "axis_types", ())):
-            return x
-    else:
-        try:
-            from jax._src.core import get_axis_env
-
-            if any(a in mesh.shape
-                   for a in get_axis_env().axis_sizes):
-                return x
-        # Private-API drift on some other old jax: fall through to
-        # the ValueError catch below (best-effort probe, per-trace-
-        # call — logging here would spam every trace).
-        except Exception:  # ptpu: ignore[EXC-SWALLOW]
-            pass
+    # blocks executing inside the pp pipeline.
+    if in_manual_context():
+        return x
 
     spec = []
     for a in axes:

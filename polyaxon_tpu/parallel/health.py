@@ -3,8 +3,8 @@
 The operator supervises pods and the control plane sweeps zombie
 heartbeats; this module covers the third failure mode — the process is
 alive but the ACCELERATOR fabric under it is not (wedged TPU runtime,
-a chip dropped off the ICI torus after preemption, a tunnel that hangs
-instead of raising).  ``check_slice_health`` runs a tiny all-device
+a chip dropped off the ICI torus after preemption, a runtime that
+hangs instead of raising).  ``check_slice_health`` runs a tiny all-device
 collective with a deadline in a worker thread: a healthy slice answers
 in milliseconds; a wedged one hangs, the deadline fires, and the caller
 can checkpoint-and-exit so the operator reschedules the gang

@@ -17,6 +17,7 @@ ICI — the hierarchical-collective recipe from the scaling playbook.
 
 from __future__ import annotations
 
+import logging
 import math
 import os
 from dataclasses import dataclass, field
@@ -25,6 +26,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 AXIS_ORDER = ("dp", "fsdp", "pp", "sp", "ep", "tp")
+
+logger = logging.getLogger(__name__)
 
 
 class MeshError(ValueError):
@@ -118,15 +121,27 @@ def build_mesh(
                 allow_split_physical_axes=allow_split_physical_axes,
             )
             return Mesh(dev_array, axis_names)
-        except (ValueError, AssertionError):
-            pass  # CPU/virtual devices: fall through to flat layout
+        except (ValueError, AssertionError) as e:
+            # CPU/virtual devices carry no slice index: fall through to
+            # the single-slice layout, and say so.
+            logger.warning(
+                "mesh %s: create_hybrid_device_mesh refused (%s: %s); "
+                "laying the devices out as one slice",
+                dict(sizes), type(e).__name__, e)
 
     try:
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=devices,
             allow_split_physical_axes=allow_split_physical_axes,
         )
-    except (ValueError, AssertionError, NotImplementedError):
+    except (ValueError, AssertionError, NotImplementedError) as e:
+        # The logical axes then follow enumeration order, not the
+        # physical torus: neighbours on an axis may not be neighbours
+        # on ICI.  Correct, possibly slower — never silent.
+        logger.warning(
+            "mesh %s: create_device_mesh refused (%s: %s); devices "
+            "reshaped flat in enumeration order",
+            dict(sizes), type(e).__name__, e)
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, axis_names)
 

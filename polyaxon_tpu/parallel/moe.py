@@ -72,10 +72,7 @@ def moe_layer(
     routes 1/ep of the tokens; the capacity limit applies per source
     rank), so per-rank expert FLOPs are 1/ep of dense — the point of EP.
     """
-    try:
-        from jax import shard_map
-    except ImportError:   # older jax: translated spellings
-        from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     b, s, d = x.shape
     e = expert_w1.shape[0]

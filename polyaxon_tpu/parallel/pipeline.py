@@ -45,11 +45,8 @@ def _manual_axes(mesh):
 
 
 def _vma_of(x):
-    """x's varying-manual-axes set; empty on older jax, which has no
-    VMA tracking (jax.typeof/pcast landed with the modern shard_map
-    surface) — there the promotion below is unnecessary by the same
-    token."""
-    return jax.typeof(x).vma if hasattr(jax, "typeof") else ()
+    """x's varying-manual-axes set."""
+    return jax.typeof(x).vma
 
 
 def _pvary_to(x, axes):
@@ -59,11 +56,8 @@ def _pvary_to(x, axes):
     check_vma=True, which makes scan carries and cond branches strict
     about VMA agreement; inputs replicated over pp (spec doesn't
     mention it) must be explicitly promoted before they meet
-    pp-varying values in a carry.  No-op on older jax (no VMA
-    tracking to promote within).
+    pp-varying values in a carry.
     """
-    if not hasattr(jax, "typeof"):
-        return x
     have = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in have)
     return jax.lax.pcast(x, missing, to="varying") if missing else x
@@ -140,10 +134,7 @@ def pipeline_apply(
     caller reads them from the last stage (psum-broadcast below makes the
     value uniform across the pp axis so downstream code is simple).
     """
-    try:
-        from jax import shard_map
-    except ImportError:   # older jax: translated spellings
-        from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     n_stages = mesh.shape.get(axis_name, 1)
     batch = x.shape[0]
@@ -289,10 +280,7 @@ def pipelined_lm_loss_1f1b(model, block, mesh, *, n_micro: int = 0,
     """
     import numpy as np
     import optax
-    try:
-        from jax import shard_map
-    except ImportError:   # older jax: translated spellings
-        from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     cfg = model.cfg
     n_stages = mesh.shape.get(axis_name, 1)

@@ -276,10 +276,7 @@ def ring_attention(
     stops rotating after ceil(window/block) hops — communication is O(W),
     not O(S).
     """
-    try:
-        from jax import shard_map
-    except ImportError:   # older jax: translated spellings
-        from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     if window is not None:
         if not causal:

@@ -130,10 +130,7 @@ def ulysses_attention(
     mask is given (the original contract) and ``attn_fn(q, k, v, mask)``
     when one is — a 3-arg kernel stays valid for unmasked use.
     """
-    try:
-        from jax import shard_map
-    except ImportError:   # older jax: translated spellings
-        from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     sp = mesh.shape.get(axis_name, 1)
     n_heads = q.shape[2]

@@ -65,6 +65,17 @@ def _merge_container_env(env, container) -> None:
             env[e.name] = str(e.value)
 
 
+def _host_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from its device nodes
+    (``/dev/accel*`` on older generations, one ``/dev/vfio/<n>`` group
+    per chip on v5e and later).  The executor's parent must not ask JAX:
+    a process that has touched JAX holds the chips its children need."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")
+               + glob.glob("/dev/vfio/[0-9]*"))
+
+
 def _port_open(host: str, port: int, timeout: float = 0.5) -> bool:
     try:
         with socket.create_connection((host, port), timeout=timeout):
@@ -403,6 +414,19 @@ class LocalExecutor:
                     del pending[replica]
                     if rc != 0:
                         failed[replica] = rc
+            if failed and pending:
+                # A gang lives and dies together: the survivors would
+                # wait on the dead replica at the coordinator (or, on a
+                # host with chips, for a chip another replica holds)
+                # until some far-off timeout.  Stop them now.
+                self._kill_all(pending)
+                detail = ", ".join(f"{r} exited {c}"
+                                   for r, c in failed.items())
+                raise ExecutionError(
+                    f"Process failure: {detail}; stopped the gang's "
+                    f"other {len(pending)} replica(s) "
+                    f"({', '.join(sorted(pending))}). "
+                    f"{self._last_log_line(run_uuid, next(iter(failed)))}")
             if not pending:
                 break
             now = time.time()
@@ -428,6 +452,14 @@ class LocalExecutor:
         for proc in procs.values():
             if proc.poll() is None:
                 proc.kill()
+
+    def _last_log_line(self, run_uuid: str, replica: str) -> str:
+        """The failed replica's own last words, for the run's message."""
+        tail = self.store.read_logs(run_uuid, replica, tail=5)
+        lines = [line.strip() for line in tail.splitlines()
+                 if line.strip()]
+        return f"Last log line of {replica}: {lines[-1][:300]}" \
+            if lines else ""
 
     def _run_service(self, run_uuid: str, compiled) -> None:
         """Run a service kind DETACHED: spawn the container in its own
@@ -520,9 +552,7 @@ class LocalExecutor:
                          timeout: Optional[float]) -> None:
         topo: ProcessTopology = normalize(compiled.run)
         port = _free_port()
-        procs: Dict[str, subprocess.Popen] = {}
-        self.store.set_status(run_uuid, V1Statuses.RUNNING,
-                              reason="LocalExecutor", force=True)
+        launches = []
         for group in topo.groups:
             container = group.spec.container or getattr(
                 compiled.run, "worker", None) and compiled.run.worker.container
@@ -535,8 +565,31 @@ class LocalExecutor:
                 topo_env["PTPU_COORDINATOR_ADDRESS"] = f"127.0.0.1:{port}"
                 env = self._build_env(run_uuid, topo_env)
                 _merge_container_env(env, container)
-                procs[replica] = self._spawn(run_uuid, argv, env, replica,
-                                             cwd=container.working_dir)
+                launches.append((replica, argv, env, container))
+        # One process holds a chip.  Nothing here gives each replica a
+        # chip of its own, so on a host with chips the second replica
+        # finds them held: it sits in libtpu's lock while the first
+        # waits two minutes for it at the coordinator (seen on a v5e
+        # host, PR 22).  Refuse at once instead; on virtual CPU devices
+        # every replica gets its own.
+        chips = _host_tpu_chips()
+        on_chips = [r for r, _, env, _ in launches
+                    if env.get("JAX_PLATFORMS", "").lower() != "cpu"]
+        if chips and len(on_chips) > 1:
+            raise ExecutionError(
+                f"{len(on_chips)} replicas ({', '.join(on_chips)}) would "
+                f"share this host's {chips} TPU chip(s): a chip belongs "
+                f"to one process at a time and the local executor does "
+                f"not bind replicas to chips. Run ONE process over the "
+                f"host's chips (worker.replicas: 1 with strategy axes, "
+                f"as examples/gpt2/onechip.yaml), or set "
+                f"JAX_PLATFORMS=cpu for the virtual-device harness.")
+        self.store.set_status(run_uuid, V1Statuses.RUNNING,
+                              reason="LocalExecutor", force=True)
+        procs: Dict[str, subprocess.Popen] = {}
+        for replica, argv, env, container in launches:
+            procs[replica] = self._spawn(run_uuid, argv, env, replica,
+                                         cwd=container.working_dir)
         self._wait(run_uuid, procs, timeout)
 
     # -- dag -------------------------------------------------------------

@@ -1378,8 +1378,6 @@ class DecodeEngine:
         """Jitted prefill (fresh cache) / extend (append at position)
         program for one piece length — the engine-side twin of the
         server's prefix-cache split programs."""
-        import jax
-
         from ..models import generate as G
 
         if self._prefill_fns is not None:
@@ -1388,10 +1386,11 @@ class DecodeEngine:
 
         def build():
             if first:
-                return jax.jit(
-                    lambda toks: G.prefill(model, variables, toks))
-            return jax.jit(lambda cache, toks, pos: G.prefill(
-                model, variables, toks, cache=cache, position=pos))
+                return G.jit_over(
+                    variables, lambda w, toks: G.prefill(model, w, toks))
+            return G.jit_over(
+                variables, lambda w, cache, toks, pos: G.prefill(
+                    model, w, toks, cache=cache, position=pos))
 
         return lru_get(self._pf_fns,
                        ("pfill" if first else "extend", s_len),
@@ -1401,18 +1400,17 @@ class DecodeEngine:
     def _pf_fn_draft(self, s_len: int, first: bool):
         """Draft-model twin of :meth:`_pf_fn` for speculative
         streams' draft prefill."""
-        import jax
-
         from ..models import generate as G
 
         draft, dvars = self.draft_model, self.draft_variables
 
         def build():
             if first:
-                return jax.jit(
-                    lambda toks: G.prefill(draft, dvars, toks))
-            return jax.jit(lambda cache, toks, pos: G.prefill(
-                draft, dvars, toks, cache=cache, position=pos))
+                return G.jit_over(
+                    dvars, lambda w, toks: G.prefill(draft, w, toks))
+            return G.jit_over(
+                dvars, lambda w, cache, toks, pos: G.prefill(
+                    draft, w, toks, cache=cache, position=pos))
 
         return lru_get(self._pf_fns_draft,
                        ("pfill" if first else "extend", s_len),
@@ -2555,6 +2553,9 @@ class DecodeEngine:
         return {
             "mesh": self.mesh.describe(),
             "mesh_devices": self.mesh.n_devices,
+            # Empty until the first prefill has shaped the pool.
+            "kv_pool_shardings":
+                self.mesh.describe_placement(self.slots.kv_pool()),
             "step_device_seconds_total":
                 round(self.step_device_s_total, 6),
             "step_wall_seconds_total": round(wall, 6),

@@ -351,6 +351,31 @@ class ServingMesh:
                 spec[axis] = "tp"
         return self._spec_sharding(*spec)
 
+    @staticmethod
+    def shardings_of(placed):
+        """The shardings a placed pytree's arrays ALREADY have — what a
+        program that takes the weights as an argument (generate.
+        jit_over) names as that argument's ``in_shardings``."""
+        import jax
+
+        return jax.tree.map(lambda x: x.sharding, placed)
+
+    @staticmethod
+    def describe_placement(pool) -> list:
+        """Where a LIVE KV pool's leaves sit, read off the arrays'
+        own shardings (not off the plan above): one
+        ``{"shape", "spec", "devices", "shard_shape"}`` per leaf —
+        the /info proof that heads are split over tp on every device
+        rather than assumed to be."""
+        import jax
+
+        return [{"shape": list(leaf.shape),
+                 "spec": str(getattr(leaf.sharding, "spec", None)),
+                 "devices": len(leaf.sharding.device_set),
+                 "shard_shape": list(
+                     leaf.sharding.shard_shape(leaf.shape))}
+                for leaf in jax.tree.leaves(pool)]
+
     # -- host-array placement --------------------------------------------
 
     def put_replicated(self, x):

@@ -453,6 +453,11 @@ class PagedSlotKVManager:
                 self.refcounts[i] = 1
             return ids, self.epoch
 
+    def kv_pool(self):
+        """The live main page pool's leaves (None before the first
+        page write shaped it)."""
+        return self._pool
+
     def page_stats(self) -> Dict[str, int]:
         with self._page_lock:
             free = len(self._free_pages)
@@ -1121,14 +1126,15 @@ class PagedSlotKVManager:
         return np.clip(d0, 0, max(0, P - n_dirty)).astype(np.int32)
 
     def _build_step(self, window: int, sampled: bool, P: int):
-        import jax
+        from ..models.generate import jit_over
 
-        body = build_step_body(self.model, self.variables, window,
-                               sampled)
+        model = self.model
         metas, treedef = self._meta, self._treedef
         n_dirty = self._n_dirty(window)
 
-        def step(pool, tables, d0, toks, positions, *extra):
+        def step(variables, pool, tables, d0, toks, positions, *extra):
+            # The weights are an ARGUMENT (jit_over), not a closure.
+            body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
             outs, stacked = body(stacked, toks, positions, *extra)
@@ -1137,13 +1143,13 @@ class PagedSlotKVManager:
             return outs, pool
 
         if self.mesh is None:
-            return jax.jit(step)
+            return jit_over(self.variables, step)
         rep = self.mesh.replicated
         n_extra = 5 if sampled else 0
-        in_sh = (self._pool_sh, rep, rep, rep, rep) \
-            + (rep,) * n_extra
-        return jax.jit(step, in_shardings=in_sh,
-                       out_shardings=(rep, self._pool_sh))
+        in_sh = (self.mesh.shardings_of(self.variables),
+                 self._pool_sh, rep, rep, rep, rep) + (rep,) * n_extra
+        return jit_over(self.variables, step, in_shardings=in_sh,
+                        out_shardings=(rep, self._pool_sh))
 
     def step(self, window: int = 1, sampled: bool = False
              ) -> np.ndarray:
@@ -1197,17 +1203,18 @@ class PagedSlotKVManager:
         return outs
 
     def _build_spec_step(self, window: int, K: int, P: int):
-        import jax
+        from ..models.generate import jit_over
 
-        body = build_spec_step_body(
-            self.model, self.variables, self.draft_model,
-            self.draft_variables, window, K)
+        model, draft = self.model, self.draft_model
+        weights = (self.variables, self.draft_variables)
         metas, treedef = self._meta, self._treedef
         d_metas, d_treedef = self._draft_meta, self._draft_treedef
         n_dirty = self._n_dirty(window * K + 1)
 
-        def step(t_pool, d_pool, tables, d0, toks, positions, idxs,
-                 keys, temps, tks, tps, sks):
+        def step(weights, t_pool, d_pool, tables, d0, toks, positions,
+                 idxs, keys, temps, tks, tps, sks):
+            body = build_spec_step_body(model, weights[0], draft,
+                                        weights[1], window, K)
             t_stacked = self._gather_tree(t_pool, metas, treedef,
                                           tables, positions)
             d_stacked = self._gather_tree(d_pool, d_metas, d_treedef,
@@ -1222,12 +1229,13 @@ class PagedSlotKVManager:
             return outs, cs, ms, t_pool, d_pool
 
         if self.mesh is None:
-            return jax.jit(step)
+            return jit_over(weights, step)
         rep = self.mesh.replicated
-        in_sh = (self._pool_sh, self._draft_pool_sh) + (rep,) * 10
-        return jax.jit(step, in_shardings=in_sh,
-                       out_shardings=(rep, rep, rep, self._pool_sh,
-                                      self._draft_pool_sh))
+        in_sh = (self.mesh.shardings_of(weights), self._pool_sh,
+                 self._draft_pool_sh) + (rep,) * 10
+        return jit_over(weights, step, in_shardings=in_sh,
+                        out_shardings=(rep, rep, rep, self._pool_sh,
+                                       self._draft_pool_sh))
 
     def step_spec(self, window: int, K: int):
         """``window`` fused SPECULATIVE rounds — the paged twin of

@@ -26,7 +26,7 @@ module closes the loop:
   attention term.  Serving MFU = tokens committed in the window x
   flops/token / (window wall x peak flops x devices); the caveats —
   analytic dense count, mean-position attention, nominal peak on
-  unknown hardware — ride the record as ``peak_flops_source`` /
+  a non-TPU backend — ride the record as ``peak_flops_source`` /
   ``flops_model`` so nobody mistakes the number for a measured
   hardware counter (docs/SERVING.md "Observability").
 
@@ -52,36 +52,25 @@ from .telemetry import ENGINE_PID
 __all__ = ["FlightRecorder", "decode_flops_per_token",
            "detect_peak_flops", "NOMINAL_PEAK_FLOPS"]
 
-# Per-chip bf16 peaks for the TPU generations the repo benches
-# (mirrors bench.chip_peak_flops — duplicated here because bench.py
-# is a script with import-time backend probing, not a library).
-_PEAK_BF16 = (("v5litepod", 197e12), ("v5e", 197e12),
-              ("v5p", 459e12), ("v4", 275e12), ("v3", 123e12),
-              ("v2", 45e12))
-
-# Unknown hardware (the CPU smoke): a NOMINAL 1 TF/s peak so the MFU
-# gauge stays finite and comparable run-to-run on one machine.  The
-# record labels it ``peak_flops_source: "nominal"`` — it is a
-# utilization TREND there, never a hardware claim.
+# Not a TPU (the CPU smoke): a NOMINAL 1 TF/s peak so the MFU gauge
+# stays finite and comparable run-to-run on one machine.  The record
+# labels it ``peak_flops_source: "nominal"`` — it is a utilization
+# TREND there, never a hardware claim.
 NOMINAL_PEAK_FLOPS = 1e12
 
 
 def detect_peak_flops() -> Dict[str, Any]:
     """``{"peak_flops": per-chip peak, "peak_flops_source":
-    "device"|"nominal"}`` for the current backend."""
-    try:
-        import jax
+    "device"|"nominal"}`` for the current backend.  The device peak
+    comes from the repo's one table (``polyaxon_tpu/chips.py``); a TPU
+    that is not in it raises."""
+    import jax
 
-        kind = (getattr(jax.devices()[0], "device_kind", "")
-                or "").lower()
-    except Exception:
-        kind = ""
-    if "tpu" in kind:
-        for key, peak in _PEAK_BF16:
-            if key in kind:
-                return {"peak_flops": peak,
-                        "peak_flops_source": "device"}
-        return {"peak_flops": 197e12, "peak_flops_source": "device"}
+    from ..chips import peak_bf16_flops
+
+    peak = peak_bf16_flops(jax.devices()[0].device_kind)
+    if peak is not None:
+        return {"peak_flops": peak, "peak_flops_source": "device"}
     return {"peak_flops": NOMINAL_PEAK_FLOPS,
             "peak_flops_source": "nominal"}
 

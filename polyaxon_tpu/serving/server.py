@@ -303,6 +303,9 @@ ENGINE_STATS_METRIC_EXEMPT = {
         "render via render_compile_cache",
     "mesh": "topology dict; renders as ptpu_serving_mesh_devices + "
             "per-axis ptpu_serving_mesh_axis_size{axis=}",
+    "kv_pool_shardings":
+        "per-leaf placement description (specs and shard shapes), "
+        "not a number; /info carries it",
 }
 
 
@@ -1234,16 +1237,19 @@ class ModelServer:
     # -- compile cache --------------------------------------------------
 
     def _fn(self, key):
-        import jax
-
         from ..models import generate as G
+
+        def jit(fn):
+            # Weights are ARGUMENTS of every program (G.jit_over): w is
+            # (target variables, draft variables or None).
+            return G.jit_over((self.variables, self.draft_variables), fn)
 
         def build():
             kind, b, p_len, new, temp, top_k, top_p, eos, beams, \
                 chunk = key
             if kind == "beam":
-                return jax.jit(lambda toks, rng: G.generate_beam(
-                    self.model, self.variables, toks,
+                return jit(lambda w, toks, rng: G.generate_beam(
+                    self.model, w[0], toks,
                     max_new_tokens=new, num_beams=beams, eos_id=eos,
                     prefill_chunk=chunk))
             if kind == "sample_pos":
@@ -1253,18 +1259,18 @@ class ModelServer:
                 # one shape shares a single compiled program — and the
                 # math is the same _sample_positional_row the engine's
                 # slot step runs.
-                return jax.jit(
-                    lambda toks, keys, temp, tk, tp:
+                return jit(
+                    lambda w, toks, keys, temp, tk, tp:
                     G.generate_positional(
-                        self.model, self.variables, toks,
+                        self.model, w[0], toks,
                         max_new_tokens=new, keys=keys,
                         temperature=temp, top_k=tk, top_p=tp,
                         eos_id=eos, prefill_chunk=chunk))
             if kind == "spec":
                 k = beams  # slot reused for the draft length
-                return jax.jit(lambda toks, rng: G.generate_speculative(
-                    self.model, self.variables, self.draft_model,
-                    self.draft_variables, toks, max_new_tokens=new,
+                return jit(lambda w, toks, rng: G.generate_speculative(
+                    self.model, w[0], self.draft_model,
+                    w[1], toks, max_new_tokens=new,
                     k=k, eos_id=eos, prefill_chunk=chunk,
                     temperature=temp, top_k=top_k, top_p=top_p,
                     rng=rng if temp != 0.0 else None))
@@ -1274,15 +1280,15 @@ class ModelServer:
                 # are pinned against, so solo and engine agree
                 # token-for-token per seed
                 k = beams  # slot reused for the draft length
-                return jax.jit(
-                    lambda toks, keys: G.generate_speculative(
-                        self.model, self.variables, self.draft_model,
-                        self.draft_variables, toks,
+                return jit(
+                    lambda w, toks, keys: G.generate_speculative(
+                        self.model, w[0], self.draft_model,
+                        w[1], toks,
                         max_new_tokens=new, k=k, eos_id=eos,
                         prefill_chunk=chunk, temperature=temp,
                         top_k=top_k, top_p=top_p, keys=keys))
-            return jax.jit(lambda toks, rng: G.generate(
-                self.model, self.variables, toks, max_new_tokens=new,
+            return jit(lambda w, toks, rng: G.generate(
+                self.model, w[0], toks, max_new_tokens=new,
                 temperature=temp, top_k=top_k, top_p=top_p,
                 eos_id=eos, rng=rng, prefill_chunk=chunk))
 
@@ -1298,8 +1304,6 @@ class ModelServer:
         """Jitted split programs for the prefix-cache path:
         ``pfill``/``extend`` produce (logits, cache); ``cont`` decodes
         from a cache.  Cached in the same LRU as the fused programs."""
-        import jax
-
         from ..models import generate as G
 
         # "cont"/"cont_pos" do not depend on chunk — keying them would
@@ -1307,29 +1311,32 @@ class ModelServer:
         key = (kind, b, p_or_s, new, temp, top_k, top_p, eos, None,
                chunk if kind not in ("cont", "cont_pos") else None)
 
+        def jit(fn):
+            return G.jit_over(self.variables, fn)
+
         def build():
             if kind == "pfill":
-                return jax.jit(lambda toks: G.prefill(
-                    self.model, self.variables, toks, chunk=chunk))
+                return jit(lambda w, toks: G.prefill(
+                    self.model, w, toks, chunk=chunk))
             if kind == "extend":
-                return jax.jit(lambda cache, toks, pos: G.prefill(
-                    self.model, self.variables, toks, chunk=chunk,
+                return jit(lambda w, cache, toks, pos: G.prefill(
+                    self.model, w, toks, chunk=chunk,
                     cache=cache, position=pos))
             if kind == "cont_pos":
                 # position-keyed sampled continue (prefix-cache hits
                 # that stay solo): one program per shape, shaping
                 # params at run time — mirrors "sample_pos"
-                return jax.jit(
-                    lambda cache, logits, pos, keys, temp, tk, tp:
+                return jit(
+                    lambda w, cache, logits, pos, keys, temp, tk, tp:
                     G.generate_continue_positional(
-                        self.model, self.variables, cache, logits,
+                        self.model, w, cache, logits,
                         pos, max_new_tokens=new, keys=keys,
                         temperature=temp, top_k=tk, top_p=tp,
                         eos_id=eos, _validated=True))
-            return jax.jit(lambda cache, logits, pos, rng:
-                           G.generate_continue(
-                               self.model, self.variables, cache,
-                               logits, pos, max_new_tokens=new,
+            return jit(lambda w, cache, logits, pos, rng:
+                       G.generate_continue(
+                           self.model, w, cache,
+                           logits, pos, max_new_tokens=new,
                                temperature=temp, top_k=top_k,
                                top_p=top_p, rng=rng, eos_id=eos,
                                _validated=True))
@@ -3001,6 +3008,8 @@ class ModelServer:
     def info(self) -> Dict[str, Any]:
         import jax
 
+        from ..ops.attention import route_counts
+
         cfg = getattr(self.model, "cfg", None)
         summary = {}
         if cfg is not None:
@@ -3037,6 +3046,10 @@ class ModelServer:
         compile_cache = self.recompile.snapshot()
         return {"model": self.model_name, "config": summary,
                 "backend": jax.default_backend(),
+                # Local attention calls traced so far, by path: the
+                # Pallas kernel or the fused-XLA route it drops to
+                # (ops/attention.py) — otherwise a silent decision.
+                "attention_routes": route_counts(),
                 "max_batch": self.max_batch,
                 "batching": self.batching,
                 "role": self.role,
@@ -3129,7 +3142,7 @@ class ModelServer:
                     "kv_pages_lazy_growths_total",
                     "kv_pages_lazy_grown_total",
                     "kv_preempt_exhaustion_total",
-                    "mesh", "mesh_devices",
+                    "mesh", "mesh_devices", "kv_pool_shardings",
                     "step_device_seconds_total",
                     "step_wall_seconds_total", "step_device_share",
                     "spec_rounds_total", "spec_drafted_total",

@@ -386,6 +386,11 @@ class SlotKVManager:
         return self.mesh.exact() if self.mesh is not None \
             else contextlib.nullcontext()
 
+    def kv_pool(self):
+        """The live main KV pool pytree (None before the first
+        prefill shaped it)."""
+        return self._stacked
+
     def _alloc_stacked(self, template_cache):
         """Zero-init the [S, ...] pool; meshed pools are committed to
         their NamedShardings at birth (heads over tp, slots over dp)."""
@@ -485,21 +490,27 @@ class SlotKVManager:
         return fn
 
     def _build_step(self, window: int, sampled: bool):
-        import jax
+        from ..models.generate import jit_over
 
-        body = build_step_body(self.model, self.variables, window,
-                               sampled)
+        model = self.model
+
+        def program(variables, *operands):
+            # The weights are an ARGUMENT (jit_over), not a closure.
+            return build_step_body(model, variables, window,
+                                   sampled)(*operands)
+
         if self.mesh is None:
-            return jax.jit(body)
+            return jit_over(self.variables, program)
         # Explicit in/out shardings: the cache stays pinned to its
         # (heads-over-tp, slots-over-dp) layout across steps, host
         # operands (tokens/positions/sampling state) commit
         # replicated, and token outputs gather back replicated.
         rep = self.mesh.replicated
         n_extra = 5 if sampled else 0
-        in_sh = (self._cache_sh, rep, rep) + (rep,) * n_extra
-        return jax.jit(body, in_shardings=in_sh,
-                       out_shardings=(rep, self._cache_sh))
+        in_sh = (self.mesh.shardings_of(self.variables),
+                 self._cache_sh, rep, rep) + (rep,) * n_extra
+        return jit_over(self.variables, program, in_shardings=in_sh,
+                        out_shardings=(rep, self._cache_sh))
 
     def step(self, window: int = 1, sampled: bool = False
              ) -> np.ndarray:
@@ -584,18 +595,24 @@ class SlotKVManager:
         verify chunk's first logits row through the shared positional
         sampler — the same token the plain step programs produce —
         and rewind to position + 1."""
-        import jax
+        from ..models.generate import jit_over
 
-        body = build_spec_step_body(
-            self.model, self.variables, self.draft_model,
-            self.draft_variables, window, K)
+        model, draft = self.model, self.draft_model
+        weights = (self.variables, self.draft_variables)
+
+        def program(weights, *operands):
+            return build_spec_step_body(
+                model, weights[0], draft, weights[1], window,
+                K)(*operands)
+
         if self.mesh is None:
-            return jax.jit(body)
+            return jit_over(weights, program)
         rep = self.mesh.replicated
-        in_sh = (self._cache_sh, self._draft_cache_sh) + (rep,) * 8
-        return jax.jit(body, in_shardings=in_sh,
-                       out_shardings=(rep, rep, rep, self._cache_sh,
-                                      self._draft_cache_sh))
+        in_sh = (self.mesh.shardings_of(weights), self._cache_sh,
+                 self._draft_cache_sh) + (rep,) * 8
+        return jit_over(weights, program, in_shardings=in_sh,
+                        out_shardings=(rep, rep, rep, self._cache_sh,
+                                       self._draft_cache_sh))
 
     def step_spec(self, window: int, K: int):
         """``window`` fused SPECULATIVE rounds across the whole pool.

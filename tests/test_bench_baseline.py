@@ -21,14 +21,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-# The probe-compiling flop-reconciliation tests used to skip when
-# ``jax.sharding.get_abstract_mesh`` was missing (older jax): the
-# sharding-constraint layer called it unconditionally inside every
-# traced forward.  The meshed-serving work made constraints.py guard
-# that probe (hasattr fallback), so the compile path works on every
-# supported jax and the skip is gone.
-
-
 def _load_bench(tmp_path=None):
     """Import bench.py, optionally as a copy rooted in tmp_path so
     run_mfu_sweep's results/baseline files land in the sandbox."""
@@ -109,10 +101,9 @@ class TestConfigMatches:
 
 
 class TestEmitVsBaseline:
-    def _emit(self, B, monkeypatch, capsys, result, baseline,
-              fallback=False):
+    def _emit(self, B, monkeypatch, capsys, result, baseline):
         monkeypatch.setattr(B, "load_baseline", lambda: baseline)
-        B.emit(result, fallback)
+        B.emit(result)
         return json.loads(capsys.readouterr().out)
 
     def test_vs_on_matching_config(self, B, monkeypatch, capsys):
@@ -137,16 +128,6 @@ class TestEmitVsBaseline:
         line = self._emit(B, monkeypatch, capsys, res, bl)
         assert line["vs_baseline"] is None
 
-    def test_fallback_never_scores(self, B, monkeypatch, capsys):
-        res = {"model": "resnet50", "backend": "cpu", "batch": 128,
-               "per_sec_per_chip": 100.0, "unit": "img/sec/chip",
-               "mfu": None, "sec_per_step": 1.0}
-        bl = {"resnet50:cpu": 100.0}
-        line = self._emit(B, monkeypatch, capsys, res, bl,
-                          fallback=True)
-        assert line["vs_baseline"] is None
-        assert line["backend"] == "cpu-fallback"
-
 
 class TestRunMfuSweep:
     def _fake_bench(self, fail_batches=(), mfu=lambda b: 0.3 + b / 100):
@@ -164,7 +145,7 @@ class TestRunMfuSweep:
 
     def _run(self, tmp_path, configs, bench, backend="tpu"):
         B = _load_bench(tmp_path)
-        B.init_backend = lambda *a, **k: (None, backend, False)
+        B.init_backend = lambda *a, **k: (None, backend)
         B.bench_model = bench
         rc = B.run_mfu_sweep("gpt2-medium", configs)
         baseline_file = tmp_path / ".bench_baseline.json"
@@ -206,125 +187,75 @@ class TestRunMfuSweep:
         # not the first, must win.
         assert baseline["gpt2-medium:tpu"]["batch"] == 16
 
-    def test_skips_off_tpu(self, tmp_path, capsys):
-        rc, baseline, rows = self._run(
-            tmp_path, self.CONFIGS, self._fake_bench(), backend="cpu")
-        assert rc == 0 and not baseline and not rows
-
-
-class TestHarvestPendingRows:
-    def _setup(self, tmp_path, entries):
+    def test_refuses_off_tpu(self, tmp_path, capsys):
+        # The sweep asks for the chip (init_backend(False)): here JAX
+        # is held to the CPU, so it exits non-zero with no row and no
+        # baseline — it never measures the CPU under the sweep's name.
         B = _load_bench(tmp_path)
-        with open(B._PENDING_ROWS, "w") as f:
-            for e in entries:
-                f.write(json.dumps(e) + "\n")
-        return B
-
-    def test_harvests_completed_tpu_row(self, tmp_path):
-        row_file = tmp_path / "late_row.json"
-        row = {"model": "gpt2-medium", "backend": "tpu", "batch": 4,
-               "per_sec_per_chip": 9000.0, "unit": "tok/sec/chip"}
-        row_file.write_text(json.dumps(row))
-        B = self._setup(tmp_path, [{"row_file": str(row_file),
-                                    "label": "train:gpt2-medium",
-                                    "ts": 1.0}])
-        assert B.harvest_pending_rows() == 1
-        rows = [json.loads(l) for l in
-                (tmp_path / "benchmarks" /
-                 "results.jsonl").read_text().splitlines()]
-        assert rows[0]["model"] == "gpt2-medium"
-        assert rows[0]["bench"] == "headline"
-        assert not row_file.exists()  # consumed
-        assert not os.path.exists(B._PENDING_ROWS)  # list drained
-
-    def test_discards_cpu_fallback_row(self, tmp_path):
-        row_file = tmp_path / "cpu_row.json"
-        row_file.write_text(json.dumps({"model": "bert-base",
-                                        "backend": "cpu"}))
-        B = self._setup(tmp_path, [{"row_file": str(row_file),
-                                    "label": "train:bert-base",
-                                    "ts": 1.0}])
-        assert B.harvest_pending_rows() == 0
-        assert not (tmp_path / "benchmarks" / "results.jsonl").exists()
-        assert not row_file.exists()  # consumed either way
-
-    def test_keeps_incomplete_fresh_drops_stale(self, tmp_path):
-        import time as _time
-
-        fresh = tmp_path / "fresh.json"
-        fresh.write_text("")  # child mid-run: empty file
-        stale = tmp_path / "stale.json"
-        stale.write_text("")
-        B = self._setup(tmp_path, [
-            {"row_file": str(fresh), "label": "a", "ts": _time.time()},
-            {"row_file": str(stale), "label": "b",
-             "ts": _time.time() - 60 * 3600},
-        ])
-        assert B.harvest_pending_rows() == 0
-        kept = [json.loads(l) for l in
-                open(B._PENDING_ROWS).read().splitlines()]
-        assert [e["label"] for e in kept] == ["a"]
-
-    def test_missing_file_dropped(self, tmp_path):
-        B = self._setup(tmp_path, [{"row_file": str(tmp_path / "gone"),
-                                    "label": "x", "ts": 1.0}])
-        assert B.harvest_pending_rows() == 0
-        assert not os.path.exists(B._PENDING_ROWS)
-
-    def test_torn_registry_line_skipped(self, tmp_path):
-        # A parent killed mid-append leaves a truncated JSON line; it
-        # must not poison the entries around it.
-        row_file = tmp_path / "good.json"
-        row_file.write_text(json.dumps({"model": "resnet50",
-                                        "backend": "tpu",
-                                        "per_sec_per_chip": 2500.0}))
-        B = _load_bench(tmp_path)
-        with open(B._PENDING_ROWS, "w") as f:
-            f.write('{"row_file": "/tmp/x", "lab\n')  # torn
-            f.write(json.dumps({"row_file": str(row_file),
-                                "label": "train:resnet50",
-                                "ts": 1.0}) + "\n")
-        assert B.harvest_pending_rows() == 1
-
-    def test_register_then_harvest_roundtrip(self, tmp_path):
-        B = _load_bench(tmp_path)
-        row_file = tmp_path / "late.json"
-        B._register_pending(str(row_file), "train:x")
-        # Child hasn't written yet (no file): entry survives as-is...
-        assert B.harvest_pending_rows() == 0
-        # (file absent -> entry dropped, matching _run_isolated's
-        # contract that a vanished file means the child cleaned up)
-        row_file.write_text(json.dumps({"backend": "tpu", "model": "x",
-                                        "per_sec_per_chip": 1.0}))
-        B._register_pending(str(row_file), "train:x")
-        assert B.harvest_pending_rows() == 1
-
-
-class TestRequireAccel:
-    def test_child_skips_on_cpu_fallback(self, tmp_path, monkeypatch,
-                                         capsys):
-        # A --row-file child (or --require-accel sweep leg) that falls
-        # back to CPU must exit with a skip line, NOT burn an hour
-        # CPU-benching a model whose row gets discarded anyway.
-        B = _load_bench(tmp_path)
-        B.init_backend = lambda *a, **k: (None, "cpu", True)
         B.bench_model = lambda *a, **k: pytest.fail(
-            "bench_model must not run on a fallen-back child")
-        monkeypatch.setattr(sys, "argv",
-                            ["bench.py", "--model", "resnet50",
-                             "--row-file", str(tmp_path / "row.json")])
+            "bench_model must not run without a TPU")
+        with pytest.raises(SystemExit) as exc:
+            B.run_mfu_sweep("gpt2-medium", self.CONFIGS)
+        assert exc.value.code not in (0, None)
+        assert not (tmp_path / ".bench_baseline.json").exists()
+        assert not (tmp_path / "benchmarks" / "results.jsonl").exists()
+        assert capsys.readouterr().out == ""
+
+
+class TestNoTpuNoFallback:
+    """bench.py finds a TPU or exits non-zero; only ``--cpu`` may put
+    a CPU number on stdout, and the metric line then says so."""
+
+    @pytest.mark.parametrize("argv", [
+        [], ["--all"], ["--model", "resnet50"], ["--decode", "gpt2-tiny"],
+        ["--model", "gpt2-tiny", "--append"],
+    ], ids=lambda a: " ".join(a) or "default")
+    def test_exits_nonzero_and_prints_no_metric(self, tmp_path,
+                                                monkeypatch, capsys,
+                                                argv):
+        B = _load_bench(tmp_path)
+        B.bench_model = lambda *a, **k: pytest.fail(
+            "bench_model must not run without a TPU")
+        B.bench_decode_row = lambda *a, **k: pytest.fail(
+            "bench_decode_row must not run without a TPU")
+        monkeypatch.setattr(sys, "argv", ["bench.py", *argv])
+        with pytest.raises(SystemExit) as exc:
+            B.main()
+        assert exc.value.code not in (0, None)
+        assert "no TPU" in str(exc.value.code)
+        assert capsys.readouterr().out == ""
+        assert not (tmp_path / "benchmarks" / "results.jsonl").exists()
+
+    def test_cpu_flag_labels_the_metric(self, tmp_path, monkeypatch,
+                                        capsys):
+        B = _load_bench(tmp_path)
+        B.bench_model = lambda jax, model, batch, *a, **k: {
+            "model": model, "backend": "cpu", "batch": batch,
+            "per_sec_per_chip": 10.0, "unit": "img/sec/chip",
+            "mfu": None, "sec_per_step": 1.0}
+        monkeypatch.setattr(sys, "argv", ["bench.py", "--cpu"])
         assert B.main() == 0
         line = json.loads(capsys.readouterr().out)
-        assert "skipped" in line["metric"]
-        # The child leaves a non-accel marker row so a pending-registry
-        # entry pointing at this file is discarded (and the temp file
-        # unlinked) by the next harvest rather than re-polled for 48h.
-        marker = json.loads((tmp_path / "row.json").read_text())
-        assert marker["backend"] == "cpu"
-        B._register_pending(str(tmp_path / "row.json"), "train:x")
-        assert B.harvest_pending_rows() == 0
-        assert not (tmp_path / "row.json").exists()
-        assert not os.path.exists(B._PENDING_ROWS)
+        assert line["backend"] == "cpu" and "(cpu," in line["metric"]
+        assert line["vs_baseline"] is None and line["mfu"] is None
+
+    def test_no_result_is_a_failure(self, tmp_path, monkeypatch,
+                                    capsys):
+        B = _load_bench(tmp_path)
+
+        def boom(*a, **k):
+            raise RuntimeError("OOM")
+        B.bench_model = boom
+        monkeypatch.setattr(sys, "argv", ["bench.py", "--cpu"])
+        assert B.main() == 1
+        assert capsys.readouterr().out == ""
+
+    @pytest.mark.parametrize("name", [
+        "probe_backend", "_reap_probe", "harvest_pending_rows",
+        "_register_pending", "_run_isolated", "last_tpu_row",
+        "chip_peak_flops", "_PEAK_BF16"])
+    def test_old_link_scaffolding_is_gone(self, B, name):
+        assert not hasattr(B, name)
 
 
 class TestRegistryOverrides:

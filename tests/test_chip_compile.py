@@ -1,0 +1,242 @@
+"""Deviceless compiles for the chip: the main path's programs, at real
+widths, through the real TPU compiler — no chip, no chip time.
+
+The ONLY tier-1 file that describes the chip.  The topology is described
+inside a module-scoped fixture (never at import, never in conftest.py,
+not autouse): only the xdist worker that is handed this file loads the
+TPU compiler's library, and every worker collects the same tests.  The
+compiles run in this process (a child could not load the library a
+second time) with the persistent compilation cache off — a deviceless
+executable can be written to it but not read back without a chip.
+
+What passes here compiled; nothing ran.  A time, a rate or a result
+comes only from ``chip_smoke.py`` on the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+SLOTS = 8           # `ptpu serve --slots` default
+DECODE_WINDOW = 8   # `ptpu serve --decode-window` default
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """Persistent cache off around the deviceless compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch, no_cache):
+    """``flash_eligible`` asks ``jax.default_backend()``, which is the
+    CPU here; steer it with the switch it already reads."""
+    monkeypatch.setenv("POLYAXON_TPU_ASSUME_TPU", "1")
+    monkeypatch.delenv("POLYAXON_TPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.delenv("POLYAXON_TPU_NO_FLASH", raising=False)
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), tree)
+
+
+# (batch, seq, heads, head_dim), causal, key-padding mask, window
+FLASH_CASES = {
+    "gpt2-medium": ((4, 1024, 16, 64), True, False, None),
+    "bert-base-kvmask": ((16, 512, 12, 64), False, True, None),
+    "tinyllama": ((2, 2048, 32, 64), True, False, None),
+    "sliding-window": ((1, 4096, 8, 128), True, False, 1023),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_fwd_bwd_compiles(one_chip, on_tpu, case):
+    """The forward and both backward kernels, at the attention shapes
+    of the models the repo trains at published widths."""
+    from polyaxon_tpu.ops.flash import flash_attention
+
+    shape, causal, padded, window = FLASH_CASES[case]
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv_mask = jax.ShapeDtypeStruct(shape[:2], jnp.bool_,
+                                   sharding=one_chip) if padded else None
+
+    def loss(q, k, v, kv_mask):
+        out = flash_attention(q, k, v, causal=causal, kv_mask=kv_mask,
+                              window=window, scale=shape[-1] ** -0.5)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, kv_mask).compile()
+    # fwd + dq + dkv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_gpt2_medium_train_step_compiles(topo, on_tpu, n_chips):
+    """The whole b4 step as ``train.py`` builds it: its optimizer
+    factory (``--optimizer sgd``, the spec of examples/gpt2/
+    onechip.yaml), its donation, its batch sharding — and it fits the
+    chip with the Pallas kernels inside.  On four chips (``--strategy
+    dp:4``) GSPMD cannot partition a Mosaic kernel: the step compiles
+    only because ops/attention.py hands the kernel its shard."""
+    from polyaxon_tpu.models.registry import get_model
+    from polyaxon_tpu.parallel import MeshSpec, build_mesh, \
+        make_train_step
+    from polyaxon_tpu.parallel.strategies import make_param_shardings
+    from polyaxon_tpu.train import make_optimizer
+
+    spec = get_model("gpt2-medium")
+    model = spec.make_model()
+    mesh = build_mesh(MeshSpec(dp=-1),
+                      devices=list(topo.devices)[:n_chips])
+    step = make_train_step(spec.loss_fn(model),
+                           make_optimizer("sgd", 2.5e-4), mesh,
+                           grad_accum=1, donate=True)
+    tokens = jax.ShapeDtypeStruct((4, 1024), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    opt = jax.eval_shape(step.optimizer.init, params)
+    step.state_shardings = {
+        "params": make_param_shardings(params, mesh),
+        "opt_state": make_param_shardings(opt, mesh),
+        "step": NamedSharding(mesh, P()),
+    }
+    state = {"params": params, "opt_state": opt,
+             "step": jax.ShapeDtypeStruct((), jnp.int32)}
+    compiled, _ = step.precompile(state, {"inputs": tokens},
+                                  jax.random.PRNGKey(0))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert ("all-reduce(" in text) == (n_chips > 1)
+    mem = compiled.memory_analysis()   # per device
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 15.75 * 2 ** 30
+
+
+def _serving_shapes(one_chip):
+    """gpt2-medium as ``ptpu serve`` holds it: the variables of
+    ``spec.init_params(batch_size=1)`` and the default pool's stacked
+    cache, as shapes on the described chip."""
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.models.registry import get_model
+
+    model = get_model("gpt2-medium").make_model()
+    variables = _abstract(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 1024), jnp.int32)), one_chip)
+    one = jax.eval_shape(lambda: G.init_cache(model, 1))
+    pool = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((SLOTS,) + l.shape, l.dtype,
+                                       sharding=one_chip), one)
+    return model, variables, pool
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_engine_decode_window_compiles(one_chip, on_tpu, sampled):
+    """The engine's fused decode-window body (serving/slots.py) over
+    the default pool.  The engine closes over its variables; passing
+    them as an argument is what lets the same body lower from shapes."""
+    from polyaxon_tpu.serving.slots import build_step_body
+
+    model, variables, pool = _serving_shapes(one_chip)
+
+    def program(variables, *operands):
+        return build_step_body(model, variables, DECODE_WINDOW,
+                               sampled)(*operands)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [pool, vec(jnp.int32), vec(jnp.int32)]
+    if sampled:
+        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
+                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    compiled = jax.jit(program).lower(variables, *operands).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
+
+
+def test_meshed_decode_window_compiles(topo, on_tpu):
+    """``ptpu serve --mesh tp=4``: the same body under the serving
+    mesh's own shardings and its exact layout — heads of the KV pool
+    cut in four, gathers but no cross-device sum in the program (the
+    reason meshed tokens can equal unmeshed ones bitwise)."""
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.models.registry import get_model
+    from polyaxon_tpu.serving.meshed import ServingMesh
+    from polyaxon_tpu.serving.slots import build_step_body
+
+    mesh = ServingMesh("tp=4", devices=list(topo.devices))
+    model = get_model("gpt2-medium").make_model()
+    variables = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 1024), jnp.int32))
+    pool = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((SLOTS,) + l.shape, l.dtype),
+        jax.eval_shape(lambda: G.init_cache(model, 1)))
+    pool_sh = mesh.cache_shardings(pool, slot_axis=True)
+    rep = mesh.replicated
+    slots = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+
+    def program(variables, *operands):
+        return build_step_body(model, variables, DECODE_WINDOW,
+                               False)(*operands)
+
+    with mesh.exact():
+        compiled = jax.jit(
+            program,
+            in_shardings=(mesh.param_shardings(variables), pool_sh,
+                          rep, rep),
+            out_shardings=(rep, pool_sh),
+        ).lower(variables, pool, slots, slots).compile()
+    text = compiled.as_text()
+    assert "all-gather(" in text and "all-reduce(" not in text
+    kv = [(l.shape, sh.shard_shape(l.shape)) for l, sh in zip(
+        jax.tree.leaves(pool), jax.tree.leaves(pool_sh))
+        if len(l.shape) >= 4]
+    assert kv and all(shard[-2] * 4 == full[-2] for full, shard in kv)
+
+
+@pytest.mark.parametrize("prompt_len", [24, 77, 512])
+def test_engine_prefill_compiles(one_chip, on_tpu, prompt_len):
+    """The engine's prefill program (``jit(G.prefill)``, engine.py) at
+    the prompt lengths the smoke sends and at a long one."""
+    from polyaxon_tpu.models import generate as G
+
+    model, variables, _ = _serving_shapes(one_chip)
+    toks = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = jax.jit(
+        lambda variables, toks: G.prefill(model, variables, toks)
+    ).lower(variables, toks).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30

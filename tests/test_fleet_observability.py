@@ -316,7 +316,8 @@ def test_engine_stats_structural_no_drift(small_model):
             f"the metric, an ENGINE_STATS_METRIC_RENAMES entry, or "
             f"an exemption with a reason): {missing}")
         stale = {k for k in ENGINE_STATS_METRIC_EXEMPT
-                 if k not in es and k != "mesh"}   # mesh: meshed only
+                 if k not in es and k not in (
+                     "mesh", "kv_pool_shardings")}   # meshed only
         assert not stale, \
             f"stale ENGINE_STATS_METRIC_EXEMPT entries: {stale}"
     finally:

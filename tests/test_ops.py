@@ -272,3 +272,54 @@ def test_window_routes_through_sp(monkeypatch):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-3, rtol=2e-3,
                                    err_msg=f"mode={mode}")
+
+
+@pytest.mark.parametrize("axes", [{"dp": 4}, {"dp": 2, "tp": 2},
+                                  {"fsdp": 2, "tp": 2}],
+                         ids=["dp4", "dp2xtp2", "fsdp2xtp2"])
+@pytest.mark.parametrize("padded", [False, True],
+                         ids=["nomask", "kvmask"])
+def test_flash_under_a_train_mesh_matches_one_device(axes, padded,
+                                                     monkeypatch):
+    """A step jitted over several devices cannot leave a Mosaic kernel
+    to GSPMD (the TPU compiler refuses to partition it), so the routed
+    call hands each device its shard of batch and heads.  Values and
+    gradients equal the one-device call's: rows and heads never mix."""
+    monkeypatch.setenv("POLYAXON_TPU_FLASH_INTERPRET", "1")
+    from polyaxon_tpu.ops.attention import route_counts
+    from polyaxon_tpu.parallel import MeshSpec, build_mesh
+    from polyaxon_tpu.parallel.constraints import ambient_mesh
+
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshSpec.from_dict({"dp": 1, **axes}),
+                      devices=jax.devices()[:n])
+    q, k, v = _qkv(b=4, s=128, h=2, d=64)
+    mask = None
+    if padded:
+        lengths = np.array([128, 100, 77, 128])
+        mask = jnp.asarray(
+            np.arange(128)[None, :] < lengths[:, None])[:, None, None, :]
+
+    def grad():
+        # A fresh function each time: the route is chosen while
+        # TRACING, and jit would hand the second call the first trace.
+        def loss(q, k, v):
+            out = dot_product_attention(q, k, v, causal=True, mask=mask)
+            return (out ** 2).sum(), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, ref), ref_g = grad()(q, k, v)
+    before = route_counts()["flash"]
+    with ambient_mesh(mesh):
+        (_, out), g = grad()(q, k, v)
+        assert "shard_map" in str(jax.make_jaxpr(
+            lambda q, k, v: dot_product_attention(
+                q, k, v, causal=True, mask=mask))(q, k, v))
+    assert route_counts()["flash"] > before   # the kernel, not XLA
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+    for a, b in zip(g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)

@@ -273,16 +273,29 @@ class TestContinuousBatching:
         the freed capacity admits a queued request at the very next
         boundary — short requests stop paying long requests' tails."""
         eng, model, variables = _tiny_engine(n_slots=1)
-        # Learn the greedy continuation, then replay with eos_id set
-        # to the SECOND generated token: solo semantics say tokens
-        # after it freeze to eos.
-        solo = np.asarray(generate(
-            model, variables, np.asarray([[3, 1, 4, 1]], np.int32),
-            max_new_tokens=6)).tolist()[0]
-        eos = solo[6]               # third generated token
-        assert eos not in solo[4:6]  # eos must fire at step 2 exactly
-        a = eng.submit(np.asarray([[3, 1, 4, 1]], np.int32), 6,
-                       eos, None)
+        # Learn a greedy continuation, then replay with eos_id set to
+        # its THIRD generated token: solo semantics say tokens after
+        # it freeze to eos.  The eos must fire at decode step 2
+        # exactly, so it may not already be one of the first two
+        # generated tokens — which a seeded random model's argmax
+        # does not promise on every host (rounding moves it).  So the
+        # precondition is constructed: take the first prompt whose
+        # continuation has it.
+        rs = np.random.RandomState(0)
+        candidates = [[3, 1, 4, 1]] + rs.randint(
+            1, 60, size=(15, 4)).tolist()
+        for tokens in candidates:
+            prompt = np.asarray([tokens], np.int32)
+            solo = np.asarray(generate(
+                model, variables, prompt,
+                max_new_tokens=6)).tolist()[0]
+            eos = solo[6]
+            if eos not in solo[4:6]:
+                break
+        else:
+            pytest.fail("no candidate prompt's greedy continuation "
+                        "has a third token unseen in its first two")
+        a = eng.submit(prompt, 6, eos, None)
         b = eng.submit(np.asarray([[9, 9, 2, 6]], np.int32), 3,
                        None, None)
         eng.tick()                  # prefill+admit A, decode step 1
@@ -299,7 +312,7 @@ class TestContinuousBatching:
         eng.run_until_idle()
         # A's padded output equals solo eos-freeze; B matches solo
         want_a = np.asarray(generate(
-            model, variables, np.asarray([[3, 1, 4, 1]], np.int32),
+            model, variables, prompt,
             max_new_tokens=6, eos_id=eos)).tolist()
         want_b = np.asarray(generate(
             model, variables, np.asarray([[9, 9, 2, 6]], np.int32),
