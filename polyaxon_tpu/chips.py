@@ -1,4 +1,5 @@
-"""Published per-chip peaks, keyed by ``device_kind``.
+"""Published per-chip peaks, keyed by ``device_kind``, and the host's
+chip device nodes.
 
 The ONE peak table of the repo: every MFU number (``bench.py``, the
 serving flight recorder) divides by a value from here.  Source: Google
@@ -11,7 +12,11 @@ spellings come first.
 
 from __future__ import annotations
 
-from typing import Optional
+import errno
+import glob
+import os
+import time
+from typing import List, Optional, Sequence
 
 PEAK_BF16_FLOPS = (
     ("v6 lite", 918e12),   # Trillium / v6e
@@ -42,3 +47,53 @@ def peak_bf16_flops(device_kind: str) -> Optional[float]:
     raise ValueError(
         f"no published bf16 peak for TPU device_kind {device_kind!r}; "
         f"add it to polyaxon_tpu/chips.py with its source")
+
+
+def chip_nodes() -> List[str]:
+    """The device nodes of this host's TPU chips (``/dev/accel*`` on
+    older generations, one ``/dev/vfio/<n>`` group per chip on v5e and
+    later).  Counted without JAX: a process that has touched JAX holds
+    the chips its children need."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def wait_for_chips(timeout_s: float = 90.0, poll_s: float = 0.25,
+                   nodes: Optional[Sequence[str]] = None) -> float:
+    """Before the first JAX call of a process that takes the host's
+    chips: wait while another process still holds one, at most
+    ``timeout_s``; returns the seconds waited.
+
+    A vfio group can be opened by one process at a time, and libtpu
+    fails at once on a held one (``open(/dev/vfio/2): Device or
+    resource busy``): JAX then has no backend and the job dies.  A
+    holder that was just killed keeps its groups until its last thread
+    has released them, which for a four-chip job killed with SIGKILL
+    took some 25 s on a v5e host (PERF.md section 6, PR 27): a job
+    restarted right after its predecessor was stopped would die of
+    that.  So each group
+    is opened and closed again here, which holds nothing; ``EBUSY``
+    means wait, anything else is libtpu's to report.  Only the vfio
+    groups are probed (opening an ``/dev/accel*`` node is not known to
+    be free of effects), and nothing where the chips were bound by hand
+    (``TPU_VISIBLE_CHIPS``, ``TPU_VISIBLE_DEVICES``)."""
+    if os.environ.get("TPU_VISIBLE_CHIPS") \
+            or os.environ.get("TPU_VISIBLE_DEVICES"):
+        return 0.0
+    if nodes is None:
+        nodes = [n for n in chip_nodes() if n.startswith("/dev/vfio/")]
+    t0 = time.monotonic()
+    busy = list(nodes)
+    while busy:
+        still = []
+        for node in busy:
+            try:
+                os.close(os.open(node, os.O_RDWR))
+            except OSError as e:
+                if e.errno == errno.EBUSY:
+                    still.append(node)
+        busy = still
+        if not busy or time.monotonic() - t0 >= timeout_s:
+            break
+        time.sleep(poll_s)
+    return time.monotonic() - t0

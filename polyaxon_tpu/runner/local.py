@@ -67,13 +67,11 @@ def _merge_container_env(env, container) -> None:
 
 def _host_tpu_chips() -> int:
     """TPU chips this host exposes, counted from its device nodes
-    (``/dev/accel*`` on older generations, one ``/dev/vfio/<n>`` group
-    per chip on v5e and later).  The executor's parent must not ask JAX:
-    a process that has touched JAX holds the chips its children need."""
-    import glob
+    (chips.chip_nodes).  The executor's parent must not ask JAX: a
+    process that has touched JAX holds the chips its children need."""
+    from ..chips import chip_nodes
 
-    return len(glob.glob("/dev/accel[0-9]*")
-               + glob.glob("/dev/vfio/[0-9]*"))
+    return len(chip_nodes())
 
 
 def _port_open(host: str, port: int, timeout: float = 0.5) -> bool:

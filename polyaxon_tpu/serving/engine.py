@@ -69,6 +69,7 @@ from .scheduler import (AdmissionQueue, DeadlineExceeded, PRIORITIES,
                         SchedulerPolicy, ShedError, Stream,
                         terminal_status)
 from .slots import SlotKVManager
+from ..spans import span, take
 from .telemetry import ENGINE_PID, Histogram, Telemetry
 
 __all__ = ["DecodeEngine", "QueueFullError", "SPEC_ACCEPT_BUCKETS"]
@@ -285,6 +286,10 @@ class DecodeEngine:
         # counterpart.
         self.step_device_s_total = 0.0
         self.step_wall_s_total = 0.0
+        # Seconds in the tick's host sections (spans.span) since the
+        # last step record took them: each record says what the host
+        # did around its dispatch (_host_fields).
+        self._host_s: Dict[str, float] = {}
         # Flight recorder (serving/profiling.py): set by the owning
         # server when --profile-every is armed.  None (the default)
         # keeps the decode loop's cost at one attribute check per
@@ -798,7 +803,8 @@ class DecodeEngine:
                 with self._wake:
                     if self._stop:
                         break
-                    self._wake.wait(timeout=0.05)
+                    with span("ptpu/idle_wait"):
+                        self._wake.wait(timeout=0.05)
         # Shutdown drain on the loop thread itself, where touching
         # _resident and the slot free-list can never race a tick.
         self._fail_all(RuntimeError("decode engine closed"))
@@ -828,9 +834,10 @@ class DecodeEngine:
         step over the resident batch.  Returns whether any work was
         done.  Single-threaded by contract (loop thread, or tests
         driving it manually)."""
-        worked = self._sweep_lifecycle()
-        if self._maybe_preempt():
-            worked = True
+        with span("ptpu/sweep"):
+            worked = self._sweep_lifecycle()
+            if self._maybe_preempt():
+                worked = True
         budget = self.policy.prefill_budget(bool(self._resident),
                                             self.slots.free_slots)
         while budget > 0:
@@ -845,11 +852,16 @@ class DecodeEngine:
                 # wait start into its causal timeline (once).
                 self._note_blocked(stream)
                 break
-            self._advance_prefill(stream)
+            with span("ptpu/prefill", self._host_s):
+                self._advance_prefill(stream)
             worked = True
             budget -= 1
         if self._resident:
-            self._decode_step()
+            # The tick's sections belong to no request: their stats
+            # say what the pool looked like.
+            with span("ptpu/decode", occupancy=len(self._resident),
+                      batch=self.slots.n_slots):
+                self._decode_step()
             worked = True
         # Step-boundary bookkeeping for the debuggability layer: the
         # watchdog's progress signal and the published /debug/state
@@ -865,7 +877,8 @@ class DecodeEngine:
             self.last_boundary_t = now
         if now - self._board_t >= self.board_interval_s:
             self._board_t = now
-            self.debug_board.publish(self.build_debug_snapshot())
+            with span("ptpu/board"):
+                self.debug_board.publish(self.build_debug_snapshot())
         return worked
 
     # -- paged-KV accounting ---------------------------------------------
@@ -1501,7 +1514,8 @@ class DecodeEngine:
         # submit can change the class-aware head between the tick's
         # head() and this pop (scheduler.AdmissionQueue.pop_stream).
         self.queue.pop_stream(stream)
-        self._admit(stream)
+        with span("ptpu/admit", self._host_s):
+            self._admit(stream)
 
     def _first_token(self, stream: Stream, logits: np.ndarray) -> int:
         """Token 0 for an admitted stream, from the prefill logits.
@@ -1779,6 +1793,28 @@ class DecodeEngine:
         return w
 
     # -- step-boundary fault containment ---------------------------------
+
+    def _host_fields(self) -> Dict[str, float]:
+        """The step record's host sections: seconds since the last
+        record, taken and reset.  ``upload_s``, ``enqueue_s``,
+        ``sync_s`` and ``lock_wait_s`` are this dispatch's (they lie
+        inside ``device_s``, but for the lock); ``admit_s`` and
+        ``prefill_s`` (one ``_advance_prefill`` outside its ``_admit``)
+        the admissions and prefill pieces since the last record;
+        ``commit_s`` the commit that ended since then, which is the
+        previous step's."""
+        tick, step = self._host_s, self.slots.host_s
+        admit = take(tick, "ptpu/admit")
+        return {
+            "upload_s": take(step, "ptpu/upload"),
+            "enqueue_s": take(step, "ptpu/enqueue"),
+            "sync_s": take(step, "ptpu/sync"),
+            "commit_s": take(tick, "ptpu/commit"),
+            "lock_wait_s": take(tick, "ptpu/lock_wait"),
+            "admit_s": admit,
+            "prefill_s": round(max(
+                0.0, take(tick, "ptpu/prefill") - admit), 6),
+        }
 
     def _dispatch_step(self, dispatch):
         """Contained step dispatch — the crash-only containment
@@ -2094,8 +2130,12 @@ class DecodeEngine:
         t0 = time.perf_counter()
 
         def dispatch():
-            with self.device_lock:
+            with span("ptpu/lock_wait", self._host_s):
+                self.device_lock.acquire()
+            try:
                 return self.slots.step(window, sampled)  # [W, S]
+            finally:
+                self.device_lock.release()
 
         toks_w = self._dispatch_step(dispatch)
         if toks_w is None:
@@ -2106,36 +2146,38 @@ class DecodeEngine:
                 self.recorder.on_step_end(0)
             return
         t1 = time.perf_counter()
-        self.decode_steps_total += window
-        emitted = 0
-        for slot, stream in list(self._resident.items()):
-            for w in range(window):
-                stream.out.append(int(toks_w[w, slot]))
-                emitted += 1
+        with span("ptpu/commit", self._host_s):
+            self.decode_steps_total += window
+            emitted = 0
+            for slot, stream in list(self._resident.items()):
+                for w in range(window):
+                    stream.out.append(int(toks_w[w, slot]))
+                    emitted += 1
+                    if stream.done():
+                        break
                 if stream.done():
-                    break
-            if stream.done():
-                del self._resident[slot]
-                self.slots.release(slot)
-                self.evicted_total += 1
-                self._note_freed(stream, "complete")
-                self._complete(stream)   # records the slot id
-                stream.slot = None
-        self.step_device_s_total += self.slots.last_step_device_s
-        self.step_wall_s_total += t1 - t0
-        if self.recorder is not None:
-            self.recorder.on_step_end(emitted)
-        self.tel.step("step", t0, t1,
-                      kind="sampled" if sampled else "plain",
-                      window=window, occupancy=occupancy,
-                      batch=self.slots.n_slots, tokens=emitted,
-                      device_s=round(self.slots.last_step_device_s,
-                                     6),
-                      **({"mesh": self.mesh.axes_str()}
-                         if self.mesh is not None else {}),
-                      **({"pages_free": self.slots.free_page_count(),
-                          "pages_total": self.slots.n_pages}
-                         if self.paged else {}))
+                    del self._resident[slot]
+                    self.slots.release(slot)
+                    self.evicted_total += 1
+                    self._note_freed(stream, "complete")
+                    self._complete(stream)   # records the slot id
+                    stream.slot = None
+            self.step_device_s_total += self.slots.last_step_device_s
+            self.step_wall_s_total += t1 - t0
+            if self.recorder is not None:
+                self.recorder.on_step_end(emitted)
+            self.tel.step("step", t0, t1,
+                          kind="sampled" if sampled else "plain",
+                          window=window, occupancy=occupancy,
+                          batch=self.slots.n_slots, tokens=emitted,
+                          device_s=round(self.slots.last_step_device_s,
+                                         6),
+                          **self._host_fields(),
+                          **({"mesh": self.mesh.axes_str()}
+                             if self.mesh is not None else {}),
+                          **({"pages_free": self.slots.free_page_count(),
+                              "pages_total": self.slots.n_pages}
+                             if self.paged else {}))
 
     def _decode_step_spec(self, window: int, K: int) -> None:
         """Advance the pool by ``window`` fused SPECULATIVE rounds
@@ -2158,8 +2200,12 @@ class DecodeEngine:
         t0 = time.perf_counter()
 
         def dispatch():
-            with self.device_lock:
+            with span("ptpu/lock_wait", self._host_s):
+                self.device_lock.acquire()
+            try:
                 return self.slots.step_spec(window, K)
+            finally:
+                self.device_lock.release()
 
         out = self._dispatch_step(dispatch)
         if out is None:
@@ -2171,49 +2217,51 @@ class DecodeEngine:
             return
         toks, commits, accepts = out
         t1 = time.perf_counter()
-        self.decode_steps_total += window
-        self.spec_rounds_total += window
-        emitted = accepted = 0
-        for slot, stream in list(self._resident.items()):
-            spec = stream.sampling.speculative
-            for w in range(window):
-                c = int(commits[w, slot])
-                if spec:
-                    stream.spec_rounds += 1
-                    stream.spec_drafted += stream.sampling.spec_k
-                    stream.spec_accepted += int(accepts[w, slot])
-                    self.spec_drafted_total += stream.sampling.spec_k
-                    self.spec_accepted_total += int(accepts[w, slot])
-                    accepted += int(accepts[w, slot])
-                for j in range(c):
-                    stream.out.append(int(toks[w, slot, j]))
-                    emitted += 1
+        with span("ptpu/commit", self._host_s):
+            self.decode_steps_total += window
+            self.spec_rounds_total += window
+            emitted = accepted = 0
+            for slot, stream in list(self._resident.items()):
+                spec = stream.sampling.speculative
+                for w in range(window):
+                    c = int(commits[w, slot])
+                    if spec:
+                        stream.spec_rounds += 1
+                        stream.spec_drafted += stream.sampling.spec_k
+                        stream.spec_accepted += int(accepts[w, slot])
+                        self.spec_drafted_total += stream.sampling.spec_k
+                        self.spec_accepted_total += int(accepts[w, slot])
+                        accepted += int(accepts[w, slot])
+                    for j in range(c):
+                        stream.out.append(int(toks[w, slot, j]))
+                        emitted += 1
+                        if stream.done():
+                            break
                     if stream.done():
                         break
                 if stream.done():
-                    break
-            if stream.done():
-                del self._resident[slot]
-                self.slots.release(slot)
-                self.evicted_total += 1
-                self._note_freed(stream, "complete")
-                self._complete(stream)   # records the slot id
-                stream.slot = None
-        self.step_device_s_total += self.slots.last_step_device_s
-        self.step_wall_s_total += t1 - t0
-        if self.recorder is not None:
-            self.recorder.on_step_end(emitted)
-        self.tel.step("step", t0, t1, kind="spec", window=window,
-                      k=K, occupancy=occupancy,
-                      batch=self.slots.n_slots, tokens=emitted,
-                      accepted=accepted,
-                      device_s=round(self.slots.last_step_device_s,
-                                     6),
-                      **({"mesh": self.mesh.axes_str()}
-                         if self.mesh is not None else {}),
-                      **({"pages_free": self.slots.free_page_count(),
-                          "pages_total": self.slots.n_pages}
-                         if self.paged else {}))
+                    del self._resident[slot]
+                    self.slots.release(slot)
+                    self.evicted_total += 1
+                    self._note_freed(stream, "complete")
+                    self._complete(stream)   # records the slot id
+                    stream.slot = None
+            self.step_device_s_total += self.slots.last_step_device_s
+            self.step_wall_s_total += t1 - t0
+            if self.recorder is not None:
+                self.recorder.on_step_end(emitted)
+            self.tel.step("step", t0, t1, kind="spec", window=window,
+                          k=K, occupancy=occupancy,
+                          batch=self.slots.n_slots, tokens=emitted,
+                          accepted=accepted,
+                          device_s=round(self.slots.last_step_device_s,
+                                         6),
+                          **self._host_fields(),
+                          **({"mesh": self.mesh.axes_str()}
+                             if self.mesh is not None else {}),
+                          **({"pages_free": self.slots.free_page_count(),
+                              "pages_total": self.slots.n_pages}
+                             if self.paged else {}))
 
     # -- completion -----------------------------------------------------
 
@@ -2532,14 +2580,25 @@ class DecodeEngine:
             # the paged refactor exists for, fed to /metrics + /info
             # from this ONE dict.
             **(self.slots.page_stats() if self.paged else {}),
-            # Mesh topology + step device/wall seconds (absent
-            # unmeshed): axis names/sizes and device count for
-            # /info, and the cumulative per-dispatch device share —
-            # on a mesh the device wall bundles compute AND
-            # collectives, so the tp=1-vs-tpN bench A/B is what
-            # isolates the collective-time share (bench_serving_load
-            # meshed leg).
+            # Mesh topology (absent unmeshed): axis names/sizes and
+            # device count for /info.  On a mesh the step counters
+            # below bundle compute AND collectives, so the
+            # tp=1-vs-tpN bench A/B is what isolates the
+            # collective-time share (bench_serving_load meshed leg).
             **(self._mesh_stats() if self.mesh is not None else {}),
+            # Per-step device share of the dispatch wall, meshed or
+            # not: device_s is a HOST clock around dispatch + sync
+            # (upload, enqueue and device_get lie inside it); the
+            # remainder is host scheduling.  On a mesh the device
+            # part bundles per-shard compute + collectives.
+            "step_device_seconds_total":
+                round(self.step_device_s_total, 6),
+            "step_wall_seconds_total":
+                round(self.step_wall_s_total, 6),
+            "step_device_share":
+                round(self.step_device_s_total
+                      / self.step_wall_s_total, 4)
+                if self.step_wall_s_total > 0 else None,
             # Recompile sentinel: compile_cache_misses must go quiet
             # once traffic has warmed its shapes (the zero-steady-
             # state contract, tests/test_analysis.py); a counter that
@@ -2549,22 +2608,12 @@ class DecodeEngine:
         }
 
     def _mesh_stats(self) -> Dict[str, Any]:
-        wall = self.step_wall_s_total
         return {
             "mesh": self.mesh.describe(),
             "mesh_devices": self.mesh.n_devices,
             # Empty until the first prefill has shaped the pool.
             "kv_pool_shardings":
                 self.mesh.describe_placement(self.slots.kv_pool()),
-            "step_device_seconds_total":
-                round(self.step_device_s_total, 6),
-            "step_wall_seconds_total": round(wall, 6),
-            # Per-step device share of the dispatch wall: the
-            # remainder is host scheduling; the device part bundles
-            # per-shard compute + collectives (see stats() note).
-            "step_device_share":
-                round(self.step_device_s_total / wall, 4)
-                if wall > 0 else None,
         }
 
     def _spec_accept_stats(self) -> Dict[str, Any]:

@@ -87,6 +87,7 @@ import numpy as np
 
 from .slots import (alloc_decode_state, build_spec_step_body,
                     build_step_body, step_annotation)
+from ..spans import span
 
 __all__ = ["PagedSlotKVManager", "PageExhausted",
            "WirePayloadError", "pack_spilled", "unpack_spilled"]
@@ -364,6 +365,7 @@ class PagedSlotKVManager:
         # shared helper, also called by crash-recovery reset()) -----
         alloc_decode_state(self)
         self.last_step_device_s = 0.0
+        self.host_s = {}    # see SlotKVManager
 
     # -- page accounting ------------------------------------------------
 
@@ -1172,25 +1174,28 @@ class PagedSlotKVManager:
                 window, sampled, P)
         elif self.sentinel is not None:
             self.sentinel.hit("slot_step", key)
-        tables = jnp.asarray(self.page_tables[:, :P])
-        d0 = jnp.asarray(self._dirty_start(P, self._n_dirty(window)))
+        host_s = self.host_s
+        with span("ptpu/upload", host_s):
+            tables = jnp.asarray(self.page_tables[:, :P])
+            d0 = jnp.asarray(self._dirty_start(P, self._n_dirty(window)))
         t0 = time.perf_counter()
-        with self._exact(), step_annotation():
-            if sampled:
-                outs, self._pool = fn(
-                    self._pool, tables, d0, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions),
-                    jnp.asarray(self.keys),
-                    jnp.asarray(self.next_index),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps))
-            else:
-                outs, self._pool = fn(
-                    self._pool, tables, d0, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions))
+        with self._exact(), step_annotation(window=window):
+            with span("ptpu/upload", host_s):
+                operands = [tables, d0, jnp.asarray(self.tokens),
+                            jnp.asarray(self.positions)]
+                if sampled:
+                    operands += [
+                        jnp.asarray(self.keys),
+                        jnp.asarray(self.next_index),
+                        jnp.asarray(self.temps),
+                        jnp.asarray(self.top_ks),
+                        jnp.asarray(self.top_ps)]
+            with span("ptpu/enqueue", host_s):
+                outs, self._pool = fn(self._pool, *operands)
             # Sync inside the marker so it spans the device
             # execution, not just the async enqueue (see slots.py).
-            outs = np.asarray(jax.device_get(outs))
+            with span("ptpu/sync", host_s):
+                outs = np.asarray(jax.device_get(outs))
         self.last_step_device_s = time.perf_counter() - t0
         self.tokens = outs[-1].copy()
         self.positions = self.positions + window
@@ -1260,21 +1265,28 @@ class PagedSlotKVManager:
                 window, K, P)
         elif self.sentinel is not None:
             self.sentinel.hit("slot_step", key)
-        tables = jnp.asarray(self.page_tables[:, :P])
-        d0 = jnp.asarray(self._dirty_start(
-            P, self._n_dirty(window * K + 1)))
+        host_s = self.host_s
+        with span("ptpu/upload", host_s):
+            tables = jnp.asarray(self.page_tables[:, :P])
+            d0 = jnp.asarray(self._dirty_start(
+                P, self._n_dirty(window * K + 1)))
         t0 = time.perf_counter()
-        with self._exact(), step_annotation():
-            outs, cs, ms, self._pool, self._draft_pool = fn(
-                self._pool, self._draft_pool, tables, d0,
-                jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.next_index), jnp.asarray(self.keys),
-                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks))
+        with self._exact(), step_annotation(window=window, k=K):
+            with span("ptpu/upload", host_s):
+                operands = [
+                    tables, d0,
+                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
+                    jnp.asarray(self.next_index), jnp.asarray(self.keys),
+                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                    jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks)]
+            with span("ptpu/enqueue", host_s):
+                outs, cs, ms, self._pool, self._draft_pool = fn(
+                    self._pool, self._draft_pool, *operands)
             # Sync inside the marker — see the plain step.
-            outs = np.asarray(jax.device_get(outs))
-            cs = np.asarray(jax.device_get(cs))
-            ms = np.asarray(jax.device_get(ms))
+            with span("ptpu/sync", host_s):
+                outs = np.asarray(jax.device_get(outs))
+                cs = np.asarray(jax.device_get(cs))
+                ms = np.asarray(jax.device_get(ms))
         self.last_step_device_s = time.perf_counter() - t0
         rows = np.arange(self.n_slots)
         adv = cs.sum(axis=0).astype(np.int32)

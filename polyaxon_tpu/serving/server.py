@@ -3407,11 +3407,8 @@ class ModelServer:
                 f"{es['spec_accepted_total']}",
             ]
             if "mesh" in es:
-                # Mesh topology + the per-step device-share counters
-                # (meshed engines only).  Axis sizes render as one
-                # labeled gauge per active axis; the step counters
-                # feed the bench's tp=1-vs-tpN collective-share
-                # derivation (see engine.stats()).
+                # Mesh topology (meshed engines only).  Axis sizes
+                # render as one labeled gauge per active axis.
                 lines += [
                     "# TYPE ptpu_serving_mesh_devices gauge",
                     f"ptpu_serving_mesh_devices {es['mesh_devices']}",
@@ -3421,6 +3418,11 @@ class ModelServer:
                     lines.append(
                         f'ptpu_serving_mesh_axis_size{{axis="{axis}"}}'
                         f' {size}')
+            if "step_device_seconds_total" in es:
+                # The per-step device-share counters, meshed or not
+                # (host clock around dispatch + sync; on a mesh they
+                # feed the bench's tp=1-vs-tpN collective-share
+                # derivation, see engine.stats()).
                 lines += [
                     "# TYPE ptpu_serving_step_device_seconds_total "
                     "counter",
@@ -3797,7 +3799,10 @@ def make_handler(ms: ModelServer):
             jax.profiler wrap.  400 when the server was started
             without --profile-dir (profiling writes device traces to
             disk — explicit opt-in); 409 on state conflicts (second
-            start, stop with nothing running)."""
+            start, stop with nothing running).  A start's optional
+            body ``{"python_tracer": true}`` turns the Python tracer
+            on (every call of every thread: for debugging, not for
+            timing); without a body it is off."""
             t0 = time.perf_counter()
             if ms.profiler is None:
                 code, resp = 400, {
@@ -3806,7 +3811,10 @@ def make_handler(ms: ModelServer):
             else:
                 try:
                     if self.path == "/profile/start":
-                        d = ms.profiler.start()
+                        n = int(self.headers.get("Content-Length", 0))
+                        body = json.loads(self.rfile.read(n) or b"{}")
+                        d = ms.profiler.start(python_tracer=bool(
+                            body.get("python_tracer", False)))
                         code, resp = 200, {"profiling": True,
                                            "dir": d}
                     else:
@@ -3815,6 +3823,9 @@ def make_handler(ms: ModelServer):
                                            "dir": d}
                 except RuntimeError as e:
                     code, resp = 409, {"error": str(e)}
+                except (ValueError, AttributeError) as e:
+                    code, resp = 400, {
+                        "error": f"bad /profile/start body: {e}"}
                 except Exception as e:
                     code, resp = 500, {
                         "error": f"{type(e).__name__}: {e}"}

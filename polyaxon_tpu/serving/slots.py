@@ -71,24 +71,22 @@ from typing import Optional
 import numpy as np
 
 from ..analysis.xprof import STEP_MARKER
+from ..spans import span
 
 
-def step_annotation():
+def step_annotation(**stats):
     """Profiler marker around ONE decode dispatch AND its blocking
     sync (inside the device lock): when a ``jax.profiler`` trace is
     active — a manual ``POST /profile/start`` or a flight-recorder
     window — every step boundary lands in the dump as a named
-    ``ptpu_step`` span, which the trace parser
+    ``ptpu_step`` span (``spans.span``), which the trace parser
     (analysis/xprof.py) uses to anchor its attribution window and
     the host-gap math to EXACTLY the profiled step boundaries.  The
     ``device_get`` sync must stay inside the marker: dispatch alone
     returns futures, and a marker spanning only the enqueue would
-    let the window clip the final step's device execution.  With
-    no trace active a TraceAnnotation is a sub-microsecond no-op
-    (measured ~0.4us), invisible next to a multi-ms dispatch."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(STEP_MARKER)
+    let the window clip the final step's device execution.  Inside
+    it lie ``ptpu/upload``, ``ptpu/enqueue`` and ``ptpu/sync``."""
+    return span(STEP_MARKER, **stats)
 
 
 # -- step-program bodies (shared with the paged manager) --------------------
@@ -323,6 +321,9 @@ class SlotKVManager:
         # lock wait is excluded) — the engine's step-timeline records
         # report it next to the scheduling wall time.
         self.last_step_device_s = 0.0
+        # Seconds in the step's host sections (spans.span) since the
+        # engine last took them for a step record.
+        self.host_s = {}
 
     # -- slot accounting ------------------------------------------------
 
@@ -537,26 +538,28 @@ class SlotKVManager:
                 self._build_step(window, sampled)
         elif self.sentinel is not None:
             self.sentinel.hit("slot_step", (window, sampled))
+        host_s = self.host_s
         t0 = time.perf_counter()
-        with self._exact(), step_annotation():
-            if sampled:
-                outs, self._stacked = fn(
-                    self._stacked, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions),
-                    jnp.asarray(self.keys),
-                    jnp.asarray(self.next_index),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps))
-            else:
-                outs, self._stacked = fn(
-                    self._stacked, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions))
+        with self._exact(), step_annotation(window=window):
+            with span("ptpu/upload", host_s):
+                operands = [jnp.asarray(self.tokens),
+                            jnp.asarray(self.positions)]
+                if sampled:
+                    operands += [
+                        jnp.asarray(self.keys),
+                        jnp.asarray(self.next_index),
+                        jnp.asarray(self.temps),
+                        jnp.asarray(self.top_ks),
+                        jnp.asarray(self.top_ps)]
+            with span("ptpu/enqueue", host_s):
+                outs, self._stacked = fn(self._stacked, *operands)
             # The sync stays INSIDE the marker: dispatch returns
             # device futures, so a marker closing here-minus-one-line
             # would span only the host enqueue and the attribution
             # window would clip the step's actual device execution
             # (inflating MFU by ~K/(K-1) on a real async backend).
-            outs = np.asarray(jax.device_get(outs))
+            with span("ptpu/sync", host_s):
+                outs = np.asarray(jax.device_get(outs))
         self.last_step_device_s = time.perf_counter() - t0
         # Arm the next step: every slot feeds back its own last token
         # at the next position (and, for sampled slots, the next
@@ -639,18 +642,23 @@ class SlotKVManager:
                 self._build_spec_step(window, K)
         elif self.sentinel is not None:
             self.sentinel.hit("slot_step", (window, "spec", K))
+        host_s = self.host_s
         t0 = time.perf_counter()
-        with self._exact(), step_annotation():
-            outs, cs, ms, self._stacked, self._draft_stacked = fn(
-                self._stacked, self._draft_stacked,
-                jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.next_index), jnp.asarray(self.keys),
-                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks))
+        with self._exact(), step_annotation(window=window, k=K):
+            with span("ptpu/upload", host_s):
+                operands = [
+                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
+                    jnp.asarray(self.next_index), jnp.asarray(self.keys),
+                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                    jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks)]
+            with span("ptpu/enqueue", host_s):
+                outs, cs, ms, self._stacked, self._draft_stacked = fn(
+                    self._stacked, self._draft_stacked, *operands)
             # Sync inside the marker — see the plain step.
-            outs = np.asarray(jax.device_get(outs))
-            cs = np.asarray(jax.device_get(cs))
-            ms = np.asarray(jax.device_get(ms))
+            with span("ptpu/sync", host_s):
+                outs = np.asarray(jax.device_get(outs))
+                cs = np.asarray(jax.device_get(cs))
+                ms = np.asarray(jax.device_get(ms))
         self.last_step_device_s = time.perf_counter() - t0
         # Arm the next round from the LAST round's per-slot commit.
         rows = np.arange(self.n_slots)

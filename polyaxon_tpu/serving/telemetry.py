@@ -367,7 +367,6 @@ class ProfileSession:
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
         self._owner: Optional[str] = None
-        self._session = None     # low-level (python-tracer-off) mode
 
     @property
     def active(self) -> bool:
@@ -378,20 +377,22 @@ class ProfileSession:
         return self._owner
 
     def start(self, owner: str = "manual",
-              python_tracer: bool = True) -> str:
-        """``python_tracer=False`` drops to jaxlib's ProfilerSession
-        with ``python_tracer_level=0``: device/runtime TraceMes and
-        the ``ptpu_step`` markers still land in the dump, but the
-        Python host tracer — which instruments EVERY Python call on
-        EVERY thread for the duration — stays off.  That is the
-        difference between a recorder window costing milliseconds
-        and costing >50% of a busy server's throughput (measured;
-        the bench's ``recorder_overhead`` leg holds it), so the
-        flight recorder always passes False; the manual endpoints
-        keep the full trace for interactive debugging."""
+              python_tracer: bool = False) -> str:
+        """Through ``spans.start_trace``, the program's one way to
+        start a trace.  The Python host tracer — which instruments
+        EVERY Python call on EVERY thread for the duration — is off
+        unless asked for: device/runtime TraceMes and the ``ptpu*``
+        spans still land in the dump, and the traced server is the
+        server that was measured untraced.  ``POST /profile/start``
+        takes ``{"python_tracer": true}`` for interactive debugging.
+        Only a manual trace carries the HLO protos: with them on,
+        every recorder window would serialize the HLO of EVERY
+        compiled module in the process (~100MB on a warmed server —
+        measured) on the engine thread, and attribution needs events,
+        not HLO."""
         import os
 
-        import jax
+        from ..spans import start_trace
 
         with self._lock:
             if self._active_dir is not None:
@@ -413,28 +414,8 @@ class ProfileSession:
                 n += 1
                 d = f"{base}_{n}"
             os.makedirs(d)
-            self._session = None
-            if not python_tracer:
-                try:
-                    from jax._src.lib import xla_client
-
-                    opts = xla_client.profiler.ProfileOptions()
-                    opts.python_tracer_level = 0
-                    # No HLO protos in recorder dumps: with them on,
-                    # every window serializes the HLO of EVERY
-                    # compiled module in the process (~100MB on a
-                    # warmed server — measured), on the engine
-                    # thread.  Attribution needs events, not HLO.
-                    opts.enable_hlo_proto = False
-                    self._session = \
-                        xla_client.profiler.ProfilerSession(opts)
-                except (ImportError, AttributeError):
-                    # jaxlib without the options surface: fall back
-                    # to the full trace (correct, just costlier —
-                    # the recorder-overhead bench leg measures it).
-                    pass
-            if self._session is None:
-                jax.profiler.start_trace(d)
+            start_trace(d, python_tracer=python_tracer,
+                        hlo_proto=owner == "manual")
             self._active_dir = d
             self._owner = owner
             return d
@@ -459,11 +440,7 @@ class ProfileSession:
             # -> 409 "nothing running", start -> jax "already
             # started") with no operator recovery but a restart.
             d = self._active_dir
-            if self._session is not None:
-                self._session.stop_and_export(d)
-                self._session = None
-            else:
-                jax.profiler.stop_trace()
+            jax.profiler.stop_trace()
             self._active_dir = None
             self._owner = None
             return d

@@ -469,16 +469,17 @@ class Run:
     # artifact; replaces the reference's pynvml-only story) -------------
 
     def start_profiler_trace(self) -> Optional[str]:
-        """Begin a jax.profiler trace into the run's artifact tree.
-        View with TensorBoard (a `tensorboard` service/init kind)."""
+        """Begin a jax.profiler trace into the run's artifact tree,
+        the Python tracer off (spans.start_trace).  View with
+        TensorBoard (a `tensorboard` service/init kind)."""
         if not self._tracks:
             return None
-        import jax
+        from ..spans import start_trace
 
         trace_dir = os.path.join(self.client.get_artifacts_path(),
                                  "traces")
         os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
+        start_trace(trace_dir)
         self._trace_dir = trace_dir
         return trace_dir
 

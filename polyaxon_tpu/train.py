@@ -21,6 +21,7 @@ into the same path.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import itertools
 import json
 import os
@@ -58,8 +59,10 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--no-resume", dest="resume", action="store_false")
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--profile-at", type=int, default=0,
-                   help="Capture a jax.profiler trace starting at this "
-                        "step (0 = off).")
+                   help="Capture a jax.profiler trace of --profile-steps "
+                        "steps starting at this step (0 = off): device "
+                        "planes plus the loop's ptpu/* host spans, the "
+                        "Python tracer off.")
     p.add_argument("--profile-steps", type=int, default=5)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--init-hf", default=None, metavar="STATE_DICT",
@@ -339,6 +342,18 @@ def _main(argv=None) -> int:
 
     enable_compilation_cache()
 
+    # 0c. the host's chips may still be held by a predecessor that was
+    #     just stopped (chips.wait_for_chips): wait for it rather than
+    #     die of "Device or resource busy" at the first JAX call.
+    if not args.cpu and \
+            os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        from .chips import wait_for_chips
+
+        waited = wait_for_chips()
+        if waited > 0.5:
+            print(f"waited {waited:.1f}s for the host's chips to be "
+                  f"released", flush=True)
+
     # 1. multi-host bootstrap from injected topology env (no-op when the
     #    run is single-process).
     from .parallel.bootstrap import initialize_from_env
@@ -364,6 +379,7 @@ def _main(argv=None) -> int:
     from .checkpoint import CheckpointManager
     from .models.registry import get_model
     from .parallel import MeshSpec, build_mesh, make_train_step
+    from .spans import span, step_span, take
     from . import tracking
 
     # 2. mesh from the strategy spec: JSON ('{"dp": 2, "ep": 4}') or the
@@ -541,51 +557,73 @@ def _main(argv=None) -> int:
     batches = itertools.chain([first], batches)
 
     last_metrics: Dict[str, Any] = {}
+    # Seconds the host spent in its named sections since the last
+    # logged block (spans.py): each block carries them, so a long block
+    # of an untraced run says whether the host or the device held it.
+    host_s: Dict[str, float] = {}
     t_block = time.perf_counter()
     block_start = start_step
     for step in range(start_step, total_steps):
         if args.profile_at and step == args.profile_at:
             run.start_profiler_trace()
-        rng, step_rng = jax.random.split(rng)
-        batch = next(batches)
-        if args.prefetch == 0:
-            batch = jax.device_put(batch, step_fn.batch_sharding)
-        state, metrics = step_fn(state, batch, step_rng)
-        if args.profile_at and step + 1 == args.profile_at + \
-                args.profile_steps:
-            jax.block_until_ready(state)
-            run.stop_profiler_trace(step=step + 1)
-        if ckpt.preempt_requested:
-            # SIGTERM landed while the bound state was donated into the
-            # in-flight step; save the fresh output state and exit within
-            # the operator's grace period (checkpoint.py).
-            ckpt.save(step + 1, state, force=True)
-            ckpt.wait()
-            print("preempted: checkpoint flushed, exiting", flush=True)
-            break
-        if args.checkpoint_every and (step + 1) % args.checkpoint_every == 0:
-            ckpt.save(step + 1, state)  # async; off the step path
-        if (step + 1) % args.log_every == 0 or step + 1 == total_steps:
-            metrics = {k: float(v) for k, v in metrics.items()}
-            dt = time.perf_counter() - t_block
-            done = step + 1 - block_start
-            throughput = per_batch * done / dt / n_chips
-            metrics[f"{unit}_per_sec_per_chip"] = round(throughput, 2)
-            if (step + 1) in eval_steps:
-                metrics["eval_accuracy"] = evaluate(state["params"],
-                                                    eval_ds)
-            run.log_metrics(step=step + 1, **metrics)
-            print(f"step {step + 1}/{total_steps} "
-                  + " ".join(f"{k}={v:.4g}" for k, v in metrics.items()),
-                  flush=True)
-            last_metrics = metrics
-            t_block = time.perf_counter()
-            block_start = step + 1
-            if target and target[0] in metrics and \
-                    target_reached(metrics[target[0]], target):
-                print(f"target {target[0]}{target[2]}{target[1]} reached",
-                      flush=True)
+        with contextlib.ExitStack() as step_mark:
+            step_mark.enter_context(step_span(step))
+            rng, step_rng = jax.random.split(rng)
+            with span("ptpu/data_wait", host_s):
+                batch = next(batches)
+                if args.prefetch == 0:
+                    batch = jax.device_put(batch, step_fn.batch_sharding)
+            with span("ptpu/enqueue", host_s):
+                state, metrics = step_fn(state, batch, step_rng)
+            if args.profile_at and step + 1 == args.profile_at + \
+                    args.profile_steps:
+                jax.block_until_ready(state)
+                # A span still open when the trace stops is lost: the
+                # last traced step closes here.
+                step_mark.close()
+                run.stop_profiler_trace(step=step + 1)
+            if ckpt.preempt_requested:
+                # SIGTERM landed while the bound state was donated into
+                # the in-flight step; save the fresh output state and
+                # exit within the operator's grace period
+                # (checkpoint.py).
+                ckpt.save(step + 1, state, force=True)
+                ckpt.wait()
+                print("preempted: checkpoint flushed, exiting", flush=True)
                 break
+            if args.checkpoint_every and \
+                    (step + 1) % args.checkpoint_every == 0:
+                with span("ptpu/checkpoint"):
+                    ckpt.save(step + 1, state)  # async; off the step path
+            if (step + 1) % args.log_every == 0 or step + 1 == total_steps:
+                with span("ptpu/log_sync", host_s):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                dt = time.perf_counter() - t_block
+                done = step + 1 - block_start
+                throughput = per_batch * done / dt / n_chips
+                metrics[f"{unit}_per_sec_per_chip"] = round(throughput, 2)
+                if (step + 1) in eval_steps:
+                    with span("ptpu/eval"):
+                        metrics["eval_accuracy"] = evaluate(
+                            state["params"], eval_ds)
+                metrics.update(
+                    host_data_wait_s=take(host_s, "ptpu/data_wait"),
+                    host_enqueue_s=take(host_s, "ptpu/enqueue"),
+                    host_log_s=take(host_s, "ptpu/log_write"))
+                with span("ptpu/log_write", host_s):
+                    run.log_metrics(step=step + 1, **metrics)
+                    print(f"step {step + 1}/{total_steps} "
+                          + " ".join(f"{k}={v:.4g}"
+                                     for k, v in metrics.items()),
+                          flush=True)
+                last_metrics = metrics
+                t_block = time.perf_counter()
+                block_start = step + 1
+                if target and target[0] in metrics and \
+                        target_reached(metrics[target[0]], target):
+                    print(f"target {target[0]}{target[2]}{target[1]} "
+                          f"reached", flush=True)
+                    break
 
     # A profile window reaching past the last step still finalizes.
     run.stop_profiler_trace(step=int(state["step"]))

@@ -505,21 +505,40 @@ def test_flight_recorder_live_window_gauges_and_report(tiny,
                 _post(base, {"prompt": [1, 2, 3],
                              "max_new_tokens": 12})
         assert rep is not None, "no recorder window analyzed in 60s"
-        # traffic is quiet now; wait for any in-flight analysis to
-        # settle so /metrics and /profile/report read one record
-        time.sleep(0.2)
-        rep = _get_json(base, "/profile/report")
+        # Traffic is quiet now.  Wait until no window is open and no
+        # analysis is in flight (every window opened has been
+        # analyzed; one the traffic left open is closed by the
+        # recorder's deadline watchdog), and until /profile/report,
+        # read before and after /metrics, agrees with it on that: then
+        # all three read one record.  (A fixed sleep did not wait for
+        # an analysis that a loaded machine stretches past it.)
+        deadline = time.time() + 60
+        while True:
+            rep = _get_json(base, "/profile/report")
+            metrics = parse_prometheus_text(_get_text(base, "/metrics"))
+            again = _get_json(base, "/profile/report")
+            counts = [(r["windows_total"], r["windows_analyzed"])
+                      for r in (rep, again)] + [(
+                metrics["ptpu_serving_profile_windows_total"],
+                metrics["ptpu_serving_profile_windows_analyzed_total"])]
+            if len(set(counts)) == 1 and counts[0][0] == counts[0][1]:
+                break
+            assert time.time() < deadline, (counts, rep["last_error"])
+            time.sleep(0.1)
         latest = rep["latest"]
-        assert latest["steps"] == 3
-        assert latest["host_fallback"] is True   # cpu smoke
-        assert latest["device_busy_share"] > 0
-        assert latest["mfu"] is not None
-        assert 0 <= latest["mfu"] < 1e6          # finite
-        assert latest["peak_flops_source"] == "nominal"
-        shares_sum = sum(latest["shares"].values())
-        assert shares_sum <= 1.0 + 1e-9
+        # what a window under traffic holds: the newest that closed at
+        # its own boundary (the watchdog's may hold fewer steps)
+        full = [w for w in rep["windows"]
+                if not w.get("deadline_closed")][-1]
+        assert full["steps"] == 3
+        assert full["host_fallback"] is True     # cpu smoke
+        assert full["device_busy_share"] > 0
+        assert full["mfu"] is not None
+        assert 0 <= full["mfu"] < 1e6            # finite
+        assert full["peak_flops_source"] == "nominal"
+        for w in rep["windows"]:
+            assert sum(w["shares"].values()) <= 1.0 + 1e-9
         # one reduction, no drift: gauges == report numbers
-        metrics = parse_prometheus_text(_get_text(base, "/metrics"))
         assert metrics["ptpu_serving_collective_share"] == \
             latest["collective_share"]
         assert metrics["ptpu_serving_host_gap_share"] == \
@@ -527,11 +546,6 @@ def test_flight_recorder_live_window_gauges_and_report(tiny,
         assert metrics["ptpu_serving_device_busy_share"] == \
             latest["device_busy_share"]
         assert metrics["ptpu_serving_mfu"] == latest["mfu"]
-        assert metrics["ptpu_serving_profile_windows_total"] == \
-            rep["windows_total"]
-        assert \
-            metrics["ptpu_serving_profile_windows_analyzed_total"] \
-            == rep["windows_analyzed"]
         # /info summarizes the same record
         info = _get_json(base, "/info")
         prof = info["profiling"]

@@ -1,0 +1,397 @@
+"""Tier-1 coverage for the host sections on the profiler's clock
+(polyaxon_tpu/spans.py): the closed list of names, the counters, what
+a CPU trace taken through ``start_trace`` holds after ``train.py``'s
+loop and after an engine tick, the Python tracer's switch, and the
+wait for chips a dying predecessor still holds (chips.py)."""
+
+import ast
+import errno
+import glob
+import json
+import os
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from polyaxon_tpu import chips, spans
+from polyaxon_tpu.models.registry import get_model
+from polyaxon_tpu.serving import ModelServer, make_server
+from polyaxon_tpu.serving.engine import DecodeEngine
+from polyaxon_tpu.serving.scheduler import SchedulerPolicy
+from polyaxon_tpu.serving.telemetry import ProfileSession, Telemetry
+
+PACKAGE = os.path.dirname(os.path.abspath(spans.__file__))
+HOST_PLANE = "/host:CPU"
+STEP_FIELDS = ("upload_s", "enqueue_s", "sync_s", "commit_s",
+               "lock_wait_s", "admit_s", "prefill_s")
+
+
+# ---------------------------------------------------------------------------
+# the list and the helper
+# ---------------------------------------------------------------------------
+
+
+def _span_literals():
+    """``(file, name)`` of every ``span("...")`` call in the package."""
+    out = []
+    for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and getattr(node.func, "id", None) == "span" \
+                    and node.args \
+                    and isinstance(node.args[0], ast.Constant):
+                out.append((os.path.relpath(path, PACKAGE),
+                            node.args[0].value))
+    return out
+
+
+def test_span_names_closed_and_every_literal_listed():
+    names = spans.SPAN_NAMES
+    assert len(set(names)) == len(names)
+    assert all(n == "ptpu_step" or n.startswith("ptpu/") for n in names)
+    assert spans.STEP_MARKER in names and spans.TRAIN_STEP in names
+    used = _span_literals()
+    assert len(used) >= 20      # train.py 6, engine 8, slots/paged 12
+    assert [u for u in used if u[1] not in names] == []
+    # every listed name is used: a literal, or one of the two markers
+    # that spans.py itself enters (step_span, slots.step_annotation)
+    assert set(names) - {n for _, n in used} \
+        == {spans.STEP_MARKER, spans.TRAIN_STEP}
+
+
+def test_one_place_starts_a_trace_and_none_uses_the_private_session():
+    starts, private = [], []
+    for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Attribute) \
+                    and node.attr == "start_trace" \
+                    and getattr(node.value, "attr", None) == "profiler":
+                starts.append(os.path.relpath(path, PACKAGE))
+            if isinstance(node, (ast.Attribute, ast.Name)) \
+                    and getattr(node, "attr", getattr(node, "id", None)) \
+                    in ("xla_client", "ProfilerSession"):
+                private.append(os.path.relpath(path, PACKAGE))
+    assert starts == ["spans.py"]
+    assert private == []
+
+
+def test_acc_sums_and_take_resets():
+    acc = {}
+    for _ in range(3):
+        with spans.span("ptpu/data_wait", acc):
+            pass
+    with spans.span("ptpu/enqueue", acc, window=8):
+        pass
+    with spans.span("ptpu/board"):      # no acc: nothing recorded
+        pass
+    assert set(acc) == {"ptpu/data_wait", "ptpu/enqueue"}
+    total = acc["ptpu/data_wait"]
+    assert 0 < total < 0.1
+    assert spans.take(acc, "ptpu/data_wait") == round(total, 6)
+    assert spans.take(acc, "ptpu/data_wait") == 0.0
+    assert spans.take(acc, "ptpu/log_write") == 0.0     # never entered
+
+
+def test_span_passes_an_exception_through_and_still_counts():
+    acc = {}
+    with pytest.raises(KeyError):
+        with spans.span("ptpu/commit", acc):
+            raise KeyError("x")
+    assert acc["ptpu/commit"] > 0
+
+
+# ---------------------------------------------------------------------------
+# what a trace holds
+# ---------------------------------------------------------------------------
+
+
+def _host_events(trace_dir):
+    """``[(line, name, start_ns, end_ns)]`` of the dump's host plane."""
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name != HOST_PLANE:
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.append((line.name, e.name, e.start_ns,
+                            e.start_ns + e.duration_ns))
+    return out
+
+
+def _ptpu(events):
+    return sorted((e for e in events if e[1].startswith("ptpu")),
+                  key=lambda e: (e[2], -e[3]))
+
+
+def _parents(events):
+    """``{(child name, parent name or None)}``: the innermost span that
+    encloses each span."""
+    out = set()
+    stack = []
+    for e in _ptpu(events):
+        while stack and stack[-1][3] <= e[2]:
+            stack.pop()
+        out.add((e[1], stack[-1][1] if stack else None))
+        stack.append(e)
+    return out
+
+
+def _python_tracer_events(events):
+    # the Python tracer names a call "$<file>:<line> <function>" (and
+    # "$<module> <builtin>")
+    return [e for e in events if e[1].startswith("$")]
+
+
+@pytest.fixture()
+def train_run(tmp_path, monkeypatch):
+    """Four steps of ``train.py``'s loop, steps 1-3 under its
+    ``--profile-at`` trace; ``(store, uuid, trace dir)``."""
+    from polyaxon_tpu.client import FileRunStore
+    from polyaxon_tpu.train import main
+
+    home = str(tmp_path / "home")
+    monkeypatch.setenv("POLYAXON_TPU_HOME", home)
+    monkeypatch.setenv("POLYAXON_TPU_NO_TPU", "1")
+    assert main(["--model", "mlp", "--cpu", "--steps", "4",
+                 "--batch-size", "8", "--log-every", "2",
+                 "--profile-at", "1", "--profile-steps", "3",
+                 "--eval-every", "2", "--checkpoint-every", "2",
+                 "--no-resume"]) == 0
+    store = FileRunStore(home)
+    uuid = store.list_runs()[0]["uuid"]
+    return store, uuid, os.path.join(store.artifacts_path(uuid), "traces")
+
+
+def test_train_loop_spans_nest_and_no_python_tracer(train_run):
+    _, _, trace_dir = train_run
+    events = _host_events(trace_dir)
+    steps = [e for e in events if e[1] == spans.TRAIN_STEP]
+    assert len(steps) == 3              # the last closes before the stop
+    assert len({e[0] for e in _ptpu(events)}) == 1      # one thread
+    parents = _parents(events)
+    assert (spans.TRAIN_STEP, None) in parents
+    # (no ptpu/eval: the synthetic data has no evaluation set)
+    for name in ("ptpu/data_wait", "ptpu/enqueue", "ptpu/checkpoint",
+                 "ptpu/log_sync", "ptpu/log_write"):
+        assert (name, spans.TRAIN_STEP) in parents, (name, parents)
+    assert all(p in (None, spans.TRAIN_STEP) for _, p in parents)
+    assert _python_tracer_events(events) == []
+
+
+def test_logged_block_carries_the_host_counters(train_run):
+    store, uuid, _ = train_run
+
+    def series(name):
+        return {e["step"]: e["value"]
+                for e in store.read_events(uuid, "metric", name)}
+
+    loss = series("loss")
+    assert sorted(loss) == [2, 4]
+    for name in ("host_data_wait_s", "host_enqueue_s", "host_log_s"):
+        values = series(name)
+        assert sorted(values) == [2, 4], name
+        assert all(0 <= v < 60 for v in values.values()), (name, values)
+    # two enqueues a block; the first block has no earlier log_write
+    assert all(v > 0 for v in series("host_enqueue_s").values())
+    assert series("host_log_s")[2] == 0.0
+    assert series("host_log_s")[4] > 0
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return get_model("gpt2-tiny").init_params(batch_size=1)
+
+
+def test_engine_tick_spans_nest_and_step_record_fields(tiny, tmp_path):
+    model, variables = tiny
+    tel = Telemetry(buffer=256)
+    eng = DecodeEngine(model, variables, autostart=False, telemetry=tel,
+                       policy=SchedulerPolicy(n_slots=2, queue_depth=8,
+                                              decode_window=1))
+    try:
+        warm = eng.submit(np.asarray([[5, 6, 7]], np.int32), 3, None, None)
+        eng.run_until_idle()            # compiles outside the trace
+        assert warm.event.is_set()
+        before = sum(1 for e in tel.events() if e["name"] == "step")
+        group = eng.submit(np.asarray([[1, 2, 3]], np.int32), 4, None,
+                           None)
+        spans.start_trace(str(tmp_path))
+        try:
+            eng.tick()      # sweep, prefill + admit, one decode step
+            eng.tick()      # the record of step 2 holds step 1's commit
+        finally:
+            import jax
+
+            jax.profiler.stop_trace()
+        eng.run_until_idle()
+        assert group.event.is_set()
+    finally:
+        eng.close()
+    events = _host_events(str(tmp_path))
+    parents = _parents(events)
+    want = {
+        ("ptpu/sweep", None), ("ptpu/prefill", None),
+        ("ptpu/admit", "ptpu/prefill"), ("ptpu/decode", None),
+        ("ptpu/lock_wait", "ptpu/decode"), ("ptpu_step", "ptpu/decode"),
+        ("ptpu/upload", "ptpu_step"), ("ptpu/enqueue", "ptpu_step"),
+        ("ptpu/sync", "ptpu_step"), ("ptpu/commit", "ptpu/decode"),
+    }
+    assert want <= parents, want - parents
+    assert parents - want <= {("ptpu/board", None)}
+    assert _python_tracer_events(events) == []
+    records = [e["args"] for e in tel.events() if e["name"] == "step"]
+    assert len(records) >= before + 2
+    for rec in records:
+        assert set(STEP_FIELDS) <= set(rec), rec
+        assert all(0 <= rec[f] < 60 for f in STEP_FIELDS)
+        assert "device_s" in rec and rec["device_s"] >= rec["sync_s"]
+    first, second = records[before:before + 2]      # under the trace
+    assert first["admit_s"] > 0 and first["enqueue_s"] > 0
+    assert second["commit_s"] > 0 and second["admit_s"] == 0.0
+    # the step counters are reported unmeshed too
+    stats = eng.stats()
+    assert stats["step_device_seconds_total"] > 0
+    assert stats["step_wall_seconds_total"] \
+        >= stats["step_device_seconds_total"]
+    assert 0 < stats["step_device_share"] <= 1
+    assert "mesh" not in stats
+
+
+def test_paged_step_has_the_same_sections(tiny):
+    model, variables = tiny
+    tel = Telemetry(buffer=64)
+    eng = DecodeEngine(model, variables, autostart=False, telemetry=tel,
+                       policy=SchedulerPolicy(n_slots=2, queue_depth=8,
+                                              decode_window=1,
+                                              kv_paged=True,
+                                              kv_page_tokens=8))
+    try:
+        group = eng.submit(np.asarray([[1, 2, 3]], np.int32), 3, None,
+                           None)
+        eng.run_until_idle()
+        assert group.event.is_set()
+    finally:
+        eng.close()
+    records = [e["args"] for e in tel.events() if e["name"] == "step"]
+    assert records and all(set(STEP_FIELDS) <= set(r) for r in records)
+    assert all(r["upload_s"] > 0 and r["sync_s"] > 0 for r in records)
+
+
+# ---------------------------------------------------------------------------
+# the Python tracer's switch
+# ---------------------------------------------------------------------------
+
+
+def _traced_call(session, **kw):
+    import jax.numpy as jnp
+
+    session.start(**kw)
+    try:
+        with spans.span("ptpu/board"):
+            json.dumps({"x": float(jnp.ones((4,)).sum())})
+    finally:
+        d = session.stop()
+    return _host_events(d)
+
+
+def test_python_tracer_off_by_default_on_when_asked(tmp_path):
+    session = ProfileSession(str(tmp_path))
+    off = _traced_call(session)
+    assert [e[1] for e in _ptpu(off)] == ["ptpu/board"]
+    assert _python_tracer_events(off) == []
+    on = _traced_call(session, python_tracer=True)
+    assert [e[1] for e in _ptpu(on)] == ["ptpu/board"]
+    assert _python_tracer_events(on) != []
+
+
+def test_profile_start_body_switches_the_tracer(tiny, tmp_path):
+    import threading
+
+    model, variables = tiny
+    ms = ModelServer(model, variables, model_name="gpt2-tiny",
+                     max_batch=4, batching="off",
+                     profile_dir=str(tmp_path))
+    seen = []
+    real = ms.profiler.start
+    ms.profiler.start = lambda **kw: (seen.append(kw), real(**kw))[1]
+    srv = make_server("127.0.0.1", 0, ms)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+
+    def post(path, data):
+        req = urllib.request.Request(base + path, data=data, method="POST")
+        with urllib.request.urlopen(req, timeout=60) as resp:
+            return json.loads(resp.read())
+
+    try:
+        for body, want in ((None, False), (b"{}", False),
+                           (b'{"python_tracer": true}', True)):
+            assert post("/profile/start", body)["profiling"] is True
+            assert post("/profile/stop", None)["profiling"] is False
+            assert seen[-1] == {"python_tracer": want}
+        with pytest.raises(urllib.error.HTTPError) as err:
+            post("/profile/start", b"[1")
+        assert err.value.code == 400
+        assert not ms.profiler.active
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        ms.close()
+
+
+# ---------------------------------------------------------------------------
+# chips a dying predecessor still holds
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("busy_polls, timeout_s, freed", [
+    (0, 5.0, True),         # free at once: no wait
+    (3, 5.0, True),         # released after three polls
+    (10 ** 6, 0.05, False),  # never released: gives up at the timeout
+])
+def test_wait_for_chips(monkeypatch, busy_polls, timeout_s, freed):
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.delenv("TPU_VISIBLE_DEVICES", raising=False)
+    opened = []
+    real_open = os.open
+
+    def fake_open(path, flags, *a):
+        if path.startswith("/dev/vfio/"):
+            opened.append(path)
+            if path == "/dev/vfio/1" and opened.count(path) <= busy_polls:
+                raise OSError(errno.EBUSY, "Device or resource busy")
+            if path == "/dev/vfio/2":
+                raise OSError(errno.EACCES, "not ours to judge")
+            return real_open(os.devnull, flags)
+        return real_open(path, flags, *a)
+
+    monkeypatch.setattr(os, "open", fake_open)
+    waited = chips.wait_for_chips(
+        timeout_s=timeout_s, poll_s=0.005,
+        nodes=["/dev/vfio/0", "/dev/vfio/1", "/dev/vfio/2"])
+    assert opened.count("/dev/vfio/0") == 1     # free ones asked once
+    assert opened.count("/dev/vfio/2") == 1
+    if freed:
+        assert opened.count("/dev/vfio/1") == busy_polls + 1
+        assert waited < timeout_s
+    else:
+        assert waited >= timeout_s
+
+
+def test_wait_for_chips_leaves_hand_bound_chips_alone(monkeypatch):
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0")
+    monkeypatch.setattr(os, "open", lambda *a: pytest.fail("probed"))
+    assert chips.wait_for_chips(nodes=["/dev/vfio/0"]) == 0.0

@@ -448,7 +448,9 @@ def test_access_log_lines(tiny):
         lines = [json.loads(ln) for ln in
                  ms._access_log_file.getvalue().splitlines()]
         assert len(lines) == 2
-        ok, bad = lines
+        # each line lands after its own reply, on its own handler
+        # thread: the two may land in either order
+        ok, bad = sorted(lines, key=lambda ln: ln["status"])
         assert ok["status"] == 200 and ok["kind"] == "sampled"
         assert ok["rows"] == 1 and ok["new_tokens"] == 2
         assert ok["ms"] > 0
