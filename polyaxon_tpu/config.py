@@ -15,6 +15,7 @@ axes) ride the same config so a deployment can pin them fleet-wide.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -75,6 +76,39 @@ def enable_compilation_cache() -> str:
     # the repeated-compile workload.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
+
+
+@contextlib.contextmanager
+def fresh_compile():
+    """Programs first called inside are compiled in this process, not
+    read from (or written to) the persistent compilation cache.
+
+    For a program whose results carry a PINNED device layout that is
+    not the device's default.  An executable that comes back from the
+    cache hands out its results labelled with the default layout,
+    whatever it was compiled to write (v5e, jax 0.9.0: PERF.md section
+    6, PR 28): the next program either refuses the array — when it pins
+    the layout of its argument, as every program here does — or reads
+    its bytes in the wrong order.  Compiled here, the label is right.
+
+    JAX decides once whether it uses the cache and remembers it, so
+    turning the switch takes ``reset_cache()`` with it, on the way in
+    and on the way out (the cache is opened again by the next compile
+    that wants it).  The switch is the process's: a thread that
+    compiles meanwhile skips the cache once too, which is harmless."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def switch(on: bool) -> None:
+        jax.config.update("jax_enable_compilation_cache", on)
+        compilation_cache.reset_cache()
+
+    was = jax.config.jax_enable_compilation_cache
+    switch(False)
+    try:
+        yield
+    finally:
+        switch(was)
 
 
 def _config_path() -> str:

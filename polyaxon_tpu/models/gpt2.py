@@ -95,7 +95,9 @@ class GPT2Block(nn.Module):
     cfg: GPT2Config
 
     @nn.compact
-    def __call__(self, x, decode: bool = False):
+    def __call__(self, x, decode: bool = False, layer=None):
+        # ``layer``: this block's index where the scanned stack
+        # carries the whole KV cache (scan_stack.LayerScanBody).
         cfg = self.cfg
         head_dim = cfg.hidden_size // cfg.num_heads
 
@@ -114,7 +116,8 @@ class GPT2Block(nn.Module):
             # enter via wpe at the embedding).
             k, v, mask, _ = append_kv_cache(self, k, v,
                                             cfg.max_position,
-                                            quantize=cfg.kv_cache_int8)
+                                            quantize=cfg.kv_cache_int8,
+                                            layer=layer)
         a = dot_product_attention(q, k, v, causal=not decode, mask=mask)
         a = a.reshape(h.shape)
         a = constrain(a, BATCH, None, "tp")
@@ -178,7 +181,7 @@ class GPT2Model(nn.Module):
 
     def run_blocks(self, x, decode: bool = False):
         if self.cfg.scan_layers:
-            x, _ = self.h(x, decode or None)
+            x, _ = self.h.run(x, decode)
             return x
         for block in self.h_blocks:
             # `decode or None`: under nn.remat a literal False would be

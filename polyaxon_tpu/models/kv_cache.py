@@ -24,8 +24,57 @@ def _quantize_chunk(x):
     return symmetric_int8(x, axes=(-1,))
 
 
+# -- one layer's view of a cache variable -----------------------------------
+#
+# ``layer`` is None where the variable is this layer's own (an
+# unrolled stack, or a scan that holds the cache as its xs/ys: T5,
+# MoE-GPT, and every cache at its creation), and the layer's index
+# (traced) where scan_stack CARRIES the stacked cache through the
+# layer loop: the variable then holds every layer's plane on a leading
+# axis, and a layer touches only what it changes.
+
+
+def _plane(var, layer):
+    """This layer's plane of ``var``, read once."""
+    if layer is None:
+        return var.value
+    return jax.lax.dynamic_index_in_dim(var.value, layer, 0,
+                                        keepdims=False)
+
+
+def _store(var, layer, value) -> None:
+    """Replace this layer's whole plane (the small leaves: an index,
+    a ring's position table)."""
+    var.value = value if layer is None \
+        else var.value.at[layer].set(value)
+
+
+def _put_rows(var, layer, rows, start) -> None:
+    """Write ``rows`` ([B, S, H, D]) at positions ``[start, start+S)``
+    of this layer's plane — on a carried stack an update of S rows in
+    place, not of the plane."""
+    if layer is None:
+        var.value = jax.lax.dynamic_update_slice(
+            var.value, rows, (0, start, 0, 0))
+    else:
+        var.value = jax.lax.dynamic_update_slice(
+            var.value, rows[None], (layer, 0, start, 0, 0))
+
+
+def _scatter_rows(var, layer, rows, slots) -> None:
+    """Write ``rows`` ([B, n, H, D]) at the ring slots ``slots`` [n]
+    (distinct) of this layer's plane."""
+    if layer is None:
+        var.value = var.value.at[:, slots].set(rows)
+    else:
+        # Two advanced indices around a slice: their axis leads.
+        var.value = var.value.at[layer, :, slots].set(
+            jnp.moveaxis(rows, 1, 0))
+
+
 def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
-                         quantize: bool = False, slack: int = 0):
+                         quantize: bool = False, slack: int = 0,
+                         layer=None):
     """Sliding-window decode with an O(window) RING cache — the
     long-context serving path for Mistral-style models.
 
@@ -55,13 +104,17 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     inside the window (destroyed max = idx+k-cap-... safe iff
     ``slack >= k-1`` — generate_speculative enforces it).
 
+    ``layer``: as for :func:`append_kv_cache` — the ring's rows are
+    scattered into the carried stack, its plane read once.
+
     Returns ``(k_full, v_full, mask, positions)`` shaped like
     :func:`append_kv_cache` but with key axis ``capacity + S``.
     """
     b, s, h, d = k.shape
     idx = mod.variable("cache", "cache_index",
                        lambda: jnp.array(0, jnp.int32))
-    pos_q = idx.value + jnp.arange(s)
+    idx0 = _plane(idx, layer)
+    pos_q = idx0 + jnp.arange(s)
     if rotate is not None:
         k = rotate(pos_q, k)
     store_dtype = jnp.int8 if quantize else k.dtype
@@ -71,12 +124,13 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     # length, or the slot arithmetic would scatter out of bounds.
     ck = mod.variable("cache", "cached_key", jnp.zeros,
                       (b, window + s + slack, h, d), store_dtype)
-    cap = ck.value.shape[1]
+    cap = ck.value.shape[-3]
     cv = mod.variable("cache", "cached_value", jnp.zeros,
                       (b, cap, h, d), store_dtype)
     # -1 marks never-written slots (masked off by the position test).
     cpos = mod.variable("cache", "cached_pos",
                         lambda: jnp.full((cap,), -1, jnp.int32))
+    cpos0 = _plane(cpos, layer)
     if quantize:
         kq, k_scale = _quantize_chunk(k)
         vq, v_scale = _quantize_chunk(v)
@@ -85,15 +139,17 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
         cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
         out_dtype = k.dtype
-        k_old = ck.value.astype(out_dtype) * cks.value.astype(out_dtype)
-        v_old = cv.value.astype(out_dtype) * cvs.value.astype(out_dtype)
+        k_old = _plane(ck, layer).astype(out_dtype) \
+            * _plane(cks, layer).astype(out_dtype)
+        v_old = _plane(cv, layer).astype(out_dtype) \
+            * _plane(cvs, layer).astype(out_dtype)
     else:
         kq, k_scale, vq, v_scale = k, None, v, None
-        k_old, v_old = ck.value, cv.value
+        k_old, v_old = _plane(ck, layer), _plane(cv, layer)
 
     k_full = jnp.concatenate([k_old, k], axis=1)
     v_full = jnp.concatenate([v_old, v], axis=1)
-    pos_k = jnp.concatenate([cpos.value, pos_q])      # [cap + S]
+    pos_k = jnp.concatenate([cpos0, pos_q])           # [cap + S]
     valid = (pos_k[None, :] <= pos_q[:, None]) & \
         (pos_k[None, :] >= pos_q[:, None] - window) & \
         (pos_k[None, :] >= 0)
@@ -103,7 +159,7 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     # the position test alone would admit both copies.  The chunk
     # carries its own entries for [idx, idx+S).
     ring_older = jnp.concatenate(
-        [cpos.value < idx.value, jnp.ones((s,), bool)])
+        [cpos0 < idx0, jnp.ones((s,), bool)])
     valid = valid & ring_older[None, :]
 
     # Scatter the chunk tail into the ring.  keep = min(S, cap) rows:
@@ -113,18 +169,18 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     keep = min(s, cap)
     tail_pos = pos_q[s - keep:]
     slots = tail_pos % cap
-    ck.value = ck.value.at[:, slots].set(kq[:, s - keep:])
-    cv.value = cv.value.at[:, slots].set(vq[:, s - keep:])
+    _scatter_rows(ck, layer, kq[:, s - keep:], slots)
+    _scatter_rows(cv, layer, vq[:, s - keep:], slots)
     if quantize:
-        cks.value = cks.value.at[:, slots].set(k_scale[:, s - keep:])
-        cvs.value = cvs.value.at[:, slots].set(v_scale[:, s - keep:])
-    cpos.value = cpos.value.at[slots].set(tail_pos)
-    idx.value = idx.value + s
+        _scatter_rows(cks, layer, k_scale[:, s - keep:], slots)
+        _scatter_rows(cvs, layer, v_scale[:, s - keep:], slots)
+    _store(cpos, layer, cpos0.at[slots].set(tail_pos))
+    _store(idx, layer, idx0 + s)
     return k_full, v_full, valid[None, None], pos_q
 
 
 def append_kv_cache(mod, k, v, max_position: int, window=None,
-                    rotate=None, quantize: bool = False):
+                    rotate=None, quantize: bool = False, layer=None):
     """Append this step's k/v ([B, S, H, D]) to ``mod``'s decode cache.
 
     Works for single-token steps AND chunked prefill (S > 1 — the
@@ -174,6 +230,21 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
     masking, RoPE, and the speculative rollback contract below are
     unchanged at any width.
 
+    IN PLACE contract (``layer``): under ``scan_stack`` a decode
+    apply over an existing cache hands every layer the WHOLE stacked
+    cache — each variable ``[num_layers, ...]``, the layer scan's
+    carry — and ``layer``, its index.  The append then
+    writes only the S new rows (and scales) of each sequence at
+    ``[layer, :, idx:idx+S]`` and bumps ``cache_index[layer]``: an
+    update XLA performs in place on a loop carry, and in place on the
+    caller's buffer when the program donates it (serving/slots.py
+    does).  The layer's keys and values are read once, as the
+    attention's operand.  Nothing else of the stack is read or
+    written; no layer is sliced out and written back.  ``layer=None``
+    (an unrolled stack, T5 and MoE-GPT's own scans, and the apply
+    that CREATES the variables) is the same arithmetic on a variable
+    that is the layer's own.
+
     Creates ``cached_key``/``cached_value``/``cache_index`` (plus
     ``cached_key_scale``/``cached_value_scale`` when quantized)
     variables in the "cache" collection on ``mod``; returns
@@ -182,7 +253,8 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
     b, s, h, d = k.shape
     idx = mod.variable("cache", "cache_index",
                        lambda: jnp.array(0, jnp.int32))
-    pos_q = idx.value + jnp.arange(s)  # absolute positions of new rows
+    idx0 = _plane(idx, layer)
+    pos_q = idx0 + jnp.arange(s)  # absolute positions of new rows
     if rotate is not None:
         k = rotate(pos_q, k)
     if quantize:
@@ -196,29 +268,27 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
                       (b, max_position, h, d), store_dtype)
     # An existing (possibly paged-view) cache keeps ITS width; only a
     # fresh creation uses max_position.
-    cap = ck.value.shape[1]
+    cap = ck.value.shape[-3]
     cv = mod.variable("cache", "cached_value", jnp.zeros,
                       (b, cap, h, d), store_dtype)
-    ck.value = jax.lax.dynamic_update_slice(ck.value, kq,
-                                            (0, idx.value, 0, 0))
-    cv.value = jax.lax.dynamic_update_slice(cv.value, vq,
-                                            (0, idx.value, 0, 0))
+    _put_rows(ck, layer, kq, idx0)
+    _put_rows(cv, layer, vq, idx0)
     if quantize:
         cks = mod.variable("cache", "cached_key_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
         cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
-        cks.value = jax.lax.dynamic_update_slice(
-            cks.value, k_scale, (0, idx.value, 0, 0))
-        cvs.value = jax.lax.dynamic_update_slice(
-            cvs.value, v_scale, (0, idx.value, 0, 0))
+        _put_rows(cks, layer, k_scale, idx0)
+        _put_rows(cvs, layer, v_scale, idx0)
         # Unwritten positions hold scale 0 -> dequantize to 0, exactly
         # like the unquantized zero-init cache (masked off anyway).
-        k_full = ck.value.astype(out_dtype) * cks.value.astype(out_dtype)
-        v_full = cv.value.astype(out_dtype) * cvs.value.astype(out_dtype)
+        k_full = _plane(ck, layer).astype(out_dtype) \
+            * _plane(cks, layer).astype(out_dtype)
+        v_full = _plane(cv, layer).astype(out_dtype) \
+            * _plane(cvs, layer).astype(out_dtype)
     else:
-        k_full, v_full = ck.value, cv.value
-    idx.value = idx.value + s
+        k_full, v_full = _plane(ck, layer), _plane(cv, layer)
+    _store(idx, layer, idx0 + s)
     keys = jnp.arange(cap)
     valid = keys[None, :] <= pos_q[:, None]  # [S, cap]
     if window is not None:

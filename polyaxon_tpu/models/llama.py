@@ -122,7 +122,7 @@ class LlamaAttention(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, decode: bool = False):
+    def __call__(self, x, decode: bool = False, layer=None):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: nn.Dense(  # noqa: E731
@@ -150,12 +150,13 @@ class LlamaAttention(nn.Module):
                 k, v, mask, pos = append_ring_kv_cache(
                     self, k, v, cfg.sliding_window, rotate=rot,
                     quantize=cfg.kv_cache_int8,
-                    slack=cfg.kv_cache_ring_slack)
+                    slack=cfg.kv_cache_ring_slack, layer=layer)
             else:
                 k, v, mask, pos = append_kv_cache(
                     self, k, v, cfg.max_position,
                     window=cfg.sliding_window,
-                    quantize=cfg.kv_cache_int8, rotate=rot)
+                    quantize=cfg.kv_cache_int8, rotate=rot,
+                    layer=layer)
             q = apply_rotary(q, q, theta=cfg.rope_theta,
                              positions=pos)[0]
         else:
@@ -176,12 +177,15 @@ class LlamaBlock(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, decode: bool = False):
+    def __call__(self, x, decode: bool = False, layer=None):
+        # ``layer``: this block's index where the scanned stack
+        # carries the whole KV cache (scan_stack.LayerScanBody).
         cfg = self.cfg
         norm = lambda name: nn.RMSNorm(  # noqa: E731
             epsilon=cfg.rms_norm_eps, dtype=jnp.float32, name=name)
         x = x + LlamaAttention(cfg, name="attn")(
-            norm("input_norm")(x).astype(cfg.dtype), decode=decode)
+            norm("input_norm")(x).astype(cfg.dtype), decode=decode,
+            layer=layer)
         x = constrain(x, BATCH, None, None)
         h = norm("post_attn_norm")(x).astype(cfg.dtype)
         gate = nn.Dense(cfg.intermediate_size, use_bias=False,
@@ -224,7 +228,7 @@ class LlamaModel(nn.Module):
 
     def run_blocks(self, x, decode: bool = False):
         if self.cfg.scan_layers:
-            x, _ = self.layers(x, decode or None)
+            x, _ = self.layers.run(x, decode)
             return x
         for block in self.blocks:
             # `decode or None`: a literal False would be traced under

@@ -27,6 +27,7 @@ from ..parallel.constraints import BATCH, constrain, current_mesh
 from ..parallel.moe import moe_layer, top1_dispatch
 from .attention import dot_product_attention
 from .kv_cache import append_kv_cache
+from .scan_stack import LayerScanBody, scan_layers
 
 
 @dataclass(frozen=True)
@@ -174,7 +175,9 @@ class MoEBlock(nn.Module):
     cfg: MoEGPTConfig
 
     @nn.compact
-    def __call__(self, x, decode: bool = False):
+    def __call__(self, x, decode: bool = False, layer=None):
+        # ``layer``: this block's index where the scanned stack
+        # carries the whole KV cache (scan_stack.LayerScanBody).
         cfg = self.cfg
         head_dim = cfg.hidden_size // cfg.num_heads
 
@@ -192,7 +195,8 @@ class MoEBlock(nn.Module):
             # switch FFN below picks its kernel by chunk size.
             k, v, mask, _ = append_kv_cache(self, k, v,
                                             cfg.max_position,
-                                            quantize=cfg.kv_cache_int8)
+                                            quantize=cfg.kv_cache_int8,
+                                            layer=layer)
         a = dot_product_attention(q, k, v, causal=not decode, mask=mask)
         a = a.reshape(h.shape)
         a = constrain(a, BATCH, None, "tp")
@@ -207,18 +211,20 @@ class MoEBlock(nn.Module):
         return constrain(x, BATCH, None, None), aux
 
 
-class _ScanMoEBlock(nn.Module):
+class _ScanMoEBlock(LayerScanBody):
     """nn.scan body: carries (x, aux_sum) so the load-balance loss flows
     out of the rolled layer stack without mutable collections.
-    ``decode`` rides as an nn.broadcast input (see scan_stack)."""
+    ``decode`` rides as an nn.broadcast input, ``layer`` comes with a
+    carried KV cache (see scan_stack)."""
 
     cfg: MoEGPTConfig
 
     @nn.compact
-    def __call__(self, carry, decode=None):
+    def __call__(self, carry, decode=None, layer=None):
         x, aux_sum = carry
         if decode:
-            x, aux = MoEBlock(self.cfg, name="block")(x, decode=True)
+            x, aux = MoEBlock(self.cfg, name="block")(
+                x, decode=True, layer=layer)
             return (x, aux_sum + aux), None
         cls = nn.remat(MoEBlock, prevent_cse=False) if self.cfg.remat \
             else MoEBlock
@@ -238,14 +244,8 @@ class MoEGPTModel(nn.Module):
                             dtype=cfg.dtype, name="wte")
         self.wpe = nn.Embed(cfg.max_position, cfg.hidden_size,
                             dtype=cfg.dtype, name="wpe")
-        self.h = nn.scan(
-            _ScanMoEBlock,
-            variable_axes={"params": 0, "cache": 0},
-            in_axes=nn.broadcast,
-            split_rngs={"params": True},
-            length=cfg.num_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, name="h")
+        self.h = scan_layers(_ScanMoEBlock, cfg.num_layers, cfg,
+                             name="h")
         self.ln_f = nn.LayerNorm(epsilon=cfg.layer_norm_eps,
                                  dtype=jnp.float32, name="ln_f")
 
@@ -262,8 +262,8 @@ class MoEGPTModel(nn.Module):
             pos = pos + decode_position
         x = x + self.wpe(pos)
         x = constrain(x, BATCH, None, None)
-        (x, aux), _ = self.h((x, jnp.zeros((), jnp.float32)),
-                             decode or None)
+        (x, aux), _ = self.h.run((x, jnp.zeros((), jnp.float32)),
+                                 decode)
         if last_only:  # prefill: one row of logits, not [B, P, V]
             x = x[:, -1:]
         x = self.ln_f(x)

@@ -214,6 +214,9 @@ class DecodeEngine:
         self.admitted_spec_total = 0
         self.evicted_total = 0
         self.decode_steps_total = 0
+        # Dispatches that failed AFTER consuming the donated KV pool
+        # (_recover_lost_pool).
+        self.kv_pool_lost_total = 0
         self.prefill_chunks_total = 0
         self.completed_total = 0
         self.completed_greedy_total = 0
@@ -1826,14 +1829,26 @@ class DecodeEngine:
 
         Classification of a failing dispatch:
 
+        - POOL LOST (``slots.pool_lost()``): the step programs take
+          the KV pool as a DONATED argument and update it in place
+          (serving/slots.py), so a failure raised AFTER the program
+          consumed the pool leaves no pool to retry on — the live
+          tree holds deleted arrays, and there is no second copy.
+          Checked first, whatever the error's class:
+          :meth:`_recover_lost_pool` requeues every resident for the
+          token-identical resume from its committed prefix and
+          rebuilds the pool (``recover_from_crash``, the supervisor's
+          own path).  A deleted buffer is never dispatched again.
         - TRANSIENT (faults.is_transient — injected TransientFault,
-          or any error carrying ``ptpu_transient``): retried in
-          place under the shared bounded jittered-backoff
-          :class:`~polyaxon_tpu.serving.recovery.RetryPolicy`.  A
-          retry re-runs the identical dispatch — no tokens were
-          committed, and a partially-written cache is rewritten with
-          identical values (every step is a pure function of the
-          committed prefix) — so retries never change output.
+          or any error carrying ``ptpu_transient``), the pool intact:
+          retried in place under the shared bounded jittered-backoff
+          :class:`~polyaxon_tpu.serving.recovery.RetryPolicy`.
+          "Intact" is every failure raised BEFORE the program took
+          the pool — every injected fault is (``faults.check`` runs
+          ahead of ``dispatch()``), and so are errors in the host
+          sections ahead of the call.  A retry re-runs the identical
+          dispatch on the unchanged pool — no tokens were committed
+          — so retries never change output.
         - POISONED (faults.is_poisoned), or transient with retries
           exhausted, or any other error with residents to protect:
           :meth:`_quarantine_step` — bisect the resident suspects
@@ -1867,6 +1882,9 @@ class DecodeEngine:
             except BaseException as e:
                 if not self._resident:
                     raise       # nothing to contain: scheduling bug
+                if self.slots.pool_lost():
+                    self._recover_lost_pool(e)
+                    return None
                 if is_transient(e) and not is_poisoned(e) \
                         and attempt < self.retry_policy.max_attempts:
                     delay = self.retry_policy.delay_s(attempt)
@@ -1897,6 +1915,24 @@ class DecodeEngine:
                 for s in self._resident.values():
                     self._suspects.discard(s.group)
             return out
+
+    def _recover_lost_pool(self, err: BaseException) -> None:
+        """A program consumed the donated KV pool and then failed:
+        nothing resident has a cache any more.  Requeue every
+        resident (and reset every partial prefill) for the
+        token-identical resume and rebuild the pool on the next
+        insertion — ``recover_from_crash``, run here on the loop
+        thread between two ticks' device sections, with the compiled
+        programs kept."""
+        self.kv_pool_lost_total += 1
+        try:
+            self.tel.instant(
+                0, "kv_pool_lost", time.perf_counter(),
+                pid=ENGINE_PID, error=type(err).__name__,
+                residents=len(self._resident))
+        except Exception:
+            self.telemetry_errors_total += 1
+        self.recover_from_crash()
 
     def _quarantine_step(self, err: BaseException) -> None:
         """One quarantine-bisection round for a poisoned step
@@ -2133,7 +2169,8 @@ class DecodeEngine:
             with span("ptpu/lock_wait", self._host_s):
                 self.device_lock.acquire()
             try:
-                return self.slots.step(window, sampled)  # [W, S]
+                return self.slots.step(        # [W, S]
+                    window, sampled, self.policy.decode_window)
             finally:
                 self.device_lock.release()
 
@@ -2507,6 +2544,16 @@ class DecodeEngine:
             "admitted_spec_total": self.admitted_spec_total,
             "evicted_total": self.evicted_total,
             "decode_steps_total": self.decode_steps_total,
+            # The KV pool updated in place (serving/slots.py):
+            # programs that took the pool, those that consumed the
+            # tree they were handed, the live pools' bytes, and the
+            # dispatches that failed after consuming it.
+            "kv_pool_dispatches_total":
+                self.slots.kv_pool_dispatches_total,
+            "kv_pool_in_place_total":
+                self.slots.kv_pool_in_place_total,
+            "kv_pool_bytes": self.slots.kv_pool_bytes,
+            "kv_pool_lost_total": self.kv_pool_lost_total,
             "prefill_chunks_total": self.prefill_chunks_total,
             "completed_total": self.completed_total,
             "completed_greedy_total": self.completed_greedy_total,
@@ -2608,12 +2655,16 @@ class DecodeEngine:
         }
 
     def _mesh_stats(self) -> Dict[str, Any]:
+        # Under the device lock: the next dispatch consumes the tree
+        # kv_pool() hands out.
+        with self.device_lock:
+            placement = self.mesh.describe_placement(
+                self.slots.kv_pool())
         return {
             "mesh": self.mesh.describe(),
             "mesh_devices": self.mesh.n_devices,
             # Empty until the first prefill has shaped the pool.
-            "kv_pool_shardings":
-                self.mesh.describe_placement(self.slots.kv_pool()),
+            "kv_pool_shardings": placement,
         }
 
     def _spec_accept_stats(self) -> Dict[str, Any]:

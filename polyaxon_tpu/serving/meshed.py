@@ -329,13 +329,6 @@ class ServingMesh:
 
         return jax.tree_util.tree_map_with_path(leaf_sharding, tree)
 
-    def place_cache(self, tree, *, slot_axis: bool = False):
-        import jax
-
-        return jax.tree_util.tree_map(
-            jax.device_put, tree,
-            self.cache_shardings(tree, slot_axis=slot_axis))
-
     # -- paged pool placement --------------------------------------------
 
     def pool_leaf_sharding(self, meta: Dict[str, Any], pool_leaf):

@@ -366,6 +366,13 @@ class PagedSlotKVManager:
         alloc_decode_state(self)
         self.last_step_device_s = 0.0
         self.host_s = {}    # see SlotKVManager
+        # The fixed-lane manager's in-place counters (engine stats).
+        # The PAGE pool keeps its own discipline — gather the views,
+        # step them (the carried layer loop updates the views in
+        # place), scatter the dirty pages into a NEW pool — and
+        # donates nothing: its dispatches count, none in place.
+        self.kv_pool_dispatches_total = 0
+        self.kv_pool_in_place_total = 0
 
     # -- page accounting ------------------------------------------------
 
@@ -459,6 +466,17 @@ class PagedSlotKVManager:
         """The live main page pool's leaves (None before the first
         page write shaped it)."""
         return self._pool
+
+    @property
+    def kv_pool_bytes(self) -> int:
+        return sum(leaf.nbytes
+                   for pool in (self._pool, self._draft_pool)
+                   for leaf in pool or () if leaf is not None)
+
+    def pool_lost(self) -> bool:
+        """Never: no program consumes the page pool (see
+        SlotKVManager.pool_lost)."""
+        return False
 
     def page_stats(self) -> Dict[str, int]:
         with self._page_lock:
@@ -852,6 +870,7 @@ class PagedSlotKVManager:
             else:
                 self._pool = self._insert_fn(P, False)(
                     self._pool, cache, jnp.asarray(tg))
+            self.kv_pool_dispatches_total += 1
 
     def insert(self, slot: int, cache, first_token: int,
                position: int, *, base_key=None, next_index: int = 1,
@@ -1139,7 +1158,8 @@ class PagedSlotKVManager:
             body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
-            outs, stacked = body(stacked, toks, positions, *extra)
+            outs, stacked = body(stacked, window, toks, positions,
+                                 *extra)
             pool = self._scatter_dirty(pool, metas, stacked, tables,
                                        d0, n_dirty)
             return outs, pool
@@ -1153,12 +1173,14 @@ class PagedSlotKVManager:
         return jit_over(self.variables, step, in_shardings=in_sh,
                         out_shardings=(rep, self._pool_sh))
 
-    def step(self, window: int = 1, sampled: bool = False
-             ) -> np.ndarray:
+    def step(self, window: int = 1, sampled: bool = False,
+             cap: Optional[int] = None) -> np.ndarray:
         """``window`` fused decode steps across the whole pool — the
         paged twin of SlotKVManager.step: gather views, run the SAME
         decode body, scatter dirty pages.  One compiled program per
-        (window, sampled, pages-per-slot pad class)."""
+        (window, sampled, pages-per-slot pad class): the dirty-page
+        bound is the window's, so ``cap`` (SlotKVManager.step) buys
+        nothing here and is not used."""
         import jax
         import jax.numpy as jnp
 
@@ -1192,6 +1214,7 @@ class PagedSlotKVManager:
                         jnp.asarray(self.top_ps)]
             with span("ptpu/enqueue", host_s):
                 outs, self._pool = fn(self._pool, *operands)
+                self.kv_pool_dispatches_total += 1
             # Sync inside the marker so it spans the device
             # execution, not just the async enqueue (see slots.py).
             with span("ptpu/sync", host_s):
@@ -1282,6 +1305,7 @@ class PagedSlotKVManager:
             with span("ptpu/enqueue", host_s):
                 outs, cs, ms, self._pool, self._draft_pool = fn(
                     self._pool, self._draft_pool, *operands)
+                self.kv_pool_dispatches_total += 1
             # Sync inside the marker — see the plain step.
             with span("ptpu/sync", host_s):
                 outs = np.asarray(jax.device_get(outs))

@@ -3118,6 +3118,9 @@ class ModelServer:
                     "admitted_greedy_total", "admitted_sampled_total",
                     "admitted_spec_total",
                     "evicted_total", "decode_steps_total",
+                    "kv_pool_dispatches_total",
+                    "kv_pool_in_place_total", "kv_pool_bytes",
+                    "kv_pool_lost_total",
                     "prefill_chunks_total", "completed_total",
                     "completed_greedy_total",
                     "completed_sampled_total",
@@ -3389,6 +3392,17 @@ class ModelServer:
                 "# TYPE ptpu_serving_decode_steps_total counter",
                 f"ptpu_serving_decode_steps_total "
                 f"{es['decode_steps_total']}",
+                "# TYPE ptpu_serving_kv_pool_dispatches_total counter",
+                f"ptpu_serving_kv_pool_dispatches_total "
+                f"{es['kv_pool_dispatches_total']}",
+                "# TYPE ptpu_serving_kv_pool_in_place_total counter",
+                f"ptpu_serving_kv_pool_in_place_total "
+                f"{es['kv_pool_in_place_total']}",
+                "# TYPE ptpu_serving_kv_pool_bytes gauge",
+                f"ptpu_serving_kv_pool_bytes {es['kv_pool_bytes']}",
+                "# TYPE ptpu_serving_kv_pool_lost_total counter",
+                f"ptpu_serving_kv_pool_lost_total "
+                f"{es['kv_pool_lost_total']}",
                 "# TYPE ptpu_serving_prefill_chunks_total counter",
                 f"ptpu_serving_prefill_chunks_total "
                 f"{es['prefill_chunks_total']}",

@@ -33,15 +33,17 @@ Device programs, compiled once each per model:
 - ``step``:   [S]-stacked cache + toks [S] + positions [S]
               -> next tokens [W, S] + updated stacked cache,
               for a WINDOW of W decode steps fused into one program
-              (``lax.scan`` over the vmapped one-token body; one
-              compiled program per power-of-two W, so a window costs
-              one dispatch + one host sync instead of W — the
+              (a loop over the vmapped one-token body, so a window
+              costs one dispatch + one host sync instead of W — the
               engine picks W so scheduling granularity is never
-              sacrificed, see engine._pick_window).  Two variants per
-              window: the pure-greedy body (argmax only — what an
-              all-greedy pool runs, unchanged from before sampling
-              support), and the SAMPLED body, selected whenever any
-              resident stream samples: every slot additionally
+              sacrificed, see engine._pick_window).  The step count
+              is an OPERAND: one compiled program per capacity (the
+              engine's ``decode_window``) runs every window up to
+              it.  Two variants: the pure-greedy body (argmax only —
+              what an all-greedy pool runs, unchanged from before
+              sampling support), and the SAMPLED body, selected
+              whenever any resident stream samples: every slot
+              additionally
               carries (base PRNG key, next token index, temperature,
               top_k, top_p) and draws its token with
               ``fold_in(base_key, index)`` through the shared
@@ -54,6 +56,21 @@ Device programs, compiled once each per model:
               index is traced, so one program serves every slot)
 - the prefill/extend programs live in engine.py (they are keyed by
   chunk length, not slot count)
+
+THE POOL IS UPDATED IN PLACE.  Every program that takes the pool
+(``step``, the speculative step's target and draft pools, ``insert``)
+takes it as a DONATED argument and returns its successor in the same
+buffers: a decode step writes one row a slot and layer
+(models/kv_cache.py, the carried layer loop of models/scan_stack.py),
+an insertion one lane.  The manager rebinds ``_stacked`` to the
+output at once and nothing else may keep the old tree: after a
+dispatch its arrays are deleted.  A program that FAILS after it
+consumed the pool leaves ``_stacked`` deleted — ``pool_lost()`` says
+so, and the engine rebuilds the pool and requeues the residents
+(engine._dispatch_step); there is no second copy to fall back on.
+The pool's device layout is pinned row-major (``_pool_formats``), the
+layout the decode loop works in, so that no program converts the
+whole pool on its way in and out.
 
 Idle slots still step (the batch shape is fixed) — they decode garbage
 into their own cache, which the next ``insert`` overwrites wholesale.
@@ -123,11 +140,21 @@ def alloc_decode_state(mgr) -> None:
 
 
 def build_step_body(model, variables, window: int, sampled: bool):
-    """Unjitted ``window``-fused decode body over a stacked cache.
+    """Unjitted decode body over a stacked cache: up to ``window``
+    fused steps.
 
-    Plain: ``step(stacked, toks, positions) -> (outs [W, S], stacked)``.
-    Sampled: ``step(stacked, toks, positions, keys, idxs, temps, tks,
-    tps)`` with the same returns."""
+    Plain: ``step(stacked, steps, toks, positions) -> (outs [window,
+    S], stacked)``.  Sampled: ``step(stacked, steps, toks, positions,
+    keys, idxs, temps, tks, tps)`` with the same returns.
+
+    ``steps`` (at most ``window``) is how many steps run; the rows of
+    ``outs`` past it stay zero.  A Python int makes the loop one of
+    static length (the paged manager, one program a window); TRACED,
+    one program serves every window up to its capacity (the
+    fixed-lane manager): the body of a step is the same program text
+    whatever the count, and a program that takes the pool is compiled
+    anew in every process (``SlotKVManager._compiling``), so each one
+    not built is seconds of a server's start."""
     import jax
     import jax.numpy as jnp
 
@@ -143,23 +170,26 @@ def build_step_body(model, variables, window: int, sampled: bool):
             mutable=["cache"])
         return G.extract_logits(out)[:, -1][0], mut["cache"]  # [V]
 
+    def no_tokens(toks):
+        return jnp.zeros((window,) + toks.shape, jnp.int32)
+
     if not sampled:
-        # The pure-greedy body — byte-for-byte the pre-sampling
-        # program, so all-greedy pools never pay the sampler's
-        # sort/cumsum and greedy-only servers compile nothing new.
+        # The pure-greedy body: all-greedy pools never pay the
+        # sampler's threshold searches and greedy-only servers
+        # compile nothing of it.
         def one(cache, tok, pos):
             logits, cache = logits_for(cache, tok, pos)
             nxt = jnp.argmax(logits).astype(jnp.int32)  # greedy
             return nxt, cache
 
-        def step(stacked, toks, positions):
-            def body(carry, _):
-                cache, tok, pos = carry
+        def step(stacked, steps, toks, positions):
+            def body(i, carry):
+                cache, tok, pos, outs = carry
                 nxt, cache = jax.vmap(one)(cache, tok, pos)
-                return (cache, nxt, pos + 1), nxt
-            (cache, _, _), outs = jax.lax.scan(
-                body, (stacked, toks, positions), None,
-                length=window)
+                return cache, nxt, pos + 1, outs.at[i].set(nxt)
+            cache, _, _, outs = jax.lax.fori_loop(
+                0, steps, body,
+                (stacked, toks, positions, no_tokens(toks)))
             return outs, cache                          # [W, S]
 
         return step
@@ -174,16 +204,16 @@ def build_step_body(model, variables, window: int, sampled: bool):
                                        tk, tp)
         return nxt, cache
 
-    def step_sampled(stacked, toks, positions, keys, idxs,
+    def step_sampled(stacked, steps, toks, positions, keys, idxs,
                      temps, tks, tps):
-        def body(carry, _):
-            cache, tok, pos, idx = carry
+        def body(i, carry):
+            cache, tok, pos, idx, outs = carry
             nxt, cache = jax.vmap(one_sampled)(
                 cache, tok, pos, keys, idx, temps, tks, tps)
-            return (cache, nxt, pos + 1, idx + 1), nxt
-        (cache, _, _, _), outs = jax.lax.scan(
-            body, (stacked, toks, positions, idxs), None,
-            length=window)
+            return cache, nxt, pos + 1, idx + 1, outs.at[i].set(nxt)
+        cache, _, _, _, outs = jax.lax.fori_loop(
+            0, steps, body,
+            (stacked, toks, positions, idxs, no_tokens(toks)))
         return outs, cache                              # [W, S]
 
     return step_sampled
@@ -297,8 +327,14 @@ class SlotKVManager:
         # constraint mode — meshed output is token-bitwise-identical
         # to unmeshed (docs/SERVING.md "Meshed serving").
         self.mesh = mesh
-        self._cache_sh = None         # stacked-pool shardings pytree
+        self._cache_sh = None         # stacked-pool formats pytree
         self._draft_cache_sh = None
+        # Whether the pinned row-major layout differs from the
+        # device's default for these leaves (read off the first
+        # prefilled cache): the pool's programs are then compiled in
+        # this process, never read from the persistent cache
+        # (config.fresh_compile says why).
+        self._pin_is_default = True
         # Recompile sentinel (analysis/recompile.py): every step/
         # insert program build is a counted compile-cache miss, so a
         # steady-state recompile storm (an unbounded key leaking into
@@ -324,6 +360,13 @@ class SlotKVManager:
         # Seconds in the step's host sections (spans.span) since the
         # engine last took them for a step record.
         self.host_s = {}
+        # Whether the pool is updated in place, counted where it can
+        # be seen: programs that took the pool (decode dispatches and
+        # insertions) and how many of them consumed the tree they
+        # were handed (``is_deleted()`` of one of its leaves, a host
+        # check).  Engine stats / /info, with ``kv_pool_bytes``.
+        self.kv_pool_dispatches_total = 0
+        self.kv_pool_in_place_total = 0
 
     # -- slot accounting ------------------------------------------------
 
@@ -389,22 +432,98 @@ class SlotKVManager:
 
     def kv_pool(self):
         """The live main KV pool pytree (None before the first
-        prefill shaped it)."""
+        prefill shaped it).  The next dispatch or insertion CONSUMES
+        it: read it under the engine's ``device_lock`` and keep no
+        reference past the lock."""
         return self._stacked
 
+    @property
+    def kv_pool_bytes(self) -> int:
+        """The live pools' logical bytes (shapes alone: safe to read
+        while a dispatch consumes the tree)."""
+        import jax
+
+        return sum(leaf.nbytes
+                   for pool in (self._stacked, self._draft_stacked)
+                   for leaf in jax.tree.leaves(pool))
+
+    def pool_lost(self) -> bool:
+        """Whether a program consumed the pool and failed before it
+        handed back the successor: the live tree then holds deleted
+        arrays and must never be dispatched again (the engine
+        rebuilds it, engine._dispatch_step)."""
+        import jax
+
+        return any(leaf.is_deleted()
+                   for pool in (self._stacked, self._draft_stacked)
+                   for leaf in jax.tree.leaves(pool))
+
+    def _count_dispatch(self, *taken) -> None:
+        """One program took the pool(s) whose probe leaves are
+        ``taken``: count it, and count it in place if every one came
+        back consumed."""
+        self.kv_pool_dispatches_total += 1
+        self.kv_pool_in_place_total += all(
+            leaf.is_deleted() for leaf in taken)
+
+    def _pool_formats(self, shapes):
+        """The device format of every pool leaf: row-major, on the
+        mesh's shardings or on the default device.  Row-major is the
+        layout the decode loop works in (a row written at a traced
+        position wants the position axis outside the tiled minor
+        two).  Left to itself the TPU gives a ``[..., positions,
+        heads, 64]`` array a position-minor layout at rest, and every
+        program that took the pool converted all of it on the way in
+        and again on the way out (PERF.md section 6, PR 28).  Every
+        program pins its pool arguments and results to these formats,
+        so a mismatch is an error at the call, never a silent copy."""
+        import jax
+        from jax.experimental.layout import Format, Layout
+        from jax.sharding import SingleDeviceSharding
+
+        if self.mesh is not None:
+            sh = self.mesh.cache_shardings(shapes, slot_axis=True)
+        else:
+            one = SingleDeviceSharding(jax.devices()[0])
+            sh = jax.tree.map(lambda _: one, shapes)
+        return jax.tree.map(
+            lambda l, s: Format(
+                Layout(major_to_minor=tuple(range(l.ndim))), s),
+            shapes, sh)
+
+    def _compiling(self, new: bool):
+        """Context for the call of a pool program: its FIRST call
+        compiles, outside the persistent cache where the pinned
+        layout is not the device's default."""
+        from ..config import fresh_compile
+
+        return fresh_compile() if new and not self._pin_is_default \
+            else contextlib.nullcontext()
+
     def _alloc_stacked(self, template_cache):
-        """Zero-init the [S, ...] pool; meshed pools are committed to
-        their NamedShardings at birth (heads over tp, slots over dp)."""
+        """Zero-init the [S, ...] pool in its pinned formats (meshed:
+        heads over tp, slots over dp, from birth).  Returns the pool
+        and its formats."""
         import jax
         import jax.numpy as jnp
 
-        stacked = jax.tree.map(
-            lambda l: jnp.zeros((self.n_slots,) + l.shape, l.dtype),
-            template_cache)
-        if self.mesh is not None:
-            sh = self.mesh.cache_shardings(stacked, slot_axis=True)
-            return self.mesh.place_cache(stacked, slot_axis=True), sh
-        return stacked, None
+        # A prefilled cache rests in the device's default layout:
+        # row-major on a CPU, position-minor on a TPU for a head
+        # dimension under 128 lanes.
+        self._pin_is_default = self._pin_is_default and all(
+            tuple(l.format.layout.major_to_minor) == tuple(range(l.ndim))
+            for l in jax.tree.leaves(template_cache)
+            if isinstance(l, jax.Array))
+        shapes = jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(
+                (self.n_slots,) + l.shape, l.dtype), template_cache)
+        fmt = self._pool_formats(shapes)
+        with self._exact(), self._compiling(True):
+            stacked = jax.jit(
+                lambda: jax.tree.map(
+                    lambda l: jnp.zeros(l.shape, l.dtype), shapes),
+                out_shardings=fmt)()
+        return stacked, fmt
 
     def _ensure_stacked(self, template_cache) -> None:
         """Allocate the stacked pool lazily from the FIRST prefilled
@@ -440,15 +559,19 @@ class SlotKVManager:
         Speculative streams pass ``draft_cache`` (the DRAFT model's
         prefill of the same prompt) and ``spec_k`` > 0; the spec step
         program drafts/verifies/commits up to ``spec_k`` tokens per
-        round for this slot."""
+        round for this slot.
+
+        The pool is DONATED to the insertion program, which writes
+        the one lane in place and returns the same buffers: the tree
+        ``_stacked`` named before the call is deleted by it."""
         self._ensure_stacked(cache)
         with self._exact():
-            self._stacked = self._get_insert_fn(False)(
-                self._stacked, cache, slot)
+            self._stacked = self._insert_into(
+                self._stacked, cache, slot, False)
             if draft_cache is not None:
                 self._ensure_draft_stacked(draft_cache)
-                self._draft_stacked = self._get_insert_fn(True)(
-                    self._draft_stacked, draft_cache, slot)
+                self._draft_stacked = self._insert_into(
+                    self._draft_stacked, draft_cache, slot, True)
         self.tokens[slot] = first_token
         self.positions[slot] = position
         if base_key is not None:
@@ -461,17 +584,27 @@ class SlotKVManager:
         self.top_ps[slot] = top_p
         self.spec_ks[slot] = spec_k
 
-    def _get_insert_fn(self, draft: bool):
-        """Jitted slot insert for the target (or draft) pool.  One
-        program per pool: meshed pools pin EXPLICIT in/out shardings
-        so the write keeps the pool committed to its layout — an
-        XLA-chosen output sharding drifting to replicated would force
-        a reshard on every subsequent step."""
+    def _insert_into(self, stacked, one, slot: int, draft: bool):
+        """``stacked`` with the B=1 cache ``one`` written into
+        ``slot``: the jitted slot insert for the target (or draft)
+        pool.  One program per pool, the pool DONATED and pinned to
+        its formats on the way in and out: the write lands in place,
+        one lane, and the pool stays committed to its layout and
+        (meshed) sharding — an XLA-chosen output sharding drifting to
+        replicated would force a reshard on every subsequent step."""
         import jax
 
+        taken = jax.tree.leaves(stacked)[0]
         fn = self._insert_fns.get(draft)
-        if fn is not None:
-            return fn
+        with self._compiling(fn is None):
+            stacked = (fn or self._build_insert(draft))(
+                stacked, one, slot)
+        self._count_dispatch(taken)
+        return stacked
+
+    def _build_insert(self, draft: bool):
+        import jax
+
         if self.sentinel is not None:
             self.sentinel.miss("slot_insert",
                                "draft" if draft else "target")
@@ -481,12 +614,9 @@ class SlotKVManager:
                 lambda s, n: jax.lax.dynamic_update_index_in_dim(
                     s, n.astype(s.dtype), idx, 0), stacked, one)
 
-        if self.mesh is not None:
-            sh = self._draft_cache_sh if draft else self._cache_sh
-            fn = jax.jit(_insert, in_shardings=(sh, None, None),
-                         out_shardings=sh)
-        else:
-            fn = jax.jit(_insert)
+        sh = self._draft_cache_sh if draft else self._cache_sh
+        fn = jax.jit(_insert, in_shardings=(sh, None, None),
+                     out_shardings=sh, donate_argnums=(0,))
         self._insert_fns[draft] = fn
         return fn
 
@@ -500,49 +630,66 @@ class SlotKVManager:
             return build_step_body(model, variables, window,
                                    sampled)(*operands)
 
-        if self.mesh is None:
-            return jit_over(self.variables, program)
-        # Explicit in/out shardings: the cache stays pinned to its
-        # (heads-over-tp, slots-over-dp) layout across steps, host
+        # The pool (argument 1: jit_over binds the weights as 0) is
+        # DONATED and pinned to its formats in and out.  Meshed, the
+        # weights keep the shardings they were placed with, host
         # operands (tokens/positions/sampling state) commit
         # replicated, and token outputs gather back replicated.
-        rep = self.mesh.replicated
-        n_extra = 5 if sampled else 0
-        in_sh = (self.mesh.shardings_of(self.variables),
-                 self._cache_sh, rep, rep) + (rep,) * n_extra
-        return jit_over(self.variables, program, in_shardings=in_sh,
-                        out_shardings=(rep, self._cache_sh))
+        n_host = 8 if sampled else 3       # steps, tokens, positions
+        rep, w_sh = None, None
+        if self.mesh is not None:
+            rep = self.mesh.replicated
+            w_sh = self.mesh.shardings_of(self.variables)
+        return jit_over(
+            self.variables, program, donate_argnums=(1,),
+            in_shardings=(w_sh, self._cache_sh) + (rep,) * n_host,
+            out_shardings=(rep, self._cache_sh))
 
-    def step(self, window: int = 1, sampled: bool = False
-             ) -> np.ndarray:
+    def step(self, window: int = 1, sampled: bool = False,
+             cap: Optional[int] = None) -> np.ndarray:
         """``window`` fused decode steps across the whole pool;
         returns the next tokens [window, S] (garbage for idle slots
         — the caller masks by occupancy).  Token selection (greedy
         argmax, or the position-keyed per-slot sampler when
-        ``sampled``) and the token feedback run inside one scanned
+        ``sampled``) and the token feedback run inside one looped
         program, so a window costs ONE dispatch + ONE host round-trip
         regardless of its length; the caller (engine._decode_step)
         passes ``sampled`` iff any resident stream samples, and
         engine._pick_window sizes the window so no admission or
-        budget-eviction boundary lands inside it."""
+        budget-eviction boundary lands inside it.
+
+        ``cap``: the widest window the caller will ever ask for (the
+        engine's ``decode_window``).  The program is built for that
+        capacity and takes ``window`` as an operand, so one program a
+        variant serves every window; without it the capacity is this
+        call's window.
+
+        The pool is DONATED: the program writes each slot's new rows
+        into the buffers it was handed and returns them, and the tree
+        ``_stacked`` named before the call is deleted by it.  If the
+        program fails after that, ``pool_lost()`` is true and the
+        pool has to be rebuilt (engine._dispatch_step)."""
         import jax
         import jax.numpy as jnp
 
         if self._stacked is None:
             raise RuntimeError("step() before any insert()")
-        fn = self._step_fns.get((window, sampled))
-        if fn is None:
+        cap = max(cap or window, window)
+        fn = self._step_fns.get((cap, sampled))
+        new = fn is None
+        if new:
             if self.sentinel is not None:
-                self.sentinel.miss("slot_step", (window, sampled))
-            fn = self._step_fns[(window, sampled)] = \
-                self._build_step(window, sampled)
+                self.sentinel.miss("slot_step", (cap, sampled))
+            fn = self._step_fns[(cap, sampled)] = \
+                self._build_step(cap, sampled)
         elif self.sentinel is not None:
-            self.sentinel.hit("slot_step", (window, sampled))
+            self.sentinel.hit("slot_step", (cap, sampled))
         host_s = self.host_s
         t0 = time.perf_counter()
         with self._exact(), step_annotation(window=window):
             with span("ptpu/upload", host_s):
-                operands = [jnp.asarray(self.tokens),
+                operands = [jnp.asarray(window, jnp.int32),
+                            jnp.asarray(self.tokens),
                             jnp.asarray(self.positions)]
                 if sampled:
                     operands += [
@@ -551,15 +698,17 @@ class SlotKVManager:
                         jnp.asarray(self.temps),
                         jnp.asarray(self.top_ks),
                         jnp.asarray(self.top_ps)]
-            with span("ptpu/enqueue", host_s):
+            with span("ptpu/enqueue", host_s), self._compiling(new):
+                taken = jax.tree.leaves(self._stacked)[0]
                 outs, self._stacked = fn(self._stacked, *operands)
+                self._count_dispatch(taken)
             # The sync stays INSIDE the marker: dispatch returns
             # device futures, so a marker closing here-minus-one-line
             # would span only the host enqueue and the attribution
             # window would clip the step's actual device execution
             # (inflating MFU by ~K/(K-1) on a real async backend).
             with span("ptpu/sync", host_s):
-                outs = np.asarray(jax.device_get(outs))
+                outs = np.asarray(jax.device_get(outs))[:window]
         self.last_step_device_s = time.perf_counter() - t0
         # Arm the next step: every slot feeds back its own last token
         # at the next position (and, for sampled slots, the next
@@ -608,14 +757,17 @@ class SlotKVManager:
                 model, weights[0], draft, weights[1], window,
                 K)(*operands)
 
-        if self.mesh is None:
-            return jit_over(weights, program)
-        rep = self.mesh.replicated
-        in_sh = (self.mesh.shardings_of(weights), self._cache_sh,
-                 self._draft_cache_sh) + (rep,) * 8
-        return jit_over(weights, program, in_shardings=in_sh,
-                        out_shardings=(rep, rep, rep, self._cache_sh,
-                                       self._draft_cache_sh))
+        # Both pools (arguments 1 and 2) donated and pinned, as in
+        # the plain step.
+        rep, w_sh = None, None
+        if self.mesh is not None:
+            rep = self.mesh.replicated
+            w_sh = self.mesh.shardings_of(weights)
+        pools = (self._cache_sh, self._draft_cache_sh)
+        return jit_over(
+            weights, program, donate_argnums=(1, 2),
+            in_shardings=(w_sh,) + pools + (rep,) * 8,
+            out_shardings=(rep, rep, rep) + pools)
 
     def step_spec(self, window: int, K: int):
         """``window`` fused SPECULATIVE rounds across the whole pool.
@@ -635,7 +787,8 @@ class SlotKVManager:
             raise RuntimeError("step_spec() before a speculative "
                                "insert()")
         fn = self._step_fns.get((window, "spec", K))
-        if fn is None:
+        new = fn is None
+        if new:
             if self.sentinel is not None:
                 self.sentinel.miss("slot_step", (window, "spec", K))
             fn = self._step_fns[(window, "spec", K)] = \
@@ -651,9 +804,12 @@ class SlotKVManager:
                     jnp.asarray(self.next_index), jnp.asarray(self.keys),
                     jnp.asarray(self.temps), jnp.asarray(self.top_ks),
                     jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks)]
-            with span("ptpu/enqueue", host_s):
+            with span("ptpu/enqueue", host_s), self._compiling(new):
+                taken = (jax.tree.leaves(self._stacked)[0],
+                         jax.tree.leaves(self._draft_stacked)[0])
                 outs, cs, ms, self._stacked, self._draft_stacked = fn(
                     self._stacked, self._draft_stacked, *operands)
+                self._count_dispatch(*taken)
             # Sync inside the marker — see the plain step.
             with span("ptpu/sync", host_s):
                 outs = np.asarray(jax.device_get(outs))

@@ -405,7 +405,7 @@ class TestWeightsAreProgramArguments:
             mgr = SlotKVManager(model, variables, n)
             mgr._ensure_stacked(cache)
             fn = mgr._build_step(2, sampled)
-            operands = (mgr.kv_pool(), vec, vec, *extra)
+            operands = (mgr.kv_pool(), np.int32(2), vec, vec, *extra)
         assert not self._weights_inside(fn, *operands)
 
     @pytest.mark.parametrize("kind", ["engine-prefill", "engine-extend",

@@ -178,12 +178,62 @@ def test_engine_decode_window_compiles(one_chip, on_tpu, sampled):
         return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
                                     sharding=one_chip)
 
-    operands = [pool, vec(jnp.int32), vec(jnp.int32)]
+    steps = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    operands = [pool, steps, vec(jnp.int32), vec(jnp.int32)]
     if sampled:
         operands += [vec(jnp.uint32, 2), vec(jnp.int32),
                      vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
     compiled = jax.jit(program).lower(variables, *operands).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
+                                                 monkeypatch, sampled):
+    """The window program as the slot manager builds it — pool
+    donated, pinned row-major in and out: the v5e compiler aliases
+    every pool leaf to an output, keeps the pool in the one layout
+    from parameter to result, and copies nothing of its size (left to
+    its default layout the pool rests position-minor, and the program
+    converts all of it on entry and again on exit)."""
+    import re
+
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, pool = _serving_shapes(one_chip)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, SLOTS)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, sampled)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32)]
+    if sampled:
+        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
+                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    compiled = fn.func.lower(*fn.args, pool, *operands).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(l.size * l.dtype.itemsize
+                     for l in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    kv = "bf16[%s]" % ",".join(
+        str(d) for d in jax.tree.leaves(pool)[-1].shape)
+    row_major = kv + "{5,4,3,2,1,0"
+    params = [l for l in text.splitlines()
+              if re.search(r"= %s\S* parameter\(\d+\), sharding"
+                           % re.escape(kv), l)]
+    assert len(params) == 2 and all(row_major in l for l in params)
+    assert not re.search(r"= %s\S* copy\(" % re.escape(kv), text)
+    # one padded pool in the arguments, no second one among the
+    # temporaries
+    assert mem.temp_size_in_bytes < pool_bytes
 
 
 def test_meshed_decode_window_compiles(topo, on_tpu):
@@ -216,9 +266,11 @@ def test_meshed_decode_window_compiles(topo, on_tpu):
         compiled = jax.jit(
             program,
             in_shardings=(mesh.param_shardings(variables), pool_sh,
-                          rep, rep),
+                          rep, rep, rep),
             out_shardings=(rep, pool_sh),
-        ).lower(variables, pool, slots, slots).compile()
+        ).lower(variables, pool,
+                jax.ShapeDtypeStruct((), jnp.int32), slots,
+                slots).compile()
     text = compiled.as_text()
     assert "all-gather(" in text and "all-reduce(" not in text
     kv = [(l.shape, sh.shard_shape(l.shape)) for l, sh in zip(

@@ -659,7 +659,12 @@ class TestFleetForensics:
                   and ("ptpu_serving_request_latency_seconds_bucket"
                        in ln)]
         assert m, "no exemplar-bearing total-latency bucket lines"
-        rid = m[-1].split('trace_id="')[1].split('"')[0]
+        # The LOWEST occupied bucket's exemplar: a steady request.
+        # The highest holds a replica's first request, whose time is
+        # compilation in prefill and decode alike, and which of the
+        # two is longer there turns on the machine's load (the decode
+        # side is one program since the step count is an operand).
+        rid = m[0].split('trace_id="')[1].split('"')[0]
         # replica-side rid is router-prefixed ("r0-<rid>"); the bare
         # id is the router-visible handle for the stitched view
         _, bare = parse_replica_rid(rid)
