@@ -861,6 +861,11 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
     model, variables = _build_serving_model(
         model_name, 1, checkpoint, int8_kv, int8_weights,
         kv_ring=kv_ring, kv_ring_slack=kv_ring_slack)
+    if (kv_paged or mesh_spec is not None) and getattr(
+            getattr(model, "cfg", None), "kv_cache_mixed", False):
+        from polyaxon_tpu.serving.slots import MIXED_CACHE_MSG
+
+        raise click.ClickException(f"{model_name}: {MIXED_CACHE_MSG}")
     draft = draft_vars = None
     if draft_model:
         # The draft mirrors the target's cache mode: a standard-cache

@@ -58,6 +58,25 @@ def jit_over(weights, fn, **jit_kw):
     return functools.partial(jax.jit(fn, **jit_kw), weights)
 
 
+# Collection a model may sow per-apply counts into when the caller
+# makes it mutable (the expert layers' token-expert pairs,
+# models/afmoe.py): the serving programs do, and hand the sums out
+# beside their results.
+STATS = "stats"
+
+
+def stats_total(stats):
+    """What one apply sowed under :data:`STATS`, summed over the layers
+    that sowed: one vector (for the expert layers ``[pairs on each held
+    expert ..., pairs routed]``), or None for a model that sows nothing.
+    Leading axes (a vmapped step: a lane a slot) are summed away."""
+    leaves = jax.tree.leaves(stats)
+    if not leaves:
+        return None
+    return sum(leaf.reshape(-1, leaf.shape[-1]).sum(axis=0)
+               for leaf in leaves)
+
+
 def init_cache(model, batch_size: int):
     """Allocate the stacked per-layer KV cache for a DECODER-ONLY
     ``model``, all zeros with cache_index 0.  (Abstract init only:
@@ -650,7 +669,7 @@ def generate(model, variables, prompt, *, max_new_tokens: int,
 
 
 def prefill(model, variables, prompt, *, chunk: Optional[int] = None,
-            cache=None, position: int = 0):
+            cache=None, position: int = 0, with_stats: bool = False):
     """Fill — or EXTEND — a decode cache with ``prompt`` tokens.
 
     With ``cache=None`` this is the standalone prefill: a fresh cache
@@ -665,11 +684,30 @@ def prefill(model, variables, prompt, *, chunk: Optional[int] = None,
     prefix and pay only for the suffix.
 
     Returns ``(last_position_logits [B, V], cache)`` — feed both to
-    :func:`generate_continue`.
+    :func:`generate_continue` — and with ``with_stats`` a third:
+    :func:`stats_total` of what the model sowed over these tokens
+    (None for a model that sows nothing).
     """
     prompt = jnp.asarray(prompt, jnp.int32)
-    return _prefill(model, variables, prompt, chunk=chunk,
-                    cache=cache, position=position)
+    return _prefill(model, variables, prompt, chunk=chunk, cache=cache,
+                    position=position, with_stats=with_stats)
+
+
+def prefill_programs(model, chunk: Optional[int] = None):
+    """``(ptpu_prefill(w, toks), ptpu_extend(w, cache, toks, pos))``:
+    :func:`prefill` into a fresh cache and onto an existing one, each
+    returning ``(logits, cache, stats)``, as functions to jit over the
+    weights.  NAMED, so that a device trace tells a server's prefill
+    programs (``jit_ptpu_prefill``, ``jit_ptpu_extend``) from its
+    decode program."""
+    def ptpu_prefill(w, toks):
+        return prefill(model, w, toks, chunk=chunk, with_stats=True)
+
+    def ptpu_extend(w, cache, toks, pos):
+        return prefill(model, w, toks, chunk=chunk, cache=cache,
+                       position=pos, with_stats=True)
+
+    return ptpu_prefill, ptpu_extend
 
 
 def generate_continue(model, variables, cache, last_logits,
@@ -781,7 +819,7 @@ def generate_seq2seq(model, variables, enc_tokens, *,
 
 
 def _prefill(model, variables, prompt, chunk: Optional[int] = None,
-             cache=None, position: int = 0):
+             cache=None, position: int = 0, with_stats: bool = False):
     """Prefill shared by generate / generate_beam /
     generate_speculative; returns (last-position logits [B, V], cache).
 
@@ -796,6 +834,9 @@ def _prefill(model, variables, prompt, chunk: Optional[int] = None,
     creating one (the public :func:`prefill` surface) — the appends
     start at ``position``, so the result equals one prefill of the
     concatenated tokens.
+
+    ``with_stats``: return a third value, :func:`stats_total` of what
+    the model sowed under :data:`STATS` over the whole prompt.
     """
     if chunk is not None and chunk < 1:
         raise ValueError(f"prefill_chunk must be >= 1; got {chunk}")
@@ -822,20 +863,28 @@ def _prefill(model, variables, prompt, chunk: Optional[int] = None,
         out, mut = model.apply(
             {"params": _params(variables), "cache": cache},
             toks, decode=True, decode_position=pos, last_only=True,
-            mutable=["cache"])
-        return extract_logits(out)[:, -1], mut["cache"]
+            mutable=["cache", STATS] if with_stats else ["cache"])
+        return (extract_logits(out)[:, -1], mut["cache"],
+                stats_total(mut.get(STATS)))
+
+    def done(logits, cache, *stats):
+        if not with_stats:
+            return logits, cache
+        stats = [s for s in stats if s is not None]
+        return logits, cache, sum(stats) if stats else None
 
     if not chunk or p_len <= chunk:
-        return apply_chunk(cache, prompt, position)
+        return done(*apply_chunk(cache, prompt, position))
 
     n_full, rem = divmod(p_len, chunk)
 
     def chunk_step(carry, toks):
         cache, pos = carry
-        _, cache = apply_chunk(cache, toks, pos)
-        return (cache, pos + chunk), None
+        _, cache, stats = apply_chunk(cache, toks, pos)
+        return (cache, pos + chunk), stats
 
     pos = jnp.array(position, jnp.int32)
+    scanned = None
     if n_full > 1:
         # All but the last full chunk run through the scan emitting
         # NOTHING — stacking per-chunk logits would add n_full x B x
@@ -844,14 +893,18 @@ def _prefill(model, variables, prompt, chunk: Optional[int] = None,
         # materialized.
         head = prompt[:, :(n_full - 1) * chunk].reshape(
             b, n_full - 1, chunk).swapaxes(0, 1)  # [n-1, B, chunk]
-        (cache, pos), _ = jax.lax.scan(chunk_step, (cache, pos), head)
-    logits, cache = apply_chunk(
+        (cache, pos), scanned = jax.lax.scan(chunk_step, (cache, pos),
+                                             head)
+        if scanned is not None:
+            scanned = scanned.sum(axis=0)
+    logits, cache, last = apply_chunk(
         cache, prompt[:, (n_full - 1) * chunk:n_full * chunk], pos)
     pos = pos + chunk
+    tail = None
     if rem:
-        logits, cache = apply_chunk(cache, prompt[:, n_full * chunk:],
-                                    pos)
-    return logits, cache
+        logits, cache, tail = apply_chunk(
+            cache, prompt[:, n_full * chunk:], pos)
+    return done(logits, cache, scanned, last, tail)
 
 
 def _rollback_cache(cache, new_index):

@@ -108,7 +108,9 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     scattered into the carried stack, its plane read once.
 
     Returns ``(k_full, v_full, mask, positions)`` shaped like
-    :func:`append_kv_cache` but with key axis ``capacity + S``.
+    :func:`append_kv_cache` but with key axis ``capacity + S`` — or
+    ``capacity`` alone where the ring has room for the chunk (the
+    write-first branch).
     """
     b, s, h, d = k.shape
     idx = mod.variable("cache", "cache_index",
@@ -138,28 +140,74 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
                            (b, cap, h, 1), jnp.bfloat16)
         cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
-        out_dtype = k.dtype
-        k_old = _plane(ck, layer).astype(out_dtype) \
-            * _plane(cks, layer).astype(out_dtype)
-        v_old = _plane(cv, layer).astype(out_dtype) \
-            * _plane(cvs, layer).astype(out_dtype)
     else:
         kq, k_scale, vq, v_scale = k, None, v, None
-        k_old, v_old = _plane(ck, layer), _plane(cv, layer)
 
+    def ring():
+        """The ring's keys and values as they lie, dequantized."""
+        if not quantize:
+            return _plane(ck, layer), _plane(cv, layer)
+        return (_plane(ck, layer).astype(k.dtype)
+                * _plane(cks, layer).astype(k.dtype),
+                _plane(cv, layer).astype(k.dtype)
+                * _plane(cvs, layer).astype(k.dtype))
+
+    def written(pos):
+        """Which ring slots hold a position that was really written
+        there: position p lives in slot ``p % cap``.  A slot that was
+        never written holds -1 — or 0, in a cache made all zeros
+        (generate.init_cache), which only slot 0 may claim."""
+        return (pos >= 0) & (pos % cap == jnp.arange(cap))
+
+    def scatter(first: int, slots):
+        _scatter_rows(ck, layer, kq[:, first:], slots)
+        _scatter_rows(cv, layer, vq[:, first:], slots)
+        if quantize:
+            _scatter_rows(cks, layer, k_scale[:, first:], slots)
+            _scatter_rows(cvs, layer, v_scale[:, first:], slots)
+
+    if s <= cap - window:
+        # The ring has ROOM for the chunk: write its rows first, then
+        # hand the attention the ring itself — no concat(old_ring,
+        # chunk), which copied every window layer's ring once a decode
+        # step.  The slots the chunk takes held positions [idx - cap,
+        # idx + S - cap); its first query needs positions >= idx -
+        # window, so nothing a query of this chunk reads is lost iff
+        # S <= cap - window.  A decode step (S = 1) always has room; a
+        # prefill chunk has it where the ring was made with slack >=
+        # its length - 1.  Validity stays by ABSOLUTE position: a
+        # stale slot from a speculative rollback either is overwritten
+        # by this chunk (same position, same slot) or lies ahead of
+        # every query here.
+        scatter(0, pos_q % cap)
+        # The position table without a scatter: slot j takes the one
+        # position of the chunk that is j mod cap, if there is one.
+        # (``cpos0.at[slots].set(pos_q)`` on a cache made inside the
+        # program folds to a scatter whose indices and updates are one
+        # iota, and the v5e compiler aborts on it.)
+        ahead = (jnp.arange(cap) - idx0) % cap
+        pos_k = jnp.where(ahead < s, idx0 + ahead, cpos0)
+        _store(cpos, layer, pos_k)
+        _store(idx, layer, idx0 + s)
+        k_full, v_full = ring()
+        valid = (pos_k[None, :] <= pos_q[:, None]) & \
+            (pos_k[None, :] >= pos_q[:, None] - window) & \
+            written(pos_k)[None, :]
+        return k_full, v_full, valid[None, None], pos_q
+
+    k_old, v_old = ring()
     k_full = jnp.concatenate([k_old, k], axis=1)
     v_full = jnp.concatenate([v_old, v], axis=1)
     pos_k = jnp.concatenate([cpos0, pos_q])           # [cap + S]
     valid = (pos_k[None, :] <= pos_q[:, None]) & \
-        (pos_k[None, :] >= pos_q[:, None] - window) & \
-        (pos_k[None, :] >= 0)
+        (pos_k[None, :] >= pos_q[:, None] - window)
     # Ring entries must be strictly OLDER than this chunk's first
     # position: after a speculative rollback the ring still holds
     # REJECTED K/V at positions the chunk is now re-committing, and
     # the position test alone would admit both copies.  The chunk
     # carries its own entries for [idx, idx+S).
     ring_older = jnp.concatenate(
-        [cpos0 < idx0, jnp.ones((s,), bool)])
+        [(cpos0 < idx0) & written(cpos0), jnp.ones((s,), bool)])
     valid = valid & ring_older[None, :]
 
     # Scatter the chunk tail into the ring.  keep = min(S, cap) rows:
@@ -169,11 +217,7 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     keep = min(s, cap)
     tail_pos = pos_q[s - keep:]
     slots = tail_pos % cap
-    _scatter_rows(ck, layer, kq[:, s - keep:], slots)
-    _scatter_rows(cv, layer, vq[:, s - keep:], slots)
-    if quantize:
-        _scatter_rows(cks, layer, k_scale[:, s - keep:], slots)
-        _scatter_rows(cvs, layer, v_scale[:, s - keep:], slots)
+    scatter(s - keep, slots)
     _store(cpos, layer, cpos0.at[slots].set(tail_pos))
     _store(idx, layer, idx0 + s)
     return k_full, v_full, valid[None, None], pos_q

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from .afmoe import AfmoeConfig, AfmoeModel
 from .bert import BertConfig, BertModel
 from .convnet import ConvNet
 from .gpt2 import GPT2Config, GPT2Model
@@ -479,6 +480,26 @@ _register(ModelSpec(
                                       MoEGPTConfig.tiny().vocab_size),
     loss_fn=_moe_lm_loss,
     default_batch_size=8,
+))
+
+_register(ModelSpec(
+    name="afmoe-tiny",
+    make_model=_cfg_model(AfmoeModel, AfmoeConfig.tiny()),
+    make_batch=lambda b: _token_batch(b, 16,
+                                      AfmoeConfig.tiny().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=8,
+))
+
+# Served only (perfbench cell trinity-serve-mixed): the batch is what
+# ``init_params`` traces the parameter shapes with, nothing trains it.
+_register(ModelSpec(
+    name="trinity-large-ep8",
+    make_model=_cfg_model(AfmoeModel, AfmoeConfig.trinity_large_ep8()),
+    make_batch=lambda b: _token_batch(
+        b, 8, AfmoeConfig.trinity_large_ep8().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=1,
 ))
 
 

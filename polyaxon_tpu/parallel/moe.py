@@ -5,12 +5,22 @@ axis; tokens route to their top-1 expert with a capacity limit, travel via
 ``all_to_all`` (ICI), run the expert MLP, and return.  Dense einsum
 dispatch/combine keeps everything MXU-shaped (no dynamic gathers — XLA
 and the TPU both prefer the one-hot matmul form).
+
+Beside it, the token-choice layer of the served sparse decoders
+(``models/afmoe.py``): :func:`sigmoid_topk_route` scores every token
+over ALL experts, and :func:`held_experts_ffn` computes the part of the
+routed sum that the experts HELD here give — one chip's share of an
+expert-parallel deployment, told ``expert_offset`` and holding
+``E_h`` experts' weights.  No token routed to a held expert is dropped;
+what absent experts would add is left out, and nothing stands in for
+the absent chips or their exchange.
 """
 
 from __future__ import annotations
 
 import functools
 from typing import Callable
+
 
 import jax
 import jax.numpy as jnp
@@ -126,3 +136,102 @@ def moe_layer(
         out_specs=(P(batch, axis_name, None), P()),
         check_vma=False,
     )(x, router_w, expert_w1, expert_w2)
+
+
+# -- token-choice top-k over held experts -----------------------------------
+
+
+def sigmoid_topk_route(x, router_w, select_bias, k: int, *,
+                       scale: float = 1.0, normalize: bool = True):
+    """Token-choice routing by sigmoid scores: ``x`` [..., d] over
+    ``router_w`` [d, E] -> ``(chosen [..., k] int32, weights [..., k]
+    f32)``.
+
+    Scores are float32 at full matmul precision whatever ``x`` is kept
+    in: the top-k cut is a discontinuity, and a rounded score moves
+    tokens between experts.  ``select_bias`` [E] enters the CHOICE only
+    (``top_k(s + b)``); the weights are the chosen scores themselves,
+    normalised over the k (``+ 1e-20``) and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * scale
+
+
+def held_pair_counts(chosen, num_held: int, expert_offset: int = 0):
+    """Token-expert pairs that fall on each HELD expert: ``chosen``
+    [..., k] (ids over all experts) -> int32 [num_held].  Plain
+    elementwise code, so under ``vmap`` every lane counts its own."""
+    local = chosen.reshape(-1) - expert_offset
+    hit = local[:, None] == jnp.arange(num_held)[None, :]
+    return jnp.sum(hit, axis=0, dtype=jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_ffn(expert_offset: int):
+    """The grouped computation for one ``expert_offset``, flat over
+    tokens, with a batching rule that FLATTENS: a vmapped decode step
+    (serving/slots.py: one lane a slot, one token a lane) becomes ONE
+    grouped matmul over every slot's token, not a matmul a lane."""
+
+    @jax.custom_batching.custom_vmap
+    def ffn(x, chosen, weights, w_gate, w_up, w_down):
+        t, k = chosen.shape
+        held_n = w_gate.shape[0]
+        local = chosen.reshape(-1) - expert_offset          # [T*k]
+        held = (local >= 0) & (local < held_n)
+        key = jnp.where(held, local, held_n)    # absent experts sort last
+        order = jnp.argsort(key, stable=True)
+        sizes = held_pair_counts(chosen, held_n, expert_offset)
+        rows = x[order // k]                                # [T*k, d]
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
+        h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
+        out = dot(h.astype(x.dtype), w_down)                # [T*k, d]
+        # Rows past the last group belong to no held expert: whatever
+        # the grouped matmul left there must not reach the sum.
+        out = jnp.where((key[order] < held_n)[:, None], out, 0)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        out = out[back].reshape(t, k, -1)
+        w = jnp.where(held.reshape(t, k), weights, 0.0)
+        return jnp.einsum("tk,tkd->td", w, out.astype(jnp.float32))
+
+    @ffn.def_vmap
+    def _flatten(axis_size, in_batched, x, chosen, weights, *ws):
+        if any(in_batched[3:]) or not all(in_batched[:3]):
+            raise NotImplementedError(
+                "held_experts_ffn under vmap: tokens batched, expert "
+                "weights shared")
+        t = x.shape[1]
+        flat = lambda a: a.reshape((axis_size * t,) + a.shape[2:])  # noqa: E731
+        y = ffn(flat(x), flat(chosen), flat(weights), *ws)
+        return y.reshape(axis_size, t, -1), True
+
+    return ffn
+
+
+def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
+                     expert_offset: int = 0):
+    """The held experts' part of ``sum_e w_e SwiGLU_e(x)``.
+
+    ``x`` [T, d]; ``chosen``/``weights`` [T, k] from
+    :func:`sigmoid_topk_route` (ids over ALL experts); ``w_gate``,
+    ``w_up`` [E_h, d, f] and ``w_down`` [E_h, f, d] the weights of
+    experts ``[expert_offset, expert_offset + E_h)``.  Returns float32
+    [T, d].
+
+    One grouped computation serves a prefill chunk and a decode step:
+    the token-expert pairs are sorted by held expert (absent experts'
+    pairs last, outside every group), each expert's rows go through its
+    weights in ``jax.lax.ragged_dot`` — a grouped matmul that reads an
+    expert's weights once and touches no other token — and the rows
+    return to their tokens under the routing weights.  The row count is
+    the static ``T * k``, every pair's place should all of them fall
+    here, so no held pair is ever dropped; no ``[T, d, f]`` copy of
+    weights a token is made."""
+    return _grouped_ffn(int(expert_offset))(
+        x, chosen, weights, w_gate, w_up, w_down)

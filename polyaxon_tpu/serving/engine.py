@@ -68,7 +68,7 @@ from .scheduler import (AdmissionQueue, DeadlineExceeded, PRIORITIES,
                         RequestCancelled, RequestGroup, SamplingSpec,
                         SchedulerPolicy, ShedError, Stream,
                         terminal_status)
-from .slots import SlotKVManager
+from .slots import MIXED_CACHE_MSG, SlotKVManager
 from ..spans import span, take
 from .telemetry import ENGINE_PID, Histogram, Telemetry
 
@@ -148,6 +148,9 @@ class DecodeEngine:
         # lanes, so occupancy under mixed-length traffic is bounded
         # by token usage, not by the widest request.
         self.paged = bool(self.policy.kv_paged)
+        if getattr(getattr(model, "cfg", None), "kv_cache_mixed",
+                   False) and (self.paged or mesh is not None):
+            raise ValueError(MIXED_CACHE_MSG)
         if self.paged:
             from .paged import PagedSlotKVManager
 
@@ -218,6 +221,7 @@ class DecodeEngine:
         # (_recover_lost_pool).
         self.kv_pool_lost_total = 0
         self.prefill_chunks_total = 0
+        self.prefill_tokens_total = 0
         self.completed_total = 0
         self.completed_greedy_total = 0
         self.completed_sampled_total = 0
@@ -382,7 +386,8 @@ class DecodeEngine:
                rid: Optional[str] = None,
                prefix_info=None,
                pre_events=None,
-               resume_tokens: int = 0) -> RequestGroup:
+               resume_tokens: int = 0,
+               record_logits: bool = False) -> RequestGroup:
         """Enqueue a request (may raise QueueFullError) and make sure
         the loop is running.  Returns the group; callers block on
         ``group.event``.  ``sampling`` carries the per-request
@@ -601,6 +606,14 @@ class DecodeEngine:
         group.prefix_info = prefix_info
         group.on_prefilled = on_prefilled
         group.record_timings = bool(record_timings)
+        if record_logits:
+            # The logits every token of these streams is chosen from,
+            # out of the programs that serve everyone (a reference
+            # check reads them): the prefill's at admission, then a
+            # row of each decode dispatch's last step, so the window
+            # is held to 1 while such a stream is resident.
+            for stream in group.streams:
+                stream.step_logits = []
         # Streams collect their span tuples when the caller asked for
         # a ``timings`` block, the history ring is armed, OR the
         # forensics core is armed — the same events back all three
@@ -1393,7 +1406,9 @@ class DecodeEngine:
     def _pf_fn(self, s_len: int, first: bool):
         """Jitted prefill (fresh cache) / extend (append at position)
         program for one piece length — the engine-side twin of the
-        server's prefix-cache split programs."""
+        server's prefix-cache split programs.  Returns ``(logits,
+        cache, stats)``: what the model sowed over the piece
+        (generate.prefill ``with_stats``; None where nothing)."""
         from ..models import generate as G
 
         if self._prefill_fns is not None:
@@ -1401,12 +1416,8 @@ class DecodeEngine:
         model, variables = self.model, self.variables
 
         def build():
-            if first:
-                return G.jit_over(
-                    variables, lambda w, toks: G.prefill(model, w, toks))
-            return G.jit_over(
-                variables, lambda w, cache, toks, pos: G.prefill(
-                    model, w, toks, cache=cache, position=pos))
+            return G.jit_over(variables,
+                              G.prefill_programs(model)[not first])
 
         return lru_get(self._pf_fns,
                        ("pfill" if first else "extend", s_len),
@@ -1466,10 +1477,12 @@ class DecodeEngine:
             try:
                 with self.device_lock, self._exact():
                     if stream.cache is None:
-                        logits, cache = self._pf_fn(piece, True)(toks)
+                        logits, cache, pairs = self._pf_fn(
+                            piece, True)(toks)
                     else:
-                        logits, cache = self._pf_fn(piece, False)(
-                            stream.cache, toks, stream.filled)
+                        logits, cache, pairs = self._pf_fn(
+                            piece, False)(stream.cache, toks,
+                                          stream.filled)
                     if spec:
                         # Speculative streams prefill the DRAFT model
                         # too (same pieces — the chunked-prefill
@@ -1483,6 +1496,10 @@ class DecodeEngine:
                                               stream.filled)
                         stream.d_cache = d_cache
                     jax.block_until_ready(logits)
+                    if pairs is not None:
+                        # The expert layers' pair counts of the piece:
+                        # a small output of the program just waited for.
+                        self.slots.count_pairs(jax.device_get(pairs))
             except BaseException as e:
                 self._fail_group(group, e)
                 return
@@ -1491,6 +1508,7 @@ class DecodeEngine:
             stream.filled += piece
             stream.pieces.pop(0)
             self.prefill_chunks_total += 1
+            self.prefill_tokens_total += piece
             self._emit(stream, "prefill", t_piece,
                        time.perf_counter(), row=stream.row,
                        piece=piece, filled=stream.filled)
@@ -1583,6 +1601,8 @@ class DecodeEngine:
                 self._fail_group(stream.group, e)
                 return
             stream.out.append(first)
+            if stream.step_logits is not None:
+                stream.step_logits.append(logits)
         stream.t_admit = time.perf_counter()
         stream.group.t_last_admit = stream.t_admit
         if stream.group.t_first_admit is None:
@@ -1748,6 +1768,9 @@ class DecodeEngine:
         cap = self.policy.decode_window
         if cap <= 1:
             return 1
+        if any(s.step_logits is not None
+               for s in self._resident.values()):
+            return 1    # a dispatch keeps its LAST step's logits
         waiters = getattr(self.device_lock, "waiters", None)
         if waiters is not None and waiters():
             # A handler thread is WAITING on the device lock right
@@ -2187,6 +2210,9 @@ class DecodeEngine:
             self.decode_steps_total += window
             emitted = 0
             for slot, stream in list(self._resident.items()):
+                if stream.step_logits is not None:
+                    stream.step_logits.append(np.asarray(
+                        self.slots.last_logits[slot]))
                 for w in range(window):
                     stream.out.append(int(toks_w[w, slot]))
                     emitted += 1
@@ -2355,6 +2381,11 @@ class DecodeEngine:
                 self.evicted_total += 1
         for stream in group.streams:
             self._release_stream_kv(stream)
+            # A failed stream's prefilled lanes go NOW, not when the
+            # last waiter lets go of the group: a server whose
+            # admissions fail would otherwise fill the chip with them
+            # (PERF.md section 6, PR 28).
+            stream.cache = stream.d_cache = stream.logits = None
         if not group.event.is_set():   # fail once, however many
             t = time.perf_counter()    # streams drag the group down
             for stream in group.streams:
@@ -2553,8 +2584,11 @@ class DecodeEngine:
             "kv_pool_in_place_total":
                 self.slots.kv_pool_in_place_total,
             "kv_pool_bytes": self.slots.kv_pool_bytes,
+            "kv_pool_bytes_by_kind": self.slots.kv_pool_bytes_by_kind,
             "kv_pool_lost_total": self.kv_pool_lost_total,
             "prefill_chunks_total": self.prefill_chunks_total,
+            "prefill_tokens_total": self.prefill_tokens_total,
+            **self._moe_stats(),
             "completed_total": self.completed_total,
             "completed_greedy_total": self.completed_greedy_total,
             "completed_sampled_total": self.completed_sampled_total,
@@ -2653,6 +2687,19 @@ class DecodeEngine:
             # storm.
             **self.sentinel.snapshot(),
         }
+
+    def _moe_stats(self) -> Dict[str, Any]:
+        """The expert layers' token-expert pairs since the start,
+        prefill and decode, every expert layer (nothing for a model
+        without): pairs routed over ALL experts, pairs that fell on
+        the experts held here, and the held experts' own counts.  An
+        idle slot's dead step counts like a live one."""
+        pairs = getattr(self.slots, "moe_pairs", None)
+        if pairs is None:
+            return {}
+        return {"moe_pairs_routed_total": int(pairs[-1]),
+                "moe_pairs_held_total": int(pairs[:-1].sum()),
+                "moe_expert_pairs": [int(n) for n in pairs[:-1]]}
 
     def _mesh_stats(self) -> Dict[str, Any]:
         # Under the device lock: the next dispatch consumes the tree

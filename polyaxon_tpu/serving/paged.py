@@ -473,6 +473,11 @@ class PagedSlotKVManager:
                    for pool in (self._pool, self._draft_pool)
                    for leaf in pool or () if leaf is not None)
 
+    @property
+    def kv_pool_bytes_by_kind(self) -> Dict[str, int]:
+        """All ``full``: a ring cache never pages (``_classify``)."""
+        return {"window": 0, "full": self.kv_pool_bytes}
+
     def pool_lost(self) -> bool:
         """Never: no program consumes the page pool (see
         SlotKVManager.pool_lost)."""
@@ -1158,8 +1163,8 @@ class PagedSlotKVManager:
             body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
-            outs, stacked = body(stacked, window, toks, positions,
-                                 *extra)
+            outs, _, stacked = body(stacked, window, toks, positions,
+                                    *extra)
             pool = self._scatter_dirty(pool, metas, stacked, tables,
                                        d0, n_dirty)
             return outs, pool

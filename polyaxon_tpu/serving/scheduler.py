@@ -362,7 +362,7 @@ class Stream:
                  "spec_drafted", "spec_accepted", "sid", "events",
                  "pf_toks", "resume", "kv_shared", "kv_epoch",
                  "last_slot", "preempts", "resumes", "blocked_t",
-                 "evicted_for")
+                 "evicted_for", "step_logits")
 
     def __init__(self, group: "RequestGroup", row: int,
                  toks: np.ndarray, new: int, eos_id: Optional[int],
@@ -389,6 +389,10 @@ class Stream:
         self.d_cache = None       # draft-model cache (spec streams)
         self.logits = None        # last-position logits once filled
         self.out: List[int] = []  # committed new tokens
+        # With engine.submit(record_logits=True): the [V] logits each
+        # committed token was chosen from, as the engine's programs
+        # computed them (None: not asked for).
+        self.step_logits: Optional[List[np.ndarray]] = None
         self.slot: Optional[int] = None
         self.pf_done = False      # prompt fully consumed (may still
         #                           be queued, waiting for a slot)

@@ -1302,7 +1302,8 @@ class ModelServer:
                    new=None, temp=None, top_k=None, top_p=None,
                    eos=None):
         """Jitted split programs for the prefix-cache path:
-        ``pfill``/``extend`` produce (logits, cache); ``cont`` decodes
+        ``pfill``/``extend`` produce (logits, cache, stats: what the
+        model sowed, the engine counts it); ``cont`` decodes
         from a cache.  Cached in the same LRU as the fused programs."""
         from ..models import generate as G
 
@@ -1315,13 +1316,9 @@ class ModelServer:
             return G.jit_over(self.variables, fn)
 
         def build():
-            if kind == "pfill":
-                return jit(lambda w, toks: G.prefill(
-                    self.model, w, toks, chunk=chunk))
-            if kind == "extend":
-                return jit(lambda w, cache, toks, pos: G.prefill(
-                    self.model, w, toks, chunk=chunk,
-                    cache=cache, position=pos))
+            if kind in ("pfill", "extend"):
+                return jit(G.prefill_programs(self.model, chunk)[
+                    kind == "extend"])
             if kind == "cont_pos":
                 # position-keyed sampled continue (prefix-cache hits
                 # that stay solo): one program per shape, shaping
@@ -2279,7 +2276,7 @@ class ModelServer:
         toks = np.asarray(rows, np.int32)
         t0 = time.perf_counter()
         with self._lock, self._exact():
-            logits, cache = self._split_fns(
+            logits, cache, _ = self._split_fns(
                 toks.shape[0], toks.shape[1], "pfill", chunk)(toks)
             jax.block_until_ready(logits)
         # Outside the device lock: the paged store re-acquires it for
@@ -2325,7 +2322,7 @@ class ModelServer:
                 pc, logits, cache = hit.p_cached, hit.logits, hit.cache
                 if pc < p_len:  # extend with the suffix, store back
                     suffix = toks[:, pc:]
-                    logits, cache = self._split_fns(
+                    logits, cache, _ = self._split_fns(
                         b, suffix.shape[1], "extend", chunk)(
                             cache, suffix, pc)
                     jax.block_until_ready(logits)
@@ -2445,6 +2442,9 @@ class ModelServer:
         want_timings = req.get("timings", False)
         if not isinstance(want_timings, bool):
             raise ValueError("'timings' must be a JSON boolean")
+        want_logits = req.get("logits", False)
+        if not isinstance(want_logits, bool):
+            raise ValueError("'logits' must be a JSON boolean")
         # Lifecycle params: the priority class (server default when
         # absent) and an optional relative deadline in ms — expiry
         # evicts the request at the next step boundary (504).
@@ -2736,7 +2736,8 @@ class ModelServer:
                                        priority=priority,
                                        deadline_s=deadline_s,
                                        rid=rid,
-                                       resume_tokens=resume_tokens)
+                                       resume_tokens=resume_tokens,
+                                       record_logits=want_logits)
             self._wait_group(group, cancel_check)
             out = group.result()
             breakdown = group.breakdown()
@@ -2943,7 +2944,27 @@ class ModelServer:
                     fetch_events[0][2] - fetch_events[0][1], 6)}
                if fetch_events else {}),
             **({"timings": timings} if timings is not None else {}),
+            **({"logits": self._logits_field(group)}
+               if want_logits else {}),
         }
+
+    @staticmethod
+    def _logits_field(group) -> Optional[Dict[str, Any]]:
+        """{"logits": true}: for row 0, the float32 logits each new
+        token was chosen from, ``[tokens, vocab]`` little-endian in
+        base64 — the prefill program's for the first, then a row of
+        each decode dispatch, the engine's own programs at one step a
+        dispatch.  None where the request left the engine (solo
+        paths keep no logits) or was resumed after a preemption."""
+        import base64
+
+        rows = group.streams[0].step_logits if group is not None \
+            else None
+        if not rows or len(rows) != len(group.streams[0].out):
+            return None
+        block = np.ascontiguousarray(np.stack(rows), "<f4")
+        return {"dtype": "float32", "shape": list(block.shape),
+                "b64": base64.b64encode(block.tobytes()).decode()}
 
     # -- telemetry helpers ----------------------------------------------
 
@@ -3112,6 +3133,11 @@ class ModelServer:
                 **({"prefix_fetch_policy":
                     self.fetch_policy.describe()}
                    if self.prefix_fetch else {}),
+                # The expert layers' pair counts, where the model
+                # has expert layers (engine._moe_stats).
+                **{k: engine[k] for k in
+                   ("moe_pairs_routed_total", "moe_pairs_held_total",
+                    "moe_expert_pairs") if k in engine},
                 **{k: engine[k] for k in
                    ("slots", "slots_active", "slot_occupancy",
                     "queue_len", "queue_depth", "admitted_total",
@@ -3120,8 +3146,9 @@ class ModelServer:
                     "evicted_total", "decode_steps_total",
                     "kv_pool_dispatches_total",
                     "kv_pool_in_place_total", "kv_pool_bytes",
-                    "kv_pool_lost_total",
-                    "prefill_chunks_total", "completed_total",
+                    "kv_pool_bytes_by_kind", "kv_pool_lost_total",
+                    "prefill_chunks_total", "prefill_tokens_total",
+                    "completed_total",
                     "completed_greedy_total",
                     "completed_sampled_total",
                     "completed_spec_total",
@@ -3406,6 +3433,25 @@ class ModelServer:
                 "# TYPE ptpu_serving_prefill_chunks_total counter",
                 f"ptpu_serving_prefill_chunks_total "
                 f"{es['prefill_chunks_total']}",
+                "# TYPE ptpu_serving_prefill_tokens_total counter",
+                f"ptpu_serving_prefill_tokens_total "
+                f"{es['prefill_tokens_total']}",
+                "# TYPE ptpu_serving_kv_pool_bytes_by_kind gauge",
+                *(f'ptpu_serving_kv_pool_bytes_by_kind{{kind="{k}"}} '
+                  f"{v}" for k, v in
+                  es["kv_pool_bytes_by_kind"].items()),
+                *([
+                    "# TYPE ptpu_serving_moe_pairs_routed_total counter",
+                    f"ptpu_serving_moe_pairs_routed_total "
+                    f"{es['moe_pairs_routed_total']}",
+                    "# TYPE ptpu_serving_moe_pairs_held_total counter",
+                    f"ptpu_serving_moe_pairs_held_total "
+                    f"{es['moe_pairs_held_total']}",
+                    "# TYPE ptpu_serving_moe_expert_pairs counter",
+                    *(f'ptpu_serving_moe_expert_pairs{{expert="{i}"}} '
+                      f"{n}" for i, n in
+                      enumerate(es["moe_expert_pairs"])),
+                ] if "moe_pairs_routed_total" in es else []),
                 # Speculative scheduling counters + the per-request
                 # acceptance-rate histogram — rendered from the SAME
                 # engine.stats() dict /info reports, so the two

@@ -91,6 +91,13 @@ from ..analysis.xprof import STEP_MARKER
 from ..spans import span
 
 
+MIXED_CACHE_MSG = (
+    "this model keeps two kinds of KV cache in one slot pool (window "
+    "rings beside full-length planes): the fixed-lane slot manager on "
+    "one chip serves it; --kv-paged and --mesh know one kind of leaf "
+    "and refuse it")
+
+
 def step_annotation(**stats):
     """Profiler marker around ONE decode dispatch AND its blocking
     sync (inside the device lock): when a ``jax.profiler`` trace is
@@ -144,8 +151,18 @@ def build_step_body(model, variables, window: int, sampled: bool):
     fused steps.
 
     Plain: ``step(stacked, steps, toks, positions) -> (outs [window,
-    S], stacked)``.  Sampled: ``step(stacked, steps, toks, positions,
-    keys, idxs, temps, tks, tps)`` with the same returns.
+    S], extras, stacked)``.  Sampled: ``step(stacked, steps, toks,
+    positions, keys, idxs, temps, tks, tps)`` with the same returns.
+
+    ``extras`` is what the steps computed anyway and nobody kept:
+    ``{"logits": [S, V] float32 of the LAST step run, "pairs": what
+    the model sowed under generate.STATS summed over steps, layers and
+    slots (the expert layers' token-expert pairs; absent for a model
+    that sows nothing)}``.  The fixed-lane manager returns them
+    from every program, so the programs a benchmark times are the ones
+    whose logits a reference check reads (fetched only on request) and
+    the pair counts ride home with the tokens; a caller that drops
+    them (the paged manager) pays nothing for them.
 
     ``steps`` (at most ``window``) is how many steps run; the rows of
     ``outs`` past it stay zero.  A Python int makes the loop one of
@@ -167,56 +184,54 @@ def build_step_body(model, variables, window: int, sampled: bool):
         out, mut = model.apply(
             {"params": G._params(variables), "cache": cache},
             tok[None, None], decode=True, decode_position=pos,
-            mutable=["cache"])
-        return G.extract_logits(out)[:, -1][0], mut["cache"]  # [V]
+            mutable=["cache", G.STATS])
+        pairs = G.stats_total(mut.get(G.STATS))
+        if pairs is None:
+            pairs = jnp.zeros((0,), jnp.int32)
+        return (G.extract_logits(out)[:, -1][0], pairs,   # [V]
+                mut["cache"])
 
-    def no_tokens(toks):
-        return jnp.zeros((window,) + toks.shape, jnp.int32)
+    def one(cache, tok, pos, *sampling):
+        """(next token, logits, pairs, cache) of one slot: argmax in
+        the pure-greedy body (all-greedy pools never pay the sampler's
+        threshold searches and greedy-only servers compile nothing of
+        it), else the shared position-keyed sampler with the slot's
+        OWN (key, index, temperature, top_k, top_p); greedy co-tenants
+        (temperature 0) take its argmax lane, producing the same
+        tokens the greedy body would."""
+        logits, pairs, cache = logits_for(cache, tok, pos)
+        nxt = G._sample_positional_row(logits, *sampling) if sampled \
+            else jnp.argmax(logits).astype(jnp.int32)
+        return nxt, logits, pairs, cache
 
-    if not sampled:
-        # The pure-greedy body: all-greedy pools never pay the
-        # sampler's threshold searches and greedy-only servers
-        # compile nothing of it.
-        def one(cache, tok, pos):
-            logits, cache = logits_for(cache, tok, pos)
-            nxt = jnp.argmax(logits).astype(jnp.int32)  # greedy
-            return nxt, cache
+    def step(stacked, steps, toks, positions, *sampling):
+        # ``sampling``: (keys, idxs, temps, tks, tps), the sampled
+        # body's; the token index advances with the step.
+        keys, idxs, shaping = sampling[:1], sampling[1:2], sampling[2:]
+        like = jax.eval_shape(
+            jax.vmap(one), stacked, toks, positions,
+            *keys, *idxs, *shaping)
 
-        def step(stacked, steps, toks, positions):
-            def body(i, carry):
-                cache, tok, pos, outs = carry
-                nxt, cache = jax.vmap(one)(cache, tok, pos)
-                return cache, nxt, pos + 1, outs.at[i].set(nxt)
-            cache, _, _, outs = jax.lax.fori_loop(
-                0, steps, body,
-                (stacked, toks, positions, no_tokens(toks)))
-            return outs, cache                          # [W, S]
-
-        return step
-
-    # Sampled body: every slot draws through the shared position-
-    # keyed sampler with ITS OWN (key, index, temperature, top_k,
-    # top_p); greedy co-tenants (temperature 0) take the argmax
-    # lane, producing the same tokens the greedy body would.
-    def one_sampled(cache, tok, pos, key, idx, temp, tk, tp):
-        logits, cache = logits_for(cache, tok, pos)
-        nxt = G._sample_positional_row(logits, key, idx, temp,
-                                       tk, tp)
-        return nxt, cache
-
-    def step_sampled(stacked, steps, toks, positions, keys, idxs,
-                     temps, tks, tps):
         def body(i, carry):
-            cache, tok, pos, idx, outs = carry
-            nxt, cache = jax.vmap(one_sampled)(
-                cache, tok, pos, keys, idx, temps, tks, tps)
-            return cache, nxt, pos + 1, idx + 1, outs.at[i].set(nxt)
-        cache, _, _, _, outs = jax.lax.fori_loop(
-            0, steps, body,
-            (stacked, toks, positions, idxs, no_tokens(toks)))
-        return outs, cache                              # [W, S]
+            cache, tok, pos, idx, outs, _, pairs = carry
+            nxt, logits, new_pairs, cache = jax.vmap(one)(
+                cache, tok, pos, *keys, *idx, *shaping)
+            return (cache, nxt, pos + 1, tuple(j + 1 for j in idx),
+                    outs.at[i].set(nxt), logits,
+                    pairs + new_pairs.sum(axis=0))
 
-    return step_sampled
+        cache, _, _, _, outs, logits, pairs = jax.lax.fori_loop(
+            0, steps, body,
+            (stacked, toks, positions, tuple(idxs),
+             jnp.zeros((window,) + toks.shape, jnp.int32),
+             jnp.zeros(like[1].shape, like[1].dtype),
+             jnp.zeros(like[2].shape[1:], like[2].dtype)))
+        extras = {"logits": logits}
+        if pairs.size:          # nothing sown: nothing to fetch
+            extras["pairs"] = pairs
+        return outs, extras, cache
+
+    return step
 
 
 def build_spec_step_body(model, variables, draft, draft_vars,
@@ -367,6 +382,22 @@ class SlotKVManager:
         # check).  Engine stats / /info, with ``kv_pool_bytes``.
         self.kv_pool_dispatches_total = 0
         self.kv_pool_in_place_total = 0
+        # What the last decode program left beside its tokens
+        # (build_step_body's ``extras``): the last step's logits [S, V],
+        # a device array nobody fetches unless a stream asked for its
+        # logits; and the expert layers' token-expert pairs, summed
+        # here since the start ([held expert ..., routed]; None until
+        # a program of a model that counts them has run).  Prefill
+        # programs add theirs through ``count_pairs``.
+        self.last_logits = None
+        self.moe_pairs = None
+
+    def count_pairs(self, pairs) -> None:
+        """Add one program's pair counts (``build_step_body``'s
+        ``pairs``, ``generate.prefill``'s stats) to ``moe_pairs``."""
+        pairs = np.asarray(pairs, np.int64)
+        self.moe_pairs = pairs if self.moe_pairs is None \
+            else self.moe_pairs + pairs
 
     # -- slot accounting ------------------------------------------------
 
@@ -446,6 +477,24 @@ class SlotKVManager:
         return sum(leaf.nbytes
                    for pool in (self._stacked, self._draft_stacked)
                    for leaf in jax.tree.leaves(pool))
+
+    @property
+    def kv_pool_bytes_by_kind(self) -> dict:
+        """``kv_pool_bytes`` split by the kind of cache a leaf belongs
+        to: ``window`` for a ring's leaves (those beside a
+        ``cached_pos`` table: kv_cache.append_ring_kv_cache), ``full``
+        for everything else."""
+        import jax
+
+        out = {"window": 0, "full": 0}
+        for pool in (self._stacked, self._draft_stacked):
+            flat = jax.tree_util.tree_flatten_with_path(pool)[0]
+            rings = {path[:-1] for path, _ in flat
+                     if "cached_pos" in jax.tree_util.keystr(path[-1:])}
+            for path, leaf in flat:
+                out["window" if path[:-1] in rings else "full"] += \
+                    leaf.nbytes
+        return out
 
     def pool_lost(self) -> bool:
         """Whether a program consumed the pool and failed before it
@@ -643,7 +692,7 @@ class SlotKVManager:
         return jit_over(
             self.variables, program, donate_argnums=(1,),
             in_shardings=(w_sh, self._cache_sh) + (rep,) * n_host,
-            out_shardings=(rep, self._cache_sh))
+            out_shardings=(rep, rep, self._cache_sh))
 
     def step(self, window: int = 1, sampled: bool = False,
              cap: Optional[int] = None) -> np.ndarray:
@@ -700,15 +749,20 @@ class SlotKVManager:
                         jnp.asarray(self.top_ps)]
             with span("ptpu/enqueue", host_s), self._compiling(new):
                 taken = jax.tree.leaves(self._stacked)[0]
-                outs, self._stacked = fn(self._stacked, *operands)
+                outs, extras, self._stacked = fn(self._stacked,
+                                                 *operands)
                 self._count_dispatch(taken)
+            self.last_logits = extras["logits"]     # stays on the device
             # The sync stays INSIDE the marker: dispatch returns
             # device futures, so a marker closing here-minus-one-line
             # would span only the host enqueue and the attribution
             # window would clip the step's actual device execution
             # (inflating MFU by ~K/(K-1) on a real async backend).
             with span("ptpu/sync", host_s):
-                outs = np.asarray(jax.device_get(outs))[:window]
+                outs, pairs = jax.device_get((outs, extras.get("pairs")))
+                outs = np.asarray(outs)[:window]
+            if pairs is not None:
+                self.count_pairs(pairs)
         self.last_step_device_s = time.perf_counter() - t0
         # Arm the next step: every slot feeds back its own last token
         # at the next position (and, for sampled slots, the next

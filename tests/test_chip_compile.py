@@ -267,7 +267,7 @@ def test_meshed_decode_window_compiles(topo, on_tpu):
             program,
             in_shardings=(mesh.param_shardings(variables), pool_sh,
                           rep, rep, rep),
-            out_shardings=(rep, pool_sh),
+            out_shardings=(rep, rep, pool_sh),
         ).lower(variables, pool,
                 jax.ShapeDtypeStruct((), jnp.int32), slots,
                 slots).compile()
@@ -292,3 +292,94 @@ def test_engine_prefill_compiles(one_chip, on_tpu, prompt_len):
         lambda variables, toks: G.prefill(model, variables, toks)
     ).lower(variables, toks).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
+
+
+# -- trinity-large-ep8: two kinds of cache in one pool, grouped experts -----
+
+TRINITY_SLOTS = 32      # perfbench/configs/trinity-large-preview.json
+
+
+def _trinity_shapes(one_chip):
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.models.registry import get_model
+
+    model = get_model("trinity-large-ep8").make_model()
+    variables = _abstract(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32)), one_chip)
+    one = jax.eval_shape(lambda: G.init_cache(model, 1))
+    return model, variables, one
+
+
+def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
+        one_chip, on_tpu, monkeypatch):
+    """The served cut's decode program as the slot manager builds it,
+    32 slots: bfloat16 weights at rest (8.65 GB) beside the pool (3.49
+    GB), every pool leaf — rings and the full plane — aliased to an
+    output, nothing of a leaf's size copied or concatenated, the
+    grouped expert matmul there as the compiler's ragged-dot call."""
+    import re
+
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, one = _trinity_shapes(one_chip)
+    pool = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((TRINITY_SLOTS,) + l.shape,
+                                       l.dtype, sharding=one_chip), one)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, TRINITY_SLOTS)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, True)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((TRINITY_SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+                vec(jnp.float32)]
+    compiled = fn.func.lower(*fn.args, pool, *operands).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    weights = sum(l.size * l.dtype.itemsize
+                  for l in jax.tree.leaves(variables))
+    pool_bytes = sum(l.size * l.dtype.itemsize
+                     for l in jax.tree.leaves(pool))
+    assert 8.5e9 < weights < 8.8e9 and 3.4e9 < pool_bytes < 3.6e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1 * 2 ** 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    lane = r"bf16\[%d,1,(4608|8192),8,128\]" % TRINITY_SLOTS
+    assert not re.search(r"= %s\S* (copy|concatenate)\(" % lane, text)
+    assert "ragged-dot" in text
+
+
+@pytest.mark.parametrize("first", [True, False],
+                         ids=["prefill", "extend"])
+def test_trinity_prefill_chunk_compiles(one_chip, on_tpu, first):
+    """A 512-token chunk of the served cut (``--prefill-chunk 512``):
+    the ring written before it is read (no cache-sized concatenate),
+    temporaries that leave room beside 12.1 GB of weights and pool."""
+    import re
+
+    from polyaxon_tpu.models import generate as G
+
+    model, variables, one = _trinity_shapes(one_chip)
+    toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    if first:
+        compiled = jax.jit(lambda w, toks: G.prefill(
+            model, w, toks, with_stats=True)).lower(
+                variables, toks).compile()
+    else:
+        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda w, cache, toks, pos: G.prefill(
+            model, w, toks, cache=cache, position=pos,
+            with_stats=True)).lower(
+                variables, _abstract(one, one_chip), toks, pos).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
+    assert not re.search(
+        r"= bf16\[1,(4608|5120),8,128\]\S* concatenate\(", text)
+    assert "ragged-dot" in text

@@ -540,8 +540,12 @@ class TestDebugState:
 
         def client(i):
             try:
+                # 32 tokens, not 6: on warm jit caches eight requests
+                # of 6 were through before the first poll returned
+                # (it failed one run in two here, parent and change
+                # alike), and no snapshot saw a resident.
                 _post(base, {"prompt": [1 + i, 2, 3],
-                             "max_new_tokens": 6})
+                             "max_new_tokens": 32})
             except Exception as e:  # noqa: BLE001
                 errors.append(f"{type(e).__name__}: {e}")
 
@@ -672,7 +676,11 @@ class TestStallWatchdog:
         t0 = time.perf_counter()
         wd.start()
         try:
-            while wd.stalls_total == 0 \
+            # The counter is bumped before the bundle is built and
+            # its path recorded: wait for the path, not the counter
+            # (under six workers the gap was once wide enough to
+            # read a stall without its "bundle").
+            while "bundle" not in (wd.last_stall or {}) \
                     and time.perf_counter() - t0 < 5.0:
                 time.sleep(0.02)
             elapsed = time.perf_counter() - t0

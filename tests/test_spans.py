@@ -28,6 +28,20 @@ STEP_FIELDS = ("upload_s", "enqueue_s", "sync_s", "commit_s",
                "lock_wait_s", "admit_s", "prefill_s")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _no_engine_left_running():
+    """A trace taken here holds every thread of the process.  Test
+    files that ran earlier on this worker build servers they never
+    close (``test_serving.py`` does), and such an engine's loop goes on
+    entering ``ptpu/idle_wait``, ``ptpu/sweep`` and ``ptpu/board``
+    beside the spans these tests count: stop those loops first."""
+    import gc
+
+    for obj in gc.get_objects():
+        if isinstance(obj, DecodeEngine):
+            obj.close()
+
+
 # ---------------------------------------------------------------------------
 # the list and the helper
 # ---------------------------------------------------------------------------
