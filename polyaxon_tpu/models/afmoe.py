@@ -9,7 +9,8 @@ What no other decoder in the zoo has, all in one block design:
   cache side by side in one cache collection: a window layer a ring of
   ``sliding_window + kv_ring_chunk`` positions
   (``kv_cache.append_ring_kv_cache``), a full layer a plane of
-  ``max_position`` (``kv_cache.append_kv_cache``).
+  ``max_position`` (``kv_cache.attend_kv_cache``: read as far as it is
+  written).
 - leading dense-FFN layers, then expert layers: token-choice top-k of
   ``num_experts`` by sigmoid scores plus a selection bias, a shared
   expert, and the routed sum over the experts HELD here
@@ -60,7 +61,7 @@ from ..ops.rotary import apply_rotary
 from ..parallel.moe import (held_experts_ffn, held_pair_counts,
                             sigmoid_topk_route)
 from .generate import STATS
-from .kv_cache import append_kv_cache, append_ring_kv_cache
+from .kv_cache import append_ring_kv_cache, attend_kv_cache
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -197,15 +198,20 @@ class AfmoeAttention(nn.Module):
         if decode:
             # The cache helpers count a window as the keys BEHIND the
             # query (i - w .. i): the published W keys are w = W - 1.
+            # ``allowed`` comes [1, 1, S, T]: one more axis for the
+            # query heads of a group.
             if window:
                 k, v, allowed, pos = append_ring_kv_cache(
                     self, k, v, cfg.sliding_window - 1, rotate=rot,
                     slack=cfg.kv_ring_chunk)
-                q = rot(pos, q)
+                a = grouped_attention(rot(pos, q), k, v,
+                                      allowed[:, :, None])
             else:
-                k, v, allowed, pos = append_kv_cache(
-                    self, k, v, cfg.max_position)
-            allowed = allowed[:, :, None]           # [1, 1, 1, S, T]
+                # The plane, read as far as it is written.
+                a = attend_kv_cache(
+                    self, lambda k, v, allowed, _: grouped_attention(
+                        q, k, v, allowed[:, :, None]),
+                    k, v, cfg.max_position)
         else:
             pos = jnp.arange(s)
             allowed = pos[None, :] <= pos[:, None]
@@ -213,7 +219,7 @@ class AfmoeAttention(nn.Module):
                 q, k = rot(pos, q), rot(pos, k)
                 allowed &= pos[None, :] > pos[:, None] \
                     - cfg.sliding_window
-        a = grouped_attention(q, k, v, allowed)
+            a = grouped_attention(q, k, v, allowed)
         a = a * jax.nn.sigmoid(_dense(cfg, hq * d, "gate_proj")(x))
         return _dense(cfg, cfg.hidden_size, "o_proj")(a)
 

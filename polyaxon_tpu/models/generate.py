@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.quant import dequantize_params
+from .kv_cache import read_extent
 
 
 def _params(variables):
@@ -699,13 +700,21 @@ def prefill_programs(model, chunk: Optional[int] = None):
     returning ``(logits, cache, stats)``, as functions to jit over the
     weights.  NAMED, so that a device trace tells a server's prefill
     programs (``jit_ptpu_prefill``, ``jit_ptpu_extend``) from its
-    decode program."""
+    decode program.  One request (or a batch that shares its index) a
+    call: the attention reads the full-length planes as far as they
+    are written (``kv_cache.read_extent``), not to ``max_position`` —
+    a fresh cache as far as the prompt's length, known while tracing
+    (one static width: no conditional to compile), an extended one as
+    far as its own index and the chunk, known at run time."""
     def ptpu_prefill(w, toks):
-        return prefill(model, w, toks, chunk=chunk, with_stats=True)
+        with read_extent(toks.shape[1]):
+            return prefill(model, w, toks, chunk=chunk,
+                           with_stats=True)
 
     def ptpu_extend(w, cache, toks, pos):
-        return prefill(model, w, toks, chunk=chunk, cache=cache,
-                       position=pos, with_stats=True)
+        with read_extent():
+            return prefill(model, w, toks, chunk=chunk, cache=cache,
+                           position=pos, with_stats=True)
 
     return ptpu_prefill, ptpu_extend
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from ..parallel.constraints import BATCH, constrain
 from .attention import dot_product_attention
-from .kv_cache import append_kv_cache
+from .kv_cache import attend_kv_cache
 from .scan_stack import remat_policy as _remat_policy
 from .scan_stack import scan_stack
 
@@ -110,15 +110,17 @@ class GPT2Block(nn.Module):
         q, k, v = jnp.split(qkv, 3, axis=-1)
         shape = h.shape[:-1] + (cfg.num_heads, head_dim)
         q, k, v = (t.reshape(shape) for t in (q, k, v))
-        mask = None
         if decode:
-            # Single-token KV-cache step (GPT-2 has no RoPE — positions
-            # enter via wpe at the embedding).
-            k, v, mask, _ = append_kv_cache(self, k, v,
-                                            cfg.max_position,
-                                            quantize=cfg.kv_cache_int8,
-                                            layer=layer)
-        a = dot_product_attention(q, k, v, causal=not decode, mask=mask)
+            # KV-cache step (GPT-2 has no RoPE — positions enter via
+            # wpe at the embedding): the append, and the attention over
+            # the plane as far as it is written.
+            a = attend_kv_cache(
+                self, lambda k, v, mask, _: dot_product_attention(
+                    q, k, v, mask=mask),
+                k, v, cfg.max_position, quantize=cfg.kv_cache_int8,
+                layer=layer)
+        else:
+            a = dot_product_attention(q, k, v, causal=True)
         a = a.reshape(h.shape)
         a = constrain(a, BATCH, None, "tp")
         # Row-parallel o_proj: XLA inserts the partial-sum allreduce and

@@ -8,12 +8,21 @@ and T5's decoder self-attention.  Two storage disciplines:
   optional int8 storage (``quantize=True``).
 - :func:`append_ring_kv_cache` — O(window) position-keyed ring for
   sliding-window models; sessions stream past max_position.
+
+:func:`attend_kv_cache` is the append AND the attention over what it
+wrote: where the caller knows how far the cache is written
+(:func:`read_extent`) the attention reads the planes only that far.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import threading
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.quant import symmetric_int8
 
@@ -28,18 +37,25 @@ def _quantize_chunk(x):
 #
 # ``layer`` is None where the variable is this layer's own (an
 # unrolled stack, or a scan that holds the cache as its xs/ys: T5,
-# MoE-GPT, and every cache at its creation), and the layer's index
+# and every cache at its creation), and the layer's index
 # (traced) where scan_stack CARRIES the stacked cache through the
 # layer loop: the variable then holds every layer's plane on a leading
 # axis, and a layer touches only what it changes.
 
 
-def _plane(var, layer):
-    """This layer's plane of ``var``, read once."""
+def _plane(var, layer, rows=None):
+    """This layer's plane of ``var``, read once — or its first
+    ``rows`` positions (static), sliced out of the variable as it
+    lies: on a carried stack no whole plane is made on the way."""
+    stack = var.value
     if layer is None:
-        return var.value
-    return jax.lax.dynamic_index_in_dim(var.value, layer, 0,
-                                        keepdims=False)
+        return stack if rows is None else stack[..., :rows, :, :]
+    if rows is None:
+        return jax.lax.dynamic_index_in_dim(stack, layer, 0,
+                                            keepdims=False)
+    sizes = (1,) + stack.shape[1:-3] + (rows,) + stack.shape[-2:]
+    return jax.lax.dynamic_slice(
+        stack, (layer,) + (0,) * (stack.ndim - 1), sizes)[0]
 
 
 def _store(var, layer, value) -> None:
@@ -223,6 +239,244 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
     return k_full, v_full, valid[None, None], pos_q
 
 
+# -- bounded reads ----------------------------------------------------------
+#
+# A key past the furthest position any query of a call may see is
+# masked, and a masked key weighs exp(-1e30 - max) = 0.0 exactly: the
+# attention over the first n rows of a plane, for any n past that
+# position, is the same arithmetic as over all of them.  So where the
+# caller says how far the cache is written (``read_extent``) the
+# attention is handed a PREFIX of the plane, of one of a few static
+# widths (``prefix_widths``), chosen at run time by ONE conditional
+# (``jax.lax.switch``): one compiled program whatever the extent, each
+# branch slicing its rows out of the variable as it lies.
+#
+# The extent is one UNBATCHED scalar a call.  Under ``jax.vmap`` over a
+# pool's slots a per-slot bound would turn the conditional into a
+# select that runs every branch, and a per-slot slice into a gather:
+# the pool's step computes one extent for all its slots, outside the
+# vmap (serving/slots.build_step_body).  A pool with one long resident
+# reads every lane to that resident's length.
+#
+# Under that SHARED extent only a plane of a carried stack is narrowed
+# (``narrows``): its read already was a slice of the stack, cut inside
+# the fusion that consumes it, and a few rows less change nothing for
+# the compiler.  A variable that is the layer's own (an unrolled stack)
+# was read whole, and slicing it is a new shape of program: ahead of a
+# grouped matmul the v5e compiler then converts the layout of the
+# WHOLE ``[slots, 1, 8192, 8, 128]`` plane in every branch (deviceless
+# compile, PERF.md section 6, PR 30) — more bytes than the slice
+# saves.  A prefill chunk narrows both kinds: one sequence's plane is
+# small beside the scores over it.
+#
+# What a width costs: every branch is traced and compiled with its
+# program, in every process for a pool's programs (never from the
+# persistent cache: config.fresh_compile), and traced again even where
+# the executable is read from it.  On the v5e's host a branch of the
+# gpt2-medium programs cost 0.2-0.3 s a program (PERF.md section 6,
+# PR 30).  So a prompt prefilled from position 0 names its extent as a
+# Python int and gets ONE static width, no conditional.
+
+# A plane is read to an eighth, a quarter, a half or all of its
+# capacity.  Few widths, and powers of two: on the chip four of them
+# served gpt2-medium's pool FASTER than eight even steps did (384 rows
+# cost 1.66 x what 256 did, and every branch weighs on the conditional),
+# at half the start-up cost (PERF.md section 6, PR 30).
+PREFIX_SHARES = (8, 4, 2, 1)
+
+# ``read_extent()`` without an argument: the extent is the cache's own
+# index plus the rows this call appends (one sequence, or a batch that
+# shares its index: the program that extends a prefilled cache).
+OWN_INDEX = object()
+
+_READ = threading.local()
+
+
+@contextlib.contextmanager
+def read_extent(extent=OWN_INDEX, shared: bool = False):
+    """While TRACING inside this scope, :func:`attend_kv_cache` reads a
+    plane only as far as ``extent``: no smaller than one past the
+    furthest position a query of the traced call sees.  A Python int
+    (a prompt prefilled from position 0: its length) picks the width
+    while tracing, and costs the program nothing; a traced scalar, or
+    the cache's own index (no argument), picks it by a conditional at
+    run time.  ``shared``: the extent is one for every lane of a vmap
+    around the call (a slot pool's step: compute it OUTSIDE the vmap).
+    Outside any scope the plane is read whole.  Open the scope INSIDE
+    the function that gets traced (a trace cached outside it knows
+    nothing of it).  Not a switch anybody sets: the callers are the
+    slot pool's step body and ``generate.prefill_programs``."""
+    was = getattr(_READ, "scope", None)
+    _READ.scope = (extent, shared)
+    try:
+        yield
+    finally:
+        _READ.scope = was
+
+
+def narrows(shared: bool, stacked: bool) -> bool:
+    """Whether a call narrows its read of a plane: always under its
+    own index (a prefill chunk), under a pool's ``shared`` extent only
+    where the plane lies in a ``stacked`` (carried) cache variable.
+    One rule for the program and for the host's count of its reads."""
+    return stacked or not shared
+
+
+@functools.lru_cache(maxsize=None)
+def prefix_widths(cap: int) -> tuple:
+    """The static widths a plane of ``cap`` rows may be read to,
+    ascending, the last one ``cap`` itself."""
+    return tuple(sorted({-(-cap // share) for share in PREFIX_SHARES}))
+
+
+def prefix_branch(extent, cap: int):
+    """Which of :func:`prefix_widths` covers rows ``[0, extent)``: the
+    narrowest that does, the widest for an extent past ``cap``.  One
+    function for the program (``extent`` traced) and for the host's
+    count of what the program read (an int or a numpy array)."""
+    edges = prefix_widths(cap)[:-1]
+    if isinstance(extent, jax.Array):       # three compares, no loop
+        return jnp.searchsorted(jnp.asarray(edges), extent, side="left",
+                                method="compare_all")
+    return np.searchsorted(edges, extent, side="left")
+
+
+def prefix_width(extent, cap: int):
+    """Rows of a ``cap``-row plane that a call with this extent reads
+    (host arithmetic: ints or numpy arrays)."""
+    return np.asarray(prefix_widths(cap))[prefix_branch(extent, cap)]
+
+
+def full_planes(cache) -> dict:
+    """``{(capacity, stacked): planes}`` of the full-length key planes
+    in ONE sequence's cache tree — every ``cached_key`` leaf that is no
+    ring's (no ``cached_pos`` beside it), a plane a layer; ``stacked``
+    where the leaf holds its layers on a leading axis (``[layers, B,
+    positions, H, D]``: the stack that decoding carries)."""
+    flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+    name = lambda path: jax.tree_util.keystr(path[-1:])  # noqa: E731
+    rings = {path[:-1] for path, _ in flat
+             if "cached_pos" in name(path)}
+    planes = {}
+    for path, leaf in flat:
+        if "cached_key'" in name(path) and path[:-1] not in rings:
+            kind = (leaf.shape[-3], leaf.ndim > 4)
+            planes[kind] = planes.get(kind, 0) \
+                + int(np.prod(leaf.shape[:-3], dtype=np.int64))
+    return planes
+
+
+class PlaneReads:
+    """The host's count of what the bounded reads took: rows of the
+    full-length planes the attention was handed (``read``: the static
+    width, by the same :func:`prefix_branch` the program switches on)
+    and the rows those planes hold (``held``), a plane a layer and
+    sequence.  Engine stats ``kv_plane_rows_read_total`` /
+    ``kv_plane_rows_held_total``."""
+
+    def __init__(self):
+        self.read = 0
+        self.held = 0
+        self.planes = None      # full_planes of one sequence's cache
+
+    def learn(self, cache) -> None:
+        """The shape of one sequence's cache, from the first one seen
+        (a prefilled request's: every later one has its shape)."""
+        if self.planes is None:
+            self.planes = full_planes(cache)
+
+    def count(self, extents, lanes: int = 1, cap=None,
+              shared: bool = False) -> None:
+        """One program ran ``len(extents)`` applies — a decode window's
+        steps over ``lanes`` slots under their ``shared`` extent, or
+        one prefill chunk — each over the planes learnt (``cap`` where
+        the program saw a narrower view of them: the paged manager's
+        gather)."""
+        extents = np.asarray(extents, np.int64)
+        for (own, stacked), n in self.planes.items():
+            held = own if cap is None else cap
+            read = prefix_width(extents, held) \
+                if narrows(shared, stacked) else held
+            self.read += lanes * n * int(
+                np.broadcast_to(read, extents.shape).sum())
+            self.held += lanes * n * held * extents.size
+
+
+def _over_prefix(attend_rows, pos_q, cap: int, stacked: bool):
+    """``attend_rows(n)`` — the attention over a plane's first ``n``
+    rows (static) — for the narrowest of :func:`prefix_widths` that the
+    extent in scope allows; for ``cap`` where none is in scope, or
+    where this plane is not narrowed under it (:func:`narrows`)."""
+    scope = getattr(_READ, "scope", None)
+    widths = prefix_widths(cap)
+    if scope is None or len(widths) == 1 \
+            or not narrows(scope[1], stacked):
+        return attend_rows(cap)
+    extent = pos_q[-1] + 1 if scope[0] is OWN_INDEX else scope[0]
+    if not isinstance(extent, jax.Array):       # known while tracing
+        return attend_rows(int(prefix_width(extent, cap)))
+    return jax.lax.switch(
+        prefix_branch(jnp.asarray(extent, jnp.int32), cap),
+        [lambda n=n: attend_rows(n) for n in widths])
+
+
+def _append(mod, k, v, max_position, window, rotate, quantize, layer):
+    """The append of :func:`append_kv_cache`.  Returns ``(read, cap,
+    positions)``: ``read(n)`` is ``(keys, values, mask)`` over the
+    plane's first ``n`` rows (static; ``cap`` for the whole plane),
+    dequantised where stored int8 — what is read, not the plane."""
+    b, s, h, d = k.shape
+    idx = mod.variable("cache", "cache_index",
+                       lambda: jnp.array(0, jnp.int32))
+    idx0 = _plane(idx, layer)
+    pos_q = idx0 + jnp.arange(s)  # absolute positions of new rows
+    if rotate is not None:
+        k = rotate(pos_q, k)
+    if quantize:
+        store_dtype, out_dtype = jnp.int8, k.dtype
+        kq, k_scale = _quantize_chunk(k)
+        vq, v_scale = _quantize_chunk(v)
+    else:
+        store_dtype, out_dtype = k.dtype, k.dtype
+        kq, k_scale, vq, v_scale = k, None, v, None
+    ck = mod.variable("cache", "cached_key", jnp.zeros,
+                      (b, max_position, h, d), store_dtype)
+    # An existing (possibly paged-view) cache keeps ITS width; only a
+    # fresh creation uses max_position.
+    cap = ck.value.shape[-3]
+    cv = mod.variable("cache", "cached_value", jnp.zeros,
+                      (b, cap, h, d), store_dtype)
+    _put_rows(ck, layer, kq, idx0)
+    _put_rows(cv, layer, vq, idx0)
+    if quantize:
+        cks = mod.variable("cache", "cached_key_scale", jnp.zeros,
+                           (b, cap, h, 1), jnp.bfloat16)
+        cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
+                           (b, cap, h, 1), jnp.bfloat16)
+        _put_rows(cks, layer, k_scale, idx0)
+        _put_rows(cvs, layer, v_scale, idx0)
+    _store(idx, layer, idx0 + s)
+
+    def read(n: int):
+        rows = None if n == cap else n
+        k_read, v_read = _plane(ck, layer, rows), _plane(cv, layer, rows)
+        if quantize:
+            # Unwritten positions hold scale 0 -> dequantize to 0,
+            # exactly like the unquantized zero-init cache (masked off
+            # anyway).
+            k_read = k_read.astype(out_dtype) \
+                * _plane(cks, layer, rows).astype(out_dtype)
+            v_read = v_read.astype(out_dtype) \
+                * _plane(cvs, layer, rows).astype(out_dtype)
+        keys = jnp.arange(n)
+        valid = keys[None, :] <= pos_q[:, None]  # [S, n]
+        if window is not None:
+            valid &= keys[None, :] >= pos_q[:, None] - window
+        return k_read, v_read, valid[None, None]
+
+    return read, cap, pos_q
+
+
 def append_kv_cache(mod, k, v, max_position: int, window=None,
                     rotate=None, quantize: bool = False, layer=None):
     """Append this step's k/v ([B, S, H, D]) to ``mod``'s decode cache.
@@ -285,59 +539,50 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
     does).  The layer's keys and values are read once, as the
     attention's operand.  Nothing else of the stack is read or
     written; no layer is sliced out and written back.  ``layer=None``
-    (an unrolled stack, T5 and MoE-GPT's own scans, and the apply
-    that CREATES the variables) is the same arithmetic on a variable
-    that is the layer's own.
+    (an unrolled stack, T5's own scan, and the apply that CREATES the
+    variables) is the same arithmetic on a variable that is the
+    layer's own.
+
+    WHAT IS READ: this function returns the layer's WHOLE plane
+    (``max_position`` keys, most of them masked) for the caller to
+    attend over.  :func:`attend_kv_cache` is the same append with the
+    attention inside it, and reads the plane only as far as it is
+    written where the caller knows how far that is.
 
     Creates ``cached_key``/``cached_value``/``cache_index`` (plus
     ``cached_key_scale``/``cached_value_scale`` when quantized)
     variables in the "cache" collection on ``mod``; returns
     ``(k_full, v_full, mask, positions)``.
     """
-    b, s, h, d = k.shape
-    idx = mod.variable("cache", "cache_index",
-                       lambda: jnp.array(0, jnp.int32))
-    idx0 = _plane(idx, layer)
-    pos_q = idx0 + jnp.arange(s)  # absolute positions of new rows
-    if rotate is not None:
-        k = rotate(pos_q, k)
-    if quantize:
-        store_dtype, out_dtype = jnp.int8, k.dtype
-        kq, k_scale = _quantize_chunk(k)
-        vq, v_scale = _quantize_chunk(v)
-    else:
-        store_dtype, out_dtype = k.dtype, k.dtype
-        kq, k_scale, vq, v_scale = k, None, v, None
-    ck = mod.variable("cache", "cached_key", jnp.zeros,
-                      (b, max_position, h, d), store_dtype)
-    # An existing (possibly paged-view) cache keeps ITS width; only a
-    # fresh creation uses max_position.
-    cap = ck.value.shape[-3]
-    cv = mod.variable("cache", "cached_value", jnp.zeros,
-                      (b, cap, h, d), store_dtype)
-    _put_rows(ck, layer, kq, idx0)
-    _put_rows(cv, layer, vq, idx0)
-    if quantize:
-        cks = mod.variable("cache", "cached_key_scale", jnp.zeros,
-                           (b, cap, h, 1), jnp.bfloat16)
-        cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
-                           (b, cap, h, 1), jnp.bfloat16)
-        _put_rows(cks, layer, k_scale, idx0)
-        _put_rows(cvs, layer, v_scale, idx0)
-        # Unwritten positions hold scale 0 -> dequantize to 0, exactly
-        # like the unquantized zero-init cache (masked off anyway).
-        k_full = _plane(ck, layer).astype(out_dtype) \
-            * _plane(cks, layer).astype(out_dtype)
-        v_full = _plane(cv, layer).astype(out_dtype) \
-            * _plane(cvs, layer).astype(out_dtype)
-    else:
-        k_full, v_full = _plane(ck, layer), _plane(cv, layer)
-    _store(idx, layer, idx0 + s)
-    keys = jnp.arange(cap)
-    valid = keys[None, :] <= pos_q[:, None]  # [S, cap]
-    if window is not None:
-        valid &= keys[None, :] >= pos_q[:, None] - window
-    return k_full, v_full, valid[None, None], pos_q
+    read, cap, pos_q = _append(mod, k, v, max_position, window, rotate,
+                               quantize, layer)
+    return read(cap) + (pos_q,)
+
+
+def attend_kv_cache(mod, attend, k, v, max_position: int, window=None,
+                    rotate=None, quantize: bool = False, layer=None):
+    """:func:`append_kv_cache`, and the attention over what it
+    returns: ``attend(k_read, v_read, mask, positions)`` (a pure
+    function of them: no module call, no variable inside it), whose
+    result is handed back.
+
+    Outside a :func:`read_extent` scope that is all: the plane is read
+    whole (solo ``generate``, beam search, the speculative round).
+    Inside one, ``attend`` gets the plane's first ``n`` rows and
+    ``mask`` ``[1, 1, S, n]``, ``n`` the narrowest of
+    :func:`prefix_widths` past the extent — chosen by a conditional at
+    run time, one compiled program — so a decode step reads its lanes
+    as far as the pool's furthest stream stands (the planes of a
+    carried stack: :func:`narrows`) and a prefill chunk as far as it
+    has written, not to the capacity.  The rows left out are
+    masked for every query of the call, so nothing of the arithmetic
+    changes: scores and softmax as ``attend`` computes them, the
+    rollback contract by absolute position as above (a stale row
+    inside the prefix is masked as it was)."""
+    read, cap, pos_q = _append(mod, k, v, max_position, window, rotate,
+                               quantize, layer)
+    return _over_prefix(lambda n: attend(*read(n), pos_q), pos_q, cap,
+                        stacked=layer is not None)
 
 
 # -- paged storage helpers --------------------------------------------------

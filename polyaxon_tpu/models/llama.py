@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from ..parallel.constraints import BATCH, constrain
 from ..ops.rotary import apply_rotary
 from .attention import dot_product_attention
-from .kv_cache import append_kv_cache, append_ring_kv_cache
+from .kv_cache import append_ring_kv_cache, attend_kv_cache
 from .scan_stack import remat_policy, scan_stack
 
 
@@ -136,38 +136,45 @@ class LlamaAttention(nn.Module):
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
 
-        mask = None
+        def attention(q, k, v, mask=None):
+            """Over the cache (``mask``: the causal-append one, window-
+            clipped) or, without one, causal over the sequence."""
+            if cfg.num_kv_heads != cfg.num_heads:
+                rep = cfg.num_heads // cfg.num_kv_heads
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+            return dot_product_attention(
+                q, k, v, causal=mask is None, mask=mask,
+                window=cfg.sliding_window if mask is None else None)
+
         if decode:
             # KV-cache step (single token or chunked prefill): keys
             # rotate at their absolute cache positions inside the
             # append (stored pre-rotated); q rotates to match with the
-            # returned positions.  The causal-append mask handles both
+            # append's positions.  The causal-append mask handles both
             # S == 1 and whole-prompt chunks, window-clipped.
             rot = lambda p, kk: apply_rotary(  # noqa: E731
                 kk, kk, theta=cfg.rope_theta, positions=p)[1]
+            rot_q = lambda p: apply_rotary(  # noqa: E731
+                q, q, theta=cfg.rope_theta, positions=p)[0]
             if cfg.kv_cache_ring:
                 # O(window) ring — unbounded streaming decode.
                 k, v, mask, pos = append_ring_kv_cache(
                     self, k, v, cfg.sliding_window, rotate=rot,
                     quantize=cfg.kv_cache_int8,
                     slack=cfg.kv_cache_ring_slack, layer=layer)
+                a = attention(rot_q(pos), k, v, mask)
             else:
-                k, v, mask, pos = append_kv_cache(
-                    self, k, v, cfg.max_position,
-                    window=cfg.sliding_window,
+                # The plane, read as far as it is written.
+                a = attend_kv_cache(
+                    self, lambda k, v, mask, pos: attention(
+                        rot_q(pos), k, v, mask),
+                    k, v, cfg.max_position, window=cfg.sliding_window,
                     quantize=cfg.kv_cache_int8, rotate=rot,
                     layer=layer)
-            q = apply_rotary(q, q, theta=cfg.rope_theta,
-                             positions=pos)[0]
         else:
             q, k = apply_rotary(q, k, theta=cfg.rope_theta)
-        if cfg.num_kv_heads != cfg.num_heads:
-            rep = cfg.num_heads // cfg.num_kv_heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        a = dot_product_attention(q, k, v, causal=not decode, mask=mask,
-                                  window=None if decode
-                                  else cfg.sliding_window)
+            a = attention(q, k, v)
         a = constrain(a.reshape(b, s, cfg.num_heads * hd),
                       BATCH, None, "tp")
         return dense(cfg.hidden_size, "o_proj")(a)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from ..parallel.constraints import BATCH, constrain, current_mesh
 from ..parallel.moe import moe_layer, top1_dispatch
 from .attention import dot_product_attention
-from .kv_cache import append_kv_cache
+from .kv_cache import attend_kv_cache
 from .scan_stack import LayerScanBody, scan_layers
 
 
@@ -189,15 +189,16 @@ class MoEBlock(nn.Module):
         q, k, v = jnp.split(qkv, 3, axis=-1)
         shape = h.shape[:-1] + (cfg.num_heads, head_dim)
         q, k, v = (t.reshape(shape) for t in (q, k, v))
-        mask = None
         if decode:
             # KV-cache step (single token or chunked prefill); the
             # switch FFN below picks its kernel by chunk size.
-            k, v, mask, _ = append_kv_cache(self, k, v,
-                                            cfg.max_position,
-                                            quantize=cfg.kv_cache_int8,
-                                            layer=layer)
-        a = dot_product_attention(q, k, v, causal=not decode, mask=mask)
+            a = attend_kv_cache(
+                self, lambda k, v, mask, _: dot_product_attention(
+                    q, k, v, mask=mask),
+                k, v, cfg.max_position, quantize=cfg.kv_cache_int8,
+                layer=layer)
+        else:
+            a = dot_product_attention(q, k, v, causal=True)
         a = a.reshape(h.shape)
         a = constrain(a, BATCH, None, "tp")
         x = x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype,

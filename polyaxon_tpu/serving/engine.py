@@ -1506,6 +1506,10 @@ class DecodeEngine:
             stream.cache = cache
             stream.logits = logits
             stream.filled += piece
+            # The chunk's attention read the full-length planes as far
+            # as it had written them (generate.prefill_programs).
+            self.slots.plane_reads.learn(cache)
+            self.slots.plane_reads.count([stream.filled])
             stream.pieces.pop(0)
             self.prefill_chunks_total += 1
             self.prefill_tokens_total += piece
@@ -2588,6 +2592,11 @@ class DecodeEngine:
             "kv_pool_lost_total": self.kv_pool_lost_total,
             "prefill_chunks_total": self.prefill_chunks_total,
             "prefill_tokens_total": self.prefill_tokens_total,
+            # Rows of the full-length planes the attention was handed,
+            # decode steps and prefill chunks, against the rows those
+            # planes hold (kv_cache.PlaneReads).
+            "kv_plane_rows_read_total": self.slots.plane_reads.read,
+            "kv_plane_rows_held_total": self.slots.plane_reads.held,
             **self._moe_stats(),
             "completed_total": self.completed_total,
             "completed_greedy_total": self.completed_greedy_total,

@@ -86,7 +86,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .slots import (alloc_decode_state, build_spec_step_body,
-                    build_step_body, step_annotation)
+                    build_step_body, step_annotation, step_extents)
+from ..models.kv_cache import PlaneReads
 from ..spans import span
 
 __all__ = ["PagedSlotKVManager", "PageExhausted",
@@ -373,6 +374,9 @@ class PagedSlotKVManager:
         # donates nothing: its dispatches count, none in place.
         self.kv_pool_dispatches_total = 0
         self.kv_pool_in_place_total = 0
+        # See SlotKVManager: here a step reads the GATHERED view (the
+        # resident pages, padded) as far as its furthest stream.
+        self.plane_reads = PlaneReads()
 
     # -- page accounting ------------------------------------------------
 
@@ -676,6 +680,7 @@ class PagedSlotKVManager:
             # Publish LAST, fully formed: a concurrent ``shaped``
             # reader must never observe meta without its pool.
             self._meta, self._treedef = meta, treedef
+            self.plane_reads.learn(template_cache)
             self._pool_sh = pool_sh
             self._pool = pool
 
@@ -1220,6 +1225,10 @@ class PagedSlotKVManager:
             with span("ptpu/enqueue", host_s):
                 outs, self._pool = fn(self._pool, *operands)
                 self.kv_pool_dispatches_total += 1
+                self.plane_reads.count(
+                    step_extents(self.positions, window),
+                    lanes=self.n_slots, cap=P * self.page_tokens,
+                    shared=True)
             # Sync inside the marker so it spans the device
             # execution, not just the async enqueue (see slots.py).
             with span("ptpu/sync", host_s):

@@ -3148,6 +3148,8 @@ class ModelServer:
                     "kv_pool_in_place_total", "kv_pool_bytes",
                     "kv_pool_bytes_by_kind", "kv_pool_lost_total",
                     "prefill_chunks_total", "prefill_tokens_total",
+                    "kv_plane_rows_read_total",
+                    "kv_plane_rows_held_total",
                     "completed_total",
                     "completed_greedy_total",
                     "completed_sampled_total",
@@ -3436,6 +3438,12 @@ class ModelServer:
                 "# TYPE ptpu_serving_prefill_tokens_total counter",
                 f"ptpu_serving_prefill_tokens_total "
                 f"{es['prefill_tokens_total']}",
+                "# TYPE ptpu_serving_kv_plane_rows_read_total counter",
+                f"ptpu_serving_kv_plane_rows_read_total "
+                f"{es['kv_plane_rows_read_total']}",
+                "# TYPE ptpu_serving_kv_plane_rows_held_total counter",
+                f"ptpu_serving_kv_plane_rows_held_total "
+                f"{es['kv_plane_rows_held_total']}",
                 "# TYPE ptpu_serving_kv_pool_bytes_by_kind gauge",
                 *(f'ptpu_serving_kv_pool_bytes_by_kind{{kind="{k}"}} '
                   f"{v}" for k, v in

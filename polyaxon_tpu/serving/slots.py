@@ -77,6 +77,17 @@ into their own cache, which the next ``insert`` overwrites wholesale.
 That is the standard continuous-batching trade: a fixed physical batch
 so there is exactly ONE compiled decode program, with logical
 occupancy managed above it.
+
+A STEP READS THE PLANES AS FAR AS THE FURTHEST STREAM.  A lane holds
+``max_position`` rows a layer; a step's attention is handed the first
+``n`` of them, ``n`` the narrowest of a few static widths past ``max(
+positions) + 1`` (``build_step_body``, models/kv_cache.attend_kv_cache:
+one conditional in the one program).  The extent is ONE scalar for
+the pool, computed outside the vmap over the slots: a per-slot bound
+would run every branch of the conditional as a select and gather each
+slot's rows.  So one long resident makes every lane read to its
+length; idle slots, parked at 0, never raise it.  The manager counts
+the rows handed over against the rows held (``plane_reads``).
 """
 
 from __future__ import annotations
@@ -88,6 +99,7 @@ from typing import Optional
 import numpy as np
 
 from ..analysis.xprof import STEP_MARKER
+from ..models.kv_cache import PlaneReads, read_extent
 from ..spans import span
 
 
@@ -144,6 +156,13 @@ def alloc_decode_state(mgr) -> None:
     mgr.top_ks = np.zeros((n,), np.int32)
     mgr.top_ps = np.zeros((n,), np.float32)
     mgr.spec_ks = np.zeros((n,), np.int32)
+
+
+def step_extents(positions, window: int):
+    """The extent each of a window's steps reads the planes to
+    (``build_step_body``): one past the furthest of the pool's
+    positions, which all advance by one a step."""
+    return int(positions.max()) + 1 + np.arange(window)
 
 
 def build_step_body(model, variables, window: int, sampled: bool):
@@ -214,8 +233,13 @@ def build_step_body(model, variables, window: int, sampled: bool):
 
         def body(i, carry):
             cache, tok, pos, idx, outs, _, pairs = carry
-            nxt, logits, new_pairs, cache = jax.vmap(one)(
-                cache, tok, pos, *keys, *idx, *shaping)
+            # How far this step's attention reads the full-length
+            # planes: to the pool's furthest stream (idle slots are
+            # parked at 0 and never raise it).  ONE scalar, computed
+            # outside the vmap: see kv_cache.read_extent.
+            with read_extent(pos.max() + 1, shared=True):
+                nxt, logits, new_pairs, cache = jax.vmap(one)(
+                    cache, tok, pos, *keys, *idx, *shaping)
             return (cache, nxt, pos + 1, tuple(j + 1 for j in idx),
                     outs.at[i].set(nxt), logits,
                     pairs + new_pairs.sum(axis=0))
@@ -382,6 +406,10 @@ class SlotKVManager:
         # check).  Engine stats / /info, with ``kv_pool_bytes``.
         self.kv_pool_dispatches_total = 0
         self.kv_pool_in_place_total = 0
+        # How far the attention read the full-length planes, against
+        # what they hold (kv_cache.PlaneReads): every decode step
+        # here, every prefill chunk by the engine.
+        self.plane_reads = PlaneReads()
         # What the last decode program left beside its tokens
         # (build_step_body's ``extras``): the last step's logits [S, V],
         # a device array nobody fetches unless a stream asked for its
@@ -582,6 +610,7 @@ class SlotKVManager:
         if self._stacked is None:
             self._stacked, self._cache_sh = \
                 self._alloc_stacked(template_cache)
+            self.plane_reads.learn(template_cache)
 
     def _ensure_draft_stacked(self, template_cache) -> None:
         if self._draft_stacked is None:
@@ -752,6 +781,9 @@ class SlotKVManager:
                 outs, extras, self._stacked = fn(self._stacked,
                                                  *operands)
                 self._count_dispatch(taken)
+                self.plane_reads.count(
+                    step_extents(self.positions, window),
+                    lanes=self.n_slots, shared=True)
             self.last_logits = extras["logits"]     # stays on the device
             # The sync stays INSIDE the marker: dispatch returns
             # device futures, so a marker closing here-minus-one-line

@@ -87,6 +87,63 @@ def test_prefill_then_decode_matches_the_reference(tiny, prompt, chunk):
                                    err_msg=f"position {t}")
 
 
+# Chunks of 4 at cache index 0, in the middle and as the last before
+# ``max_position`` (64: the full layer's plane is read to 8, 16, 32 or
+# 64 rows, so 4 and 24 rows end inside a width and 40 crosses into the
+# last).
+@pytest.mark.parametrize("filled", [0, 20, 36, 60])
+def test_a_chunk_read_to_its_extent_equals_the_chunk_read_whole(filled):
+    """The engine's prefill programs read the full layer's plane only
+    as far as the chunk has written it: the same logits and the same
+    cache, rings and plane, as the chunk that reads the plane whole."""
+    model = AfmoeModel(TINY)
+    ids = jax.random.randint(jax.random.PRNGKey(5), (1, 64), 0,
+                             TINY.vocab_size)
+    variables = {"params": perturbed(model.init(
+        jax.random.PRNGKey(0), ids[:, :8])["params"])}
+    ptpu_prefill, ptpu_extend = G.prefill_programs(model)
+    chunk = ids[:, filled:filled + 4]
+    if not filled:
+        # from position 0 the extent is the prompt's length, known
+        # while tracing: one static width, nothing to branch on
+        assert "cond" not in str(jax.make_jaxpr(ptpu_prefill)(
+            variables, chunk))
+        got = ptpu_prefill(variables, chunk)
+        want = G.prefill(model, variables, chunk, with_stats=True)
+    else:
+        _, cache = G.prefill(model, variables, ids[:, :filled], chunk=4)
+        assert "cond" in str(jax.make_jaxpr(ptpu_extend)(
+            variables, cache, chunk, filled))
+        got = ptpu_extend(variables, cache, chunk, filled)
+        want = G.prefill(model, variables, chunk, cache=cache,
+                         position=filled, with_stats=True)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    assert np.array_equal(got[2], want[2])      # the pairs sown
+
+
+def test_a_rollback_inside_the_prefix_stays_masked(tiny):
+    """24 tokens cached, the index rewound to 20 (a speculative
+    round's rejection; the rings' slack of 4 allows no more), then a
+    chunk of 2: rows 22 and 23 stay stale and lie INSIDE the width the
+    chunk reads (24 of 64).  Its logits are those of a cache that never
+    held the rejected tokens, bounded or whole."""
+    model, variables, ids, _ = tiny
+    _, ptpu_extend = G.prefill_programs(model)
+    _, cache = G.prefill(model, variables, ids[:, :24], chunk=4)
+    other = (ids[:, 30:32] + 1) % TINY.vocab_size
+    rewound = G._rollback_cache(cache, 20)
+    got, _, _ = ptpu_extend(variables, rewound, other, 20)
+    whole, _ = G.prefill(model, variables, other, cache=rewound,
+                         position=20)
+    _, clean = G.prefill(model, variables, ids[:, :20], chunk=4)
+    want, _ = G.prefill(model, variables, other, cache=clean,
+                        position=20)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(got, whole, rtol=1e-6, atol=1e-6)
+
+
 def test_the_cache_holds_rings_and_a_plane(tiny):
     model = tiny[0]
     cache = G.init_cache(model, 1)
@@ -257,6 +314,20 @@ def test_pair_counters_count_prefill_and_decode(served):
     assert 0 < info["moe_pairs_held_total"] < info["moe_pairs_routed_total"]
     assert "ptpu_serving_moe_pairs_held_total" in metrics
     assert 'ptpu_serving_kv_pool_bytes_by_kind{kind="window"}' in metrics
+
+
+def test_plane_rows_count_chunks_to_their_extent_and_steps_whole(served):
+    """2 requests x 5 chunks of 4: the full layer's plane (64 rows, read
+    to 8, 16, 32 or 64) handed to the extents 4..20 as 8, 8, 16, 16, 32
+    rows.
+    The pool's step reads it whole: it is its layer's own variable
+    (kv_cache.narrows)."""
+    info, metrics = served[4], served[5]
+    steps = info["decode_steps_total"] * info["slots"] * 64
+    assert info["kv_plane_rows_read_total"] == 2 * 80 + steps
+    assert info["kv_plane_rows_held_total"] == 2 * 5 * 64 + steps
+    assert "ptpu_serving_kv_plane_rows_read_total" in metrics
+    assert "ptpu_serving_kv_plane_rows_held_total" in metrics
 
 
 @pytest.mark.parametrize("option", ["paged", "mesh"])

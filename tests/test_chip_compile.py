@@ -236,6 +236,89 @@ def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
     assert mem.temp_size_in_bytes < pool_bytes
 
 
+def _computations(text):
+    """``{name: body}`` of an HLO module's computations."""
+    import re
+
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}", text, re.M | re.S)}
+
+
+def _whole_plane_scores(text, dims):
+    """How many of the computations that the program's conditional
+    branches to make a float32 result whose shape holds ``dims`` (a
+    regex: the whole plane's keys on an axis — scores or weights over
+    all of it); 1 is the widest branch alone.  Fails where such a
+    result is made anywhere else."""
+    import re
+
+    comps = _computations(text)
+    branches = {b.strip().lstrip("%") for m in re.finditer(
+        r"branch_computations=\{([^}]*)\}", text)
+        for b in m.group(1).split(",")}
+    assert branches
+    wide = re.compile(r"= f32\[(?:\d+,)*%s(?:,\d+)*\]\S* " % dims)
+
+    def reach(name, seen):
+        """``name`` and every computation it calls."""
+        if name in seen or name not in comps:
+            return seen
+        seen.add(name)
+        for callee in re.findall(r"(?:calls|to_apply|body|condition)="
+                                 r"%?([\w.\-]+)", comps[name]):
+            reach(callee, seen)
+        return seen
+
+    inside = {b: reach(b, set()) for b in branches}
+    owned = set().union(*inside.values())
+    outside = [n for n, body in comps.items()
+               if n not in owned and wide.search(body)]
+    assert not outside, outside
+    return sum(any(wide.search(comps[n]) for n in names)
+               for names in inside.values())
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_decode_window_reads_no_whole_plane_outside_the_widest_branch(
+        one_chip, on_tpu, monkeypatch, sampled):
+    """gpt2-medium's decode program reads each layer's K and V plane
+    through ONE conditional over the prefix widths
+    (kv_cache.attend_kv_cache): outside its widest branch no operation
+    has a whole plane's shape — none is sliced out of the pool — and
+    none makes scores over all 1 024 keys."""
+    import re
+
+    from polyaxon_tpu.models.kv_cache import prefix_widths
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, pool = _serving_shapes(one_chip)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, SLOTS)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, sampled)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32)]
+    if sampled:
+        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
+                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    text = fn.func.lower(*fn.args, pool, *operands).compile().as_text()
+    conds = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    assert len(conds) == 1      # one layer body, one conditional
+    assert len(conds[0].split(",")) == len(prefix_widths(1024)) == 4
+    # a plane of the pool: [slots, 1, 1024, heads, 64] of any dtype
+    assert not re.search(r"= \w+\[%d,1,1024,16,64\]\S* " % SLOTS, text)
+    # (hidden is 1 024 too: the scores are [.., keys, heads] or
+    # [.., heads, keys])
+    assert _whole_plane_scores(text, "(?:1024,16|16,1024)") == 1
+
+
 def test_meshed_decode_window_compiles(topo, on_tpu):
     """``ptpu serve --mesh tp=4``: the same body under the serving
     mesh's own shardings and its exact layout — heads of the KV pool
@@ -281,17 +364,20 @@ def test_meshed_decode_window_compiles(topo, on_tpu):
 
 @pytest.mark.parametrize("prompt_len", [24, 77, 512])
 def test_engine_prefill_compiles(one_chip, on_tpu, prompt_len):
-    """The engine's prefill program (``jit(G.prefill)``, engine.py) at
-    the prompt lengths the smoke sends and at a long one."""
+    """The engine's prefill program (``G.prefill_programs``,
+    engine.py) at the prompt lengths the smoke sends and at a long
+    one."""
     from polyaxon_tpu.models import generate as G
 
     model, variables, _ = _serving_shapes(one_chip)
     toks = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32,
                                 sharding=one_chip)
-    compiled = jax.jit(
-        lambda variables, toks: G.prefill(model, variables, toks)
-    ).lower(variables, toks).compile()
+    compiled = jax.jit(G.prefill_programs(model)[0]).lower(
+        variables, toks).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
+    # the planes read as far as the prompt's length, chosen while
+    # tracing: nothing to branch on
+    assert " conditional(" not in compiled.as_text()
 
 
 # -- trinity-large-ep8: two kinds of cache in one pool, grouped experts -----
@@ -354,6 +440,11 @@ def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
     lane = r"bf16\[%d,1,(4608|8192),8,128\]" % TRINITY_SLOTS
     assert not re.search(r"= %s\S* (copy|concatenate)\(" % lane, text)
     assert "ragged-dot" in text
+    # The full layer's plane is its layer's own variable, read whole
+    # in the pool's step (kv_cache.narrows): sliced under a conditional
+    # the compiler converted the layout of all of it in every branch,
+    # 2 x 537 MB a step among the temporaries.
+    assert " conditional(" not in text
 
 
 @pytest.mark.parametrize("first", [True, False],
@@ -368,18 +459,27 @@ def test_trinity_prefill_chunk_compiles(one_chip, on_tpu, first):
 
     model, variables, one = _trinity_shapes(one_chip)
     toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    ptpu_prefill, ptpu_extend = G.prefill_programs(model)
     if first:
-        compiled = jax.jit(lambda w, toks: G.prefill(
-            model, w, toks, with_stats=True)).lower(
-                variables, toks).compile()
+        compiled = jax.jit(ptpu_prefill).lower(variables, toks).compile()
     else:
         pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-        compiled = jax.jit(lambda w, cache, toks, pos: G.prefill(
-            model, w, toks, cache=cache, position=pos,
-            with_stats=True)).lower(
-                variables, _abstract(one, one_chip), toks, pos).compile()
+        compiled = jax.jit(ptpu_extend).lower(
+            variables, _abstract(one, one_chip), toks, pos).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
     assert not re.search(
         r"= bf16\[1,(4608|5120),8,128\]\S* concatenate\(", text)
     assert "ragged-dot" in text
+    # The full layer's plane is read as far as the chunk has written
+    # it.  From position 0 that is known while tracing: the first of
+    # the four widths, no conditional, no scores over 8 192 keys
+    # anywhere.  An extended cache: one conditional over the widths,
+    # and outside its widest branch no scores over all 8 192 keys.
+    if first:
+        assert " conditional(" not in text
+        assert not re.search(r"= f32\[(?:\d+,)*8192(?:,\d+)*\]", text)
+        assert re.search(r"= f32\[(?:\d+,)*1024(?:,\d+)*\]", text)
+    else:
+        assert len(re.findall(r" conditional\(", text)) == 1
+        assert _whole_plane_scores(text, "8192") == 1

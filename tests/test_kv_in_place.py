@@ -1,18 +1,25 @@
 """The KV pool is updated IN PLACE (serving/slots.py, models/
 scan_stack.py, models/kv_cache.py).
 
-Four obligations: (a) every program that takes the pool consumes the
+Five obligations: (a) every program that takes the pool consumes the
 tree it was handed, and the manager counts it; (b) the compiled decode
 window aliases every pool leaf to an output and moves nothing of the
 pool's size but the rows it writes; (c) what the pool decodes equals,
 token for token, a forward that has no cache at all; (d) a dispatch
 that fails AFTER it consumed the pool goes to recovery, one that fails
-before it is still retried in place.
+before it is still retried in place; (e) a step reads the planes only
+as far as the pool's furthest position — the same tokens and logits as
+a step that reads them whole, and the rows the host counts are the rows
+the program took.
 """
 
 import collections
+import contextlib
 import dataclasses
+import importlib.util
+import os
 import re
+import types
 
 import numpy as np
 import pytest
@@ -21,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from polyaxon_tpu.models import generate as G
+from polyaxon_tpu.models import kv_cache
 from polyaxon_tpu.models.gpt2 import GPT2Config, GPT2Model
 from polyaxon_tpu.serving import (DecodeEngine, FaultPlan, RetryPolicy)
 from polyaxon_tpu.serving.paged import PagedSlotKVManager
@@ -370,3 +378,242 @@ def test_fault_before_dispatch_still_retries_in_place(small_model):
     assert st["step_retries_total"] == 2
     assert st["kv_pool_lost_total"] == 0
     assert st["requests_requeued_total"] == 0
+
+
+# -- (e) the planes are read as far as the furthest position ----------------
+
+CAP = 64            # _model()'s max_position: widths 8, 16, 32, 64
+
+
+@contextlib.contextmanager
+def _whole_plane():
+    """No read extent reaches a program traced in here: the step body
+    reads every plane whole, as before the bounded read."""
+    from polyaxon_tpu.serving import slots
+
+    real = slots.read_extent
+    slots.read_extent = lambda *a, **kw: contextlib.nullcontext()
+    try:
+        yield
+    finally:
+        slots.read_extent = real
+
+
+@pytest.fixture(scope="module")
+def twin_pools():
+    """For a storage and a variant: two managers over the same model,
+    one whose programs read to the extent and one whose programs read
+    the planes whole, and the prefill of a position.  Compiled once;
+    a case resets them."""
+    memo = {}
+
+    def get(storage, sampled):
+        if (storage, sampled) not in memo:
+            model, variables = _model(int8=(storage == "int8"))
+            pair = (SlotKVManager(model, variables, SLOTS),
+                    SlotKVManager(model, variables, SLOTS))
+            caches = {}
+
+            def cache_at(position):
+                if position not in caches:
+                    toks = (np.arange(position) * 7 + 3) % 32
+                    caches[position] = G.init_cache(model, 1) \
+                        if not position else G.prefill(
+                            model, variables, toks[None].astype(
+                                np.int32))[1]
+                return caches[position]
+
+            memo[(storage, sampled)] = pair, cache_at
+        return memo[(storage, sampled)]
+    return get
+
+
+def _step_both(pair, cache_at, positions, window, sampled):
+    """Reset both managers, seat a stream at each of ``positions``
+    (the other slots stay idle, parked at 0), run one window on each.
+    Returns (tokens, last logits) of the bounded and of the whole
+    read."""
+    out = []
+    for whole, mgr in enumerate(pair):
+        mgr.reset()
+        for slot, position in enumerate(positions):
+            assert mgr.acquire() == slot
+            extra = dict(base_key=_key(slot), **SAMP) if sampled else {}
+            mgr.insert(slot, cache_at(position), 1 + slot, position,
+                       **extra)
+        with _whole_plane() if whole else contextlib.nullcontext():
+            toks = mgr.step(window, sampled, 8)
+        out.append((toks, np.asarray(mgr.last_logits)))
+    return out
+
+
+# The furthest slot at 0; one under, on and one past the first width's
+# edge (a step at position p reads rows [0, p]: extent p + 1); at the
+# last position; and a window of 8 from position 5, whose extents 6..13
+# cross the edge inside the loop.
+EXTENT_CASES = {"at-0": (0, 1), "under-the-edge": (6, 1),
+                "on-the-edge": (7, 1), "past-the-edge": (8, 1),
+                "at-cap-1": (CAP - 1, 1), "window-crosses": (5, 8)}
+
+
+@pytest.mark.parametrize("case", sorted(EXTENT_CASES))
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("storage", ["plain", "int8"])
+def test_bounded_read_equals_the_whole_plane_read(twin_pools, storage,
+                                                  sampled, case):
+    """Two streams at unlike positions and two idle slots: the step
+    that reads to the extent gives the tokens of the step that reads
+    the planes whole, and its logits to float32 rounding."""
+    furthest, window = EXTENT_CASES[case]
+    pair, cache_at = twin_pools(storage, sampled)
+    positions = (furthest, min(furthest, 3))
+    (toks, logits), (toks_w, logits_w) = _step_both(
+        pair, cache_at, positions, window, sampled)
+    live = slice(0, len(positions))
+    assert toks[:, live].tolist() == toks_w[:, live].tolist()
+    np.testing.assert_allclose(logits[live], logits_w[live],
+                               rtol=1e-6, atol=1e-6)
+    # what the host counted is what the extents give: a plane a layer
+    # and slot, every step of the window
+    bounded = pair[0]
+    before = (bounded.plane_reads.read, bounded.plane_reads.held)
+    _step_both(pair[:1], cache_at, positions, window, sampled)
+    widths = kv_cache.prefix_width(
+        furthest + 1 + np.arange(window), CAP)
+    planes = 2 * SLOTS            # layers x slots
+    assert bounded.plane_reads.read - before[0] == planes * widths.sum()
+    assert bounded.plane_reads.held - before[1] == planes * CAP * window
+
+
+def _poisoned(pool, first_row, last_row=None):
+    """The pool with NaN in rows [first_row, last_row) of every VALUE
+    plane: a masked key weighs exactly 0, and 0 x NaN is NaN, so a
+    poisoned row that the program reads shows in every logit."""
+    def one(path, leaf):
+        if jax.tree_util.keystr(path).endswith("cached_value']"):
+            return leaf.at[..., first_row:last_row, :, :].set(jnp.nan)
+        return leaf
+    return jax.tree_util.tree_map_with_path(one, pool)
+
+
+@pytest.mark.parametrize("furthest", [0, 6, 7, 8, 30, CAP - 1])
+def test_the_rows_counted_are_the_rows_the_program_took(twin_pools,
+                                                        furthest):
+    """``prefix_width`` against the compiled program: the step leaves
+    the rows past the counted width unread (poisoned, they change
+    nothing) and reads the last row inside it (poisoned, though masked,
+    it turns the logits to NaN)."""
+    (mgr, _), cache_at = twin_pools("plain", False)
+    positions = (furthest, min(furthest, 3))
+
+    def step(poison):
+        mgr.reset()
+        for slot, position in enumerate(positions):
+            mgr.acquire()
+            mgr.insert(slot, cache_at(position), 1 + slot, position)
+        mgr._stacked = poison(mgr._stacked)
+        before = mgr.plane_reads.read
+        mgr.step(1, False, 8)
+        return (np.asarray(mgr.last_logits)[:len(positions)],
+                (mgr.plane_reads.read - before) // (2 * SLOTS))
+
+    clean, width = step(lambda pool: pool)
+    assert width == kv_cache.prefix_width(furthest + 1, CAP) \
+        >= furthest + 1
+    if width < CAP:
+        beyond, _ = step(lambda pool: _poisoned(pool, width))
+        assert np.array_equal(beyond, clean)
+    if width - 1 > furthest:        # a masked row inside the width
+        inside, _ = step(lambda pool: _poisoned(pool, width - 1, width))
+        assert np.isnan(inside).all()
+
+
+@pytest.mark.parametrize("cap", [5, 64, 100, 1024, 8192])
+def test_prefix_width_covers_the_extent_and_no_more_than_the_plane(cap):
+    choices = kv_cache.prefix_widths(cap)
+    assert choices == tuple(sorted(set(choices))) and choices[-1] == cap
+    assert len(choices) <= len(kv_cache.PREFIX_SHARES)
+    extents = np.arange(0, cap + 40)
+    widths = kv_cache.prefix_width(extents, cap)
+    assert (np.diff(widths) >= 0).all()                 # monotone
+    assert (widths >= np.minimum(extents, cap)).all()   # covers it
+    assert set(widths) == set(choices)
+    # the narrowest that covers: the next narrower one would not
+    branch = kv_cache.prefix_branch(extents, cap)
+    narrower = np.asarray((0,) + choices)[branch]
+    assert (narrower < np.maximum(extents, 1)).all()
+    # the traced branch index is the host's
+    traced = jax.jit(lambda e: kv_cache.prefix_branch(e, cap))(
+        jnp.asarray(extents, jnp.int32))
+    assert np.array_equal(traced, branch)
+
+
+def test_without_an_extent_the_plane_is_read_whole(small_model):
+    """Solo ``generate``'s step, the speculative round, beam search:
+    nothing opens a read extent, no conditional is traced."""
+    model, variables = small_model
+    cache = G.init_cache(model, 1)
+    tok = jnp.zeros((1, 1), jnp.int32)
+
+    def apply(cache, tok):
+        return model.apply({"params": variables["params"],
+                            "cache": cache}, tok, decode=True,
+                           decode_position=0, mutable=["cache"])
+
+    def bounded(cache, tok):    # the scope opens INSIDE what is traced
+        with kv_cache.read_extent():
+            return apply(cache, tok)
+
+    assert "cond" not in str(jax.make_jaxpr(apply)(cache, tok))
+    assert "cond" in str(jax.make_jaxpr(bounded)(cache, tok))
+
+
+def test_engine_counts_plane_rows_of_steps_and_chunks(small_model):
+    model, variables = small_model
+    eng = _engine(model, variables)
+    try:
+        groups = [eng.submit(p, new, None, None, sampling=s)
+                  for p, new, s in _requests()]
+        for g in groups:
+            assert g.event.wait(timeout=120), "hung caller"
+        st = eng.stats()
+    finally:
+        eng.close()
+    # every stream stands under position 17 of 64: no step and no
+    # prefill read past the third of the four widths
+    assert 0 < st["kv_plane_rows_read_total"] \
+        <= st["kv_plane_rows_held_total"] * 32 // CAP
+    # 2 layers: a plane a layer for each prefill, a plane a layer and
+    # slot for each step
+    assert st["kv_plane_rows_held_total"] == 2 * CAP * (
+        st["prefill_chunks_total"] + 4 * st["decode_steps_total"])
+
+
+def _reader(name):
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "layer_metrics",
+        name + ".py")
+    spec = importlib.util.spec_from_file_location("reader_" + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+@pytest.mark.parametrize("info_open,info_close,want", [
+    ({"kv_plane_rows_read_total": 1000, "kv_plane_rows_held_total": 4000},
+     {"kv_plane_rows_read_total": 4000, "kv_plane_rows_held_total": 14000},
+     30.0),
+    ({"kv_plane_rows_read_total": 0, "kv_plane_rows_held_total": 0},
+     {"kv_plane_rows_read_total": 512, "kv_plane_rows_held_total": 512},
+     100.0),
+    # nothing stepped in the window; a program without the counters
+    ({"kv_plane_rows_read_total": 7, "kv_plane_rows_held_total": 9},
+     {"kv_plane_rows_read_total": 7, "kv_plane_rows_held_total": 9},
+     None),
+    ({"decode_steps_total": 1}, {"decode_steps_total": 90}, None),
+], ids=["a-third", "whole", "idle", "parent"])
+def test_kv_plane_read_pct_reader(info_open, info_close, want):
+    ctx = types.SimpleNamespace(collected={"info_open": info_open,
+                                           "info_close": info_close})
+    assert _reader("kv_plane_read_pct")(ctx) == want
