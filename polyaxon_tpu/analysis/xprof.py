@@ -51,7 +51,7 @@ __all__ = ["CATEGORIES", "classify_name", "find_trace_file",
            "attribute_dump", "STEP_MARKER"]
 
 # The TraceAnnotation name the slot managers wrap every decode
-# dispatch in (serving/slots.py step_annotation) — the parser's
+# dispatch in (serving/slots.py SlotManager._dispatch) — the parser's
 # window anchor.
 STEP_MARKER = "ptpu_step"
 

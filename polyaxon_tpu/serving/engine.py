@@ -1282,7 +1282,7 @@ class DecodeEngine:
         thread only (it reads the slot arrays the tick mutates)."""
         if not self._resident:
             return 0.0
-        return float(np.mean([self.slots.positions[s]
+        return float(np.mean([self.slots.state.positions[s]
                               for s in self._resident]))
 
     def run_until_idle(self, max_ticks: int = 100000) -> None:
@@ -2092,7 +2092,7 @@ class DecodeEngine:
                 budget = self._kv_tokens_needed(stream.p_len,
                                                 stream.new)
                 need = min(budget,
-                           int(self.slots.positions[slot]) + span)
+                           int(self.slots.state.positions[slot]) + span)
                 grown = self.slots.grow_slot(slot, need)
                 if grown is None and self.page_reclaim is not None:
                     # STORED-BUT-IDLE prefix pages yield before any
@@ -2165,28 +2165,38 @@ class DecodeEngine:
         the SAME boundary.  Within a window a stream stops consuming
         at its own eos/budget (each token depends only on its prefix
         and rows never interact, so the window's later tokens for that
-        stream are discardable garbage — exactness is untouched)."""
+        stream are discardable garbage — exactness is untouched).
+
+        ONE sequence for every kind of step; the kinds differ in the
+        program the manager runs and in how a window's output is dealt
+        to the streams (``_take_tokens``, ``_take_rounds``)."""
         window = self._pick_window()
         # Program selection is a pool property: any speculative
-        # resident switches the pool to the SPEC program (greedy/
-        # sampled co-tenants ride its one-token plain lane, advancing
-        # by 1 per round while spec slots advance by accept-count);
-        # otherwise one sampled resident selects the sampled program
-        # (greedy co-tenants ride its argmax lane); an all-greedy
-        # pool keeps the cheapest argmax-only program.
-        spec_ks = [s.sampling.spec_k for s in self._resident.values()
-                   if s.sampling.speculative]
-        if spec_ks:
-            self._decode_step_spec(window, max(spec_ks))
-            return
+        # resident switches the pool to the SPEC program of width K,
+        # the largest resident spec_k (greedy/sampled co-tenants ride
+        # its one-token plain lane, advancing by 1 per round while
+        # spec slots advance by accept-count); otherwise one sampled
+        # resident selects the sampled program (greedy co-tenants ride
+        # its argmax lane); an all-greedy pool keeps the cheapest
+        # argmax-only program.
+        K = max((s.sampling.spec_k for s in self._resident.values()
+                 if s.sampling.speculative), default=0)
+        if K:
+            kind = "spec"
+        else:
+            kind = "sampled" if any(
+                s.sampling.sampled
+                for s in self._resident.values()) else "plain"
+        # Positions a slot may write in this dispatch: a window's
+        # tokens, or — a spec round's verify chunk — up to window*K+1
+        # past the last committed token.
         if self.paged and self.slots.lazy \
-                and not self._ensure_lazy_growth(window):
+                and not self._ensure_lazy_growth(
+                    window * K + 1 if K else window):
             # Exhaustion preemptions consumed this boundary (the
             # resident set mutated); the next tick re-plans with the
             # survivors' grown tables.
             return
-        sampled = any(s.sampling.sampled
-                      for s in self._resident.values())
         occupancy = len(self._resident)
         if self.recorder is not None:
             self.recorder.on_step_start()
@@ -2196,13 +2206,15 @@ class DecodeEngine:
             with span("ptpu/lock_wait", self._host_s):
                 self.device_lock.acquire()
             try:
+                if K:       # tokens [W, S, K], commits, accepts [W, S]
+                    return self.slots.step_spec(window, K)
                 return self.slots.step(        # [W, S]
-                    window, sampled, self.policy.decode_window)
+                    window, kind == "sampled", self.policy.decode_window)
             finally:
                 self.device_lock.release()
 
-        toks_w = self._dispatch_step(dispatch)
-        if toks_w is None:
+        out = self._dispatch_step(dispatch)
+        if out is None:
             # Containment resolved the boundary by mutating the
             # resident set (quarantine evictions / a conviction)
             # instead of producing tokens — the next tick re-plans.
@@ -2212,100 +2224,16 @@ class DecodeEngine:
         t1 = time.perf_counter()
         with span("ptpu/commit", self._host_s):
             self.decode_steps_total += window
-            emitted = 0
-            for slot, stream in list(self._resident.items()):
-                if stream.step_logits is not None:
-                    stream.step_logits.append(np.asarray(
-                        self.slots.last_logits[slot]))
-                for w in range(window):
-                    stream.out.append(int(toks_w[w, slot]))
-                    emitted += 1
-                    if stream.done():
-                        break
-                if stream.done():
-                    del self._resident[slot]
-                    self.slots.release(slot)
-                    self.evicted_total += 1
-                    self._note_freed(stream, "complete")
-                    self._complete(stream)   # records the slot id
-                    stream.slot = None
-            self.step_device_s_total += self.slots.last_step_device_s
-            self.step_wall_s_total += t1 - t0
-            if self.recorder is not None:
-                self.recorder.on_step_end(emitted)
-            self.tel.step("step", t0, t1,
-                          kind="sampled" if sampled else "plain",
-                          window=window, occupancy=occupancy,
-                          batch=self.slots.n_slots, tokens=emitted,
-                          device_s=round(self.slots.last_step_device_s,
-                                         6),
-                          **self._host_fields(),
-                          **({"mesh": self.mesh.axes_str()}
-                             if self.mesh is not None else {}),
-                          **({"pages_free": self.slots.free_page_count(),
-                              "pages_total": self.slots.n_pages}
-                             if self.paged else {}))
-
-    def _decode_step_spec(self, window: int, K: int) -> None:
-        """Advance the pool by ``window`` fused SPECULATIVE rounds
-        (program width ``K`` = the largest resident spec_k).  Each
-        spec slot commits its own accepted prefix per round —
-        variable advance — while non-spec co-tenants commit exactly
-        one token per round; budgets are accounted in COMMITTED
-        tokens, and a stream stops consuming at its own eos/budget
-        (later tokens are discardable garbage, exactly like the
-        windowed plain step)."""
-        if self.paged and self.slots.lazy \
-                and not self._ensure_lazy_growth(window * K + 1):
-            # A spec round's verify chunk writes up to window*K+1
-            # positions past the last committed token — grow (or
-            # preempt) for the whole span before dispatch.
-            return
-        occupancy = len(self._resident)
-        if self.recorder is not None:
-            self.recorder.on_step_start()
-        t0 = time.perf_counter()
-
-        def dispatch():
-            with span("ptpu/lock_wait", self._host_s):
-                self.device_lock.acquire()
-            try:
-                return self.slots.step_spec(window, K)
-            finally:
-                self.device_lock.release()
-
-        out = self._dispatch_step(dispatch)
-        if out is None:
-            # Containment mutated the resident set instead of
-            # producing tokens — the next tick re-plans (see the
-            # plain step).
-            if self.recorder is not None:
-                self.recorder.on_step_end(0)
-            return
-        toks, commits, accepts = out
-        t1 = time.perf_counter()
-        with span("ptpu/commit", self._host_s):
-            self.decode_steps_total += window
-            self.spec_rounds_total += window
             emitted = accepted = 0
+            if K:
+                self.spec_rounds_total += window
             for slot, stream in list(self._resident.items()):
-                spec = stream.sampling.speculative
-                for w in range(window):
-                    c = int(commits[w, slot])
-                    if spec:
-                        stream.spec_rounds += 1
-                        stream.spec_drafted += stream.sampling.spec_k
-                        stream.spec_accepted += int(accepts[w, slot])
-                        self.spec_drafted_total += stream.sampling.spec_k
-                        self.spec_accepted_total += int(accepts[w, slot])
-                        accepted += int(accepts[w, slot])
-                    for j in range(c):
-                        stream.out.append(int(toks[w, slot, j]))
-                        emitted += 1
-                        if stream.done():
-                            break
-                    if stream.done():
-                        break
+                if K:
+                    n, a = self._take_rounds(stream, slot, *out)
+                    accepted += a
+                else:
+                    n = self._take_tokens(stream, slot, out)
+                emitted += n
                 if stream.done():
                     del self._resident[slot]
                     self.slots.release(slot)
@@ -2317,10 +2245,11 @@ class DecodeEngine:
             self.step_wall_s_total += t1 - t0
             if self.recorder is not None:
                 self.recorder.on_step_end(emitted)
-            self.tel.step("step", t0, t1, kind="spec", window=window,
-                          k=K, occupancy=occupancy,
+            self.tel.step("step", t0, t1, kind=kind, window=window,
+                          occupancy=occupancy,
                           batch=self.slots.n_slots, tokens=emitted,
-                          accepted=accepted,
+                          **({"k": K, "accepted": accepted}
+                             if K else {}),
                           device_s=round(self.slots.last_step_device_s,
                                          6),
                           **self._host_fields(),
@@ -2329,6 +2258,45 @@ class DecodeEngine:
                           **({"pages_free": self.slots.free_page_count(),
                               "pages_total": self.slots.n_pages}
                              if self.paged else {}))
+
+    def _take_tokens(self, stream: Stream, slot: int, toks) -> int:
+        """Deal a plain or sampled window's tokens ``[W, S]`` to the
+        stream in ``slot``, as far as its own eos/budget; the tokens
+        it took."""
+        if stream.step_logits is not None:
+            stream.step_logits.append(np.asarray(
+                self.slots.last_logits[slot]))
+        for n, tok in enumerate(toks[:, slot], 1):
+            stream.out.append(int(tok))
+            if stream.done():
+                break
+        return n
+
+    def _take_rounds(self, stream: Stream, slot: int, toks, commits,
+                     accepts) -> Tuple[int, int]:
+        """Deal speculative rounds (tokens ``[W, S, K]``, commits and
+        accepts ``[W, S]``) to the stream in ``slot``: a spec slot
+        commits its own accepted prefix per round — variable advance —
+        a co-tenant exactly one token; budgets are accounted in
+        COMMITTED tokens.  Returns the tokens it took and the draft
+        tokens accepted (the acceptance-rate metric)."""
+        spec = stream.sampling.speculative
+        taken = accepted = 0
+        for w in range(len(commits)):
+            if spec:
+                a = int(accepts[w, slot])
+                stream.spec_rounds += 1
+                stream.spec_drafted += stream.sampling.spec_k
+                stream.spec_accepted += a
+                self.spec_drafted_total += stream.sampling.spec_k
+                self.spec_accepted_total += a
+                accepted += a
+            for j in range(int(commits[w, slot])):
+                stream.out.append(int(toks[w, slot, j]))
+                taken += 1
+                if stream.done():
+                    return taken, accepted
+        return taken, accepted
 
     # -- completion -----------------------------------------------------
 
@@ -2703,7 +2671,7 @@ class DecodeEngine:
         without): pairs routed over ALL experts, pairs that fell on
         the experts held here, and the held experts' own counts.  An
         idle slot's dead step counts like a live one."""
-        pairs = getattr(self.slots, "moe_pairs", None)
+        pairs = self.slots.moe_pairs
         if pairs is None:
             return {}
         return {"moe_pairs_routed_total": int(pairs[-1]),

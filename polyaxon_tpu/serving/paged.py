@@ -69,26 +69,31 @@ RESERVATION DISCIPLINE (two modes):
   a sole resident can always grow to its full budget — lazy mode is
   still deadlock-free.
 
+WHAT THIS MANAGER OWNS is the storage: page accounting, tables, the
+pad class of a dispatch, the dirty-page window, gather/scatter, spill
+and the wire format.  The slots' host state and the host half of a
+decode dispatch are ``slots.SlotManager``'s, shared with the fixed
+lanes: ``step`` and ``step_spec`` hand ``_dispatch`` the program for
+a key, the pools' names, the page tables with each slot's first dirty
+page (uploaded with the slots' arrays, inside ``device_s``) and the
+gathered view's width, and nothing else.
+
 Locking: page refcounts and the free list are mutated ONLY under
 ``_page_lock`` (machine-checked by the PAGE-REF rule in
 analysis/rules.py — handler threads pin/unpin prefix pages while the
-engine thread admits and releases).  Slot tables and the decode state
-arrays stay engine-thread-only, like the fixed-lane manager's.
+engine thread admits and releases).  Slot tables and the slots' state
+stay engine-thread-only.
 """
 
 from __future__ import annotations
 
-import contextlib
 import threading
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .slots import (alloc_decode_state, build_spec_step_body,
-                    build_step_body, step_annotation, step_extents)
-from ..models.kv_cache import PlaneReads
-from ..spans import span
+from .slots import (SlotManager, build_spec_step_body,
+                    build_step_body)
 
 __all__ = ["PagedSlotKVManager", "PageExhausted",
            "WirePayloadError", "pack_spilled", "unpack_spilled"]
@@ -224,13 +229,15 @@ def _pow2ceil(n: int) -> int:
     return p
 
 
-class PagedSlotKVManager:
+class PagedSlotKVManager(SlotManager):
     """Fixed pool of ``n_slots`` decode slots over a PAGED KV pool.
 
     Same engine-facing surface as :class:`slots.SlotKVManager`
-    (acquire/release/insert/step/step_spec + the host decode-state
-    arrays), plus the page accounting the engine's admission gate and
-    the server's shared-prefix store ride on (``can_admit`` /
+    (acquire/release/insert/step/step_spec over the shared
+    :class:`slots.SlotManager`: the slots' host state and the decode
+    dispatch are its), plus the page accounting the engine's
+    admission gate and the server's shared-prefix store ride on
+    (``can_admit`` /
     ``pin`` / ``unpin`` / ``scatter_cache`` / ``materialize``).
     """
 
@@ -257,21 +264,15 @@ class PagedSlotKVManager:
             raise ValueError(
                 f"paged KV needs the model's max_position; got "
                 f"{max_position}")
-        self.model = model
-        self.variables = variables
-        self.draft_model = draft_model
-        self.draft_variables = draft_variables
-        self.sentinel = sentinel
-        # Serving mesh (serving/meshed.py): page pools shard their
-        # HEADS axis over tp; page tables/decode state stay host-side
-        # and commit replicated through the programs' explicit
-        # in_shardings.  Gather/scatter move pages within a head
-        # shard — no cross-device math, so paged == fixed-lane
-        # byte-identity holds per mesh shape.
-        self.mesh = mesh
+        super().__init__(model, variables, n_slots, draft_model,
+                         draft_variables, sentinel, mesh)
+        # Meshed, page pools shard their HEADS axis over tp; page
+        # tables/decode state stay host-side and commit replicated
+        # through the programs' explicit in_shardings.  Gather/scatter
+        # move pages within a head shard — no cross-device math, so
+        # paged == fixed-lane byte-identity holds per mesh shape.
         self._pool_sh = None
         self._draft_pool_sh = None
-        self.n_slots = int(n_slots)
         self.page_tokens = int(page_tokens)
         self.max_position = int(max_position)
         pt = self.page_tokens
@@ -326,7 +327,6 @@ class PagedSlotKVManager:
             self.epoch = 0
 
         # -- slot state (engine thread only) ---------------------------
-        self._free = list(range(self.n_slots))
         self.page_tables = np.empty((self.n_slots, self.table_width),
                                     np.int32)
         for s in range(self.n_slots):
@@ -352,7 +352,6 @@ class PagedSlotKVManager:
         self._draft_pool: Optional[List[Any]] = None
         self._draft_meta: Optional[List[Dict[str, Any]]] = None
         self._draft_treedef = None
-        self._step_fns: Dict[Tuple, Any] = {}
         self._insert_fns: Dict[Tuple, Any] = {}
         self._gather_fns: Dict[int, Any] = {}
         # First-touch pool shaping is double-checked under this lock:
@@ -361,22 +360,13 @@ class PagedSlotKVManager:
         # allocate — the loser's pool would replace a pool the winner
         # already wrote pages into, silently dropping its KV.
         self._shape_lock = threading.Lock()
-
-        # -- per-slot decode state (identical to SlotKVManager;
-        # shared helper, also called by crash-recovery reset()) -----
-        alloc_decode_state(self)
-        self.last_step_device_s = 0.0
-        self.host_s = {}    # see SlotKVManager
-        # The fixed-lane manager's in-place counters (engine stats).
-        # The PAGE pool keeps its own discipline — gather the views,
-        # step them (the carried layer loop updates the views in
-        # place), scatter the dirty pages into a NEW pool — and
-        # donates nothing: its dispatches count, none in place.
-        self.kv_pool_dispatches_total = 0
-        self.kv_pool_in_place_total = 0
-        # See SlotKVManager: here a step reads the GATHERED view (the
-        # resident pages, padded) as far as its furthest stream.
-        self.plane_reads = PlaneReads()
+        # Of the shared counters (SlotManager): the PAGE pool keeps
+        # its own discipline — gather the views, step them (the
+        # carried layer loop updates the views in place), scatter the
+        # dirty pages into a NEW pool — and donates nothing, so its
+        # dispatches count and none counts in place; ``plane_reads``
+        # counts a step's reads of the GATHERED view (the resident
+        # pages, padded) as far as its furthest stream.
 
     # -- page accounting ------------------------------------------------
 
@@ -517,17 +507,6 @@ class PagedSlotKVManager:
 
     # -- slot accounting ------------------------------------------------
 
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    @property
-    def active_slots(self) -> int:
-        return self.n_slots - len(self._free)
-
-    def acquire(self) -> Optional[int]:
-        return self._free.pop(0) if self._free else None
-
     def reset(self) -> None:
         """Crash-recovery pool rebuild (recovery.EngineSupervisor):
         every page reference — resident tables, prefix-store pins,
@@ -544,7 +523,6 @@ class PagedSlotKVManager:
             self.refcounts[self.n_pages:] = 1  # scratch/trash pinned
             self._free_pages = list(range(self.n_pages))
             self.epoch += 1     # prior-generation page ids are dead
-        self._free = list(range(self.n_slots))
         for s in range(self.n_slots):
             self.page_tables[s, :] = self.scratch0 + s
         self._slot_pages = [None] * self.n_slots
@@ -552,7 +530,7 @@ class PagedSlotKVManager:
         self._slot_budget[:] = 0
         self._pool = None
         self._draft_pool = None
-        alloc_decode_state(self)
+        self.state.reset()
 
     def release(self, slot: int) -> None:
         """Evict: park the slot (same contract as the fixed-lane
@@ -560,10 +538,7 @@ class PagedSlotKVManager:
         one reference dropped per mapped page, so privately-owned
         pages free immediately while shared prefix pages live on
         under the entries/slots still referencing them."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} already free")
-        self._free.append(slot)
-        self._free.sort()
+        self.state.park(slot)
         held = self._slot_pages[slot]
         if held is not None:
             self._slot_pages[slot] = None
@@ -571,14 +546,6 @@ class PagedSlotKVManager:
         self.page_tables[slot, :] = self.scratch0 + slot
         self._slot_need[slot] = 0
         self._slot_budget[slot] = 0
-        self.tokens[slot] = 0
-        self.positions[slot] = 0
-        self.keys[slot] = 0
-        self.next_index[slot] = 0
-        self.temps[slot] = 0.0
-        self.top_ks[slot] = 0
-        self.top_ps[slot] = 0.0
-        self.spec_ks[slot] = 0
 
     # -- leaf classification / pools ------------------------------------
 
@@ -636,11 +603,6 @@ class PagedSlotKVManager:
             metas.append({"kind": "paged", "pos_axis": axes[0],
                           "shape": leaf.shape, "dtype": leaf.dtype})
         return metas, treedef
-
-    def _exact(self):
-        """Serving-exact trace context (no-op unmeshed)."""
-        return self.mesh.exact() if self.mesh is not None \
-            else contextlib.nullcontext()
 
     def _alloc_pool(self, metas):
         """Zero-init pool leaves (None for index leaves); meshed
@@ -933,17 +895,8 @@ class PagedSlotKVManager:
         self._slot_pages[slot] = (ids, len(shared))
         self._slot_need[slot] = n_need
         self._slot_budget[slot] = n_total
-        self.tokens[slot] = first_token
-        self.positions[slot] = position
-        if base_key is not None:
-            self.keys[slot] = np.asarray(base_key, np.uint32)
-        else:
-            self.keys[slot] = 0
-        self.next_index[slot] = next_index
-        self.temps[slot] = temperature
-        self.top_ks[slot] = top_k
-        self.top_ps[slot] = top_p
-        self.spec_ks[slot] = spec_k
+        self.state.arm(slot, first_token, position, base_key,
+                       next_index, temperature, top_k, top_p, spec_k)
 
     # -- lazy growth (engine thread, step boundaries) --------------------
 
@@ -1153,7 +1106,7 @@ class PagedSlotKVManager:
         prefix page, identical bytes under the serialized device lock
         are benign — content equality is the invariant, and no reader
         can observe a difference)."""
-        d0 = self.positions // self.page_tokens
+        d0 = self.state.positions // self.page_tokens
         return np.clip(d0, 0, max(0, P - n_dirty)).astype(np.int32)
 
     def _build_step(self, window: int, sampled: bool, P: int):
@@ -1168,11 +1121,11 @@ class PagedSlotKVManager:
             body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
-            outs, _, stacked = body(stacked, window, toks, positions,
-                                    *extra)
+            outs, extras, stacked = body(stacked, window, toks,
+                                         positions, *extra)
             pool = self._scatter_dirty(pool, metas, stacked, tables,
                                        d0, n_dirty)
-            return outs, pool
+            return outs, extras, pool
 
         if self.mesh is None:
             return jit_over(self.variables, step)
@@ -1181,67 +1134,26 @@ class PagedSlotKVManager:
         in_sh = (self.mesh.shardings_of(self.variables),
                  self._pool_sh, rep, rep, rep, rep) + (rep,) * n_extra
         return jit_over(self.variables, step, in_shardings=in_sh,
-                        out_shardings=(rep, self._pool_sh))
+                        out_shardings=(rep, rep, self._pool_sh))
 
     def step(self, window: int = 1, sampled: bool = False,
              cap: Optional[int] = None) -> np.ndarray:
-        """``window`` fused decode steps across the whole pool — the
-        paged twin of SlotKVManager.step: gather views, run the SAME
-        decode body, scatter dirty pages.  One compiled program per
-        (window, sampled, pages-per-slot pad class): the dirty-page
-        bound is the window's, so ``cap`` (SlotKVManager.step) buys
-        nothing here and is not used."""
-        import jax
-        import jax.numpy as jnp
-
+        """``window`` fused decode steps across the whole pool: gather
+        views, run the SAME decode body as the fixed lanes, scatter
+        dirty pages.  One compiled program per (window, sampled,
+        pages-per-slot pad class): the dirty-page bound is the
+        window's, so ``cap`` (SlotKVManager.step) buys nothing here
+        and is not used."""
         if self._pool is None:
             raise RuntimeError("step() before any insert()")
         P = self._resident_pad()
-        key = (window, sampled, P)
-        fn = self._step_fns.get(key)
-        if fn is None:
-            if self.sentinel is not None:
-                self.sentinel.miss("slot_step", key)
-            fn = self._step_fns[key] = self._build_step(
-                window, sampled, P)
-        elif self.sentinel is not None:
-            self.sentinel.hit("slot_step", key)
-        host_s = self.host_s
-        with span("ptpu/upload", host_s):
-            tables = jnp.asarray(self.page_tables[:, :P])
-            d0 = jnp.asarray(self._dirty_start(P, self._n_dirty(window)))
-        t0 = time.perf_counter()
-        with self._exact(), step_annotation(window=window):
-            with span("ptpu/upload", host_s):
-                operands = [tables, d0, jnp.asarray(self.tokens),
-                            jnp.asarray(self.positions)]
-                if sampled:
-                    operands += [
-                        jnp.asarray(self.keys),
-                        jnp.asarray(self.next_index),
-                        jnp.asarray(self.temps),
-                        jnp.asarray(self.top_ks),
-                        jnp.asarray(self.top_ps)]
-            with span("ptpu/enqueue", host_s):
-                outs, self._pool = fn(self._pool, *operands)
-                self.kv_pool_dispatches_total += 1
-                self.plane_reads.count(
-                    step_extents(self.positions, window),
-                    lanes=self.n_slots, cap=P * self.page_tokens,
-                    shared=True)
-            # Sync inside the marker so it spans the device
-            # execution, not just the async enqueue (see slots.py).
-            with span("ptpu/sync", host_s):
-                outs = np.asarray(jax.device_get(outs))
-        self.last_step_device_s = time.perf_counter() - t0
-        self.tokens = outs[-1].copy()
-        self.positions = self.positions + window
-        self.next_index = self.next_index + window
-        if self._free:
-            idle = np.asarray(self._free, np.int32)
-            self.tokens[idle] = 0
-            self.positions[idle] = 0
-            self.next_index[idle] = 0
+        outs, = self._dispatch(
+            "sampled" if sampled else "plain", (window, sampled, P),
+            lambda: self._build_step(window, sampled, P), ("_pool",),
+            leading=(self.page_tables[:, :P],
+                     self._dirty_start(P, self._n_dirty(window))),
+            plane_cap=P * self.page_tokens, window=window)
+        self.state.advance(window, outs[-1])
         return outs
 
     def _build_spec_step(self, window: int, K: int, P: int):
@@ -1280,60 +1192,20 @@ class PagedSlotKVManager:
                                        self._draft_pool_sh))
 
     def step_spec(self, window: int, K: int):
-        """``window`` fused SPECULATIVE rounds — the paged twin of
-        SlotKVManager.step_spec.  The in-program rollback stays a
-        pure ``cache_index`` rewind on the gathered view: pages are
-        reserved to budget, so rejection never touches the page
-        accounting (no truncation, no refcount traffic — the
+        """``window`` fused SPECULATIVE rounds.  The in-program
+        rollback stays a pure ``cache_index`` rewind on the gathered
+        view: pages are reserved to budget, so rejection never touches
+        the page accounting (no truncation, no refcount traffic — the
         full-reservation dividend)."""
-        import jax
-        import jax.numpy as jnp
-
         if self._pool is None or self._draft_pool is None:
             raise RuntimeError("step_spec() before a speculative "
                                "insert()")
         P = self._resident_pad()
-        key = (window, "spec", K, P)
-        fn = self._step_fns.get(key)
-        if fn is None:
-            if self.sentinel is not None:
-                self.sentinel.miss("slot_step", key)
-            fn = self._step_fns[key] = self._build_spec_step(
-                window, K, P)
-        elif self.sentinel is not None:
-            self.sentinel.hit("slot_step", key)
-        host_s = self.host_s
-        with span("ptpu/upload", host_s):
-            tables = jnp.asarray(self.page_tables[:, :P])
-            d0 = jnp.asarray(self._dirty_start(
-                P, self._n_dirty(window * K + 1)))
-        t0 = time.perf_counter()
-        with self._exact(), step_annotation(window=window, k=K):
-            with span("ptpu/upload", host_s):
-                operands = [
-                    tables, d0,
-                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                    jnp.asarray(self.next_index), jnp.asarray(self.keys),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks)]
-            with span("ptpu/enqueue", host_s):
-                outs, cs, ms, self._pool, self._draft_pool = fn(
-                    self._pool, self._draft_pool, *operands)
-                self.kv_pool_dispatches_total += 1
-            # Sync inside the marker — see the plain step.
-            with span("ptpu/sync", host_s):
-                outs = np.asarray(jax.device_get(outs))
-                cs = np.asarray(jax.device_get(cs))
-                ms = np.asarray(jax.device_get(ms))
-        self.last_step_device_s = time.perf_counter() - t0
-        rows = np.arange(self.n_slots)
-        adv = cs.sum(axis=0).astype(np.int32)
-        self.tokens = outs[-1, rows, cs[-1] - 1].astype(np.int32)
-        self.positions = self.positions + adv
-        self.next_index = self.next_index + adv
-        if self._free:
-            idle = np.asarray(self._free, np.int32)
-            self.tokens[idle] = 0
-            self.positions[idle] = 0
-            self.next_index[idle] = 0
-        return outs, cs, ms
+        outs, commits, accepts = self._dispatch(
+            "spec", (window, "spec", K, P),
+            lambda: self._build_spec_step(window, K, P),
+            ("_pool", "_draft_pool"),
+            leading=(self.page_tables[:, :P], self._dirty_start(
+                P, self._n_dirty(window * K + 1))), window=window, k=K)
+        self.state.advance_spec(outs, commits)
+        return outs, commits, accepts

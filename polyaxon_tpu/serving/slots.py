@@ -28,6 +28,18 @@ token comes from the verify chunk's FIRST logits row through the
 shared positional sampler — the same value the plain step programs
 produce.
 
+THE HOST HALF OF A DECODE DISPATCH IS WRITTEN ONCE, for this manager
+and the paged one (serving/paged.py) and for every kind of step:
+``SlotManager._dispatch`` — program lookup and the recompile
+sentinel, the step marker, upload, the program's call, the counters,
+one ``device_get``, ``last_step_device_s``.  The slots' host state
+(free list, feedback token, position and sampling operands) is one
+object, ``SlotState``, held by either manager as ``state``.  To a
+dispatch a manager contributes four things and nothing else: the
+program for a key not yet compiled, the pool argument(s) and where
+their successors are rebound, its own leading operands, and the width
+its program sees of the planes.
+
 Device programs, compiled once each per model:
 
 - ``step``:   [S]-stacked cache + toks [S] + positions [S]
@@ -110,19 +122,105 @@ MIXED_CACHE_MSG = (
     "and refuse it")
 
 
-def step_annotation(**stats):
-    """Profiler marker around ONE decode dispatch AND its blocking
-    sync (inside the device lock): when a ``jax.profiler`` trace is
-    active — a manual ``POST /profile/start`` or a flight-recorder
-    window — every step boundary lands in the dump as a named
-    ``ptpu_step`` span (``spans.span``), which the trace parser
-    (analysis/xprof.py) uses to anchor its attribution window and
-    the host-gap math to EXACTLY the profiled step boundaries.  The
-    ``device_get`` sync must stay inside the marker: dispatch alone
-    returns futures, and a marker spanning only the enqueue would
-    let the window clip the final step's device execution.  Inside
-    it lie ``ptpu/upload``, ``ptpu/enqueue`` and ``ptpu/sync``."""
-    return span(STEP_MARKER, **stats)
+class SlotState:
+    """The host's half of the slot pool: which slots are free, and per
+    slot what the next step program is fed — feedback token and
+    absolute position, the sampled variant's operands (base PRNG key,
+    next-token index, shaping params: inert zeros for a greedy or idle
+    slot) and the draft length (> 0 marks a SPECULATIVE slot).
+
+    ONE owner, held by either manager as ``state``: the eight arrays
+    are written slot by slot here and nowhere else, and a
+    crash-recovery ``reset()`` builds them as construction does, so a
+    field added here can never survive a supervised restart carrying
+    stale pre-crash state.  Engine thread only."""
+
+    def __init__(self, n_slots: int):
+        self.n_slots = int(n_slots)
+        self.reset()
+
+    def reset(self) -> None:
+        n = self.n_slots
+        self.free = list(range(n))
+        self.tokens = np.zeros((n,), np.int32)
+        self.positions = np.zeros((n,), np.int32)
+        self.keys = np.zeros((n, 2), np.uint32)
+        self.next_index = np.zeros((n,), np.int32)
+        self.temps = np.zeros((n,), np.float32)
+        self.top_ks = np.zeros((n,), np.int32)
+        self.top_ps = np.zeros((n,), np.float32)
+        self.spec_ks = np.zeros((n,), np.int32)
+
+    def acquire(self) -> Optional[int]:
+        return self.free.pop(0) if self.free else None
+
+    def arm(self, slot: int, first_token: int, position: int,
+            base_key, next_index: int, temperature: float, top_k: int,
+            top_p: float, spec_k: int) -> None:
+        """``first_token`` at ``position`` is the slot's next step
+        input (solo generate's sample-first contract).  A sampled
+        stream brings ``base_key`` (its fold_in(PRNGKey(seed), row)
+        key) and the index the NEXT step draws; a greedy one leaves
+        temperature 0, the sampler's argmax lane."""
+        self.tokens[slot] = first_token
+        self.positions[slot] = position
+        self.keys[slot] = 0 if base_key is None \
+            else np.asarray(base_key, np.uint32)
+        self.next_index[slot] = next_index
+        self.temps[slot] = temperature
+        self.top_ks[slot] = top_k
+        self.top_ps[slot] = top_p
+        self.spec_ks[slot] = spec_k
+
+    def park(self, slot: int) -> None:
+        """Free ``slot`` and park it at position 0, so its dead
+        stepping never drifts into out-of-range position-embedding
+        lookups, with zeroed sampling state, so it steps through the
+        cheap greedy lane of the sampled program."""
+        if slot in self.free:
+            raise ValueError(f"slot {slot} already free")
+        self.free.append(slot)
+        self.free.sort()
+        self.arm(slot, 0, 0, None, 0, 0.0, 0, 0.0, 0)
+
+    def operands(self, kind: str):
+        """The arrays a ``kind`` of step uploads, in its program's
+        argument order (``build_step_body``, ``build_spec_step_body``)."""
+        if kind == "spec":
+            return (self.tokens, self.positions, self.next_index,
+                    self.keys, self.temps, self.top_ks, self.top_ps,
+                    self.spec_ks)
+        if kind == "sampled":
+            return (self.tokens, self.positions, self.keys,
+                    self.next_index, self.temps, self.top_ks,
+                    self.top_ps)
+        return (self.tokens, self.positions)
+
+    def advance(self, window: int, last_tokens) -> None:
+        """Arm the step after a plain or sampled window: every slot
+        feeds back its own last token at the next position (and token
+        index)."""
+        self.tokens = last_tokens.copy()
+        self._move(window)
+
+    def advance_spec(self, outs, commits) -> None:
+        """Arm the round after speculative rounds, from each slot's
+        LAST commit and by as many positions as it committed."""
+        rows = np.arange(self.n_slots)
+        self.tokens = outs[-1, rows, commits[-1] - 1].astype(np.int32)
+        self._move(commits.sum(axis=0).astype(np.int32))
+
+    def _move(self, by) -> None:
+        self.positions = self.positions + by
+        self.next_index = self.next_index + by
+        # Re-park free slots at position 0 so their dead stepping
+        # stays bounded by one window and can never drift past
+        # max_position on a long-lived resident batch.
+        if self.free:
+            idle = np.asarray(self.free, np.int32)
+            self.tokens[idle] = 0
+            self.positions[idle] = 0
+            self.next_index[idle] = 0
 
 
 # -- step-program bodies (shared with the paged manager) --------------------
@@ -134,35 +232,6 @@ def step_annotation(**stats):
 # gather before and a dirty-page scatter after.  Exactness across the
 # two storage disciplines is free by construction: one traced body,
 # two cache layouts with identical materialized content.
-
-
-def alloc_decode_state(mgr) -> None:
-    """(Re)allocate the host-side per-slot decode state the step
-    programs consume: feedback token + absolute position per slot,
-    the sampled variant's extra operands (base PRNG key, next-token
-    index, shaping params — inert zeros for greedy/idle slots), and
-    the per-slot draft length (> 0 marks a SPECULATIVE slot).
-
-    ONE helper shared by SlotKVManager and PagedSlotKVManager, and
-    by BOTH construction and crash-recovery ``reset()`` — so a field
-    added here can never silently survive a supervised restart
-    carrying stale pre-crash state."""
-    n = mgr.n_slots
-    mgr.tokens = np.zeros((n,), np.int32)
-    mgr.positions = np.zeros((n,), np.int32)
-    mgr.keys = np.zeros((n, 2), np.uint32)
-    mgr.next_index = np.zeros((n,), np.int32)
-    mgr.temps = np.zeros((n,), np.float32)
-    mgr.top_ks = np.zeros((n,), np.int32)
-    mgr.top_ps = np.zeros((n,), np.float32)
-    mgr.spec_ks = np.zeros((n,), np.int32)
-
-
-def step_extents(positions, window: int):
-    """The extent each of a window's steps reads the planes to
-    (``build_step_body``): one past the furthest of the pool's
-    positions, which all advance by one a step."""
-    return int(positions.max()) + 1 + np.arange(window)
 
 
 def build_step_body(model, variables, window: int, sampled: bool):
@@ -177,11 +246,10 @@ def build_step_body(model, variables, window: int, sampled: bool):
     ``{"logits": [S, V] float32 of the LAST step run, "pairs": what
     the model sowed under generate.STATS summed over steps, layers and
     slots (the expert layers' token-expert pairs; absent for a model
-    that sows nothing)}``.  The fixed-lane manager returns them
-    from every program, so the programs a benchmark times are the ones
-    whose logits a reference check reads (fetched only on request) and
-    the pair counts ride home with the tokens; a caller that drops
-    them (the paged manager) pays nothing for them.
+    that sows nothing)}``.  Both managers return them from every
+    program, so the programs a benchmark times are the ones whose
+    logits a reference check reads (fetched only on request) and the
+    pair counts ride home with the tokens.
 
     ``steps`` (at most ``window``) is how many steps run; the rows of
     ``outs`` past it stay zero.  A Python int makes the loop one of
@@ -338,61 +406,45 @@ def build_spec_step_body(model, variables, draft, draft_vars,
     return step
 
 
-class SlotKVManager:
-    """Fixed pool of ``n_slots`` decode slots over one model.
+class SlotManager:
+    """What the two KV managers share: the slots' host state
+    (``state``, a :class:`SlotState`) and the host half of ONE decode
+    dispatch (:meth:`_dispatch`), with everything that half counts and
+    keeps.  A subclass owns a storage discipline and nothing of the
+    dispatch: the stacked pools, their pinned formats and donation
+    (:class:`SlotKVManager`); pages, tables, gather and scatter
+    (:class:`~.paged.PagedSlotKVManager`).  Device work only — request
+    bookkeeping lives in engine.py/scheduler.py."""
 
-    Owns the stacked cache pytree (every leaf gains a leading
-    ``n_slots`` axis), the free-slot list, and the jitted step/insert
-    programs.  Device work only — request bookkeeping lives in
-    engine.py/scheduler.py.
-    """
-
-    paged = False
-
-    def __init__(self, model, variables, n_slots: int,
-                 draft_model=None, draft_variables=None,
-                 sentinel=None, mesh=None):
+    def __init__(self, model, variables, n_slots: int, draft_model,
+                 draft_variables, sentinel, mesh):
         self.model = model
         self.variables = variables
         # Draft model for SPECULATIVE slots (optional): its per-slot
-        # caches stack into a second pool stepped by the spec
-        # program's draft scan.
+        # caches make a second pool stepped by the spec program's
+        # draft scan.
         self.draft_model = draft_model
         self.draft_variables = draft_variables
-        # Serving mesh (serving/meshed.py): when set, the stacked
-        # pools live under NamedSharding (heads over tp, slot axis
-        # over dp) and every step/insert program compiles with
-        # EXPLICIT in/out shardings under the serving-exact
-        # constraint mode — meshed output is token-bitwise-identical
-        # to unmeshed (docs/SERVING.md "Meshed serving").
-        self.mesh = mesh
-        self._cache_sh = None         # stacked-pool formats pytree
-        self._draft_cache_sh = None
-        # Whether the pinned row-major layout differs from the
-        # device's default for these leaves (read off the first
-        # prefilled cache): the pool's programs are then compiled in
-        # this process, never read from the persistent cache
-        # (config.fresh_compile says why).
-        self._pin_is_default = True
         # Recompile sentinel (analysis/recompile.py): every step/
         # insert program build is a counted compile-cache miss, so a
         # steady-state recompile storm (an unbounded key leaking into
         # the program set) is observable instead of being mystery
         # tail latency.
         self.sentinel = sentinel
+        # Serving mesh (serving/meshed.py): when set, the pools live
+        # under NamedSharding and every program compiles with EXPLICIT
+        # in/out shardings under the serving-exact constraint mode —
+        # meshed output is token-bitwise-identical to unmeshed
+        # (docs/SERVING.md "Meshed serving").
+        self.mesh = mesh
         self.n_slots = int(n_slots)
-        self._stacked = None          # pytree, leaves [S, ...]
-        self._draft_stacked = None    # draft pytree, leaves [S, ...]
-        self._free = list(range(self.n_slots))
-        self._step_fns = {}           # (window, variant) -> jitted scan
-        self._insert_fns = {}         # draft? -> jitted insert
-        # Host-side per-slot decode state (fed to the step program)
-        # — allocated by the shared helper both construction AND
-        # crash-recovery reset() call, so a new field can never
-        # silently survive a supervised restart with stale state.
-        alloc_decode_state(self)
-        # Wall-clock of the LAST step/step_spec device section
-        # (dispatch + host sync, measured inside the device lock so
+        self.state = SlotState(self.n_slots)
+        self._step_fns = {}           # program key -> jitted step
+        # Whether the pools' pinned layout is the device's default
+        # (``_compiling``).  A manager that pins none leaves it so.
+        self._pin_is_default = True
+        # Wall-clock of the LAST dispatch's device section (upload,
+        # enqueue and host sync, measured inside the device lock so
         # lock wait is excluded) — the engine's step-timeline records
         # report it next to the scheduling wall time.
         self.last_step_device_s = 0.0
@@ -413,7 +465,8 @@ class SlotKVManager:
         # What the last decode program left beside its tokens
         # (build_step_body's ``extras``): the last step's logits [S, V],
         # a device array nobody fetches unless a stream asked for its
-        # logits; and the expert layers' token-expert pairs, summed
+        # logits (None after a speculative round, whose body keeps
+        # none); and the expert layers' token-expert pairs, summed
         # here since the start ([held expert ..., routed]; None until
         # a program of a model that counts them has run).  Prefill
         # programs add theirs through ``count_pairs``.
@@ -427,18 +480,141 @@ class SlotKVManager:
         self.moe_pairs = pairs if self.moe_pairs is None \
             else self.moe_pairs + pairs
 
-    # -- slot accounting ------------------------------------------------
-
     @property
     def free_slots(self) -> int:
-        return len(self._free)
+        return len(self.state.free)
 
     @property
     def active_slots(self) -> int:
-        return self.n_slots - len(self._free)
+        return self.n_slots - len(self.state.free)
 
     def acquire(self) -> Optional[int]:
-        return self._free.pop(0) if self._free else None
+        return self.state.acquire()
+
+    def _exact(self):
+        """Serving-exact trace context (no-op unmeshed) — wraps every
+        call that can TRACE a program over sharded operands."""
+        return self.mesh.exact() if self.mesh is not None \
+            else contextlib.nullcontext()
+
+    def _compiling(self, new: bool):
+        """Context for the call of a pool program: its FIRST call
+        compiles, outside the persistent cache where the pinned
+        layout is not the device's default."""
+        from ..config import fresh_compile
+
+        return fresh_compile() if new and not self._pin_is_default \
+            else contextlib.nullcontext()
+
+    def _count_dispatch(self, *taken) -> None:
+        """One program took the pool(s) whose probe leaves are
+        ``taken``: count it, and count it in place if every one came
+        back consumed."""
+        self.kv_pool_dispatches_total += 1
+        self.kv_pool_in_place_total += all(
+            leaf.is_deleted() for leaf in taken)
+
+    def _dispatch(self, kind: str, key, build, pools, leading=(),
+                  plane_cap=None, **stats):
+        """The host half of ONE decode dispatch, whatever the manager
+        and the ``kind`` of step (``plain``, ``sampled``, ``spec``).
+        The manager brings what its storage decides: the program's
+        ``key`` and ``build()`` for a key not yet compiled; ``pools``,
+        the names of the attributes holding the pool argument(s),
+        rebound to their successors as soon as the program hands them
+        back; its own ``leading`` operands; and the ``plane_cap`` its
+        program's view of the planes has (None: the planes' own).
+        Returns the program's host outputs as numpy arrays:
+        ``[tokens]``, or ``[tokens, commits, accepts]`` of speculative
+        rounds.
+
+        ``stats`` (``window``, ``k``) label the ``ptpu_step`` marker
+        around the dispatch AND its blocking sync (inside the device
+        lock): when a ``jax.profiler`` trace is active — a manual
+        ``POST /profile/start`` or a flight-recorder window — the
+        trace parser (analysis/xprof.py) anchors its attribution
+        window and the host-gap math to EXACTLY these step boundaries.
+        The sync stays INSIDE the marker: dispatch returns device
+        futures, so a marker closing before it would span only the
+        host enqueue and the attribution window would clip the step's
+        actual device execution (inflating MFU by ~K/(K-1) on a real
+        async backend).  Inside it lie ``ptpu/upload``,
+        ``ptpu/enqueue`` and ``ptpu/sync``."""
+        import jax
+        import jax.numpy as jnp
+
+        fn = self._step_fns.get(key)
+        new = fn is None
+        if new:
+            if self.sentinel is not None:
+                self.sentinel.miss("slot_step", key)
+            fn = self._step_fns[key] = build()
+        elif self.sentinel is not None:
+            self.sentinel.hit("slot_step", key)
+        state, host_s, spec = self.state, self.host_s, kind == "spec"
+        t0 = time.perf_counter()
+        with self._exact(), span(STEP_MARKER, **stats):
+            with span("ptpu/upload", host_s):
+                operands = [jnp.asarray(a) for a in
+                            (*leading, *state.operands(kind))]
+            with span("ptpu/enqueue", host_s), self._compiling(new):
+                held = [getattr(self, name) for name in pools]
+                taken = [jax.tree.leaves(pool)[0] for pool in held]
+                out = fn(*held, *operands)
+                for name, pool in zip(pools, out[-len(pools):]):
+                    setattr(self, name, pool)
+                self._count_dispatch(*taken)
+                if not spec:
+                    # The extent each of the window's steps reads the
+                    # planes to (``build_step_body``): one past the
+                    # furthest of the pool's positions, which all
+                    # advance by one a step.
+                    self.plane_reads.count(
+                        int(state.positions.max()) + 1
+                        + np.arange(stats["window"]),
+                        lanes=self.n_slots, cap=plane_cap, shared=True)
+            # A plain or sampled program's last host output is the
+            # body's ``extras``; the speculative body keeps none.
+            host, extras = out[:-len(pools)], {}
+            if not spec:
+                *host, extras = host
+            self.last_logits = extras.get("logits")  # stays on the device
+            with span("ptpu/sync", host_s):
+                *host, pairs = jax.device_get(
+                    (*host, extras.get("pairs")))
+            if pairs is not None:
+                self.count_pairs(pairs)
+        self.last_step_device_s = time.perf_counter() - t0
+        return host
+
+
+class SlotKVManager(SlotManager):
+    """Fixed pool of ``n_slots`` decode slots over one model.
+
+    Owns the stacked cache pytree (every leaf gains a leading
+    ``n_slots`` axis), its pinned formats and the jitted step/insert
+    programs, every one of which takes the pool DONATED.
+    """
+
+    paged = False
+
+    def __init__(self, model, variables, n_slots: int,
+                 draft_model=None, draft_variables=None,
+                 sentinel=None, mesh=None):
+        super().__init__(model, variables, n_slots, draft_model,
+                         draft_variables, sentinel, mesh)
+        # Meshed, the stacked pools shard heads over tp and the slot
+        # axis over dp.
+        self._cache_sh = None         # stacked-pool formats pytree
+        self._draft_cache_sh = None
+        # ``_pin_is_default`` is read off the first prefilled cache:
+        # where the pinned row-major layout differs from the device's
+        # default for these leaves, the pool's programs are compiled
+        # in this process, never read from the persistent cache
+        # (config.fresh_compile says why).
+        self._stacked = None          # pytree, leaves [S, ...]
+        self._draft_stacked = None    # draft pytree, leaves [S, ...]
+        self._insert_fns = {}         # draft? -> jitted insert
 
     def reset(self) -> None:
         """Crash-recovery pool rebuild (recovery.EngineSupervisor):
@@ -449,8 +625,7 @@ class SlotKVManager:
         steady-state recompiles (pinned in tests/test_faults.py)."""
         self._stacked = None
         self._draft_stacked = None
-        self._free = list(range(self.n_slots))
-        alloc_decode_state(self)
+        self.state.reset()
 
     def release(self, slot: int) -> None:
         """Evict: the slot is reusable the SAME step — no device work,
@@ -459,35 +634,14 @@ class SlotKVManager:
         here — eos/budget completion, engine failure, CANCELLATION,
         deadline expiry, and SLO preemption (engine._cancel_group /
         _maybe_preempt) — because the safety argument is identical:
-        the dead slot parks at position 0 with zeroed sampling state,
-        its KV is unreachable until an insert overwrites it
-        wholesale, and a preempted request re-enters through insert()
-        with a freshly prefilled cache rather than trusting anything
-        left here."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} already free")
-        self._free.append(slot)
-        self._free.sort()
-        # Park the idle slot at position 0 so its dead stepping never
-        # drifts into out-of-range position-embedding lookups, and
-        # zero the sampling state so it steps through the cheap
-        # greedy lane of the sampled program.
-        self.tokens[slot] = 0
-        self.positions[slot] = 0
-        self.keys[slot] = 0
-        self.next_index[slot] = 0
-        self.temps[slot] = 0.0
-        self.top_ks[slot] = 0
-        self.top_ps[slot] = 0.0
-        self.spec_ks[slot] = 0
+        the dead slot parks at position 0 with zeroed sampling state
+        (``SlotState.park``), its KV is unreachable until an insert
+        overwrites it wholesale, and a preempted request re-enters
+        through insert() with a freshly prefilled cache rather than
+        trusting anything left here."""
+        self.state.park(slot)
 
     # -- device programs ------------------------------------------------
-
-    def _exact(self):
-        """Serving-exact trace context (no-op unmeshed) — wraps every
-        call that can TRACE a program over sharded operands."""
-        return self.mesh.exact() if self.mesh is not None \
-            else contextlib.nullcontext()
 
     def kv_pool(self):
         """The live main KV pool pytree (None before the first
@@ -535,14 +689,6 @@ class SlotKVManager:
                    for pool in (self._stacked, self._draft_stacked)
                    for leaf in jax.tree.leaves(pool))
 
-    def _count_dispatch(self, *taken) -> None:
-        """One program took the pool(s) whose probe leaves are
-        ``taken``: count it, and count it in place if every one came
-        back consumed."""
-        self.kv_pool_dispatches_total += 1
-        self.kv_pool_in_place_total += all(
-            leaf.is_deleted() for leaf in taken)
-
     def _pool_formats(self, shapes):
         """The device format of every pool leaf: row-major, on the
         mesh's shardings or on the default device.  Row-major is the
@@ -567,15 +713,6 @@ class SlotKVManager:
             lambda l, s: Format(
                 Layout(major_to_minor=tuple(range(l.ndim))), s),
             shapes, sh)
-
-    def _compiling(self, new: bool):
-        """Context for the call of a pool program: its FIRST call
-        compiles, outside the persistent cache where the pinned
-        layout is not the device's default."""
-        from ..config import fresh_compile
-
-        return fresh_compile() if new and not self._pin_is_default \
-            else contextlib.nullcontext()
 
     def _alloc_stacked(self, template_cache):
         """Zero-init the [S, ...] pool in its pinned formats (meshed:
@@ -624,15 +761,9 @@ class SlotKVManager:
                spec_k: int = 0) -> None:
         """Admit a prefilled request into ``slot`` at a step boundary:
         write its B=1 cache into the pool and arm the slot's decode
-        state (``first_token`` at ``position`` is the next step's
-        input, matching solo generate's sample-first contract).
-
-        Sampled streams additionally arm the slot's sampling state:
-        ``base_key`` (the stream's fold_in(PRNGKey(seed), row) key)
-        and ``next_index`` (the token index the NEXT decode step
-        draws — 1, because token 0 was sampled from the prefill
-        logits at admission).  Greedy streams leave the defaults
-        (temperature 0 routes them through the argmax lane).
+        state (``SlotState.arm``; ``next_index`` is 1 for a fresh
+        stream, because token 0 was sampled from the prefill logits
+        at admission).
 
         Speculative streams pass ``draft_cache`` (the DRAFT model's
         prefill of the same prompt) and ``spec_k`` > 0; the spec step
@@ -650,17 +781,8 @@ class SlotKVManager:
                 self._ensure_draft_stacked(draft_cache)
                 self._draft_stacked = self._insert_into(
                     self._draft_stacked, draft_cache, slot, True)
-        self.tokens[slot] = first_token
-        self.positions[slot] = position
-        if base_key is not None:
-            self.keys[slot] = np.asarray(base_key, np.uint32)
-        else:
-            self.keys[slot] = 0
-        self.next_index[slot] = next_index
-        self.temps[slot] = temperature
-        self.top_ks[slot] = top_k
-        self.top_ps[slot] = top_p
-        self.spec_ks[slot] = spec_k
+        self.state.arm(slot, first_token, position, base_key,
+                       next_index, temperature, top_k, top_p, spec_k)
 
     def _insert_into(self, stacked, one, slot: int, draft: bool):
         """``stacked`` with the B=1 cache ``one`` written into
@@ -747,70 +869,15 @@ class SlotKVManager:
         ``_stacked`` named before the call is deleted by it.  If the
         program fails after that, ``pool_lost()`` is true and the
         pool has to be rebuilt (engine._dispatch_step)."""
-        import jax
-        import jax.numpy as jnp
-
         if self._stacked is None:
             raise RuntimeError("step() before any insert()")
         cap = max(cap or window, window)
-        fn = self._step_fns.get((cap, sampled))
-        new = fn is None
-        if new:
-            if self.sentinel is not None:
-                self.sentinel.miss("slot_step", (cap, sampled))
-            fn = self._step_fns[(cap, sampled)] = \
-                self._build_step(cap, sampled)
-        elif self.sentinel is not None:
-            self.sentinel.hit("slot_step", (cap, sampled))
-        host_s = self.host_s
-        t0 = time.perf_counter()
-        with self._exact(), step_annotation(window=window):
-            with span("ptpu/upload", host_s):
-                operands = [jnp.asarray(window, jnp.int32),
-                            jnp.asarray(self.tokens),
-                            jnp.asarray(self.positions)]
-                if sampled:
-                    operands += [
-                        jnp.asarray(self.keys),
-                        jnp.asarray(self.next_index),
-                        jnp.asarray(self.temps),
-                        jnp.asarray(self.top_ks),
-                        jnp.asarray(self.top_ps)]
-            with span("ptpu/enqueue", host_s), self._compiling(new):
-                taken = jax.tree.leaves(self._stacked)[0]
-                outs, extras, self._stacked = fn(self._stacked,
-                                                 *operands)
-                self._count_dispatch(taken)
-                self.plane_reads.count(
-                    step_extents(self.positions, window),
-                    lanes=self.n_slots, shared=True)
-            self.last_logits = extras["logits"]     # stays on the device
-            # The sync stays INSIDE the marker: dispatch returns
-            # device futures, so a marker closing here-minus-one-line
-            # would span only the host enqueue and the attribution
-            # window would clip the step's actual device execution
-            # (inflating MFU by ~K/(K-1) on a real async backend).
-            with span("ptpu/sync", host_s):
-                outs, pairs = jax.device_get((outs, extras.get("pairs")))
-                outs = np.asarray(outs)[:window]
-            if pairs is not None:
-                self.count_pairs(pairs)
-        self.last_step_device_s = time.perf_counter() - t0
-        # Arm the next step: every slot feeds back its own last token
-        # at the next position (and, for sampled slots, the next
-        # token index); idle slots' state is overwritten by the
-        # insert that reactivates them.
-        self.tokens = outs[-1].copy()
-        self.positions = self.positions + window
-        self.next_index = self.next_index + window
-        # Re-park free slots at position 0 so their dead stepping
-        # stays bounded by one window and can never drift past
-        # max_position on a long-lived resident batch.
-        if self._free:
-            idle = np.asarray(self._free, np.int32)
-            self.tokens[idle] = 0
-            self.positions[idle] = 0
-            self.next_index[idle] = 0
+        outs, = self._dispatch(
+            "sampled" if sampled else "plain", (cap, sampled),
+            lambda: self._build_step(cap, sampled), ("_stacked",),
+            leading=(np.int32(window),), window=window)
+        outs = outs[:window]
+        self.state.advance(window, outs[-1])
         return outs
 
     # -- speculative step ------------------------------------------------
@@ -866,51 +933,12 @@ class SlotKVManager:
         — the pool max; slots with smaller ``spec_k`` commit at most
         their own k (exactness per slot is unchanged, see
         _spec_verify_row)."""
-        import jax
-        import jax.numpy as jnp
-
         if self._stacked is None or self._draft_stacked is None:
             raise RuntimeError("step_spec() before a speculative "
                                "insert()")
-        fn = self._step_fns.get((window, "spec", K))
-        new = fn is None
-        if new:
-            if self.sentinel is not None:
-                self.sentinel.miss("slot_step", (window, "spec", K))
-            fn = self._step_fns[(window, "spec", K)] = \
-                self._build_spec_step(window, K)
-        elif self.sentinel is not None:
-            self.sentinel.hit("slot_step", (window, "spec", K))
-        host_s = self.host_s
-        t0 = time.perf_counter()
-        with self._exact(), step_annotation(window=window, k=K):
-            with span("ptpu/upload", host_s):
-                operands = [
-                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                    jnp.asarray(self.next_index), jnp.asarray(self.keys),
-                    jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-                    jnp.asarray(self.top_ps), jnp.asarray(self.spec_ks)]
-            with span("ptpu/enqueue", host_s), self._compiling(new):
-                taken = (jax.tree.leaves(self._stacked)[0],
-                         jax.tree.leaves(self._draft_stacked)[0])
-                outs, cs, ms, self._stacked, self._draft_stacked = fn(
-                    self._stacked, self._draft_stacked, *operands)
-                self._count_dispatch(*taken)
-            # Sync inside the marker — see the plain step.
-            with span("ptpu/sync", host_s):
-                outs = np.asarray(jax.device_get(outs))
-                cs = np.asarray(jax.device_get(cs))
-                ms = np.asarray(jax.device_get(ms))
-        self.last_step_device_s = time.perf_counter() - t0
-        # Arm the next round from the LAST round's per-slot commit.
-        rows = np.arange(self.n_slots)
-        adv = cs.sum(axis=0).astype(np.int32)
-        self.tokens = outs[-1, rows, cs[-1] - 1].astype(np.int32)
-        self.positions = self.positions + adv
-        self.next_index = self.next_index + adv
-        if self._free:
-            idle = np.asarray(self._free, np.int32)
-            self.tokens[idle] = 0
-            self.positions[idle] = 0
-            self.next_index[idle] = 0
-        return outs, cs, ms
+        outs, commits, accepts = self._dispatch(
+            "spec", (window, "spec", K),
+            lambda: self._build_spec_step(window, K),
+            ("_stacked", "_draft_stacked"), window=window, k=K)
+        self.state.advance_spec(outs, commits)
+        return outs, commits, accepts

@@ -175,12 +175,9 @@ def test_decode_window_aliases_the_pool_and_moves_only_rows(
     _fill(mgr, model, variables, sampled=sampled)
     mgr.step(8, sampled)
     fn = mgr._step_fns[(8, sampled)]        # partial(jitted, weights)
-    operands = [jnp.asarray(8, jnp.int32), jnp.asarray(mgr.tokens),
-                jnp.asarray(mgr.positions)]
-    if sampled:
-        operands += [jnp.asarray(x) for x in (
-            mgr.keys, mgr.next_index, mgr.temps, mgr.top_ks,
-            mgr.top_ps)]
+    operands = [jnp.asarray(8, jnp.int32)] + [
+        jnp.asarray(x) for x in mgr.state.operands(
+            "sampled" if sampled else "plain")]
     pool = mgr.kv_pool()
     text = fn.func.lower(*fn.args, pool, *operands).compile().as_text()
 

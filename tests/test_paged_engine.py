@@ -433,6 +433,39 @@ class TestPagedServer:
         finally:
             ms.close()
 
+    def test_logits_on_request_match_the_fixed_lanes(self):
+        """``{"logits": true}`` is accepted by any server; a paged one
+        answered it with an AttributeError in the engine thread while
+        its step programs dropped what the shared body computed.  One
+        dispatch keeps the last step's logits for both managers: the
+        same rows from the same greedy request."""
+        import base64
+
+        from polyaxon_tpu.models.registry import get_model
+        from polyaxon_tpu.serving import ModelServer
+
+        model, variables = get_model("gpt2-tiny").init_params(
+            batch_size=1)
+        body = {"prompt": [5, 6, 7, 8, 9], "max_new_tokens": 10,
+                "logits": True}
+        rows = []
+        for paged in (dict(kv_paged=True, kv_page_tokens=8), {}):
+            ms = ModelServer(model, variables, model_name="gpt2-tiny",
+                             n_slots=2, decode_window=4, **paged)
+            try:
+                reply = ms.generate(dict(body))
+            finally:
+                ms.close()
+            field = reply["logits"]
+            got = np.frombuffer(base64.b64decode(field["b64"]),
+                                "<f4").reshape(field["shape"])
+            assert got.shape == (10, model.cfg.vocab_size)
+            assert reply["new_tokens"][0] == \
+                [int(t) for t in got.argmax(-1)]
+            rows.append(got)
+        np.testing.assert_allclose(rows[0], rows[1], rtol=1e-6,
+                                   atol=1e-6)
+
     def test_http_level_kv_pages_shed(self, small_model):
         ms = self._server(small_model, n_slots=2, kv_pages=2,
                           prefix_cache=0)

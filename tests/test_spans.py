@@ -70,12 +70,23 @@ def test_span_names_closed_and_every_literal_listed():
     assert all(n == "ptpu_step" or n.startswith("ptpu/") for n in names)
     assert spans.STEP_MARKER in names and spans.TRAIN_STEP in names
     used = _span_literals()
-    assert len(used) >= 20      # train.py 6, engine 8, slots/paged 12
+    assert len(used) >= 17      # train.py 6, engine 8, the dispatch 3
     assert [u for u in used if u[1] not in names] == []
     # every listed name is used: a literal, or one of the two markers
-    # that spans.py itself enters (step_span, slots.step_annotation)
+    # entered by name (spans.step_span, slots.SlotManager._dispatch)
     assert set(names) - {n for _, n in used} \
         == {spans.STEP_MARKER, spans.TRAIN_STEP}
+
+
+@pytest.mark.parametrize("name", ["ptpu/upload", "ptpu/enqueue",
+                                  "ptpu/sync"])
+def test_a_dispatch_section_has_one_call_site_in_serving(name):
+    """The host half of a decode dispatch is written once
+    (slots.SlotManager._dispatch), whatever the KV manager and the
+    kind of step."""
+    sites = [f for f, n in _span_literals()
+             if n == name and f.startswith("serving" + os.sep)]
+    assert sites == [os.path.join("serving", "slots.py")]
 
 
 def test_one_place_starts_a_trace_and_none_uses_the_private_session():
@@ -229,19 +240,35 @@ def tiny():
     return get_model("gpt2-tiny").init_params(batch_size=1)
 
 
-def test_engine_tick_spans_nest_and_step_record_fields(tiny, tmp_path):
+@pytest.mark.parametrize("kind", ["plain", "spec"])
+@pytest.mark.parametrize("kv_paged", [False, True],
+                         ids=["lanes", "paged"])
+def test_engine_tick_spans_nest_and_step_record_fields(
+        tiny, tmp_path, kv_paged, kind):
+    """Both KV managers and both kinds of step run the ONE dispatch
+    (slots.SlotManager._dispatch) under the one tick: the same
+    sections, nested the same way, and the same record fields."""
+    from polyaxon_tpu.serving.scheduler import SamplingSpec
+
     model, variables = tiny
     tel = Telemetry(buffer=256)
-    eng = DecodeEngine(model, variables, autostart=False, telemetry=tel,
-                       policy=SchedulerPolicy(n_slots=2, queue_depth=8,
-                                              decode_window=1))
+    spec = kind == "spec"
+    eng = DecodeEngine(
+        model, variables, autostart=False, telemetry=tel,
+        policy=SchedulerPolicy(n_slots=2, queue_depth=8, decode_window=1,
+                               **(dict(kv_paged=True, kv_page_tokens=8)
+                                  if kv_paged else {})),
+        **(dict(draft_model=model, draft_variables=variables)
+           if spec else {}))
+    sampling = SamplingSpec(spec_k=2) if spec else None
     try:
-        warm = eng.submit(np.asarray([[5, 6, 7]], np.int32), 3, None, None)
+        warm = eng.submit(np.asarray([[5, 6, 7]], np.int32), 12, None,
+                          None, sampling=sampling)
         eng.run_until_idle()            # compiles outside the trace
         assert warm.event.is_set()
         before = sum(1 for e in tel.events() if e["name"] == "step")
-        group = eng.submit(np.asarray([[1, 2, 3]], np.int32), 4, None,
-                           None)
+        group = eng.submit(np.asarray([[1, 2, 3]], np.int32), 12, None,
+                           None, sampling=sampling)
         spans.start_trace(str(tmp_path))
         try:
             eng.tick()      # sweep, prefill + admit, one decode step
@@ -272,6 +299,10 @@ def test_engine_tick_spans_nest_and_step_record_fields(tiny, tmp_path):
         assert set(STEP_FIELDS) <= set(rec), rec
         assert all(0 <= rec[f] < 60 for f in STEP_FIELDS)
         assert "device_s" in rec and rec["device_s"] >= rec["sync_s"]
+        assert rec["upload_s"] > 0 and rec["sync_s"] > 0
+        assert rec["kind"] == kind
+        assert ("k" in rec, "accepted" in rec) == (spec, spec)
+        assert ("pages_free" in rec) == kv_paged
     first, second = records[before:before + 2]      # under the trace
     assert first["admit_s"] > 0 and first["enqueue_s"] > 0
     assert second["commit_s"] > 0 and second["admit_s"] == 0.0
@@ -282,26 +313,6 @@ def test_engine_tick_spans_nest_and_step_record_fields(tiny, tmp_path):
         >= stats["step_device_seconds_total"]
     assert 0 < stats["step_device_share"] <= 1
     assert "mesh" not in stats
-
-
-def test_paged_step_has_the_same_sections(tiny):
-    model, variables = tiny
-    tel = Telemetry(buffer=64)
-    eng = DecodeEngine(model, variables, autostart=False, telemetry=tel,
-                       policy=SchedulerPolicy(n_slots=2, queue_depth=8,
-                                              decode_window=1,
-                                              kv_paged=True,
-                                              kv_page_tokens=8))
-    try:
-        group = eng.submit(np.asarray([[1, 2, 3]], np.int32), 3, None,
-                           None)
-        eng.run_until_idle()
-        assert group.event.is_set()
-    finally:
-        eng.close()
-    records = [e["args"] for e in tel.events() if e["name"] == "step"]
-    assert records and all(set(STEP_FIELDS) <= set(r) for r in records)
-    assert all(r["upload_s"] > 0 and r["sync_s"] > 0 for r in records)
 
 
 # ---------------------------------------------------------------------------
