@@ -177,8 +177,14 @@ def _build_serving_model(name: str, batch_size: int,
                          kv_ring: bool = False, kv_ring_slack: int = 0):
     """Shared by ``generate`` and ``serve``: zoo model + variables
     with the serving options applied (int8 KV / ring-cache config,
-    checkpoint restore, weight quantization)."""
+    checkpoint restore, weight quantization, and the tree at rest in
+    the dtype the modules compute in: serving/weights.py), and the
+    float32 bytes that last step rounded.  A draft model's tree goes
+    the same way: both commands build it through this function."""
     from polyaxon_tpu.models.registry import get_model
+    from polyaxon_tpu.serving.weights import (declared_tree,
+                                              rest_as_declared,
+                                              resting_overrides)
 
     spec = get_model(name)
     kw = {}
@@ -245,7 +251,17 @@ def _build_serving_model(name: str, batch_size: int,
         from polyaxon_tpu.ops.quant import quantize_params
 
         variables = {"params": quantize_params(variables["params"])}
-    return model, variables
+    # Made, restored and quantized in float32 as ever; THEN each leaf
+    # is rounded once to what the serving model declares for it (the
+    # float32 buffers go with the old tree).
+    cast_bytes = 0
+    rest = resting_overrides(model)
+    if rest:
+        model = spec.make_model(**kw, **rest)
+        variables, cast_bytes = rest_as_declared(
+            variables, declared_tree(
+                model, spec.make_batch(batch_size)["inputs"]))
+    return model, variables, cast_bytes
 
 
 @cli.command()
@@ -323,7 +339,7 @@ def generate(model_name, prompt, max_new_tokens, temperature, top_k,
     # in-window slots on rollback: build both models with that slack
     # so --kv-ring + --draft-model works out of the box.
     ring_slack = (spec_k - 1) if (kv_ring and draft_model) else 0
-    model, variables = _build_serving_model(
+    model, variables, _ = _build_serving_model(
         model_name, b, checkpoint, int8_kv, int8_weights,
         kv_ring=kv_ring, kv_ring_slack=ring_slack)
     import numpy as np
@@ -350,7 +366,7 @@ def generate(model_name, prompt, max_new_tokens, temperature, top_k,
                     "speculative --top-k/--top-p need --temperature "
                     "> 0 (temperature=0 is greedy and would ignore "
                     "them)")
-            draft, draft_vars = _build_serving_model(
+            draft, draft_vars, _ = _build_serving_model(
                 draft_model, b, draft_checkpoint, int8_kv,
                 int8_weights, kv_ring=kv_ring,
                 kv_ring_slack=ring_slack)
@@ -858,7 +874,7 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
         _check_spec_k(spec_k)
     except ValueError as e:
         raise click.ClickException(str(e))
-    model, variables = _build_serving_model(
+    model, variables, cast_bytes = _build_serving_model(
         model_name, 1, checkpoint, int8_kv, int8_weights,
         kv_ring=kv_ring, kv_ring_slack=kv_ring_slack)
     if (kv_paged or mesh_spec is not None) and getattr(
@@ -871,9 +887,10 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
         # The draft mirrors the target's cache mode: a standard-cache
         # draft would re-impose the max_position bound --kv-ring
         # exists to lift.
-        draft, draft_vars = _build_serving_model(
+        draft, draft_vars, draft_cast = _build_serving_model(
             draft_model, 1, draft_checkpoint, int8_kv, int8_weights,
             kv_ring=kv_ring, kv_ring_slack=kv_ring_slack)
+        cast_bytes += draft_cast
     from polyaxon_tpu.serving.meshed import MeshError
 
     try:
@@ -907,6 +924,7 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
                          request_timeout_s=request_timeout,
                          prefix_cache=prefix_cache,
                          draft_model=draft, draft_variables=draft_vars,
+                         weights_cast_bytes=cast_bytes,
                          spec_k=spec_k,
                          trace_buffer=trace_buffer,
                          profile_dir=profile_dir,

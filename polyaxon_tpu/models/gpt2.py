@@ -37,6 +37,12 @@ class GPT2Config:
     max_position: int = 1024
     layer_norm_eps: float = 1e-5
     dtype: jnp.dtype = jnp.bfloat16
+    # What the Dense and Embed leaves REST in.  float32 for training
+    # and checkpoints; a served tree rests in ``dtype``, rounded once
+    # at start-up (serving/weights.py), where float32 leaves are
+    # rounded on every use.  The LayerNorms compute in float32 and
+    # keep float32 leaves.
+    param_dtype: jnp.dtype = jnp.float32
     # Rematerialize each block in the backward pass: trades ~30% more
     # FLOPs for O(layers) less activation HBM — the standard TPU knob
     # for long sequences / big batches.
@@ -104,7 +110,7 @@ class GPT2Block(nn.Module):
         h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          name="ln1")(x).astype(cfg.dtype)
         qkv = nn.Dense(3 * cfg.hidden_size, dtype=cfg.dtype,
-                       name="qkv")(h)
+                       param_dtype=cfg.param_dtype, name="qkv")(h)
         # Column-parallel output: heads land sharded over tp.
         qkv = constrain(qkv, BATCH, None, "tp")
         q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -126,16 +132,18 @@ class GPT2Block(nn.Module):
         # Row-parallel o_proj: XLA inserts the partial-sum allreduce and
         # the residual returns to the canonical batch-sharded layout.
         x = x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype,
                          name="o_proj")(a)
         x = constrain(x, BATCH, None, None)
 
         h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          name="ln2")(x).astype(cfg.dtype)
         h = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
-                     name="fc1")(h)
+                     param_dtype=cfg.param_dtype, name="fc1")(h)
         h = constrain(h, BATCH, None, "tp")
         h = nn.gelu(h)
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="fc2")(h)
+        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype, name="fc2")(h)
         x = x + h
         return constrain(x, BATCH, None, None)
 
@@ -153,9 +161,11 @@ class GPT2Model(nn.Module):
     def setup(self):
         cfg = self.cfg
         self.wte = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                            dtype=cfg.dtype, name="wte")
+                            dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="wte")
         self.wpe = nn.Embed(cfg.max_position, cfg.hidden_size,
-                            dtype=cfg.dtype, name="wpe")
+                            dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="wpe")
         if cfg.scan_layers:
             # One traced block, rolled over the layer axis; params carry
             # a leading [num_layers] dim (what pipeline_apply stacks

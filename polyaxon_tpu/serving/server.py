@@ -451,6 +451,7 @@ class ModelServer:
                  request_timeout_s: Optional[float] = 600.0,
                  prefix_cache: int = 4,
                  draft_model=None, draft_variables=None,
+                 weights_cast_bytes: int = 0,
                  spec_k: int = 4,
                  mesh=None,
                  trace_buffer: int = 4096,
@@ -553,6 +554,10 @@ class ModelServer:
         # ask for a bigger k, decode solo — see _note_fallback).
         self.draft_model = draft_model
         self.draft_variables = draft_variables
+        # The float32 bytes the serving build rounded once to the
+        # compute dtype before handing the trees over
+        # (serving/weights.py): 0 where they came as declared.
+        self.weights_cast_bytes = int(weights_cast_bytes)
         from ..models.generate import _check_spec_k
 
         _check_spec_k(spec_k)
@@ -3026,6 +3031,17 @@ class ModelServer:
                 "prefix_evict_hints_total": self._evict_hints_total,
             }
 
+    def _weights_stats(self) -> Dict[str, Any]:
+        """The trees the programs are handed, target and draft, as
+        they rest (serving/weights.py): one dict for /info and
+        /metrics."""
+        from .weights import weights_report
+
+        return weights_report(
+            [self.variables, self.draft_variables],
+            getattr(getattr(self.model, "cfg", None), "dtype", None),
+            self.weights_cast_bytes)
+
     def info(self) -> Dict[str, Any]:
         import jax
 
@@ -3123,6 +3139,7 @@ class ModelServer:
                    if self.faults is not None else {}),
                 "kv_paged": self.kv_paged,
                 "kv_lazy": self.kv_lazy,
+                **self._weights_stats(),
                 # Host-spill tier (tentpole b): bytes/entries/hit
                 # counters from the same _spill_stats() dict /metrics
                 # renders.
@@ -3263,6 +3280,17 @@ class ModelServer:
             # caring whether the knob is armed).
             "# TYPE ptpu_serving_stalls_total counter",
             f"ptpu_serving_stalls_total {stalls}",
+        ]
+        ws = self._weights_stats()
+        lines += [
+            "# TYPE ptpu_serving_weights_bytes gauge",
+            f"ptpu_serving_weights_bytes {ws['weights_bytes']}",
+            "# TYPE ptpu_serving_weights_cast_bytes gauge",
+            f"ptpu_serving_weights_cast_bytes "
+            f"{ws['weights_cast_bytes']}",
+            "# TYPE ptpu_serving_weights_bytes_by_dtype gauge",
+            *(f'ptpu_serving_weights_bytes_by_dtype{{dtype="{k}"}} {v}'
+              for k, v in ws["weights_bytes_by_dtype"].items()),
         ]
         # Recompile sentinel (analysis/recompile.py): ONE counter set
         # across the server/engine/slot program caches, rendered by

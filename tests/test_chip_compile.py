@@ -142,17 +142,23 @@ def test_gpt2_medium_train_step_compiles(topo, on_tpu, n_chips):
         < 15.75 * 2 ** 30
 
 
-def _serving_shapes(one_chip):
+def _serving_shapes(one_chip, served=True):
     """gpt2-medium as ``ptpu serve`` holds it: the variables of
-    ``spec.init_params(batch_size=1)`` and the default pool's stacked
-    cache, as shapes on the described chip."""
+    ``spec.init_params(batch_size=1)`` at rest as the serving model
+    declares them (serving/weights.py; ``served=False``: as they are
+    drawn, float32) and the default pool's stacked cache, as shapes on
+    the described chip."""
     from polyaxon_tpu.models import generate as G
     from polyaxon_tpu.models.registry import get_model
+    from polyaxon_tpu.serving.weights import (declared_tree,
+                                              resting_overrides)
 
-    model = get_model("gpt2-medium").make_model()
-    variables = _abstract(jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1024), jnp.int32)), one_chip)
+    spec = get_model("gpt2-medium")
+    model = spec.make_model()
+    if served:
+        model = spec.make_model(**resting_overrides(model))
+    variables = _abstract(declared_tree(
+        model, jax.ShapeDtypeStruct((1, 1024), jnp.int32)), one_chip)
     one = jax.eval_shape(lambda: G.init_cache(model, 1))
     pool = jax.tree.map(
         lambda l: jax.ShapeDtypeStruct((SLOTS,) + l.shape, l.dtype,
@@ -317,6 +323,81 @@ def test_decode_window_reads_no_whole_plane_outside_the_widest_branch(
     # (hidden is 1 024 too: the scores are [.., keys, heads] or
     # [.., heads, keys])
     assert _whole_plane_scores(text, "(?:1024,16|16,1024)") == 1
+
+
+# fc1, fc2, qkv, o_proj (a stack of 24), wte
+WEIGHT_SHAPES = ("24,1024,4096", "24,4096,1024", "24,1024,3072",
+                 "24,1024,1024", "50257,1024")
+
+
+def _weight_converts(text):
+    """The weights' shapes that ``text`` converts from a float32 array
+    it was handed: a ``convert`` to bfloat16, of a weight's shape,
+    whose operand is a float32 parameter of its computation (the
+    program's own, or a fusion's).  A float32 product inside a fusion
+    (the head's multiply-and-reduce over the table on a chip without
+    bfloat16 vector units) is no such pass over memory."""
+    import re
+
+    found = set()
+    for body in _computations(text).values():
+        f32_params = set(re.findall(
+            r"%([\w.\-]+) = f32\[[\d,]*\]\S* parameter\(", body))
+        for shape, operand in re.findall(
+                r"= bf16\[([\d,]*)\]\S* convert\(%([\w.\-]+)\)", body):
+            if shape in WEIGHT_SHAPES and operand in f32_params:
+                found.add(shape)
+    return sorted(found)
+
+
+@pytest.mark.parametrize("program", ["decode-window", "prefill"])
+def test_served_programs_convert_no_weight(one_chip, on_tpu,
+                                           monkeypatch, program):
+    """gpt2-medium's decode window and a prefill program, lowered from
+    the served tree's avals: the compiled program converts no weight
+    from float32, where the same program over the float32 tree
+    converts all five in every dispatch (which also shows that the
+    check can fail)."""
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    def compiled_text(served):
+        model, variables, pool = _serving_shapes(one_chip, served)
+        if program == "prefill":
+            toks = jax.ShapeDtypeStruct((1, 24), jnp.int32,
+                                        sharding=one_chip)
+            return variables, jax.jit(G.prefill_programs(model)[0]) \
+                .lower(variables, toks).compile().as_text()
+        mgr = SlotKVManager(model, variables, SLOTS)
+        mgr._cache_sh = mgr._pool_formats(pool)
+        fn = mgr._build_step(DECODE_WINDOW, True)
+        operands = [
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+            vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+            vec(jnp.float32)]
+        return variables, fn.func.lower(
+            *fn.args, pool, *operands).compile().as_text()
+
+    variables, text = compiled_text(served=True)
+    by_dtype = {}
+    for leaf in jax.tree.leaves(variables):
+        by_dtype[leaf.dtype.name] = by_dtype.get(leaf.dtype.name, 0) \
+            + leaf.size * leaf.dtype.itemsize
+    # 354.8 M parameters in bfloat16; the LayerNorms' 0.1 M in float32
+    assert 0.70e9 < by_dtype["bfloat16"] < 0.72e9
+    assert 0.3e6 < by_dtype["float32"] < 0.5e6
+    assert _weight_converts(text) == []
+    assert "bf16[24,1024,4096]" in text     # the weights ARE there
+    _, text = compiled_text(served=False)
+    assert _weight_converts(text) == sorted(WEIGHT_SHAPES)
 
 
 def test_meshed_decode_window_compiles(topo, on_tpu):
