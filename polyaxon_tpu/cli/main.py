@@ -877,11 +877,11 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
     model, variables, cast_bytes = _build_serving_model(
         model_name, 1, checkpoint, int8_kv, int8_weights,
         kv_ring=kv_ring, kv_ring_slack=kv_ring_slack)
-    if (kv_paged or mesh_spec is not None) and getattr(
-            getattr(model, "cfg", None), "kv_cache_mixed", False):
-        from polyaxon_tpu.serving.slots import MIXED_CACHE_MSG
+    from polyaxon_tpu.serving.slots import pool_refusal
 
-        raise click.ClickException(f"{model_name}: {MIXED_CACHE_MSG}")
+    # A speculative option in play: a draft model, or --spec-k given.
+    speculative = bool(draft_model) or click.get_current_context() \
+        .get_parameter_source("spec_k").name != "DEFAULT"
     draft = draft_vars = None
     if draft_model:
         # The draft mirrors the target's cache mode: a standard-cache
@@ -891,6 +891,11 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
             draft_model, 1, draft_checkpoint, int8_kv, int8_weights,
             kv_ring=kv_ring, kv_ring_slack=kv_ring_slack)
         cast_bytes += draft_cast
+    refusal = pool_refusal((model, draft), paged=kv_paged,
+                           meshed=mesh_spec is not None,
+                           speculative=speculative)
+    if refusal:
+        raise click.ClickException(f"{model_name}: {refusal}")
     from polyaxon_tpu.serving.meshed import MeshError
 
     try:

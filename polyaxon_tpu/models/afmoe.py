@@ -103,10 +103,6 @@ class AfmoeConfig:
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
-    # The cache holds two kinds of leaf: the paged manager and the
-    # serving mesh, which know one, refuse this model.
-    kv_cache_mixed = True
-
     def __post_init__(self):
         bad = [t for t in self.layer_types if t not in (WINDOW, FULL)]
         if bad:

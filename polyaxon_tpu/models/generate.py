@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.quant import dequantize_params
-from .kv_cache import read_extent
+from .kv_cache import leaf_kinds, read_extent
 
 
 def _params(variables):
@@ -922,7 +922,14 @@ def _rollback_cache(cache, new_index):
     Stale entries past the index are invisible (the causal-append mask
     admits only positions <= the query's) and get overwritten by the
     next append, so rollback is just resetting every ``cache_index``
-    leaf — no data movement."""
+    leaf — no data movement.  A ``state`` leaf (kv_cache.leaf_kinds)
+    has no index and no earlier self to return to: refused."""
+    if any(kind == "state" for _, _, kind in leaf_kinds(cache)):
+        raise ValueError(
+            "a cache that holds recurrent state without a position "
+            "axis cannot be rewound: no speculative decoding over "
+            "this model")
+
     def one(path, leaf):
         if jax.tree_util.keystr(path).endswith("cache_index']"):
             return jnp.full_like(leaf, new_index)

@@ -12,6 +12,12 @@ and T5's decoder self-attention.  Two storage disciplines:
 :func:`attend_kv_cache` is the append AND the attention over what it
 wrote: where the caller knows how far the cache is written
 (:func:`read_extent`) the attention reads the planes only that far.
+
+A cache tree may also hold a recurrent layer's state, which has no
+position axis and goes through none of the helpers above
+(models/jamba.py declares it): :func:`leaf_kinds` tells the three
+kinds of leaf apart — ``window``, ``full``, ``state`` — for everyone
+who has to.
 """
 
 from __future__ import annotations
@@ -347,23 +353,79 @@ def prefix_width(extent, cap: int):
     return np.asarray(prefix_widths(cap))[prefix_branch(extent, cap)]
 
 
-def full_planes(cache) -> dict:
-    """``{(capacity, stacked): planes}`` of the full-length key planes
-    in ONE sequence's cache tree — every ``cached_key`` leaf that is no
-    ring's (no ``cached_pos`` beside it), a plane a layer; ``stacked``
-    where the leaf holds its layers on a leading axis (``[layers, B,
-    positions, H, D]``: the stack that decoding carries)."""
+# -- the kinds of leaf ------------------------------------------------------
+#
+# A decode cache tree holds leaves of three kinds, and ONE function
+# says which (``leaf_kinds``): what the slot pool reports by kind, what
+# the bounded reads count, and what a storage discipline refuses at
+# start-up all read it.
+#
+# - ``full``: a plane of ``max_position`` rows and what lies beside it
+#   (its ``cache_index``, int8 scales).  Position-keyed: rewinding the
+#   index rewinds the layer, pages cut it along its position axis, a
+#   mesh shards its heads.
+# - ``window``: a ring's leaves (those beside a ``cached_pos`` table).
+#   Position-keyed too, by the table.
+# - ``state``: a recurrent layer's state (``STATE_LEAVES``): a fixed
+#   size whatever the position, NO position axis, no index.  It is the
+#   whole past after exactly the tokens it has seen: it can be stored,
+#   copied into a slot and carried from piece to piece, never rewound
+#   to an earlier position and never cut into pages.
+
+STATE_LEAVES = ("ssm_state", "conv_tail")
+KINDS = ("window", "full", "state")
+
+
+def leaf_kinds(cache) -> list:
+    """``[(path, leaf, kind)]`` of every leaf of a cache tree (one
+    sequence's, or a pool's), ``kind`` one of :data:`KINDS`."""
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
     name = lambda path: jax.tree_util.keystr(path[-1:])  # noqa: E731
     rings = {path[:-1] for path, _ in flat
              if "cached_pos" in name(path)}
+    return [(path, leaf,
+             "state" if any(s in name(path) for s in STATE_LEAVES)
+             else "window" if path[:-1] in rings else "full")
+            for path, leaf in flat]
+
+
+def cache_kinds(model) -> tuple:
+    """The kinds of leaf ``model``'s decode cache holds, in the order
+    of :data:`KINDS` (shapes alone: nothing is allocated).  A model
+    that makes no decode cache this way holds ``full`` leaves as far
+    as anyone here is concerned."""
+    from .generate import init_cache
+
+    try:
+        tree = jax.eval_shape(lambda: init_cache(model, 1))
+    except Exception:           # not a decoder-only zoo model
+        return ("full",)
+    held = {kind for _, _, kind in leaf_kinds(tree)}
+    return tuple(k for k in KINDS if k in held) or ("full",)
+
+
+def full_planes(cache) -> dict:
+    """``{(capacity, stacked): planes}`` of the full-length key planes
+    in ONE sequence's cache tree — every ``cached_key`` leaf of kind
+    ``full``, a plane a layer; ``stacked`` where the leaf holds its
+    layers on a leading axis (``[layers, B, positions, H, D]``: the
+    stack that decoding carries).  A ``state`` leaf has no rows to
+    read to an extent and is passed by."""
     planes = {}
-    for path, leaf in flat:
-        if "cached_key'" in name(path) and path[:-1] not in rings:
-            kind = (leaf.shape[-3], leaf.ndim > 4)
-            planes[kind] = planes.get(kind, 0) \
+    for path, leaf, kind in leaf_kinds(cache):
+        if kind == "full" and "cached_key'" in \
+                jax.tree_util.keystr(path[-1:]):
+            key = (leaf.shape[-3], leaf.ndim > 4)
+            planes[key] = planes.get(key, 0) \
                 + int(np.prod(leaf.shape[:-3], dtype=np.int64))
     return planes
+
+
+def state_layers(cache) -> int:
+    """How many layers of ONE sequence's cache tree keep a recurrent
+    state (``ssm_state`` leaves)."""
+    return sum("ssm_state" in jax.tree_util.keystr(path[-1:])
+               for path, _, _ in leaf_kinds(cache))
 
 
 class PlaneReads:
@@ -372,18 +434,44 @@ class PlaneReads:
     width, by the same :func:`prefix_branch` the program switches on)
     and the rows those planes hold (``held``), a plane a layer and
     sequence.  Engine stats ``kv_plane_rows_read_total`` /
-    ``kv_plane_rows_held_total``."""
+    ``kv_plane_rows_held_total``.
+
+    And of what the ``state`` leaves went through, for a model that
+    keeps any (``state_layers`` > 0): ``scan_tokens``, positions x
+    state layers through the prefill scan (a piece of more than one
+    position: ``ops/selective_scan.selective_scan``), and
+    ``state_steps``, sequence-steps of the one-position update (a
+    decode step a slot, idle slots too; a prefill piece of one
+    position).  Engine stats ``ssm_scan_tokens_total`` /
+    ``ssm_state_steps_total``."""
 
     def __init__(self):
         self.read = 0
         self.held = 0
         self.planes = None      # full_planes of one sequence's cache
+        self.state_layers = 0
+        self.scan_tokens = 0
+        self.state_steps = 0
 
     def learn(self, cache) -> None:
         """The shape of one sequence's cache, from the first one seen
         (a prefilled request's: every later one has its shape)."""
         if self.planes is None:
             self.planes = full_planes(cache)
+            self.state_layers = state_layers(cache)
+
+    def count_piece(self, piece: int) -> None:
+        """One prefill piece of ``piece`` positions ran."""
+        if self.state_layers:
+            if piece > 1:
+                self.scan_tokens += piece * self.state_layers
+            else:
+                self.state_steps += 1
+
+    def count_steps(self, steps: int, lanes: int) -> None:
+        """A decode window of ``steps`` steps over ``lanes`` slots."""
+        if self.state_layers:
+            self.state_steps += steps * lanes
 
     def count(self, extents, lanes: int = 1, cap=None,
               shared: bool = False) -> None:

@@ -21,6 +21,7 @@ from .afmoe import AfmoeConfig, AfmoeModel
 from .bert import BertConfig, BertModel
 from .convnet import ConvNet
 from .gpt2 import GPT2Config, GPT2Model
+from .jamba import JambaConfig, JambaModel
 from .llama import LlamaConfig, LlamaModel
 from .mlp import MLP
 from .moe_gpt import MoEGPTConfig, MoEGPTModel
@@ -498,6 +499,25 @@ _register(ModelSpec(
     make_model=_cfg_model(AfmoeModel, AfmoeConfig.trinity_large_ep8()),
     make_batch=lambda b: _token_batch(
         b, 8, AfmoeConfig.trinity_large_ep8().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=1,
+))
+
+_register(ModelSpec(
+    name="jamba-tiny",
+    make_model=_cfg_model(JambaModel, JambaConfig.tiny()),
+    make_batch=lambda b: _token_batch(b, 16,
+                                      JambaConfig.tiny().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=8,
+))
+
+# Served only (perfbench cell jamba2-serve-chat), uncut.
+_register(ModelSpec(
+    name="jamba2-3b",
+    make_model=_cfg_model(JambaModel, JambaConfig.jamba2_3b()),
+    make_batch=lambda b: _token_batch(
+        b, 8, JambaConfig.jamba2_3b().vocab_size),
     loss_fn=_lm_loss,
     default_batch_size=1,
 ))

@@ -68,7 +68,7 @@ from .scheduler import (AdmissionQueue, DeadlineExceeded, PRIORITIES,
                         RequestCancelled, RequestGroup, SamplingSpec,
                         SchedulerPolicy, ShedError, Stream,
                         terminal_status)
-from .slots import MIXED_CACHE_MSG, SlotKVManager
+from .slots import SlotKVManager, pool_refusal
 from ..spans import span, take
 from .telemetry import ENGINE_PID, Histogram, Telemetry
 
@@ -99,6 +99,12 @@ class DecodeEngine:
         # is a no-op), and every engine-owned trace runs under the
         # serving-exact constraint mode — output stays token-bitwise
         # identical to the unmeshed engine per seed.
+        refusal = pool_refusal(
+            (model, draft_model),
+            paged=bool((policy or SchedulerPolicy()).kv_paged),
+            meshed=mesh is not None, speculative=draft_model is not None)
+        if refusal:
+            raise ValueError(refusal)
         if mesh is not None:
             from .meshed import ServingMesh
 
@@ -148,9 +154,6 @@ class DecodeEngine:
         # lanes, so occupancy under mixed-length traffic is bounded
         # by token usage, not by the widest request.
         self.paged = bool(self.policy.kv_paged)
-        if getattr(getattr(model, "cfg", None), "kv_cache_mixed",
-                   False) and (self.paged or mesh is not None):
-            raise ValueError(MIXED_CACHE_MSG)
         if self.paged:
             from .paged import PagedSlotKVManager
 
@@ -1510,6 +1513,7 @@ class DecodeEngine:
             # as it had written them (generate.prefill_programs).
             self.slots.plane_reads.learn(cache)
             self.slots.plane_reads.count([stream.filled])
+            self.slots.plane_reads.count_piece(piece)
             stream.pieces.pop(0)
             self.prefill_chunks_total += 1
             self.prefill_tokens_total += piece
@@ -2566,6 +2570,7 @@ class DecodeEngine:
             "kv_plane_rows_read_total": self.slots.plane_reads.read,
             "kv_plane_rows_held_total": self.slots.plane_reads.held,
             **self._moe_stats(),
+            **self._ssm_stats(),
             "completed_total": self.completed_total,
             "completed_greedy_total": self.completed_greedy_total,
             "completed_sampled_total": self.completed_sampled_total,
@@ -2677,6 +2682,18 @@ class DecodeEngine:
         return {"moe_pairs_routed_total": int(pairs[-1]),
                 "moe_pairs_held_total": int(pairs[:-1].sum()),
                 "moe_expert_pairs": [int(n) for n in pairs[:-1]]}
+
+    def _ssm_stats(self) -> Dict[str, Any]:
+        """What the recurrent layers' state went through since the
+        start (nothing for a model without, or before the first
+        prefill shaped the cache): positions x state layers through
+        the prefill scan, and sequence-steps of the one-position
+        update (kv_cache.PlaneReads)."""
+        reads = self.slots.plane_reads
+        if not reads.state_layers:
+            return {}
+        return {"ssm_scan_tokens_total": reads.scan_tokens,
+                "ssm_state_steps_total": reads.state_steps}
 
     def _mesh_stats(self) -> Dict[str, Any]:
         # Under the device lock: the next dispatch consumes the tree

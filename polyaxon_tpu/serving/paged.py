@@ -469,8 +469,9 @@ class PagedSlotKVManager(SlotManager):
 
     @property
     def kv_pool_bytes_by_kind(self) -> Dict[str, int]:
-        """All ``full``: a ring cache never pages (``_classify``)."""
-        return {"window": 0, "full": self.kv_pool_bytes}
+        """All ``full``: neither a ring nor a state ever pages
+        (``_classify``, ``slots.pool_refusal``)."""
+        return {"window": 0, "full": self.kv_pool_bytes, "state": 0}
 
     def pool_lost(self) -> bool:
         """Never: no program consumes the page pool (see

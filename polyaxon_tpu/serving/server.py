@@ -3046,6 +3046,7 @@ class ModelServer:
         import jax
 
         from ..ops.attention import route_counts
+        from ..ops.selective_scan import route_counts as scan_routes
 
         cfg = getattr(self.model, "cfg", None)
         summary = {}
@@ -3087,6 +3088,10 @@ class ModelServer:
                 # Pallas kernel or the fused-XLA route it drops to
                 # (ops/attention.py) — otherwise a silent decision.
                 "attention_routes": route_counts(),
+                # State-space scans traced so far, by path
+                # (ops/selective_scan.py): the Pallas kernel or the
+                # ``lax.scan`` it drops to.
+                "scan_routes": scan_routes(),
                 "max_batch": self.max_batch,
                 "batching": self.batching,
                 "role": self.role,
@@ -3155,6 +3160,11 @@ class ModelServer:
                 **{k: engine[k] for k in
                    ("moe_pairs_routed_total", "moe_pairs_held_total",
                     "moe_expert_pairs") if k in engine},
+                # What the recurrent layers' state went through, where
+                # the model keeps one (engine._ssm_stats).
+                **{k: engine[k] for k in
+                   ("ssm_scan_tokens_total", "ssm_state_steps_total")
+                   if k in engine},
                 **{k: engine[k] for k in
                    ("slots", "slots_active", "slot_occupancy",
                     "queue_len", "queue_depth", "admitted_total",
@@ -3209,6 +3219,8 @@ class ModelServer:
         # One rejection counter, owned by the admission queue (bumped
         # in submit) — the HTTP 429 path and in-process callers both
         # land there, so /metrics and /info can never disagree.
+        from ..ops.selective_scan import route_counts as scan_routes
+
         es = self.engine.stats() if self.engine is not None else {}
         rejected = es.get("rejected_total", 0)
         stalls = self.watchdog.stalls_total \
@@ -3488,6 +3500,17 @@ class ModelServer:
                       f"{n}" for i, n in
                       enumerate(es["moe_expert_pairs"])),
                 ] if "moe_pairs_routed_total" in es else []),
+                *([
+                    "# TYPE ptpu_serving_ssm_scan_tokens_total counter",
+                    f"ptpu_serving_ssm_scan_tokens_total "
+                    f"{es['ssm_scan_tokens_total']}",
+                    "# TYPE ptpu_serving_ssm_state_steps_total counter",
+                    f"ptpu_serving_ssm_state_steps_total "
+                    f"{es['ssm_state_steps_total']}",
+                    "# TYPE ptpu_serving_scan_routes counter",
+                    *(f'ptpu_serving_scan_routes{{route="{k}"}} {n}'
+                      for k, n in scan_routes().items()),
+                ] if "ssm_scan_tokens_total" in es else []),
                 # Speculative scheduling counters + the per-request
                 # acceptance-rate histogram — rendered from the SAME
                 # engine.stats() dict /info reports, so the two

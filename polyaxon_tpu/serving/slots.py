@@ -85,7 +85,9 @@ layout the decode loop works in, so that no program converts the
 whole pool on its way in and out.
 
 Idle slots still step (the batch shape is fixed) — they decode garbage
-into their own cache, which the next ``insert`` overwrites wholesale.
+into their own cache (rows of a plane, or a recurrent layer's whole
+state: ``kv_cache.leaf_kinds``), which the next ``insert`` overwrites
+wholesale.
 That is the standard continuous-batching trade: a fixed physical batch
 so there is exactly ONE compiled decode program, with logical
 occupancy managed above it.
@@ -111,15 +113,48 @@ from typing import Optional
 import numpy as np
 
 from ..analysis.xprof import STEP_MARKER
-from ..models.kv_cache import PlaneReads, read_extent
+from ..models.kv_cache import (KINDS, PlaneReads, cache_kinds,
+                               leaf_kinds, read_extent)
 from ..spans import span
 
 
-MIXED_CACHE_MSG = (
-    "this model keeps two kinds of KV cache in one slot pool (window "
-    "rings beside full-length planes): the fixed-lane slot manager on "
-    "one chip serves it; --kv-paged and --mesh know one kind of leaf "
-    "and refuse it")
+_KIND_WORDS = {"window": "window rings", "full": "full-length planes",
+               "state": "recurrent state without a position axis"}
+
+
+def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
+                 speculative: bool = False) -> Optional[str]:
+    """The ONE line with which a server refuses ``models`` (target
+    and draft; None entries skipped) at start-up, or None where the
+    options in play can hold their caches.  Read off the kinds of leaf
+    the models' decode caches hold (``kv_cache.cache_kinds``):
+    ``--kv-paged`` (``paged._classify`` cuts ONE position axis into
+    pages) and ``--mesh`` (``meshed.cache_shardings`` shards heads)
+    know one kind of leaf and refuse a pool of several, or of state; a
+    speculative slot REWINDS by position (the accept/rewind contract),
+    which a state leaf cannot, so a draft model or ``--spec-k`` refuses
+    a model that keeps one."""
+    if not (paged or meshed or speculative):
+        return None
+    for model in models:
+        if model is None:
+            continue
+        kinds = cache_kinds(model)
+        stateful = "state" in kinds
+        if ((paged or meshed) and (len(kinds) > 1 or stateful)) \
+                or (speculative and stateful):
+            count = {1: "one kind", 2: "two kinds",
+                     3: "three kinds"}[len(kinds)]
+            return (
+                f"this model keeps {count} of KV cache in one slot "
+                f"pool ({' beside '.join(_KIND_WORDS[k] for k in kinds)}"
+                f"): the fixed-lane slot manager on one chip serves it; "
+                f"--kv-paged and --mesh know one kind of leaf, with a "
+                f"position axis, and refuse it"
+                + ("; a state has no position to rewind to, so "
+                   "speculative decoding (--draft-model, --spec-k) "
+                   "refuses it too" if stateful else ""))
+    return None
 
 
 class SlotState:
@@ -459,8 +494,9 @@ class SlotManager:
         self.kv_pool_dispatches_total = 0
         self.kv_pool_in_place_total = 0
         # How far the attention read the full-length planes, against
-        # what they hold (kv_cache.PlaneReads): every decode step
-        # here, every prefill chunk by the engine.
+        # what they hold, and what the state leaves went through
+        # (kv_cache.PlaneReads): every decode step here, every
+        # prefill chunk by the engine.
         self.plane_reads = PlaneReads()
         # What the last decode program left beside its tokens
         # (build_step_body's ``extras``): the last step's logits [S, V],
@@ -573,6 +609,8 @@ class SlotManager:
                         int(state.positions.max()) + 1
                         + np.arange(stats["window"]),
                         lanes=self.n_slots, cap=plane_cap, shared=True)
+                    self.plane_reads.count_steps(stats["window"],
+                                                 self.n_slots)
             # A plain or sampled program's last host output is the
             # body's ``extras``; the speculative body keeps none.
             host, extras = out[:-len(pools)], {}
@@ -663,19 +701,13 @@ class SlotKVManager(SlotManager):
     @property
     def kv_pool_bytes_by_kind(self) -> dict:
         """``kv_pool_bytes`` split by the kind of cache a leaf belongs
-        to: ``window`` for a ring's leaves (those beside a
-        ``cached_pos`` table: kv_cache.append_ring_kv_cache), ``full``
-        for everything else."""
-        import jax
-
-        out = {"window": 0, "full": 0}
+        to (``kv_cache.leaf_kinds``): ``window`` for a ring's leaves,
+        ``state`` for a recurrent layer's, ``full`` for everything
+        else."""
+        out = dict.fromkeys(KINDS, 0)
         for pool in (self._stacked, self._draft_stacked):
-            flat = jax.tree_util.tree_flatten_with_path(pool)[0]
-            rings = {path[:-1] for path, _ in flat
-                     if "cached_pos" in jax.tree_util.keystr(path[-1:])}
-            for path, leaf in flat:
-                out["window" if path[:-1] in rings else "full"] += \
-                    leaf.nbytes
+            for _, leaf, kind in leaf_kinds(pool):
+                out[kind] += leaf.nbytes
         return out
 
     def pool_lost(self) -> bool:

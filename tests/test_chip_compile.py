@@ -564,3 +564,101 @@ def test_trinity_prefill_chunk_compiles(one_chip, on_tpu, first):
     else:
         assert len(re.findall(r" conditional\(", text)) == 1
         assert _whole_plane_scores(text, "8192") == 1
+
+
+# -- jamba2-3b: state without a position axis beside two MQA planes ---------
+
+JAMBA_SLOTS = 128       # perfbench/configs/ai21-jamba2-3b.json
+
+
+def _jamba_shapes(one_chip):
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.models.registry import get_model
+
+    model = get_model("jamba2-3b").make_model()
+    variables = _abstract(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32)), one_chip)
+    one = jax.eval_shape(lambda: G.init_cache(model, 1))
+    return model, variables, one
+
+
+def test_jamba_scan_kernel_compiles_at_the_served_piece(one_chip, on_tpu):
+    """One layer's selective scan over a 128-position piece at the
+    published widths (d_inner 5 120, d_state 16), gated, a state
+    carried in: the Mosaic compiler takes it."""
+    from polyaxon_tpu.ops import selective_scan as S
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(S.selective_scan).lower(
+        arr(1, 128, 5120), arr(1, 128, 5120), arr(16, 5120),
+        arr(1, 128, 16), arr(1, 128, 16), arr(5120,), arr(1, 16, 5120),
+        arr(1, 128, 5120)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+
+
+def test_jamba_prefill_piece_takes_the_kernel(one_chip, on_tpu):
+    """A 128-token piece of the whole model (``--prefill-chunk 128``):
+    every one of the 26 Mamba layers through the Pallas scan, 6.06 GB
+    of weights, one slot's cache out."""
+    from polyaxon_tpu.models import generate as G
+
+    model, variables, one = _jamba_shapes(one_chip)
+    toks = jax.ShapeDtypeStruct((1, 128), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(G.prefill_programs(model)[0]).lower(
+        variables, toks).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 26
+    weights = sum(l.size * l.dtype.itemsize
+                  for l in jax.tree.leaves(variables))
+    assert 6.0e9 < weights < 6.1e9
+    # The single KV head is not padded to a tile: a slot's cache and
+    # the logits come out in 10.9 MB (10.37 MB logical + 0.26).
+    assert mem.output_size_in_bytes < 11.5e6
+    assert mem.temp_size_in_bytes < 0.5 * 2 ** 30
+
+
+def test_jamba_decode_window_keeps_state_and_planes_in_place(
+        one_chip, on_tpu, monkeypatch):
+    """The decode program as the slot manager builds it, 128 slots:
+    every pool leaf — state, tails, planes — aliased to an output, the
+    one-position update plain XLA (no kernel), everything within the
+    chip beside the weights."""
+    from polyaxon_tpu.models.kv_cache import leaf_kinds
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, one = _jamba_shapes(one_chip)
+    pool = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((JAMBA_SLOTS,) + l.shape,
+                                       l.dtype, sharding=one_chip), one)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, JAMBA_SLOTS)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, True)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((JAMBA_SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+                vec(jnp.float32)]
+    compiled = fn.func.lower(*fn.args, pool, *operands).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    by_kind = {}
+    for _, leaf, kind in leaf_kinds(pool):
+        by_kind[kind] = by_kind.get(kind, 0) \
+            + leaf.size * leaf.dtype.itemsize
+    assert 1.18e9 < by_kind["state"] < 1.20e9       # 9.32 MB a slot
+    assert 0.13e9 < by_kind["full"] < 0.14e9        # 1.05 MB a slot
+    assert mem.alias_size_in_bytes >= sum(by_kind.values())
+    # ...and 1.495 GB as it rests: the tail's 3 rows in a tile of 8.
+    assert mem.alias_size_in_bytes < 1.6e9
+    assert mem.temp_size_in_bytes < 0.5 * 2 ** 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert "tpu_custom_call" not in text
