@@ -40,9 +40,22 @@ the slot pool doesn't speak).
 Threading: ``submit`` may be called from any handler thread; all slot
 and queue mutation happens on the engine loop thread (or, in tests,
 via manual ``tick()`` calls with the loop not started — never both).
-Device work (prefill chunks, decode steps) runs under ``device_lock``
-shared with the solo path, so engine ticks and solo requests
-interleave at step granularity.
+Device work (prefill chunks, decode steps) is ENQUEUED under
+``device_lock`` shared with the solo path, so engine ticks and solo
+requests interleave at step granularity.
+
+THE LOOP THREAD NEVER WAITS FOR WORK IT HAS JUST ENQUEUED.  A prefill
+piece is enqueued and not awaited; whatever device work a stream's
+first token needs is enqueued right behind its LAST piece and
+admission fetches the finished scalar; and the decode dispatch runs
+ONE AHEAD: dispatch N+1 is launched before dispatch N's tokens are
+fetched, wherever the boundary between them can be decided without
+those tokens (``_serial_reason`` names what forbids it).  While
+residents exist the device's queue is then never empty: the host
+deals out N's tokens, completes streams and enqueues the next
+admission while N+1 runs.  The tokens are bitwise the serial order's
+— the programs' arithmetic is the same, only WHEN the host reads it
+moves (docs/SERVING.md "The tick, one dispatch ahead").
 """
 
 from __future__ import annotations
@@ -52,7 +65,7 @@ import sys
 import threading
 import time
 import traceback
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -69,7 +82,7 @@ from .scheduler import (AdmissionQueue, DeadlineExceeded, PRIORITIES,
                         SchedulerPolicy, ShedError, Stream,
                         terminal_status)
 from .slots import SlotKVManager, pool_refusal
-from ..spans import span, take
+from ..spans import STEP_MARKER, span, take
 from .telemetry import ENGINE_PID, Histogram, Telemetry
 
 __all__ = ["DecodeEngine", "QueueFullError", "SPEC_ACCEPT_BUCKETS"]
@@ -77,6 +90,23 @@ __all__ = ["DecodeEngine", "QueueFullError", "SPEC_ACCEPT_BUCKETS"]
 # Acceptance-rate histogram bucket upper bounds (le) for completed
 # speculative requests; the last implicit bucket is +Inf.
 SPEC_ACCEPT_BUCKETS = (0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
+
+
+class _InFlight:
+    """One decode dispatch from its launch to its commit: the
+    manager's :class:`~.slots.Flight` and the engine's own snapshot
+    of it.  ``streams`` is the dispatch's slot -> (stream, tokens it
+    will take) as launched: by the time it is committed a slot may
+    hold another stream (a foreseen eviction re-armed it), so tokens
+    are dealt by THIS map, never by ``_resident``."""
+
+    __slots__ = ("flight", "k", "streams", "occupancy")
+
+    def __init__(self, flight, k: int, streams: dict):
+        self.flight = flight
+        self.k = k
+        self.streams = streams
+        self.occupancy = len(streams)
 
 
 class DecodeEngine:
@@ -207,10 +237,26 @@ class DecodeEngine:
         self._thread_lock = threading.Lock()
         self._wake = threading.Condition()
         self._stop = False
-        # jitted first-token sampler for sampled admissions (token
-        # index 0, drawn from the prefill logits) — compiled once,
-        # shared by every stream
-        self._admit_sample_fn = None
+        # jitted first-token programs (token index 0, from the
+        # prefill logits): sampled? -> the positional sampler, or the
+        # argmax — each compiled once, shared by every stream
+        self._first_fns: Dict[bool, Any] = {}
+        # How far prefill may run ahead of the device: pieces enqueued
+        # and not yet finished (``_pieces``: their logits, oldest
+        # first), each of which holds a lane — a slot's worth of KV —
+        # outside the pool.  A sixteenth of the pool, two at least:
+        # what the pool's own size says the device can spare.
+        self._pieces_cap = max(2, self.policy.n_slots // 16)
+        self._pieces: "deque" = deque()
+        # The decode dispatch launched and not yet collected (None:
+        # nothing in flight), and how the dispatches were ordered:
+        # launched before the previous one was collected (ahead), or
+        # not, by the name of what forbade it (``_serial_reason``;
+        # ``first``: nothing was in flight to run ahead of).
+        self._flight: Optional[_InFlight] = None
+        self.decode_dispatches_total = 0
+        self.decode_dispatches_ahead_total = 0
+        self.decode_serial_reasons: Dict[str, int] = {}
         # counters (read unlocked by metrics — monotonic ints);
         # admitted/completed split by mode so pool utilization under
         # mixed greedy/sampled load is observable
@@ -273,7 +319,6 @@ class DecodeEngine:
         # cumulative histogram — lifetime bucket counts never decay,
         # so one bad period would otherwise latch aggressive batch
         # preemption until process restart.
-        from collections import deque
         self._ttft_recent: "deque[float]" = deque(maxlen=64)
         # Sweep fast path: the boundary sweep scans residents + the
         # whole queue, which is pure waste for deployments that never
@@ -289,11 +334,11 @@ class DecodeEngine:
         # 503), finish everything already accepted — the /drain
         # endpoint's engine half.  One-way per engine lifetime.
         self.draining = False
-        # Meshed step accounting: cumulative device wall (dispatch +
-        # sync, from the manager's last_step_device_s) vs scheduling
-        # wall per decode dispatch — a host-clock ESTIMATE of device
-        # time; the flight recorder below is the device-truth
-        # counterpart.
+        # Meshed step accounting: cumulative device wall (the step
+        # markers' seconds: launch + wait, the records' ``device_s``)
+        # vs scheduling wall per decode dispatch — a host-clock
+        # ESTIMATE of device time; the flight recorder below is the
+        # device-truth counterpart.
         self.step_device_s_total = 0.0
         self.step_wall_s_total = 0.0
         # Seconds in the tick's host sections (spans.span) since the
@@ -777,6 +822,13 @@ class DecodeEngine:
             except ValueError:
                 pass
         self._resident.clear()
+        # Streams that left their slots at a launch and wait for its
+        # tokens live in the dispatch in flight alone.
+        landing, self._flight = self._flight, None
+        for stream, _ in (landing.streams.values() if landing else ()):
+            stream.in_flight = 0
+            stream.group.fail(err)
+            self._record_history(stream.group)
         while True:
             stream = self.queue.pop_head()
             if stream is None:
@@ -850,17 +902,25 @@ class DecodeEngine:
         (cancellations, expired deadlines, queue-deadline sheds),
         preempt a batch resident if the interactive TTFT SLO demands
         it, admit/prefill within the policy budget, then one decode
-        step over the resident batch.  Returns whether any work was
-        done.  Single-threaded by contract (loop thread, or tests
-        driving it manually)."""
+        dispatch over the resident batch — launched BEFORE the one in
+        flight is collected where the boundary allows
+        (``_decode_step``), so a prefill piece enqueued here runs
+        behind the dispatch in flight and ahead of the next.  Returns
+        whether any work was done.  Single-threaded by contract (loop
+        thread, or tests driving it manually)."""
         with span("ptpu/sweep"):
             worked = self._sweep_lifecycle()
             if self._maybe_preempt():
                 worked = True
-        budget = self.policy.prefill_budget(bool(self._resident),
-                                            self.slots.free_slots)
+        # A piece is enqueued, not awaited, and its lane is allocated
+        # when it is enqueued: the budget of a boundary is held to
+        # ``_pieces_cap``, which also bounds the prefilled streams
+        # that wait a boundary for their first token.
+        budget = min(self._pieces_cap, self.policy.prefill_budget(
+            bool(self._resident), self.slots.free_slots))
+        waiting: list = []      # heads whose first token is on its way
         while budget > 0:
-            stream = self._queue_head()
+            stream = self._queue_head(waiting)
             if stream is None:
                 break
             if stream.group.error is not None:
@@ -872,16 +932,28 @@ class DecodeEngine:
                 self._note_blocked(stream)
                 break
             with span("ptpu/prefill", self._host_s):
-                self._advance_prefill(stream)
+                piece, settled = self._advance_prefill(stream)
             worked = True
-            budget -= 1
-        if self._resident:
+            # The budget counts prefill CHUNKS: admitting a stream
+            # whose prompt was consumed at an earlier boundary runs
+            # none (free slots bound those).
+            budget -= piece
+            if not settled:
+                # The head's first token is on its way behind the
+                # piece just enqueued: the next boundary reads it
+                # finished.  The budget left goes to those behind it.
+                waiting.append(stream)
+        if self._resident or self._flight is not None:
             # The tick's sections belong to no request: their stats
             # say what the pool looked like.
             with span("ptpu/decode", occupancy=len(self._resident),
                       batch=self.slots.n_slots):
                 self._decode_step()
             worked = True
+        if not worked:
+            # Going idle: no dispatch is coming to bring the last
+            # prefill pieces' pair counts home.
+            self.slots.flush_pairs()
         # Step-boundary bookkeeping for the debuggability layer: the
         # watchdog's progress signal and the published /debug/state
         # snapshot (throttled to board_interval_s) — host-side only,
@@ -959,16 +1031,20 @@ class DecodeEngine:
             return False
         return True
 
-    def _queue_head(self) -> Optional[Stream]:
+    def _queue_head(self, skip=()) -> Optional[Stream]:
         """Admission head: the class-aware queue head, SKIPPING
         streams under an active exhaustion bar — a barred evictee
         (possibly of a higher class) must never head-of-line-block
-        the stream it was evicted for."""
+        the stream it was evicted for — and those in ``skip``: heads
+        the tick has already served, whose first token is on its way
+        (they keep their place: the next boundary admits them in this
+        order, ahead of whoever was prefilled behind them)."""
         head = self.queue.head()
-        if head is None or not self._stream_barred(head):
+        if head is None or not (self._stream_barred(head)
+                                or head in skip):
             return head
         for s in self.queue.snapshot():
-            if not self._stream_barred(s):
+            if not (self._stream_barred(s) or s in skip):
                 return s
         return None
 
@@ -1133,6 +1209,10 @@ class DecodeEngine:
         and wake the waiter."""
         status = terminal_status(err)
         self.queue.drop_group(group)
+        # With a dispatch in flight the slot is free from HERE, but
+        # that dispatch still steps it: the tokens it brings for the
+        # group are never dealt (``_forget``).
+        self._forget(group.streams)
         for slot, stream in list(self._resident.items()):
             if stream.group is not group:
                 continue
@@ -1214,7 +1294,7 @@ class DecodeEngine:
         for slot, stream in self._resident.items():
             if stream.group.priority != "batch":
                 continue
-            rem = stream.new - len(stream.out)
+            rem = stream.remaining
             if victim is None or rem > victim[2]:
                 victim = (slot, stream, rem)
         if victim is None:
@@ -1252,8 +1332,14 @@ class DecodeEngine:
         ``release=False`` skips the slot release for crash recovery,
         whose wholesale pool rebuild (slots.reset) makes per-slot
         release both redundant and — paged — unsafe (the page
-        accounting it would touch is about to be reset)."""
-        del self._resident[slot]
+        accounting it would touch is about to be reset).
+
+        Tokens of the stream in a dispatch in flight are dropped with
+        it (``_forget``): they were never committed, and the resume
+        draws them again at the same position keys."""
+        self._forget((stream,))
+        if self._resident.get(slot) is stream:
+            del self._resident[slot]
         if release:
             self.slots.release(slot)
         self.evicted_total += 1
@@ -1308,7 +1394,10 @@ class DecodeEngine:
         - Every RESIDENT stream is requeued through the preempt-
           resume path: its committed tokens are host-side state, so
           resumption is token-identical per seed however the engine
-          died (pinned in tests/test_faults.py).
+          died (pinned in tests/test_faults.py).  So is every stream
+          that had left its slot at a launch and was waiting for that
+          dispatch's tokens: tokens in flight were never committed,
+          and the dispatch in flight is dropped whole.
         - Every PARTIAL PREFILL (and stored-prefix seed) is reset to
           re-prefill from its tokens — the partial cache referenced
           a device state the crash made untrustworthy; chunked
@@ -1335,8 +1424,16 @@ class DecodeEngine:
         # Exhaustion bars die with the pool generation: the rebuilt
         # all-free pool has no pending growth to protect.
         self._exhaust_bars.clear()
+        self._pieces.clear()
         n = 0
-        for slot, stream in sorted(list(self._resident.items())):
+        landing, self._flight = self._flight, None
+        departed = [(slot, stream) for slot, (stream, _) in
+                    (landing.streams.items() if landing else ())
+                    if self._resident.get(slot) is not stream
+                    and not stream.group.event.is_set()]
+        for slot, stream in sorted(
+                list(self._resident.items()) + departed,
+                key=lambda at: at[0]):
             self._evict_requeue(slot, stream, "crash_requeued", now,
                                 release=False)
             n += 1
@@ -1351,6 +1448,7 @@ class DecodeEngine:
                 stream.cache = None
                 stream.d_cache = None
                 stream.logits = None
+                stream.first = None
                 stream.pf_done = False
                 stream.blocked_t = None
         with self.device_lock:
@@ -1447,14 +1545,29 @@ class DecodeEngine:
                        self._pf_cap, build,
                        sentinel=self.sentinel, kind="draft_prefill")
 
-    def _advance_prefill(self, stream: Stream) -> None:
-        """Run ONE prefill piece for the head-of-queue stream; admit it
-        into a slot when the prompt is fully consumed AND a slot is
-        free (prefill works AHEAD while all slots are busy, so a
-        freshly evicted slot admits an already-prefilled request the
-        same boundary).  Chunked prefill is position-keyed cache
+    def _advance_prefill(self, stream: Stream) -> Tuple[bool, bool]:
+        """ENQUEUE one prefill piece for the head-of-queue stream;
+        admit it into a slot when the prompt is fully consumed AND a
+        slot is free (prefill works AHEAD while all slots are busy,
+        so a freshly evicted slot admits an already-prefilled request
+        the same boundary).  Chunked prefill is position-keyed cache
         extension (models/generate._prefill): piecewise equals
-        one-shot, so interleaving changes latency, never tokens."""
+        one-shot, so interleaving changes latency, never tokens.
+
+        The piece is not awaited: nothing the host does next needs
+        its result but the next piece (a device operand) and the
+        first token, whose device work is enqueued right behind the
+        LAST piece (``_enqueue_first``).  With a decode dispatch in
+        flight the piece runs behind it, so the stream is admitted at
+        the NEXT boundary, which reads a finished scalar; with
+        nothing in flight the device is idle anyway and admission
+        waits for the piece here, as it always did.
+
+        Returns ``(piece, settled)``: whether a piece was enqueued
+        (what the tick's prefill budget counts), and whether the
+        stream is done with for this boundary as the queue's head
+        (False: it waits there for its first token, and the tick
+        passes over it)."""
         import jax
 
         group = stream.group
@@ -1467,7 +1580,8 @@ class DecodeEngine:
             # full-length prefix hits).
             self._emit(stream, "queue", group.t_submit,
                        stream.t_prefill_start, row=stream.row)
-        if stream.pieces:               # full-length prefix hits skip
+        ran = bool(stream.pieces)
+        if ran:                         # full-length prefix hits skip
             piece = stream.pieces[0]
             # pf_toks, not toks: a PREEMPTED stream re-prefills
             # prompt ++ committed[:-1] (Stream.prepare_resume) so its
@@ -1498,16 +1612,31 @@ class DecodeEngine:
                                 piece, False)(stream.d_cache, toks,
                                               stream.filled)
                         stream.d_cache = d_cache
-                    jax.block_until_ready(logits)
-                    if pairs is not None:
-                        # The expert layers' pair counts of the piece:
-                        # a small output of the program just waited for.
-                        self.slots.count_pairs(jax.device_get(pairs))
+                    stream.logits = logits
+                    if len(stream.pieces) == 1:
+                        self._enqueue_first(stream)
             except BaseException as e:
                 self._fail_group(group, e)
-                return
+                return True, True
+            # Never more than ``_pieces_cap`` pieces ahead of the
+            # device: a wait only where the host has run that far
+            # ahead, so the device's queue is long, not empty.
+            self._pieces.append(logits)
+            while len(self._pieces) > self._pieces_cap:
+                try:
+                    jax.block_until_ready(self._pieces.popleft())
+                except Exception:
+                    # A failed piece is its own stream's to meet, at
+                    # the fetch of its first token (_admit).
+                    import logging
+
+                    logging.getLogger(__name__).debug(
+                        "an earlier prefill piece failed",
+                        exc_info=True)
+            # The expert layers' pair counts of the piece ride home
+            # with the next dispatch's tokens.
+            self.slots.defer_pairs(pairs)
             stream.cache = cache
-            stream.logits = logits
             stream.filled += piece
             # The chunk's attention read the full-length planes as far
             # as it had written them (generate.prefill_programs).
@@ -1521,9 +1650,19 @@ class DecodeEngine:
                        time.perf_counter(), row=stream.row,
                        piece=piece, filled=stream.filled)
             if stream.pieces:
-                return                  # more prompt to consume
+                return True, True       # more prompt to consume
         if not stream.pf_done:
             stream.pf_done = True
+            if stream.first is None:
+                # No piece ran here (a full-length prefix hit): the
+                # first token comes from the logits the stream
+                # brought.
+                try:
+                    with self.device_lock, self._exact():
+                        self._enqueue_first(stream)
+                except BaseException as e:
+                    self._fail_group(group, e)
+                    return ran, True
             # Never on a resumed stream: its pf_toks mix generated
             # tokens into the prefill, which must not be stored back
             # as a prompt prefix.
@@ -1538,49 +1677,77 @@ class DecodeEngine:
                     logging.getLogger(__name__).debug(
                         "on_prefilled hook failed", exc_info=True)
         if not self._can_admit_stream(stream):
-            return          # wait, fully prefilled, for slot/pages
+            return ran, True    # wait, fully prefilled, for slot/pages
+        if ran and self._flight is not None:
+            return ran, False   # its first token: the next boundary
         # Pop THIS stream, never "the head": a concurrent interactive
         # submit can change the class-aware head between the tick's
         # head() and this pop (scheduler.AdmissionQueue.pop_stream).
         self.queue.pop_stream(stream)
         with span("ptpu/admit", self._host_s):
             self._admit(stream)
+        return ran, True
 
-    def _first_token(self, stream: Stream, logits: np.ndarray) -> int:
-        """Token 0 for an admitted stream, from the prefill logits.
-        Greedy: host argmax (np and jnp agree on first-max
-        tie-breaking).  Sampled: the SAME position-keyed sampler the
-        slot step program runs, at token index 0, with the stream's
-        fold_in(PRNGKey(seed), row) base key — jitted once so
-        admission stays cheap."""
+    def _enqueue_first(self, stream: Stream) -> None:
+        """Enqueue, right behind the stream's LAST prefill piece (the
+        caller holds the device lock), whatever device work admission
+        needs, and leave the futures on ``stream.first`` as ``(token
+        0, base key)``: admission fetches finished scalars instead of
+        launching programs it then waits for.
+
+        Token 0 comes from the prefill logits.  Greedy: the argmax
+        (np and jnp agree on first-max tie-breaking).  Sampled: the
+        SAME position-keyed sampler the slot step program runs
+        (``generate._sample_positional_row``), at token index 0, with
+        the stream's ``fold_in(PRNGKey(seed), row)`` base key — each
+        jitted once.  A RESUMED stream draws none: all its committed
+        tokens exist.
+
+        The base key is made for every stream whose slot will want
+        one and has none yet: sampled streams, and speculative ones —
+        greedy speculative streams never draw token 0 from the PRNG,
+        but the spec step program still wants the slot's base key
+        operand (the sampled lanes are dead at temperature 0 — zeros
+        would work — yet arming the real key keeps one invariant:
+        every speculative slot's key is ``fold_in(PRNGKey(seed),
+        row)``).  A CROSS-REPLICA resumed sampled stream (submit
+        ``resume_tokens=``) drew its token 0 in a prior attempt, so
+        its key is made here too: same fold_in, a pure function of
+        the request."""
         import jax
+        import jax.numpy as jnp
 
-        spec = stream.sampling
-        if not spec.sampled:
-            return int(np.argmax(logits))
         from ..models import generate as G
 
-        if stream.base_key is None:
-            # device_get, not bare np.asarray: the sync is 8 bytes
-            # and intentional — spell it so (HOST-SYNC).
-            stream.base_key = np.asarray(jax.device_get(
-                jax.random.fold_in(jax.random.PRNGKey(spec.seed),
-                                   stream.row)))
-        if self._admit_sample_fn is None:
-            self.sentinel.miss("admit_sample")
-            self._admit_sample_fn = jax.jit(
-                lambda l, k, t, tk, tp:
-                G._sample_positional_row(l, k, 0, t, tk, tp))
-        with self.device_lock:
-            return int(self._admit_sample_fn(
-                logits, stream.base_key,
-                np.float32(spec.temperature), np.int32(spec.top_k),
-                np.float32(spec.top_p)))
+        spec = stream.sampling
+        key = tok = None
+        if stream.base_key is None and (spec.sampled
+                                        or spec.speculative):
+            key = jax.random.fold_in(jax.random.PRNGKey(spec.seed),
+                                     stream.row)
+        if not stream.resume:
+            fn = self._first_fns.get(spec.sampled)
+            if fn is None:
+                self.sentinel.miss("admit_sample" if spec.sampled
+                                   else "admit_argmax")
+                fn = self._first_fns[spec.sampled] = jax.jit(
+                    (lambda l, k, t, tk, tp:
+                     G._sample_positional_row(l[0], k, 0, t, tk, tp))
+                    if spec.sampled else
+                    (lambda l: jnp.argmax(l[0]).astype(jnp.int32)))
+            tok = fn(stream.logits,
+                     stream.base_key if key is None else key,
+                     np.float32(spec.temperature),
+                     np.int32(spec.top_k), np.float32(spec.top_p)) \
+                if spec.sampled else fn(stream.logits)
+        stream.first = (tok, key)
 
     def _admit(self, stream: Stream) -> None:
-        """Step-boundary admission: first token from the prefill
-        logits (argmax, or the position-keyed sampler for sampled
-        streams), cache into a free slot.  Device failures
+        """Step-boundary admission: fetch the first token
+        (``_enqueue_first`` put its device work behind the last
+        prefill piece), cache into a free slot — the insertion is
+        ENQUEUED, behind whatever dispatch is in flight: the donated
+        pool orders them on the device.  Device failures
         (including the FIRST insert's lazy stacked-pool allocation —
         the engine's largest device buy) release the slot and fail
         the group: a waiter must never hang on an admission that
@@ -1600,17 +1767,24 @@ class DecodeEngine:
         #                              exhaustion bar
         spec = stream.sampling
         resumed = stream.resume
-        if not resumed:
-            try:
-                logits = np.asarray(jax.device_get(stream.logits))[0]
-                first = self._first_token(stream, logits)
-            except BaseException as e:
-                self.slots.release(slot)
-                self._fail_group(stream.group, e)
-                return
-            stream.out.append(first)
-            if stream.step_logits is not None:
-                stream.step_logits.append(logits)
+        try:
+            # HOST-SYNC: 4 bytes, and a sampled stream's 8 of key,
+            # enqueued behind the last prefill piece
+            # (_enqueue_first); a failure of that piece surfaces here.
+            first, key = jax.device_get(stream.first)
+            if first is not None and stream.step_logits is not None:
+                # HOST-SYNC: on request only ({"logits": true}).
+                stream.step_logits.append(
+                    np.asarray(jax.device_get(stream.logits))[0])
+        except BaseException as e:
+            self.slots.release(slot)
+            self._fail_group(stream.group, e)
+            return
+        stream.first = None
+        if key is not None:
+            stream.base_key = np.asarray(key)
+        if first is not None:
+            stream.out.append(int(first))
         stream.t_admit = time.perf_counter()
         stream.group.t_last_admit = stream.t_admit
         if stream.group.t_first_admit is None:
@@ -1651,21 +1825,6 @@ class DecodeEngine:
             self._count_admitted(spec, stream.group.priority)
             self.evicted_total += 1
             return
-        if (spec.speculative or (resumed and spec.sampled)) \
-                and stream.base_key is None:
-            # Greedy speculative streams never drew token 0 from the
-            # PRNG, but the spec step program still wants the slot's
-            # base key operand (the sampled lanes are dead at
-            # temperature 0 — zeros would work — yet arming the real
-            # key keeps one invariant: every speculative slot's key
-            # is fold_in(PRNGKey(seed), row)).  A CROSS-REPLICA
-            # resumed sampled stream (submit resume_tokens=) skipped
-            # _first_token on THIS engine entirely — its token 0 was
-            # drawn by the prior attempt — so the key is armed here:
-            # same fold_in, pure function of the request.
-            stream.base_key = np.asarray(jax.device_get(
-                jax.random.fold_in(jax.random.PRNGKey(spec.seed),
-                                   stream.row)))
         kw = {}
         if self.paged:
             # Ownership of the pinned shared pages passes to insert
@@ -1818,7 +1977,7 @@ class DecodeEngine:
         # budget (no wasted rounds, and — because a spec round's
         # verify chunk touches up to position + spec_k — no slot ever
         # writes past the capacity the server validated).
-        rem = min((s.new - len(s.out)) //
+        rem = min(s.remaining //
                   (s.sampling.spec_k if s.sampling.speculative else 1)
                   for s in self._resident.values())
         w, cap = 1, min(cap, max(1, rem))
@@ -1830,16 +1989,19 @@ class DecodeEngine:
 
     def _host_fields(self) -> Dict[str, float]:
         """The step record's host sections: seconds since the last
-        record, taken and reset.  ``upload_s``, ``enqueue_s``,
-        ``sync_s`` and ``lock_wait_s`` are this dispatch's (they lie
-        inside ``device_s``, but for the lock); ``admit_s`` and
-        ``prefill_s`` (one ``_advance_prefill`` outside its ``_admit``)
-        the admissions and prefill pieces since the last record;
-        ``commit_s`` the commit that ended since then, which is the
-        previous step's."""
+        record, taken and reset, so that no second is in two records.
+        ``device_s`` is the step markers' own time (a host clock
+        around launch and wait: one marker in steady state, holding
+        the NEXT dispatch's ``upload_s`` and ``enqueue_s`` and this
+        one's ``sync_s``; the lock wait lies outside it); ``admit_s``
+        and ``prefill_s`` (one ``_advance_prefill`` outside its
+        ``_admit``) the admissions and prefill pieces since the last
+        record; ``commit_s`` the commit that ended since then, which
+        is the previous dispatch's."""
         tick, step = self._host_s, self.slots.host_s
         admit = take(tick, "ptpu/admit")
         return {
+            "device_s": take(step, STEP_MARKER),
             "upload_s": take(step, "ptpu/upload"),
             "enqueue_s": take(step, "ptpu/enqueue"),
             "sync_s": take(step, "ptpu/sync"),
@@ -2139,7 +2301,7 @@ class DecodeEngine:
             _bslot, bstream = blocked
             victim = None
             for slot, stream in self._resident.items():
-                rem = stream.new - len(stream.out)
+                rem = stream.remaining
                 if victim is None or rem > victim[2]:
                     victim = (slot, stream, rem)
             slot, stream, _rem = victim
@@ -2163,17 +2325,108 @@ class DecodeEngine:
             if not self._resident:
                 return False
 
+    def _serial_reason(self) -> Optional[str]:
+        """Why the boundary ahead CANNOT be decided without the
+        tokens of the dispatch in flight — the name the dispatch is
+        counted under (``decode_serial_reasons``) — or None where it
+        can: every eviction ahead is then a budget eviction, which
+        the host foresees from ``Stream.remaining`` alone, and
+        positions, token indices and sampling operands are the
+        host's to compute.  Read off what the engine can observe at
+        the boundary, never an option; a serial dispatch is the same
+        code collected at once (``_decode_step``).
+
+        - ``drain``: draining or closing — what is in flight is all
+          that is left to wait for;
+        - ``fault``: an armed fault injector, or quarantine suspects:
+          the containment ladder's in-place retry and its bisection
+          re-dispatch the SAME boundary, which needs the serial order;
+        - ``paged``: ``--kv-lazy`` page growth reads the positions
+          the last dispatch committed and may evict on exhaustion;
+        - ``lock_waiter``: a handler thread waits on the device lock —
+          it gets the device between two dispatches, not behind two;
+        - ``spec``: a speculative resident — commit counts are data;
+        - ``eos``: a resident that may stop at any token;
+        - ``logits``: a resident that keeps each dispatch's LAST
+          step's logits;
+        - ``deadline``: an armed deadline on a resident, or an
+          interactive head under an armed TTFT SLO — every boundary
+          is a delivery or preemption point."""
+        if self.draining or self._stop:
+            return "drain"
+        if self.faults is not None or self._suspects:
+            return "fault"
+        if self.paged and self.slots.lazy:
+            return "paged"
+        waiters = getattr(self.device_lock, "waiters", None)
+        if waiters is not None and waiters():
+            return "lock_waiter"
+        found = set()
+        for s in self._resident.values():
+            if s.sampling.speculative:
+                found.add("spec")
+            if s.eos_id is not None:
+                found.add("eos")
+            if s.step_logits is not None:
+                found.add("logits")
+            if s.group.deadline is not None:
+                found.add("deadline")
+        for reason in ("spec", "eos", "logits", "deadline"):
+            if reason in found:
+                return reason
+        if self.policy.slo_ttft_s is not None:
+            head = self.queue.head()
+            if head is not None \
+                    and head.group.priority == "interactive":
+                return "deadline"
+        return None
+
+    def _forget(self, streams) -> None:
+        """Drop ``streams`` from the dispatch in flight: whatever it
+        brings for them is never dealt (a cancelled, failed, expired
+        or requeued stream's tokens in flight were never committed)."""
+        landing = self._flight
+        for stream in streams:
+            stream.in_flight = 0
+            if landing is not None:
+                for slot in [slot for slot, (held, _)
+                             in landing.streams.items()
+                             if held is stream]:
+                    del landing.streams[slot]
+
     def _decode_step(self) -> None:
-        """Advance every resident stream by one fused window of decode
-        steps; evict finished streams so their slots are admissible
-        the SAME boundary.  Within a window a stream stops consuming
+        """Launch one fused window of decode steps over the resident
+        streams and commit one: the dispatch launched at the LAST
+        boundary, collected only now that its successor is in the
+        device's queue — or, where the boundary cannot be decided
+        without the tokens in flight (``_serial_reason``), today's
+        order: collect and commit what is in flight, plan, launch,
+        collect and commit.  Within a window a stream stops consuming
         at its own eos/budget (each token depends only on its prefix
         and rows never interact, so the window's later tokens for that
         stream are discardable garbage — exactness is untouched).
 
-        ONE sequence for every kind of step; the kinds differ in the
-        program the manager runs and in how a window's output is dealt
-        to the streams (``_take_tokens``, ``_take_rounds``)."""
+        ONE sequence for every kind of step and both orders; the
+        kinds differ in the program the manager runs and in how a
+        window's output is dealt to the streams (``_take_tokens``,
+        ``_take_rounds``), the orders in WHICH dispatch the marker's
+        collect half waits for (``SlotManager._dispatch``)."""
+        ahead = self._flight
+        reason = self._serial_reason() if self._resident else "drain"
+        t0 = time.perf_counter()
+        if ahead is not None and reason is not None:
+            # The boundary waits for the tokens in flight (or nothing
+            # is left to launch): the serial order from here on.
+            try:
+                self.slots.collect(ahead.flight)
+            except BaseException as e:
+                self._recover_lost_pool(e)
+                return
+            self._flight = None
+            self._commit(ahead, t0)
+            ahead, t0 = None, time.perf_counter()
+        if not self._resident:
+            return
         window = self._pick_window()
         # Program selection is a pool property: any speculative
         # resident switches the pool to the SPEC program of width K,
@@ -2201,62 +2454,132 @@ class DecodeEngine:
             # resident set mutated); the next tick re-plans with the
             # survivors' grown tables.
             return
-        occupancy = len(self._resident)
         if self.recorder is not None:
             self.recorder.on_step_start()
-        t0 = time.perf_counter()
+        # What the marker's collect half waits for: this dispatch
+        # (serial), the one in flight (ahead), or nothing yet (the
+        # first of a run: it stays in flight, the next runs ahead).
+        collect = True if reason is not None \
+            else ahead.flight if ahead is not None else None
 
         def dispatch():
             with span("ptpu/lock_wait", self._host_s):
                 self.device_lock.acquire()
+            held = [self.device_lock]
             try:
-                if K:       # tokens [W, S, K], commits, accepts [W, S]
-                    return self.slots.step_spec(window, K)
-                return self.slots.step(        # [W, S]
-                    window, kind == "sampled", self.policy.decode_window)
+                # The lock goes back between the launch and the wait:
+                # nothing a collect touches needs it.
+                return self.slots.launch(
+                    window, sampled=kind == "sampled",
+                    cap=self.policy.decode_window, K=K,
+                    collect=collect,
+                    launched=lambda: held.pop().release())
             finally:
-                self.device_lock.release()
+                if held:
+                    held.pop().release()
 
-        out = self._dispatch_step(dispatch)
-        if out is None:
-            # Containment resolved the boundary by mutating the
-            # resident set (quarantine evictions / a conviction)
-            # instead of producing tokens — the next tick re-plans.
-            if self.recorder is not None:
-                self.recorder.on_step_end(0)
+        if ahead is None:
+            flight = self._dispatch_step(dispatch)
+            if flight is None:
+                # Containment resolved the boundary by mutating the
+                # resident set (quarantine evictions / a conviction)
+                # instead of producing tokens — the next tick
+                # re-plans.
+                if self.recorder is not None:
+                    self.recorder.on_step_end(0)
+                return
+        else:
+            try:
+                flight = dispatch()
+            except BaseException as e:
+                # A failure with a dispatch in flight surfaces after
+                # further programs consumed the pool: whatever its
+                # class, everything in flight is dropped and every
+                # stream resumes from its committed prefix.
+                self._recover_lost_pool(e)
+                return
+        self.decode_dispatches_total += 1
+        if ahead is not None:
+            self.decode_dispatches_ahead_total += 1
+        else:
+            name = reason or "first"
+            self.decode_serial_reasons[name] = \
+                self.decode_serial_reasons.get(name, 0) + 1
+        mine = self._launched(flight, K)
+        if reason is not None:
+            self._commit(mine, t0)
             return
+        self._flight = mine
+        if ahead is not None:
+            self._commit(ahead, t0)
+        else:
+            # The first of a run commits nothing here: its section
+            # still belongs to the dispatch wall.
+            self.step_wall_s_total += time.perf_counter() - t0
+
+    def _launched(self, flight, K: int) -> _InFlight:
+        """The engine's half of a launch: the dispatch's own slot ->
+        stream map, the tokens each stream has in flight, and THE
+        FORESEEN EVICTIONS — a stream without an ``eos_id`` whose
+        budget ends with this dispatch leaves its slot NOW, whatever
+        the tokens turn out to be: the slot is parked, or re-armed by
+        an admission whose insertion is enqueued behind this dispatch
+        (the donated pool orders them on the device), and the stream
+        is completed when the dispatch is committed.  Speculative
+        rounds are committed at once and foresee nothing."""
+        streams = {}
+        for slot, stream in list(self._resident.items()):
+            take = 0 if K else min(flight.window, stream.remaining)
+            stream.in_flight += take
+            streams[slot] = (stream, take)
+            if take and stream.eos_id is None \
+                    and stream.remaining == 0:
+                del self._resident[slot]
+                self.slots.release(slot)
+                self._note_freed(stream, "complete")
+        return _InFlight(flight, K, streams)
+
+    def _commit(self, landed: _InFlight, t0: float) -> None:
+        """Deal a collected dispatch's tokens to the streams it was
+        launched with, complete the finished ones (evicting those no
+        launch foresaw: an eos, a speculative commit) so their slots
+        are admissible the SAME boundary, and write its step record.
+        ``t0``: when the tick's section that collected it began."""
         t1 = time.perf_counter()
+        out, K, window = landed.flight.host, landed.k, \
+            landed.flight.window
         with span("ptpu/commit", self._host_s):
             self.decode_steps_total += window
             emitted = accepted = 0
             if K:
                 self.spec_rounds_total += window
-            for slot, stream in list(self._resident.items()):
+            for slot, (stream, take) in landed.streams.items():
+                stream.in_flight -= take
                 if K:
                     n, a = self._take_rounds(stream, slot, *out)
                     accepted += a
                 else:
-                    n = self._take_tokens(stream, slot, out)
+                    n = self._take_tokens(stream, slot, out[0])
                 emitted += n
                 if stream.done():
-                    del self._resident[slot]
-                    self.slots.release(slot)
+                    if self._resident.get(slot) is stream:
+                        del self._resident[slot]
+                        self.slots.release(slot)
+                        self._note_freed(stream, "complete")
                     self.evicted_total += 1
-                    self._note_freed(stream, "complete")
                     self._complete(stream)   # records the slot id
                     stream.slot = None
-            self.step_device_s_total += self.slots.last_step_device_s
+            fields = self._host_fields()
+            self.step_device_s_total += fields["device_s"]
             self.step_wall_s_total += t1 - t0
             if self.recorder is not None:
                 self.recorder.on_step_end(emitted)
-            self.tel.step("step", t0, t1, kind=kind, window=window,
-                          occupancy=occupancy,
+            self.tel.step("step", t0, t1, kind=landed.flight.kind,
+                          window=window, occupancy=landed.occupancy,
                           batch=self.slots.n_slots, tokens=emitted,
                           **({"k": K, "accepted": accepted}
                              if K else {}),
-                          device_s=round(self.slots.last_step_device_s,
-                                         6),
-                          **self._host_fields(),
+                          **fields,
                           **({"mesh": self.mesh.axes_str()}
                              if self.mesh is not None else {}),
                           **({"pages_free": self.slots.free_page_count(),
@@ -2350,6 +2673,7 @@ class DecodeEngine:
         reclaim its resources; OTHER groups' streams keep running (a
         stranger's OOM must not kill the batch)."""
         self.queue.drop_group(group)
+        self._forget(group.streams)
         for slot, stream in list(self._resident.items()):
             if stream.group is group:
                 del self._resident[slot]
@@ -2362,6 +2686,7 @@ class DecodeEngine:
             # admissions fail would otherwise fill the chip with them
             # (PERF.md section 6, PR 28).
             stream.cache = stream.d_cache = stream.logits = None
+            stream.first = None
         if not group.event.is_set():   # fail once, however many
             t = time.perf_counter()    # streams drag the group down
             for stream in group.streams:
@@ -2508,6 +2833,7 @@ class DecodeEngine:
             "last_step_age_s": round(
                 max(0.0, now - self.last_boundary_t), 3),
             "decode_steps_total": self.decode_steps_total,
+            "dispatch_in_flight": self._flight is not None,
         }
         if self.paged:
             snap["pages"] = {**self.slots.page_stats(),
@@ -2538,11 +2864,17 @@ class DecodeEngine:
         # counters only.
         fstats = self.faults.stats() if self.faults is not None \
             else None       # one lock-guarded build per scrape
+        # A slot whose stream left it at a launch is still being
+        # stepped for that stream until the dispatch lands (one
+        # reading of the free list: the engine thread moves on).
+        landing, free = self._flight, set(self.slots.state.free)
+        active = self.slots.n_slots - len(free) + sum(
+            slot in free
+            for slot in (list(landing.streams) if landing else ()))
         return {
             "slots": self.slots.n_slots,
-            "slots_active": self.slots.active_slots,
-            "slot_occupancy": round(
-                self.slots.active_slots / self.slots.n_slots, 4),
+            "slots_active": active,
+            "slot_occupancy": round(active / self.slots.n_slots, 4),
             "queue_len": len(self.queue),
             "queue_depth": self.policy.queue_depth,
             "admitted_total": self.admitted_total,
@@ -2551,6 +2883,14 @@ class DecodeEngine:
             "admitted_spec_total": self.admitted_spec_total,
             "evicted_total": self.evicted_total,
             "decode_steps_total": self.decode_steps_total,
+            # How the decode dispatches were ordered (_decode_step):
+            # launched, launched before the previous one was
+            # collected, and the others by what forbade it
+            # (_serial_reason; ``first``: nothing was in flight).
+            "decode_dispatches_total": self.decode_dispatches_total,
+            "decode_dispatches_ahead_total":
+                self.decode_dispatches_ahead_total,
+            "decode_serial_reasons": dict(self.decode_serial_reasons),
             # The KV pool updated in place (serving/slots.py):
             # programs that took the pool, those that consumed the
             # tree they were handed, the live pools' bytes, and the
@@ -2650,9 +2990,9 @@ class DecodeEngine:
             # collective-time share (bench_serving_load meshed leg).
             **(self._mesh_stats() if self.mesh is not None else {}),
             # Per-step device share of the dispatch wall, meshed or
-            # not: device_s is a HOST clock around dispatch + sync
-            # (upload, enqueue and device_get lie inside it); the
-            # remainder is host scheduling.  On a mesh the device
+            # not: device_s is a HOST clock around launch + wait
+            # (the step markers: upload, enqueue and device_get lie
+            # inside them); the remainder is host scheduling.  On a mesh the device
             # part bundles per-shard compute + collectives.
             "step_device_seconds_total":
                 round(self.step_device_s_total, 6),

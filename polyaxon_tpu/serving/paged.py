@@ -73,10 +73,14 @@ WHAT THIS MANAGER OWNS is the storage: page accounting, tables, the
 pad class of a dispatch, the dirty-page window, gather/scatter, spill
 and the wire format.  The slots' host state and the host half of a
 decode dispatch are ``slots.SlotManager``'s, shared with the fixed
-lanes: ``step`` and ``step_spec`` hand ``_dispatch`` the program for
-a key, the pools' names, the page tables with each slot's first dirty
-page (uploaded with the slots' arrays, inside ``device_s``) and the
-gathered view's width, and nothing else.
+lanes: ``_program`` hands a launch the program for a key, the pools'
+names, the page tables with each slot's first dirty page (uploaded
+with the slots' arrays, inside ``device_s``) and the gathered view's
+width, and nothing else.  Tables and dirty pages are read off the
+slots' positions, which move at a LAUNCH, so a dispatch can be
+launched before the one ahead of it is collected wherever the tables
+themselves do not wait for its tokens (engine._serial_reason:
+``--kv-lazy`` growth does).
 
 Locking: page refcounts and the free list are mutated ONLY under
 ``_page_lock`` (machine-checked by the PAGE-REF rule in
@@ -531,7 +535,7 @@ class PagedSlotKVManager(SlotManager):
         self._slot_budget[:] = 0
         self._pool = None
         self._draft_pool = None
-        self.state.reset()
+        self._reset_slots()
 
     def release(self, slot: int) -> None:
         """Evict: park the slot (same contract as the fixed-lane
@@ -1117,13 +1121,14 @@ class PagedSlotKVManager(SlotManager):
         metas, treedef = self._meta, self._treedef
         n_dirty = self._n_dirty(window)
 
-        def step(variables, pool, tables, d0, toks, positions, *extra):
+        def step(variables, pool, tables, d0, toks, fed, fresh,
+                 positions, *extra):
             # The weights are an ARGUMENT (jit_over), not a closure.
             body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
-            outs, extras, stacked = body(stacked, window, toks,
-                                         positions, *extra)
+            outs, extras, stacked = body(stacked, window, toks, fed,
+                                         fresh, positions, *extra)
             pool = self._scatter_dirty(pool, metas, stacked, tables,
                                        d0, n_dirty)
             return outs, extras, pool
@@ -1133,29 +1138,40 @@ class PagedSlotKVManager(SlotManager):
         rep = self.mesh.replicated
         n_extra = 5 if sampled else 0
         in_sh = (self.mesh.shardings_of(self.variables),
-                 self._pool_sh, rep, rep, rep, rep) + (rep,) * n_extra
+                 self._pool_sh) + (rep,) * (6 + n_extra)
         return jit_over(self.variables, step, in_shardings=in_sh,
                         out_shardings=(rep, rep, self._pool_sh))
 
-    def step(self, window: int = 1, sampled: bool = False,
-             cap: Optional[int] = None) -> np.ndarray:
-        """``window`` fused decode steps across the whole pool: gather
-        views, run the SAME decode body as the fixed lanes, scatter
-        dirty pages.  One compiled program per (window, sampled,
-        pages-per-slot pad class): the dirty-page bound is the
-        window's, so ``cap`` (SlotKVManager.step) buys nothing here
-        and is not used."""
-        if self._pool is None:
-            raise RuntimeError("step() before any insert()")
+    def _program(self, window: int, sampled: bool,
+                 cap: Optional[int], K: int):
+        """What this manager contributes to a launch
+        (``SlotManager._launch``): gather views, run the SAME decode
+        body as the fixed lanes, scatter dirty pages.  One compiled
+        program per (window, sampled or K, pages-per-slot pad class):
+        the dirty-page bound is the window's, so ``cap``
+        (SlotKVManager._program) buys nothing here and is not used.
+        The speculative body's in-program rollback stays a pure
+        ``cache_index`` rewind on the gathered view: pages are
+        reserved to budget, so rejection never touches the page
+        accounting (no truncation, no refcount traffic — the
+        full-reservation dividend)."""
+        if self._pool is None or (K and self._draft_pool is None):
+            raise RuntimeError("step_spec() before a speculative "
+                               "insert()" if K
+                               else "step() before any insert()")
         P = self._resident_pad()
-        outs, = self._dispatch(
-            "sampled" if sampled else "plain", (window, sampled, P),
-            lambda: self._build_step(window, sampled, P), ("_pool",),
-            leading=(self.page_tables[:, :P],
-                     self._dirty_start(P, self._n_dirty(window))),
-            plane_cap=P * self.page_tokens, window=window)
-        self.state.advance(window, outs[-1])
-        return outs
+        tables = self.page_tables[:, :P]
+        if K:
+            return ((window, "spec", K, P),
+                    lambda: self._build_spec_step(window, K, P),
+                    ("_pool", "_draft_pool"),
+                    (tables, self._dirty_start(
+                        P, self._n_dirty(window * K + 1))))
+        return ((window, sampled, P),
+                lambda: self._build_step(window, sampled, P),
+                ("_pool",),
+                (tables, self._dirty_start(P, self._n_dirty(window))),
+                P * self.page_tokens)
 
     def _build_spec_step(self, window: int, K: int, P: int):
         from ..models.generate import jit_over
@@ -1191,22 +1207,3 @@ class PagedSlotKVManager(SlotManager):
         return jit_over(weights, step, in_shardings=in_sh,
                         out_shardings=(rep, rep, rep, self._pool_sh,
                                        self._draft_pool_sh))
-
-    def step_spec(self, window: int, K: int):
-        """``window`` fused SPECULATIVE rounds.  The in-program
-        rollback stays a pure ``cache_index`` rewind on the gathered
-        view: pages are reserved to budget, so rejection never touches
-        the page accounting (no truncation, no refcount traffic — the
-        full-reservation dividend)."""
-        if self._pool is None or self._draft_pool is None:
-            raise RuntimeError("step_spec() before a speculative "
-                               "insert()")
-        P = self._resident_pad()
-        outs, commits, accepts = self._dispatch(
-            "spec", (window, "spec", K, P),
-            lambda: self._build_spec_step(window, K, P),
-            ("_pool", "_draft_pool"),
-            leading=(self.page_tables[:, :P], self._dirty_start(
-                P, self._n_dirty(window * K + 1))), window=window, k=K)
-        self.state.advance_spec(outs, commits)
-        return outs, commits, accepts

@@ -3,7 +3,7 @@ attribution, published live.
 
 PR 4 gave the server manual ``POST /profile/start|stop`` and left the
 operator staring at Perfetto; the host-side step timings everywhere
-else (``SlotKVManager.last_step_device_s``, ``step_device_share``)
+else (the step records' ``device_s``, ``step_device_share``)
 are perf_counter deltas around a blocking sync — ESTIMATES that
 conflate dispatch overhead, host gaps, and real device work.  This
 module closes the loop:

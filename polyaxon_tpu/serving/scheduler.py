@@ -362,7 +362,7 @@ class Stream:
                  "spec_drafted", "spec_accepted", "sid", "events",
                  "pf_toks", "resume", "kv_shared", "kv_epoch",
                  "last_slot", "preempts", "resumes", "blocked_t",
-                 "evicted_for", "step_logits")
+                 "evicted_for", "step_logits", "first", "in_flight")
 
     def __init__(self, group: "RequestGroup", row: int,
                  toks: np.ndarray, new: int, eos_id: Optional[int],
@@ -388,7 +388,16 @@ class Stream:
         self.cache = None         # partial B=1 cache during prefill
         self.d_cache = None       # draft-model cache (spec streams)
         self.logits = None        # last-position logits once filled
+        # What admission fetches, enqueued right behind the LAST
+        # prefill piece (engine._enqueue_first): device values
+        # ``(token 0 or None, base key or None)``, finished by the
+        # time the stream finds a slot.
+        self.first = None
         self.out: List[int] = []  # committed new tokens
+        # Tokens of this stream in a decode dispatch the engine has
+        # launched and not yet collected: ``remaining`` is what
+        # planning reads while they are on their way.
+        self.in_flight = 0
         # With engine.submit(record_logits=True): the [V] logits each
         # committed token was chosen from, as the engine's programs
         # computed them (None: not asked for).
@@ -474,7 +483,14 @@ class Stream:
         self.cache = None
         self.d_cache = None
         self.logits = None
+        self.first = None
         self.slot = None
+        self.in_flight = 0
+
+    @property
+    def remaining(self) -> int:
+        """Budget left once the tokens in flight have landed."""
+        return self.new - len(self.out) - self.in_flight
 
     def done(self) -> bool:
         if len(self.out) >= self.new:

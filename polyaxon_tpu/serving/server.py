@@ -3171,6 +3171,9 @@ class ModelServer:
                     "admitted_greedy_total", "admitted_sampled_total",
                     "admitted_spec_total",
                     "evicted_total", "decode_steps_total",
+                    "decode_dispatches_total",
+                    "decode_dispatches_ahead_total",
+                    "decode_serial_reasons",
                     "kv_pool_dispatches_total",
                     "kv_pool_in_place_total", "kv_pool_bytes",
                     "kv_pool_bytes_by_kind", "kv_pool_lost_total",
@@ -3461,6 +3464,17 @@ class ModelServer:
                 "# TYPE ptpu_serving_decode_steps_total counter",
                 f"ptpu_serving_decode_steps_total "
                 f"{es['decode_steps_total']}",
+                "# TYPE ptpu_serving_decode_dispatches_total counter",
+                f"ptpu_serving_decode_dispatches_total "
+                f"{es['decode_dispatches_total']}",
+                "# TYPE ptpu_serving_decode_dispatches_ahead_total "
+                "counter",
+                f"ptpu_serving_decode_dispatches_ahead_total "
+                f"{es['decode_dispatches_ahead_total']}",
+                "# TYPE ptpu_serving_decode_serial_reasons counter",
+                *(f'ptpu_serving_decode_serial_reasons'
+                  f'{{reason="{reason}"}} {n}' for reason, n in
+                  sorted(es["decode_serial_reasons"].items())),
                 "# TYPE ptpu_serving_kv_pool_dispatches_total counter",
                 f"ptpu_serving_kv_pool_dispatches_total "
                 f"{es['kv_pool_dispatches_total']}",

@@ -29,20 +29,38 @@ shared positional sampler — the same value the plain step programs
 produce.
 
 THE HOST HALF OF A DECODE DISPATCH IS WRITTEN ONCE, for this manager
-and the paged one (serving/paged.py) and for every kind of step:
-``SlotManager._dispatch`` — program lookup and the recompile
-sentinel, the step marker, upload, the program's call, the counters,
-one ``device_get``, ``last_step_device_s``.  The slots' host state
+and the paged one (serving/paged.py) and for every kind of step, and
+it is CUT IN TWO at the one place where the host waits:
+``SlotManager._launch`` — program lookup and the recompile sentinel,
+upload, the program's call, rebinding the pool to its successor, the
+counters: everything up to the futures, which a ``Flight`` holds —
+and ``SlotManager._collect`` — the one ``device_get`` of a flight's
+tokens.  ``SlotManager._dispatch`` runs a launch and then a collect
+under ONE step marker, and the collect need not be of the dispatch
+just launched: the engine launches dispatch N+1 and THEN collects
+dispatch N (engine._decode_step), so that the device's queue is not
+empty while the host deals N's tokens out.  The serial order is the
+same code with the collect of the flight just launched.  What makes
+that possible is that the FEEDBACK TOKEN never needs the host: a step
+program hands the last token of every slot on as a device array
+(``extras["tok"]``), the next launch takes it as an operand
+(``SlotState.fed``), and the host's own value goes in only for the
+slots it armed or parked since (``SlotState.fresh``, a mask merged
+inside the program).  Positions, token indices and sampling operands
+stay the host's to compute: they advance at the launch, by the
+window, whatever the tokens turn out to be.  The slots' host state
 (free list, feedback token, position and sampling operands) is one
 object, ``SlotState``, held by either manager as ``state``.  To a
-dispatch a manager contributes four things and nothing else: the
-program for a key not yet compiled, the pool argument(s) and where
-their successors are rebound, its own leading operands, and the width
-its program sees of the planes.
+dispatch a manager contributes four things and nothing else
+(``_program``): the program for a key not yet compiled, the pool
+argument(s) and where their successors are rebound, its own leading
+operands, and the width its program sees of the planes.
 
 Device programs, compiled once each per model:
 
-- ``step``:   [S]-stacked cache + toks [S] + positions [S]
+- ``step``:   [S]-stacked cache + toks [S] (the host's, the last
+              dispatch's on the device, and the mask that picks
+              between them) + positions [S]
               -> next tokens [W, S] + updated stacked cache,
               for a WINDOW of W decode steps fused into one program
               (a loop over the vmapped one-token body, so a window
@@ -107,7 +125,6 @@ the rows handed over against the rows held (``plane_reads``).
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Optional
 
 import numpy as np
@@ -164,7 +181,19 @@ class SlotState:
     next-token index, shaping params: inert zeros for a greedy or idle
     slot) and the draft length (> 0 marks a SPECULATIVE slot).
 
-    ONE owner, held by either manager as ``state``: the eight arrays
+    THE FEEDBACK TOKEN HAS TWO HOMES.  ``fed`` is the device array a
+    plain or sampled step program handed on: every slot's last token,
+    which the next launch takes as an operand without the host ever
+    having read it.  ``tokens`` is the host's value, and ``fresh``
+    marks the slots where it is the one that counts: armed or parked
+    since the last launch, idle, or all of them where the host knows
+    every token (after ``reset``, after speculative rounds).  The
+    program merges the two by the mask.  ``tokens`` of the other
+    slots is a mirror, brought up to date when the newest dispatch is
+    collected (``landed``); positions and token indices advance at
+    the LAUNCH (``launched``), by the window.
+
+    ONE owner, held by either manager as ``state``: the nine arrays
     are written slot by slot here and nowhere else, and a
     crash-recovery ``reset()`` builds them as construction does, so a
     field added here can never survive a supervised restart carrying
@@ -185,6 +214,8 @@ class SlotState:
         self.top_ks = np.zeros((n,), np.int32)
         self.top_ps = np.zeros((n,), np.float32)
         self.spec_ks = np.zeros((n,), np.int32)
+        self.fresh = np.ones((n,), bool)
+        self.fed = None
 
     def acquire(self) -> Optional[int]:
         return self.free.pop(0) if self.free else None
@@ -198,6 +229,7 @@ class SlotState:
         key) and the index the NEXT step draws; a greedy one leaves
         temperature 0, the sampler's argmax lane."""
         self.tokens[slot] = first_token
+        self.fresh[slot] = True
         self.positions[slot] = position
         self.keys[slot] = 0 if base_key is None \
             else np.asarray(base_key, np.uint32)
@@ -225,24 +257,33 @@ class SlotState:
             return (self.tokens, self.positions, self.next_index,
                     self.keys, self.temps, self.top_ks, self.top_ps,
                     self.spec_ks)
+        head = (self.tokens, self.fed, self.fresh, self.positions)
         if kind == "sampled":
-            return (self.tokens, self.positions, self.keys,
-                    self.next_index, self.temps, self.top_ks,
-                    self.top_ps)
-        return (self.tokens, self.positions)
+            return head + (self.keys, self.next_index, self.temps,
+                           self.top_ks, self.top_ps)
+        return head
 
-    def advance(self, window: int, last_tokens) -> None:
-        """Arm the step after a plain or sampled window: every slot
-        feeds back its own last token at the next position (and token
-        index)."""
-        self.tokens = last_tokens.copy()
+    def launched(self, window: int, fed) -> None:
+        """Arm the step after a plain or sampled window, AT ITS
+        LAUNCH: every slot feeds back its own last token, ``fed`` on
+        the device, at the next position (and token index)."""
+        self.fed = fed
+        self.fresh[:] = False
         self._move(window)
+
+    def landed(self, last_tokens) -> None:
+        """The NEWEST dispatch's last tokens have reached the host:
+        the mirror of every slot not armed or parked since."""
+        self.tokens = np.where(self.fresh, self.tokens,
+                               last_tokens).astype(np.int32)
 
     def advance_spec(self, outs, commits) -> None:
         """Arm the round after speculative rounds, from each slot's
-        LAST commit and by as many positions as it committed."""
+        LAST commit and by as many positions as it committed: the
+        host read every token, so every slot is fresh."""
         rows = np.arange(self.n_slots)
         self.tokens = outs[-1, rows, commits[-1] - 1].astype(np.int32)
+        self.fresh[:] = True
         self._move(commits.sum(axis=0).astype(np.int32))
 
     def _move(self, by) -> None:
@@ -254,6 +295,7 @@ class SlotState:
         if self.free:
             idle = np.asarray(self.free, np.int32)
             self.tokens[idle] = 0
+            self.fresh[idle] = True
             self.positions[idle] = 0
             self.next_index[idle] = 0
 
@@ -273,12 +315,16 @@ def build_step_body(model, variables, window: int, sampled: bool):
     """Unjitted decode body over a stacked cache: up to ``window``
     fused steps.
 
-    Plain: ``step(stacked, steps, toks, positions) -> (outs [window,
-    S], extras, stacked)``.  Sampled: ``step(stacked, steps, toks,
-    positions, keys, idxs, temps, tks, tps)`` with the same returns.
+    Plain: ``step(stacked, steps, toks, fed, fresh, positions) ->
+    (outs [window, S], extras, stacked)``.  Sampled: ``step(stacked,
+    steps, toks, fed, fresh, positions, keys, idxs, temps, tks, tps)``
+    with the same returns.  A slot's first input token is the host's
+    ``toks`` where ``fresh``, else ``fed``: the last token the
+    dispatch before this one left on the device (``SlotState``).
 
     ``extras`` is what the steps computed anyway and nobody kept:
-    ``{"logits": [S, V] float32 of the LAST step run, "pairs": what
+    ``{"tok": [S] the last token of every slot, the next dispatch's
+    ``fed``, "logits": [S, V] float32 of the LAST step run, "pairs": what
     the model sowed under generate.STATS summed over steps, layers and
     slots (the expert layers' token-expert pairs; absent for a model
     that sows nothing)}``.  Both managers return them from every
@@ -326,9 +372,10 @@ def build_step_body(model, variables, window: int, sampled: bool):
             else jnp.argmax(logits).astype(jnp.int32)
         return nxt, logits, pairs, cache
 
-    def step(stacked, steps, toks, positions, *sampling):
+    def step(stacked, steps, toks, fed, fresh, positions, *sampling):
         # ``sampling``: (keys, idxs, temps, tks, tps), the sampled
         # body's; the token index advances with the step.
+        toks = jnp.where(fresh, toks, fed)
         keys, idxs, shaping = sampling[:1], sampling[1:2], sampling[2:]
         like = jax.eval_shape(
             jax.vmap(one), stacked, toks, positions,
@@ -347,13 +394,13 @@ def build_step_body(model, variables, window: int, sampled: bool):
                     outs.at[i].set(nxt), logits,
                     pairs + new_pairs.sum(axis=0))
 
-        cache, _, _, _, outs, logits, pairs = jax.lax.fori_loop(
+        cache, tok, _, _, outs, logits, pairs = jax.lax.fori_loop(
             0, steps, body,
             (stacked, toks, positions, tuple(idxs),
              jnp.zeros((window,) + toks.shape, jnp.int32),
              jnp.zeros(like[1].shape, like[1].dtype),
              jnp.zeros(like[2].shape[1:], like[2].dtype)))
-        extras = {"logits": logits}
+        extras = {"tok": tok, "logits": logits}
         if pairs.size:          # nothing sown: nothing to fetch
             extras["pairs"] = pairs
         return outs, extras, cache
@@ -441,11 +488,30 @@ def build_spec_step_body(model, variables, draft, draft_vars,
     return step
 
 
+class Flight:
+    """One decode dispatch between its launch and its collect: what
+    the program handed back as futures (``out``: tokens, and after
+    speculative rounds commits and accepts; ``pairs``: the pair counts
+    that ride home with them, the program's own and those of the
+    prefill pieces enqueued ahead of it) and, once collected, the same
+    as numpy arrays (``host``; tokens cut to the ``window``)."""
+
+    __slots__ = ("kind", "window", "out", "pairs", "host")
+
+    def __init__(self, kind: str, window: int, out, pairs):
+        self.kind = kind
+        self.window = window
+        self.out = out
+        self.pairs = pairs
+        self.host = None
+
+
 class SlotManager:
     """What the two KV managers share: the slots' host state
     (``state``, a :class:`SlotState`) and the host half of ONE decode
-    dispatch (:meth:`_dispatch`), with everything that half counts and
-    keeps.  A subclass owns a storage discipline and nothing of the
+    dispatch (:meth:`_dispatch`: :meth:`_launch`, then
+    :meth:`_collect`), with everything that half counts and keeps.
+    A subclass owns a storage discipline and nothing of the
     dispatch: the stacked pools, their pinned formats and donation
     (:class:`SlotKVManager`); pages, tables, gather and scatter
     (:class:`~.paged.PagedSlotKVManager`).  Device work only — request
@@ -478,14 +544,17 @@ class SlotManager:
         # Whether the pools' pinned layout is the device's default
         # (``_compiling``).  A manager that pins none leaves it so.
         self._pin_is_default = True
-        # Wall-clock of the LAST dispatch's device section (upload,
-        # enqueue and host sync, measured inside the device lock so
-        # lock wait is excluded) — the engine's step-timeline records
-        # report it next to the scheduling wall time.
-        self.last_step_device_s = 0.0
         # Seconds in the step's host sections (spans.span) since the
-        # engine last took them for a step record.
+        # engine last took them for a step record: upload, enqueue
+        # and sync, and the marker that holds them (the record's
+        # ``device_s``: a host clock, lock wait excluded).
         self.host_s = {}
+        # The last dispatch launched: the one whose last tokens the
+        # host's mirror follows (``SlotState.landed``).
+        self._newest = None
+        # Pair counts of prefill pieces enqueued since the last
+        # launch (``defer_pairs``): device arrays nobody waits for.
+        self._piece_pairs = []
         # Whether the pool is updated in place, counted where it can
         # be seen: programs that took the pool (decode dispatches and
         # insertions) and how many of them consumed the tree they
@@ -516,6 +585,32 @@ class SlotManager:
         self.moe_pairs = pairs if self.moe_pairs is None \
             else self.moe_pairs + pairs
 
+    def _reset_slots(self) -> None:
+        """The slots' host state as construction left it, and nothing
+        of a dispatch launched before (a manager's ``reset``)."""
+        self.state.reset()
+        self._newest = None
+        self._piece_pairs = []
+
+    def defer_pairs(self, pairs) -> None:
+        """The pair counts of a prefill piece just ENQUEUED (a device
+        array, or None where the model sows nothing): counted when
+        the next dispatch launched is collected, the first thing
+        behind the piece that the host waits for anyway."""
+        if pairs is not None:
+            self._piece_pairs.append(pairs)
+
+    def flush_pairs(self) -> None:
+        """Count the deferred pair counts now, where no dispatch is
+        coming to bring them home (the pool has gone idle)."""
+        import jax
+
+        if self._piece_pairs:
+            pending, self._piece_pairs = self._piece_pairs, []
+            # HOST-SYNC: a few int32, of pieces long finished.
+            for pairs in jax.device_get(pending):
+                self.count_pairs(pairs)
+
     @property
     def free_slots(self) -> int:
         return len(self.state.free)
@@ -526,6 +621,14 @@ class SlotManager:
 
     def acquire(self) -> Optional[int]:
         return self.state.acquire()
+
+    def _fed_sharding(self):
+        """Where the feedback token rests between two dispatches
+        (None: uncommitted, on the default device): the first launch
+        (every slot fresh, ``fed`` never read) puts zeros there, the
+        kind of array the step programs hand out, so that a program
+        sees ONE signature from its first call on."""
+        return self.mesh.replicated if self.mesh is not None else None
 
     def _exact(self):
         """Serving-exact trace context (no-op unmeshed) — wraps every
@@ -550,32 +653,67 @@ class SlotManager:
         self.kv_pool_in_place_total += all(
             leaf.is_deleted() for leaf in taken)
 
-    def _dispatch(self, kind: str, key, build, pools, leading=(),
-                  plane_cap=None, **stats):
+    def _dispatch(self, launch=None, collect=None, launched=None,
+                  **stats):
         """The host half of ONE decode dispatch, whatever the manager
-        and the ``kind`` of step (``plain``, ``sampled``, ``spec``).
-        The manager brings what its storage decides: the program's
+        and the kind of step, under ONE ``ptpu_step`` marker: the
+        LAUNCH of a dispatch (``launch``: what :meth:`_launch` takes;
+        None launches nothing) and then the COLLECT of one
+        (``collect``: a :class:`Flight` launched earlier, True for
+        the one just launched, None for none).  Returns the flight
+        launched.
+
+        The engine runs one dispatch ahead: a tick's marker holds the
+        launch of dispatch N+1 and then the collect of dispatch N, so
+        that N+1 is in the device's queue before the host starts to
+        wait for N.  The serial order (``collect=True``) and a drain
+        (no ``launch``) are the same code.  ``launched()`` is called
+        between the two halves: the caller gives up the device lock
+        there, which the wait for tokens does not need.
+
+        ``stats`` (``window``, ``k``: the launched dispatch's, or the
+        collected one's in a drain) label the marker.  When a
+        ``jax.profiler`` trace is active — a manual ``POST
+        /profile/start`` or a flight-recorder window — the trace
+        parser (analysis/xprof.py) anchors its attribution window to
+        the span of these markers.  Launch and collect BOTH lie
+        inside the marker, so the markers of a run of dispatches
+        cover it from the first launch to the last collect: a marker
+        that closed before the wait would span only the host's
+        enqueues and clip the device's execution out of the window.
+        A marker no longer brackets the execution of the program it
+        launched (that one runs while the host is in its NEXT
+        marker's wait, and in the commit between them); it brackets
+        the wait for the one before.  Inside it lie ``ptpu/upload``,
+        ``ptpu/enqueue`` (the launch) and ``ptpu/sync`` (the
+        collect); its own seconds are the step record's
+        ``device_s``."""
+        with self._exact(), span(STEP_MARKER, self.host_s, **stats):
+            flight = None
+            if launch is not None:
+                flight = self._launch(*launch, **stats)
+            if launched is not None:
+                launched()
+            if collect is True:
+                collect = flight
+            if collect is not None:
+                self._collect(collect)
+        return flight
+
+    def _launch(self, kind: str, key, build, pools, leading=(),
+                plane_cap=None, **stats) -> Flight:
+        """The launch half: everything up to the futures.  The manager
+        brings what its storage decides (``_program``): the program's
         ``key`` and ``build()`` for a key not yet compiled; ``pools``,
         the names of the attributes holding the pool argument(s),
         rebound to their successors as soon as the program hands them
         back; its own ``leading`` operands; and the ``plane_cap`` its
         program's view of the planes has (None: the planes' own).
-        Returns the program's host outputs as numpy arrays:
-        ``[tokens]``, or ``[tokens, commits, accepts]`` of speculative
-        rounds.
-
-        ``stats`` (``window``, ``k``) label the ``ptpu_step`` marker
-        around the dispatch AND its blocking sync (inside the device
-        lock): when a ``jax.profiler`` trace is active — a manual
-        ``POST /profile/start`` or a flight-recorder window — the
-        trace parser (analysis/xprof.py) anchors its attribution
-        window and the host-gap math to EXACTLY these step boundaries.
-        The sync stays INSIDE the marker: dispatch returns device
-        futures, so a marker closing before it would span only the
-        host enqueue and the attribution window would clip the step's
-        actual device execution (inflating MFU by ~K/(K-1) on a real
-        async backend).  Inside it lie ``ptpu/upload``,
-        ``ptpu/enqueue`` and ``ptpu/sync``."""
+        The slots' host state moves on HERE, by the window
+        (``SlotState.launched``): the next launch needs nothing of
+        this one's results but ``fed``, which stays on the device.
+        Speculative rounds move it at their collect (commit counts
+        are data), so they are always collected at once."""
         import jax
         import jax.numpy as jnp
 
@@ -588,42 +726,120 @@ class SlotManager:
         elif self.sentinel is not None:
             self.sentinel.hit("slot_step", key)
         state, host_s, spec = self.state, self.host_s, kind == "spec"
-        t0 = time.perf_counter()
-        with self._exact(), span(STEP_MARKER, **stats):
-            with span("ptpu/upload", host_s):
-                operands = [jnp.asarray(a) for a in
-                            (*leading, *state.operands(kind))]
-            with span("ptpu/enqueue", host_s), self._compiling(new):
-                held = [getattr(self, name) for name in pools]
-                taken = [jax.tree.leaves(pool)[0] for pool in held]
-                out = fn(*held, *operands)
-                for name, pool in zip(pools, out[-len(pools):]):
-                    setattr(self, name, pool)
-                self._count_dispatch(*taken)
-                if not spec:
-                    # The extent each of the window's steps reads the
-                    # planes to (``build_step_body``): one past the
-                    # furthest of the pool's positions, which all
-                    # advance by one a step.
-                    self.plane_reads.count(
-                        int(state.positions.max()) + 1
-                        + np.arange(stats["window"]),
-                        lanes=self.n_slots, cap=plane_cap, shared=True)
-                    self.plane_reads.count_steps(stats["window"],
-                                                 self.n_slots)
+        window = stats["window"]
+        with span("ptpu/upload", host_s):
+            if state.fed is None:
+                state.fed = jax.device_put(
+                    np.zeros((self.n_slots,), np.int32),
+                    self._fed_sharding())
+            # A COPY of every host array: the slots' arrays are
+            # written in place between this launch and the program's
+            # run, and a backend may read the host buffer it was
+            # handed as late as that.
+            operands = [jnp.asarray(a.copy() if isinstance(a, np.ndarray)
+                                    else a) for a in
+                        (*leading, *state.operands(kind))]
+        with span("ptpu/enqueue", host_s), self._compiling(new):
+            held = [getattr(self, name) for name in pools]
+            taken = [jax.tree.leaves(pool)[0] for pool in held]
+            out = fn(*held, *operands)
+            for name, pool in zip(pools, out[-len(pools):]):
+                setattr(self, name, pool)
+            self._count_dispatch(*taken)
             # A plain or sampled program's last host output is the
             # body's ``extras``; the speculative body keeps none.
             host, extras = out[:-len(pools)], {}
             if not spec:
+                # The extent each of the window's steps reads the
+                # planes to (``build_step_body``): one past the
+                # furthest of the pool's positions, which all
+                # advance by one a step.
+                self.plane_reads.count(
+                    int(state.positions.max()) + 1 + np.arange(window),
+                    lanes=self.n_slots, cap=plane_cap, shared=True)
+                self.plane_reads.count_steps(window, self.n_slots)
                 *host, extras = host
+                state.launched(window, extras["tok"])
             self.last_logits = extras.get("logits")  # stays on the device
-            with span("ptpu/sync", host_s):
-                *host, pairs = jax.device_get(
-                    (*host, extras.get("pairs")))
-            if pairs is not None:
-                self.count_pairs(pairs)
-        self.last_step_device_s = time.perf_counter() - t0
-        return host
+            pairs, self._piece_pairs = \
+                [extras.get("pairs"), *self._piece_pairs], []
+        flight = self._newest = Flight(kind, window, tuple(host), pairs)
+        return flight
+
+    def _collect(self, flight: Flight) -> None:
+        """The collect half: the ONE wait of a dispatch, for its
+        tokens (``flight.host``) and the pair counts that ride home
+        with them.  The host's mirror of the feedback token follows
+        the newest dispatch only; an older one's last tokens are
+        already superseded on the device."""
+        import jax
+
+        with span("ptpu/sync", self.host_s):
+            # HOST-SYNC: the one intentional wait of a dispatch.
+            host, pairs = jax.device_get((flight.out, flight.pairs))
+        for counted in pairs:
+            if counted is not None:
+                self.count_pairs(counted)
+        flight.out = flight.pairs = None
+        if flight.kind == "spec":
+            self.state.advance_spec(*host[:2])
+        else:
+            host = (host[0][:flight.window],)
+            if flight is self._newest:
+                self.state.landed(host[0][-1])
+        flight.host = host
+
+    def launch(self, window: int = 1, *, sampled: bool = False,
+               cap: Optional[int] = None, K: int = 0, collect=True,
+               launched=None) -> Flight:
+        """Launch ``window`` fused decode steps across the whole pool
+        (``K`` > 0: speculative rounds of draft width ``K``; else the
+        plain or, with ``sampled``, the sampled program) and collect
+        ``collect`` in the same marker (:meth:`_dispatch`): True, this
+        dispatch, the serial order; an earlier :class:`Flight`, the
+        engine one dispatch ahead; None, nothing yet.  Token selection
+        and the token feedback run inside one looped program, so a
+        window costs ONE dispatch + ONE host round-trip whatever its
+        length; the caller (engine._decode_step) passes ``sampled``
+        iff any resident stream samples, and engine._pick_window sizes
+        the window so no admission or budget-eviction boundary lands
+        inside it.
+
+        The pool is DONATED where the manager donates it: the tree
+        named before the call is deleted by it, and if the program
+        fails after that, ``pool_lost()`` is true and the pool has to
+        be rebuilt (engine._dispatch_step)."""
+        kind = "spec" if K else "sampled" if sampled else "plain"
+        stats = {"window": window, **({"k": K} if K else {})}
+        return self._dispatch(
+            (kind, *self._program(window, sampled, cap, K)),
+            collect, launched, **stats)
+
+    def collect(self, flight: Flight) -> None:
+        """Collect a dispatch launched earlier with nothing launched
+        behind it: a drain (:meth:`_dispatch`)."""
+        self._dispatch(None, flight, window=flight.window)
+
+    def step(self, window: int = 1, sampled: bool = False,
+             cap: Optional[int] = None) -> np.ndarray:
+        """``window`` fused decode steps, launched and collected:
+        the next tokens [window, S] (garbage for idle slots — the
+        caller masks by occupancy).  ``cap``: the widest window the
+        caller will ever ask for (the engine's ``decode_window``)."""
+        return self.launch(window, sampled=sampled, cap=cap).host[0]
+
+    def step_spec(self, window: int, K: int):
+        """``window`` fused SPECULATIVE rounds across the whole pool,
+        launched and collected.  Returns ``(tokens [window, S, K],
+        commits [window, S], accepts [window, S])``: round w commits
+        ``tokens[w, s, :commits[w, s]]`` for slot s (1 for
+        non-speculative slots, garbage for idle ones — the caller
+        masks by occupancy), and ``accepts`` counts the accepted draft
+        tokens (the engine's acceptance-rate metric).  ``K`` is the
+        program's draft width — the pool max; slots with smaller
+        ``spec_k`` commit at most their own k (exactness per slot is
+        unchanged, see _spec_verify_row)."""
+        return self.launch(window, K=K).host
 
 
 class SlotKVManager(SlotManager):
@@ -654,6 +870,21 @@ class SlotKVManager(SlotManager):
         self._draft_stacked = None    # draft pytree, leaves [S, ...]
         self._insert_fns = {}         # draft? -> jitted insert
 
+    def _fed_sharding(self):
+        """This manager's pool is COMMITTED to its pinned formats, so
+        whatever its programs hand out is committed too, unmeshed as
+        well: they pin their host outputs here and the first ``fed``
+        is put here.  A second signature of a pool program would be
+        compiled at a later call, outside ``_compiling``: into and,
+        in the next process, out of the persistent cache, whose
+        executables mislabel a pinned layout (config.fresh_compile;
+        PERF.md section 6, PR 34)."""
+        import jax
+        from jax.sharding import SingleDeviceSharding
+
+        return super()._fed_sharding() \
+            or SingleDeviceSharding(jax.devices()[0])
+
     def reset(self) -> None:
         """Crash-recovery pool rebuild (recovery.EngineSupervisor):
         drop ALL resident KV and per-slot decode state while KEEPING
@@ -663,7 +894,7 @@ class SlotKVManager(SlotManager):
         steady-state recompiles (pinned in tests/test_faults.py)."""
         self._stacked = None
         self._draft_stacked = None
-        self.state.reset()
+        self._reset_slots()
 
     def release(self, slot: int) -> None:
         """Evict: the slot is reusable the SAME step — no device work,
@@ -867,50 +1098,40 @@ class SlotKVManager(SlotManager):
         # weights keep the shardings they were placed with, host
         # operands (tokens/positions/sampling state) commit
         # replicated, and token outputs gather back replicated.
-        n_host = 8 if sampled else 3       # steps, tokens, positions
+        # steps; tokens, fed, fresh, positions; the sampler's five
+        n_host = 10 if sampled else 5
         rep, w_sh = None, None
         if self.mesh is not None:
             rep = self.mesh.replicated
             w_sh = self.mesh.shardings_of(self.variables)
+        # The host outputs rest where ``fed`` does, meshed or not
+        # (``_fed_sharding``: one signature a program).
         return jit_over(
             self.variables, program, donate_argnums=(1,),
             in_shardings=(w_sh, self._cache_sh) + (rep,) * n_host,
-            out_shardings=(rep, rep, self._cache_sh))
+            out_shardings=(self._fed_sharding(),) * 2
+            + (self._cache_sh,))
 
-    def step(self, window: int = 1, sampled: bool = False,
-             cap: Optional[int] = None) -> np.ndarray:
-        """``window`` fused decode steps across the whole pool;
-        returns the next tokens [window, S] (garbage for idle slots
-        — the caller masks by occupancy).  Token selection (greedy
-        argmax, or the position-keyed per-slot sampler when
-        ``sampled``) and the token feedback run inside one looped
-        program, so a window costs ONE dispatch + ONE host round-trip
-        regardless of its length; the caller (engine._decode_step)
-        passes ``sampled`` iff any resident stream samples, and
-        engine._pick_window sizes the window so no admission or
-        budget-eviction boundary lands inside it.
-
-        ``cap``: the widest window the caller will ever ask for (the
-        engine's ``decode_window``).  The program is built for that
-        capacity and takes ``window`` as an operand, so one program a
-        variant serves every window; without it the capacity is this
-        call's window.
-
-        The pool is DONATED: the program writes each slot's new rows
-        into the buffers it was handed and returns them, and the tree
-        ``_stacked`` named before the call is deleted by it.  If the
-        program fails after that, ``pool_lost()`` is true and the
-        pool has to be rebuilt (engine._dispatch_step)."""
+    def _program(self, window: int, sampled: bool,
+                 cap: Optional[int], K: int):
+        """What this manager contributes to a launch (``_launch``).
+        The plain and sampled programs are built for a CAPACITY
+        (``cap``, the widest window the caller will ever ask for) and
+        take ``window`` as an operand, so one program a variant serves
+        every window; without ``cap`` the capacity is this call's
+        window.  One speculative program per (window, K)."""
+        if K:
+            if self._stacked is None or self._draft_stacked is None:
+                raise RuntimeError("step_spec() before a speculative "
+                                   "insert()")
+            return ((window, "spec", K),
+                    lambda: self._build_spec_step(window, K),
+                    ("_stacked", "_draft_stacked"))
         if self._stacked is None:
             raise RuntimeError("step() before any insert()")
         cap = max(cap or window, window)
-        outs, = self._dispatch(
-            "sampled" if sampled else "plain", (cap, sampled),
-            lambda: self._build_step(cap, sampled), ("_stacked",),
-            leading=(np.int32(window),), window=window)
-        outs = outs[:window]
-        self.state.advance(window, outs[-1])
-        return outs
+        return ((cap, sampled), lambda: self._build_step(cap, sampled),
+                ("_stacked",), (np.int32(window),))
 
     # -- speculative step ------------------------------------------------
 
@@ -953,24 +1174,3 @@ class SlotKVManager(SlotManager):
             weights, program, donate_argnums=(1, 2),
             in_shardings=(w_sh,) + pools + (rep,) * 8,
             out_shardings=(rep, rep, rep) + pools)
-
-    def step_spec(self, window: int, K: int):
-        """``window`` fused SPECULATIVE rounds across the whole pool.
-        Returns ``(tokens [window, S, K], commits [window, S],
-        accepts [window, S])``: round w commits ``tokens[w, s,
-        :commits[w, s]]`` for slot s (1 for non-speculative slots,
-        garbage for idle ones — the caller masks by occupancy), and
-        ``accepts`` counts the accepted draft tokens (the engine's
-        acceptance-rate metric).  ``K`` is the program's draft width
-        — the pool max; slots with smaller ``spec_k`` commit at most
-        their own k (exactness per slot is unchanged, see
-        _spec_verify_row)."""
-        if self._stacked is None or self._draft_stacked is None:
-            raise RuntimeError("step_spec() before a speculative "
-                               "insert()")
-        outs, commits, accepts = self._dispatch(
-            "spec", (window, "spec", K),
-            lambda: self._build_spec_step(window, K),
-            ("_stacked", "_draft_stacked"), window=window, k=K)
-        self.state.advance_spec(outs, commits)
-        return outs, commits, accepts

@@ -389,6 +389,7 @@ class TestWeightsAreProgramArguments:
                              np.zeros((1, 8), np.int32))
         n = 4
         vec = np.zeros((n,), np.int32)
+        fresh = np.ones((n,), bool)     # tokens, fed, fresh, positions
         extra = (np.zeros((n, 2), np.uint32), vec,
                  np.zeros((n,), np.float32), vec,
                  np.zeros((n,), np.float32)) if sampled else ()
@@ -400,12 +401,13 @@ class TestWeightsAreProgramArguments:
             P = 2
             fn = mgr._build_step(2, sampled, P)
             operands = (mgr.kv_pool(), np.zeros((n, P), np.int32), vec,
-                        vec, vec, *extra)
+                        vec, vec, fresh, vec, *extra)
         else:
             mgr = SlotKVManager(model, variables, n)
             mgr._ensure_stacked(cache)
             fn = mgr._build_step(2, sampled)
-            operands = (mgr.kv_pool(), np.int32(2), vec, vec, *extra)
+            operands = (mgr.kv_pool(), np.int32(2), vec, vec, fresh,
+                        vec, *extra)
         assert not self._weights_inside(fn, *operands)
 
     @pytest.mark.parametrize("kind", ["engine-prefill", "engine-extend",

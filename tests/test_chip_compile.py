@@ -185,7 +185,8 @@ def test_engine_decode_window_compiles(one_chip, on_tpu, sampled):
                                     sharding=one_chip)
 
     steps = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    operands = [pool, steps, vec(jnp.int32), vec(jnp.int32)]
+    operands = [pool, steps, vec(jnp.int32), vec(jnp.int32),
+                vec(jnp.bool_), vec(jnp.int32)]
     if sampled:
         operands += [vec(jnp.uint32, 2), vec(jnp.int32),
                      vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
@@ -219,7 +220,8 @@ def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
                                     sharding=one_chip)
 
     operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32)]
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32)]
     if sampled:
         operands += [vec(jnp.uint32, 2), vec(jnp.int32),
                      vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
@@ -310,7 +312,8 @@ def test_decode_window_reads_no_whole_plane_outside_the_widest_branch(
                                     sharding=one_chip)
 
     operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32)]
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32)]
     if sampled:
         operands += [vec(jnp.uint32, 2), vec(jnp.int32),
                      vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
@@ -380,7 +383,8 @@ def test_served_programs_convert_no_weight(one_chip, on_tpu,
         fn = mgr._build_step(DECODE_WINDOW, True)
         operands = [
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-            vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+            vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+            vec(jnp.int32), vec(jnp.uint32, 2),
             vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
             vec(jnp.float32)]
         return variables, fn.func.lower(
@@ -429,11 +433,12 @@ def test_meshed_decode_window_compiles(topo, on_tpu):
     with mesh.exact():
         compiled = jax.jit(
             program,
-            in_shardings=(mesh.param_shardings(variables), pool_sh,
-                          rep, rep, rep),
+            in_shardings=(mesh.param_shardings(variables), pool_sh)
+            + (rep,) * 5,
             out_shardings=(rep, rep, pool_sh),
         ).lower(variables, pool,
-                jax.ShapeDtypeStruct((), jnp.int32), slots,
+                jax.ShapeDtypeStruct((), jnp.int32), slots, slots,
+                jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
                 slots).compile()
     text = compiled.as_text()
     assert "all-gather(" in text and "all-reduce(" not in text
@@ -504,7 +509,8 @@ def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
                                     sharding=one_chip)
 
     operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32), vec(jnp.uint32, 2),
                 vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
                 vec(jnp.float32)]
     compiled = fn.func.lower(*fn.args, pool, *operands).compile()
@@ -644,7 +650,8 @@ def test_jamba_decode_window_keeps_state_and_planes_in_place(
                                     sharding=one_chip)
 
     operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32, 2),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32), vec(jnp.uint32, 2),
                 vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
                 vec(jnp.float32)]
     compiled = fn.func.lower(*fn.args, pool, *operands).compile()

@@ -322,21 +322,21 @@ def test_failure_after_the_pool_was_consumed_goes_to_recovery(
     the pool is rebuilt, and every reply is the solo reference's."""
     model, variables = small_model
     eng = _engine(model, variables)
-    real, calls = eng.slots.step, []
+    real, calls = eng.slots.launch, []
 
-    def step(window, *args):
+    def launch(window, **kw):
         calls.append(window)
         if len(calls) == 3:
             old = jax.tree.leaves(eng.slots.kv_pool())
-            real(window, *args)
+            real(window, **kw)
             # what a failed execution leaves behind: the old tree,
             # its arrays deleted
             eng.slots._stacked = jax.tree.unflatten(
                 jax.tree.structure(eng.slots._stacked), old)
             raise RuntimeError("the device failed mid-program")
-        return real(window, *args)
+        return real(window, **kw)
 
-    eng.slots.step = step
+    eng.slots.launch = launch
     try:
         groups = [eng.submit(p, new, None, None, sampling=s)
                   for p, new, s in _requests()]

@@ -259,10 +259,13 @@ class TestPriorityAndPreemption:
                       slo_ttft_s=0.0001)
 
         def preempt_once(k):
-            """Preempt the victim once it has committed k(+1)
-            tokens — the +1 is deterministic: the tick that prefills
-            the interactive head also decodes once, and preemption
-            fires at the NEXT boundary."""
+            """Preempt the victim once it has committed k(+2)
+            tokens — the +2 is deterministic: the engine runs one
+            dispatch ahead, so one token is in flight when the
+            interactive request arrives; the tick that prefills the
+            interactive head collects it and (serial now: an
+            interactive head under an armed SLO) decodes once more,
+            and preemption fires at the NEXT boundary."""
             victim = eng.submit(PROMPT, 34, None, None,
                                 priority="batch")
             while len(victim.streams[0].out) < k:
@@ -273,10 +276,10 @@ class TestPriorityAndPreemption:
             assert victim.event.is_set() and inter.event.is_set()
 
         # Warm with the LARGEST resume length in the pow2 band
-        # (k=27 -> resume length 31 = [16, 8, 4, 2, 1]): that one
+        # (k=26 -> resume length 31 = [16, 8, 4, 2, 1]): that one
         # run compiles every piece program smaller lengths in the
         # band can use.
-        preempt_once(27)
+        preempt_once(26)
         warm = eng.sentinel.snapshot()["compile_cache_misses"]
         for k in (12, 18, 24):           # new, smaller commit points
             preempt_once(k)

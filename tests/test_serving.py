@@ -465,25 +465,36 @@ class TestContinuousBatching:
     def test_window_drops_to_single_steps_under_pressure(self):
         """A queued request with a free slot forces single-step
         granularity (admission next boundary), and the window never
-        fuses past the earliest budget eviction."""
+        fuses past the earliest budget eviction.  The engine runs one
+        dispatch ahead, so a window is read where it is LAUNCHED:
+        tokens committed plus tokens in flight."""
         eng, _, _ = _tiny_engine(n_slots=2, decode_window=8)
+
+        def launched(group):
+            stream = group.streams[0]
+            return len(stream.out) + stream.in_flight
+
         a = eng.submit(np.asarray([[3, 1, 4, 1]], np.int32), 20,
                        None, None)
         eng.tick()          # admit A (token 1) + one full window of 8
-        assert len(a.streams[0].out) == 9
+        assert launched(a) == 9
         # alone, rem=11 -> full window
         assert eng._pick_window() == 8
         b = eng.submit(np.asarray([[2, 7]], np.int32), 4, None, None)
         # queued + a free slot -> single step (admission next tick)
         assert eng._pick_window() == 1
+        eng.tick()          # prefills B behind the window in flight
+        assert launched(a) == 10 and b.streams[0].pf_done
         eng.tick()          # admits B; window = min(rem) = 3 -> 2
         assert len(eng.queue) == 0
-        assert len(b.streams[0].out) == 3
+        assert launched(b) == 3
         # B one token from budget: the window clamps to it
         assert eng._pick_window() == 1
-        eng.tick()          # B completes exactly at the window end
+        eng.tick()          # B's budget ends with this launch ...
+        assert launched(b) == 4 and b.streams[0].slot not in eng._resident
+        assert eng._pick_window() == 4      # A alone again, rem 7
+        eng.tick()          # ... and B completes when it is collected
         assert b.event.is_set()
-        assert eng._pick_window() == 8      # A alone again, rem 8
         eng.run_until_idle()
         assert a.event.is_set()
 
@@ -505,13 +516,14 @@ class TestContinuousBatching:
             eng.tick()
             assert eng._pick_window() == 1
         assert not b.streams[0].pf_done
-        before = len(a.streams[0].out)
+        stream = a.streams[0]
+        before = len(stream.out) + stream.in_flight
         # The tick that finishes B's last chunk resumes fusion in its
         # own decode phase (prefilled, pool full, no eos: the only
         # capacity event is A's budget eviction).
         eng.tick()
         assert b.streams[0].pf_done
-        assert len(a.streams[0].out) - before > 1
+        assert len(stream.out) + stream.in_flight - before > 1
         eng.run_until_idle()
         assert a.event.is_set() and b.event.is_set()
 
