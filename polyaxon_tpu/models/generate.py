@@ -66,16 +66,29 @@ def jit_over(weights, fn, **jit_kw):
 STATS = "stats"
 
 
-def stats_total(stats):
-    """What one apply sowed under :data:`STATS`, summed over the layers
-    that sowed: one vector (for the expert layers ``[pairs on each held
-    expert ..., pairs routed]``), or None for a model that sows nothing.
-    Leading axes (a vmapped step: a lane a slot) are summed away."""
+def stats_rows(stats):
+    """What one apply sowed under :data:`STATS`, a row a layer that
+    sowed (for the expert layers ``[pairs on each held expert ...,
+    pairs routed]``), or None for a model that sows nothing.  Inside a
+    vmapped step a row is ONE lane's: the caller sums the lanes before
+    :func:`stats_total`."""
     leaves = jax.tree.leaves(stats)
     if not leaves:
         return None
-    return sum(leaf.reshape(-1, leaf.shape[-1]).sum(axis=0)
-               for leaf in leaves)
+    return jnp.concatenate([leaf.reshape(-1, leaf.shape[-1])
+                            for leaf in leaves])
+
+
+def stats_total(rows):
+    """:func:`stats_rows` of one program run, every lane in, as one
+    vector: the rows' sum, then how many (layer, held expert) took at
+    least one pair — ``[pairs on each held expert ..., pairs routed,
+    held experts touched]``.  A touched expert's weights are what the
+    layer's grouped matmuls cannot avoid reading."""
+    if rows is None:
+        return None
+    touched = jnp.count_nonzero(rows[:, :-1]).astype(rows.dtype)
+    return jnp.concatenate([rows.sum(axis=0), touched[None]])
 
 
 def init_cache(model, batch_size: int):
@@ -874,7 +887,7 @@ def _prefill(model, variables, prompt, chunk: Optional[int] = None,
             toks, decode=True, decode_position=pos, last_only=True,
             mutable=["cache", STATS] if with_stats else ["cache"])
         return (extract_logits(out)[:, -1], mut["cache"],
-                stats_total(mut.get(STATS)))
+                stats_total(stats_rows(mut.get(STATS))))
 
     def done(logits, cache, *stats):
         if not with_stats:

@@ -13,11 +13,16 @@ and T5's decoder self-attention.  Two storage disciplines:
 wrote: where the caller knows how far the cache is written
 (:func:`read_extent`) the attention reads the planes only that far.
 
+:func:`attend_latent_cache` is the same for a layer that caches ONE
+latent row a position (models/deepseek_v2.py) where the others cache a
+key and a value a head: one plane ``[B, positions, width]``, no heads
+axis, no value plane.
+
 A cache tree may also hold a recurrent layer's state, which has no
 position axis and goes through none of the helpers above
-(models/jamba.py declares it): :func:`leaf_kinds` tells the three
-kinds of leaf apart — ``window``, ``full``, ``state`` — for everyone
-who has to.
+(models/jamba.py declares it): :func:`leaf_kinds` tells the four
+kinds of leaf apart — ``window``, ``full``, ``state``, ``latent`` —
+for everyone who has to.
 """
 
 from __future__ import annotations
@@ -49,17 +54,21 @@ def _quantize_chunk(x):
 # axis, and a layer touches only what it changes.
 
 
-def _plane(var, layer, rows=None):
+def _plane(var, layer, rows=None, tail: int = 2):
     """This layer's plane of ``var``, read once — or its first
     ``rows`` positions (static), sliced out of the variable as it
-    lies: on a carried stack no whole plane is made on the way."""
+    lies: on a carried stack no whole plane is made on the way.
+    ``tail``: the axes behind the position axis (heads and features of
+    a K or V plane; 1 for a latent plane's width)."""
     stack = var.value
+    axis = stack.ndim - 1 - tail
     if layer is None:
-        return stack if rows is None else stack[..., :rows, :, :]
+        return stack if rows is None \
+            else jax.lax.slice_in_dim(stack, 0, rows, axis=axis)
     if rows is None:
         return jax.lax.dynamic_index_in_dim(stack, layer, 0,
                                             keepdims=False)
-    sizes = (1,) + stack.shape[1:-3] + (rows,) + stack.shape[-2:]
+    sizes = (1,) + stack.shape[1:axis] + (rows,) + stack.shape[axis + 1:]
     return jax.lax.dynamic_slice(
         stack, (layer,) + (0,) * (stack.ndim - 1), sizes)[0]
 
@@ -72,15 +81,17 @@ def _store(var, layer, value) -> None:
 
 
 def _put_rows(var, layer, rows, start) -> None:
-    """Write ``rows`` ([B, S, H, D]) at positions ``[start, start+S)``
-    of this layer's plane — on a carried stack an update of S rows in
-    place, not of the plane."""
+    """Write ``rows`` ([B, S, H, D], or [B, S, width] of a latent
+    plane) at positions ``[start, start+S)`` of this layer's plane —
+    on a carried stack an update of S rows in place, not of the
+    plane."""
+    behind = (0,) * (rows.ndim - 2)
     if layer is None:
         var.value = jax.lax.dynamic_update_slice(
-            var.value, rows, (0, start, 0, 0))
+            var.value, rows, (0, start) + behind)
     else:
         var.value = jax.lax.dynamic_update_slice(
-            var.value, rows[None], (layer, 0, start, 0, 0))
+            var.value, rows[None], (layer, 0, start) + behind)
 
 
 def _scatter_rows(var, layer, rows, slots) -> None:
@@ -355,7 +366,7 @@ def prefix_width(extent, cap: int):
 
 # -- the kinds of leaf ------------------------------------------------------
 #
-# A decode cache tree holds leaves of three kinds, and ONE function
+# A decode cache tree holds leaves of four kinds, and ONE function
 # says which (``leaf_kinds``): what the slot pool reports by kind, what
 # the bounded reads count, and what a storage discipline refuses at
 # start-up all read it.
@@ -371,9 +382,16 @@ def prefix_width(extent, cap: int):
 #   whole past after exactly the tokens it has seen: it can be stored,
 #   copied into a slot and carried from piece to piece, never rewound
 #   to an earlier position and never cut into pages.
+# - ``latent``: a latent-attention layer's plane (``cached_latent``,
+#   ``[B, positions, width]``) and the index beside it: ONE row a
+#   position for all the heads, no heads axis and no value plane.
+#   Position-keyed as ``full`` is (it rewinds, and reads to an extent),
+#   but there are no heads for a mesh to shard and the paged pool's
+#   gather and scatter know leaves of K and V by name.
 
 STATE_LEAVES = ("ssm_state", "conv_tail")
-KINDS = ("window", "full", "state")
+LATENT_LEAF = "cached_latent"
+KINDS = ("window", "full", "state", "latent")
 
 
 def leaf_kinds(cache) -> list:
@@ -381,11 +399,13 @@ def leaf_kinds(cache) -> list:
     sequence's, or a pool's), ``kind`` one of :data:`KINDS`."""
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
     name = lambda path: jax.tree_util.keystr(path[-1:])  # noqa: E731
-    rings = {path[:-1] for path, _ in flat
-             if "cached_pos" in name(path)}
+    beside = lambda leaf: {path[:-1] for path, _ in flat  # noqa: E731
+                           if leaf in name(path)}
+    rings, latents = beside("cached_pos"), beside(LATENT_LEAF)
     return [(path, leaf,
              "state" if any(s in name(path) for s in STATE_LEAVES)
-             else "window" if path[:-1] in rings else "full")
+             else "window" if path[:-1] in rings
+             else "latent" if path[:-1] in latents else "full")
             for path, leaf in flat]
 
 
@@ -404,20 +424,29 @@ def cache_kinds(model) -> tuple:
     return tuple(k for k in KINDS if k in held) or ("full",)
 
 
-def full_planes(cache) -> dict:
-    """``{(capacity, stacked): planes}`` of the full-length key planes
-    in ONE sequence's cache tree — every ``cached_key`` leaf of kind
-    ``full``, a plane a layer; ``stacked`` where the leaf holds its
-    layers on a leading axis (``[layers, B, positions, H, D]``: the
-    stack that decoding carries).  A ``state`` leaf has no rows to
-    read to an extent and is passed by."""
+def full_planes(cache, kind: str = None) -> dict:
+    """``{(capacity, stacked): planes}`` of the full-length planes in
+    ONE sequence's cache tree that are read to an extent — every
+    ``cached_key`` leaf of kind ``full`` and every ``cached_latent``
+    leaf, a plane a layer (``kind``: those of one kind alone);
+    ``stacked`` where the leaf holds its layers on a leading axis
+    (``[layers, B, positions, H, D]``: the stack that decoding
+    carries).  A ``state`` leaf has no rows to read to an extent and
+    is passed by."""
     planes = {}
-    for path, leaf, kind in leaf_kinds(cache):
-        if kind == "full" and "cached_key'" in \
-                jax.tree_util.keystr(path[-1:]):
-            key = (leaf.shape[-3], leaf.ndim > 4)
+    for path, leaf, leaf_kind in leaf_kinds(cache):
+        name = jax.tree_util.keystr(path[-1:])
+        if leaf_kind == "full" and "cached_key'" in name:
+            behind = 2                          # heads, features
+        elif leaf_kind == "latent" and LATENT_LEAF in name:
+            behind = 1                          # the row's width
+        else:
+            continue
+        if kind in (None, leaf_kind):
+            lead = leaf.shape[:-1 - behind]
+            key = (leaf.shape[-1 - behind], len(lead) > 1)
             planes[key] = planes.get(key, 0) \
-                + int(np.prod(leaf.shape[:-3], dtype=np.int64))
+                + int(np.prod(lead, dtype=np.int64))
     return planes
 
 
@@ -426,6 +455,13 @@ def state_layers(cache) -> int:
     state (``ssm_state`` leaves)."""
     return sum("ssm_state" in jax.tree_util.keystr(path[-1:])
                for path, _, _ in leaf_kinds(cache))
+
+
+def causal_pairs(start, length):
+    """Query-key pairs of ``length`` queries at positions ``[start,
+    start + length)``, each over the keys up to its own: ``length *
+    start + length (length + 1) / 2`` (ints or numpy arrays)."""
+    return length * start + length * (length + 1) // 2
 
 
 class PlaneReads:
@@ -443,7 +479,19 @@ class PlaneReads:
     ``state_steps``, sequence-steps of the one-position update (a
     decode step a slot, idle slots too; a prefill piece of one
     position).  Engine stats ``ssm_scan_tokens_total`` /
-    ``ssm_state_steps_total``."""
+    ``ssm_state_steps_total``.
+
+    And of the two paths of a latent-attention layer
+    (``latent_planes``: its planes, of one capacity; models/
+    deepseek_v2.py says which call takes which): ``pairs_expanded``
+    and ``pairs_absorbed``, causal query-key pairs x latent layers of
+    the calls that expanded the rows they read to a key and a value a
+    head (a piece of more than one position) and of those that
+    attended over the latents as they lie (one position: a decode step
+    a slot, idle slots too); and ``rows_expanded``, the cached rows
+    the former sent through the expansion (the static width read, a
+    layer).  Engine stats ``latent_pairs_expanded_total`` /
+    ``latent_pairs_absorbed_total`` / ``latent_rows_expanded_total``."""
 
     def __init__(self):
         self.read = 0
@@ -452,6 +500,11 @@ class PlaneReads:
         self.state_layers = 0
         self.scan_tokens = 0
         self.state_steps = 0
+        self.latent_planes = 0
+        self.latent_cap = 0
+        self.pairs_expanded = 0
+        self.pairs_absorbed = 0
+        self.rows_expanded = 0
 
     def learn(self, cache) -> None:
         """The shape of one sequence's cache, from the first one seen
@@ -459,19 +512,37 @@ class PlaneReads:
         if self.planes is None:
             self.planes = full_planes(cache)
             self.state_layers = state_layers(cache)
+            for (cap, _), n in full_planes(cache, "latent").items():
+                self.latent_planes += n
+                self.latent_cap = cap
 
-    def count_piece(self, piece: int) -> None:
-        """One prefill piece of ``piece`` positions ran."""
+    def count_piece(self, piece: int, filled: int) -> None:
+        """One prefill piece of ``piece`` positions ran, the last of
+        the ``filled`` its cache now holds."""
         if self.state_layers:
             if piece > 1:
                 self.scan_tokens += piece * self.state_layers
             else:
                 self.state_steps += 1
+        if self.latent_planes:
+            pairs = self.latent_planes * int(
+                causal_pairs(filled - piece, piece))
+            if piece > 1:
+                self.pairs_expanded += pairs
+                self.rows_expanded += self.latent_planes * int(
+                    prefix_width(filled, self.latent_cap))
+            else:
+                self.pairs_absorbed += pairs
 
-    def count_steps(self, steps: int, lanes: int) -> None:
-        """A decode window of ``steps`` steps over ``lanes`` slots."""
+    def count_steps(self, steps: int, positions) -> None:
+        """A decode window of ``steps`` steps over the slots that stand
+        at ``positions`` (every lane of the pool, idle ones too)."""
+        positions = np.asarray(positions, np.int64)
         if self.state_layers:
-            self.state_steps += steps * lanes
+            self.state_steps += steps * positions.size
+        if self.latent_planes:
+            self.pairs_absorbed += self.latent_planes * int(
+                causal_pairs(positions, steps).sum())
 
     def count(self, extents, lanes: int = 1, cap=None,
               shared: bool = False) -> None:
@@ -671,6 +742,41 @@ def attend_kv_cache(mod, attend, k, v, max_position: int, window=None,
                                quantize, layer)
     return _over_prefix(lambda n: attend(*read(n), pos_q), pos_q, cap,
                         stacked=layer is not None)
+
+
+def attend_latent_cache(mod, attend, rows, max_position: int,
+                        rotate=None):
+    """:func:`attend_kv_cache` for a layer that caches ONE row a
+    position: append ``rows`` ([B, S, width], through ``rotate(
+    positions, rows)`` where given: a rope part is stored rotated) to
+    the ``cached_latent`` plane ``[B, max_position, width]`` at the
+    layer's ``cache_index``, and hand back ``attend(read, mask,
+    positions)`` over the plane's first ``n`` rows, ``mask`` ``[1, 1,
+    S, n]``: ``n`` by the extent in scope exactly as there, the whole
+    plane outside one.  The contracts of :func:`append_kv_cache` hold
+    as they stand — absolute positions, the capacity of an existing
+    cache, the rollback by index — since nothing of them speaks of
+    heads.  The plane is its layer's own variable (an unrolled stack):
+    no model carries a stack of latent planes yet."""
+    b, s, width = rows.shape
+    idx = mod.variable("cache", "cache_index",
+                       lambda: jnp.array(0, jnp.int32))
+    idx0 = idx.value
+    pos_q = idx0 + jnp.arange(s)
+    if rotate is not None:
+        rows = rotate(pos_q, rows)
+    plane = mod.variable("cache", LATENT_LEAF, jnp.zeros,
+                         (b, max_position, width), rows.dtype)
+    cap = plane.value.shape[-2]
+    _put_rows(plane, None, rows, idx0)
+    idx.value = idx0 + s
+
+    def attend_rows(n: int):
+        read = _plane(plane, None, None if n == cap else n, tail=1)
+        valid = jnp.arange(n)[None, :] <= pos_q[:, None]     # [S, n]
+        return attend(read, valid[None, None], pos_q)
+
+    return _over_prefix(attend_rows, pos_q, cap, stacked=False)
 
 
 # -- paged storage helpers --------------------------------------------------

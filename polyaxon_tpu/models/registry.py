@@ -20,6 +20,7 @@ import optax
 from .afmoe import AfmoeConfig, AfmoeModel
 from .bert import BertConfig, BertModel
 from .convnet import ConvNet
+from .deepseek_v2 import DeepseekV2Config, DeepseekV2Model
 from .gpt2 import GPT2Config, GPT2Model
 from .jamba import JambaConfig, JambaModel
 from .llama import LlamaConfig, LlamaModel
@@ -518,6 +519,26 @@ _register(ModelSpec(
     make_model=_cfg_model(JambaModel, JambaConfig.jamba2_3b()),
     make_batch=lambda b: _token_batch(
         b, 8, JambaConfig.jamba2_3b().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=1,
+))
+
+_register(ModelSpec(
+    name="deepseek-v2-tiny",
+    make_model=_cfg_model(DeepseekV2Model, DeepseekV2Config.tiny()),
+    make_batch=lambda b: _token_batch(
+        b, 16, DeepseekV2Config.tiny().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=8,
+))
+
+# Served only (perfbench cell dsv2lite-serve-longdoc): stage 0 of four.
+_register(ModelSpec(
+    name="deepseek-v2-lite-stage0",
+    make_model=_cfg_model(DeepseekV2Model,
+                          DeepseekV2Config.v2_lite_stage0()),
+    make_batch=lambda b: _token_batch(
+        b, 8, DeepseekV2Config.v2_lite_stage0().vocab_size),
     loss_fn=_lm_loss,
     default_batch_size=1,
 ))

@@ -34,11 +34,23 @@ _ROUTES: "collections.Counter[str]" = collections.Counter()
 _ROUTES_LOCK = threading.Lock()
 
 
+ROUTES = ("flash", "xla", "latent_expanded", "latent_absorbed")
+
+
 def route_counts() -> Dict[str, int]:
-    """``{"flash": n, "xla": n}``: local attention calls traced so far
-    in this process, by the path they took."""
+    """``{"flash": n, "xla": n, "latent_expanded": n, "latent_absorbed":
+    n}``: attention calls traced so far in this process, by the path
+    they took — the local attention's two, and the two of a latent
+    attention layer (models/deepseek_v2.py: the cached rows expanded to
+    keys and values, or attended over as they lie)."""
     with _ROUTES_LOCK:
-        return {"flash": _ROUTES["flash"], "xla": _ROUTES["xla"]}
+        return {route: _ROUTES[route] for route in ROUTES}
+
+
+def note_latent_route(route: str) -> None:
+    """A latent attention layer traced a call by ``route``."""
+    with _ROUTES_LOCK:
+        _ROUTES[route] += 1
 
 
 def _note_route(route: str, q, k, mask, causal, window) -> None:

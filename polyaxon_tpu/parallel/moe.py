@@ -7,10 +7,11 @@ dispatch/combine keeps everything MXU-shaped (no dynamic gathers — XLA
 and the TPU both prefer the one-hot matmul form).
 
 Beside it, the token-choice layer of the served sparse decoders
-(``models/afmoe.py``): :func:`sigmoid_topk_route` scores every token
-over ALL experts, and :func:`held_experts_ffn` computes the part of the
-routed sum that the experts HELD here give — one chip's share of an
-expert-parallel deployment, told ``expert_offset`` and holding
+(``models/afmoe.py``, ``models/deepseek_v2.py``):
+:func:`sigmoid_topk_route` and :func:`softmax_topk_route` score every
+token over ALL experts, and :func:`held_experts_ffn` computes the part
+of the routed sum that the experts HELD here give — one chip's share
+of an expert-parallel deployment, told ``expert_offset`` and holding
 ``E_h`` experts' weights.  No token routed to a held expert is dropped;
 what absent experts would add is left out, and nothing stands in for
 the absent chips or their exchange.
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.grouped_matmul import grouped_matmul
 from .mesh import active_batch_axes
 
 
@@ -162,6 +164,20 @@ def sigmoid_topk_route(x, router_w, select_bias, k: int, *,
     return chosen.astype(jnp.int32), w * scale
 
 
+def softmax_topk_route(x, router_w, k: int, *, scale: float = 1.0):
+    """Token-choice routing by softmax scores, greedy: ``x`` [..., d]
+    over ``router_w`` [d, E] -> ``(chosen [..., k] int32, weights
+    [..., k] f32)``.  The scores are the softmax over ALL experts,
+    float32 at full matmul precision as in :func:`sigmoid_topk_route`
+    and for its reason; the weights are the k largest as they are
+    (they do not add up to 1) times ``scale``."""
+    g = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, chosen = jax.lax.top_k(g, k)
+    return chosen.astype(jnp.int32), w * scale
+
+
 def held_pair_counts(chosen, num_held: int, expert_offset: int = 0):
     """Token-expert pairs that fall on each HELD expert: ``chosen``
     [..., k] (ids over all experts) -> int32 [num_held].  Plain
@@ -188,7 +204,7 @@ def _grouped_ffn(expert_offset: int):
         order = jnp.argsort(key, stable=True)
         sizes = held_pair_counts(chosen, held_n, expert_offset)
         rows = x[order // k]                                # [T*k, d]
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
+        dot = functools.partial(grouped_matmul, sizes=sizes)
         h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
         out = dot(h.astype(x.dtype), w_down)                # [T*k, d]
         # Rows past the last group belong to no held expert: whatever
@@ -218,8 +234,8 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
                      expert_offset: int = 0):
     """The held experts' part of ``sum_e w_e SwiGLU_e(x)``.
 
-    ``x`` [T, d]; ``chosen``/``weights`` [T, k] from
-    :func:`sigmoid_topk_route` (ids over ALL experts); ``w_gate``,
+    ``x`` [T, d]; ``chosen``/``weights`` [T, k] from a route above
+    (ids over ALL experts); ``w_gate``,
     ``w_up`` [E_h, d, f] and ``w_down`` [E_h, f, d] the weights of
     experts ``[expert_offset, expert_offset + E_h)``.  Returns float32
     [T, d].
@@ -227,8 +243,10 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
     One grouped computation serves a prefill chunk and a decode step:
     the token-expert pairs are sorted by held expert (absent experts'
     pairs last, outside every group), each expert's rows go through its
-    weights in ``jax.lax.ragged_dot`` — a grouped matmul that reads an
-    expert's weights once and touches no other token — and the rows
+    weights in ``ops/grouped_matmul.grouped_matmul`` — the Pallas
+    kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere: a grouped matmul
+    that reads an expert's weights once and touches no other token —
+    and the rows
     return to their tokens under the routing weights.  The row count is
     the static ``T * k``, every pair's place should all of them fall
     here, so no held pair is ever dropped; no ``[T, d, f]`` copy of

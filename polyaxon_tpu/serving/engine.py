@@ -1642,7 +1642,7 @@ class DecodeEngine:
             # as it had written them (generate.prefill_programs).
             self.slots.plane_reads.learn(cache)
             self.slots.plane_reads.count([stream.filled])
-            self.slots.plane_reads.count_piece(piece)
+            self.slots.plane_reads.count_piece(piece, stream.filled)
             stream.pieces.pop(0)
             self.prefill_chunks_total += 1
             self.prefill_tokens_total += piece
@@ -2911,6 +2911,7 @@ class DecodeEngine:
             "kv_plane_rows_held_total": self.slots.plane_reads.held,
             **self._moe_stats(),
             **self._ssm_stats(),
+            **self._latent_stats(),
             "completed_total": self.completed_total,
             "completed_greedy_total": self.completed_greedy_total,
             "completed_sampled_total": self.completed_sampled_total,
@@ -3014,14 +3015,18 @@ class DecodeEngine:
         """The expert layers' token-expert pairs since the start,
         prefill and decode, every expert layer (nothing for a model
         without): pairs routed over ALL experts, pairs that fell on
-        the experts held here, and the held experts' own counts.  An
-        idle slot's dead step counts like a live one."""
+        the experts held here, the held experts' own counts, and the
+        held experts that took at least one pair, a layer a program
+        run (a decode step, a prefill piece): whose weights a grouped
+        matmul had to read.  An idle slot's dead step counts like a
+        live one."""
         pairs = self.slots.moe_pairs
         if pairs is None:
             return {}
-        return {"moe_pairs_routed_total": int(pairs[-1]),
-                "moe_pairs_held_total": int(pairs[:-1].sum()),
-                "moe_expert_pairs": [int(n) for n in pairs[:-1]]}
+        return {"moe_pairs_routed_total": int(pairs[-2]),
+                "moe_pairs_held_total": int(pairs[:-2].sum()),
+                "moe_expert_pairs": [int(n) for n in pairs[:-2]],
+                "moe_experts_touched_total": int(pairs[-1])}
 
     def _ssm_stats(self) -> Dict[str, Any]:
         """What the recurrent layers' state went through since the
@@ -3034,6 +3039,20 @@ class DecodeEngine:
             return {}
         return {"ssm_scan_tokens_total": reads.scan_tokens,
                 "ssm_state_steps_total": reads.state_steps}
+
+    def _latent_stats(self) -> Dict[str, Any]:
+        """What the latent attention layers' two paths took since the
+        start (nothing for a model without, or before the first
+        prefill shaped the cache): causal query-key pairs x latent
+        layers of the calls that expanded the rows they read and of
+        those that attended over them as they lie, and the rows the
+        former expanded (kv_cache.PlaneReads)."""
+        reads = self.slots.plane_reads
+        if not reads.latent_planes:
+            return {}
+        return {"latent_pairs_expanded_total": reads.pairs_expanded,
+                "latent_pairs_absorbed_total": reads.pairs_absorbed,
+                "latent_rows_expanded_total": reads.rows_expanded}
 
     def _mesh_stats(self) -> Dict[str, Any]:
         # Under the device lock: the next dispatch consumes the tree

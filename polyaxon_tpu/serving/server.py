@@ -110,6 +110,12 @@ BATCHING_MODES = ("continuous", "coalesce", "off")
 # prefills over the wire-fetch lane.
 ROLES = ("prefill", "decode", "both")
 
+# engine._latent_stats: on /info and /metrics where the model has
+# latent attention layers.
+LATENT_COUNTERS = ("latent_pairs_expanded_total",
+                   "latent_pairs_absorbed_total",
+                   "latent_rows_expanded_total")
+
 
 class _PagedPrefix:
     """Radix payload for a PAGE-BACKED prefix entry: the stored
@@ -3046,6 +3052,7 @@ class ModelServer:
         import jax
 
         from ..ops.attention import route_counts
+        from ..ops.grouped_matmul import route_counts as matmul_routes
         from ..ops.selective_scan import route_counts as scan_routes
 
         cfg = getattr(self.model, "cfg", None)
@@ -3092,6 +3099,10 @@ class ModelServer:
                 # (ops/selective_scan.py): the Pallas kernel or the
                 # ``lax.scan`` it drops to.
                 "scan_routes": scan_routes(),
+                # The expert layers' grouped matmuls traced so far, by
+                # path (ops/grouped_matmul.py): the Pallas kernel or
+                # XLA's ``ragged_dot``.
+                "grouped_matmul_routes": matmul_routes(),
                 "max_batch": self.max_batch,
                 "batching": self.batching,
                 "role": self.role,
@@ -3159,11 +3170,16 @@ class ModelServer:
                 # has expert layers (engine._moe_stats).
                 **{k: engine[k] for k in
                    ("moe_pairs_routed_total", "moe_pairs_held_total",
-                    "moe_expert_pairs") if k in engine},
+                    "moe_expert_pairs", "moe_experts_touched_total")
+                   if k in engine},
                 # What the recurrent layers' state went through, where
                 # the model keeps one (engine._ssm_stats).
                 **{k: engine[k] for k in
                    ("ssm_scan_tokens_total", "ssm_state_steps_total")
+                   if k in engine},
+                # What the two paths of the latent attention layers
+                # took, where the model has any (engine._latent_stats).
+                **{k: engine[k] for k in LATENT_COUNTERS
                    if k in engine},
                 **{k: engine[k] for k in
                    ("slots", "slots_active", "slot_occupancy",
@@ -3513,6 +3529,10 @@ class ModelServer:
                     *(f'ptpu_serving_moe_expert_pairs{{expert="{i}"}} '
                       f"{n}" for i, n in
                       enumerate(es["moe_expert_pairs"])),
+                    "# TYPE ptpu_serving_moe_experts_touched_total "
+                    "counter",
+                    f"ptpu_serving_moe_experts_touched_total "
+                    f"{es['moe_experts_touched_total']}",
                 ] if "moe_pairs_routed_total" in es else []),
                 *([
                     "# TYPE ptpu_serving_ssm_scan_tokens_total counter",
@@ -3525,6 +3545,10 @@ class ModelServer:
                     *(f'ptpu_serving_scan_routes{{route="{k}"}} {n}'
                       for k, n in scan_routes().items()),
                 ] if "ssm_scan_tokens_total" in es else []),
+                *(line for name in LATENT_COUNTERS if name in es
+                  for line in (
+                      f"# TYPE ptpu_serving_{name} counter",
+                      f"ptpu_serving_{name} {es[name]}")),
                 # Speculative scheduling counters + the per-request
                 # acceptance-rate histogram — rendered from the SAME
                 # engine.stats() dict /info reports, so the two

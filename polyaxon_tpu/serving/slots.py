@@ -136,7 +136,8 @@ from ..spans import span
 
 
 _KIND_WORDS = {"window": "window rings", "full": "full-length planes",
-               "state": "recurrent state without a position axis"}
+               "state": "recurrent state without a position axis",
+               "latent": "latent planes without a heads axis"}
 
 
 def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
@@ -146,11 +147,14 @@ def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
     options in play can hold their caches.  Read off the kinds of leaf
     the models' decode caches hold (``kv_cache.cache_kinds``):
     ``--kv-paged`` (``paged._classify`` cuts ONE position axis into
-    pages) and ``--mesh`` (``meshed.cache_shardings`` shards heads)
-    know one kind of leaf and refuse a pool of several, or of state; a
+    pages, of leaves it knows by name) and ``--mesh``
+    (``meshed.cache_shardings`` shards heads) know one kind of leaf,
+    per-head K and V, and refuse a pool of several, of state, or of
+    latent planes (no heads to shard, no K or V to name); a
     speculative slot REWINDS by position (the accept/rewind contract),
     which a state leaf cannot, so a draft model or ``--spec-k`` refuses
-    a model that keeps one."""
+    a model that keeps one.  A latent plane rewinds as a K plane does:
+    speculative slots serve it."""
     if not (paged or meshed or speculative):
         return None
     for model in models:
@@ -158,7 +162,8 @@ def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
             continue
         kinds = cache_kinds(model)
         stateful = "state" in kinds
-        if ((paged or meshed) and (len(kinds) > 1 or stateful)) \
+        if ((paged or meshed) and (len(kinds) > 1 or stateful
+                                   or "latent" in kinds)) \
                 or (speculative and stateful):
             count = {1: "one kind", 2: "two kinds",
                      3: "three kinds"}[len(kinds)]
@@ -166,8 +171,8 @@ def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
                 f"this model keeps {count} of KV cache in one slot "
                 f"pool ({' beside '.join(_KIND_WORDS[k] for k in kinds)}"
                 f"): the fixed-lane slot manager on one chip serves it; "
-                f"--kv-paged and --mesh know one kind of leaf, with a "
-                f"position axis, and refuse it"
+                f"--kv-paged and --mesh know one kind of leaf, K and V "
+                f"a head along a position axis, and refuse it"
                 + ("; a state has no position to rewind to, so "
                    "speculative decoding (--draft-model, --spec-k) "
                    "refuses it too" if stateful else ""))
@@ -326,8 +331,9 @@ def build_step_body(model, variables, window: int, sampled: bool):
     ``{"tok": [S] the last token of every slot, the next dispatch's
     ``fed``, "logits": [S, V] float32 of the LAST step run, "pairs": what
     the model sowed under generate.STATS summed over steps, layers and
-    slots (the expert layers' token-expert pairs; absent for a model
-    that sows nothing)}``.  Both managers return them from every
+    slots (``generate.stats_total``: the expert layers' token-expert
+    pairs and the experts they touched; absent for a model that sows
+    nothing)}``.  Both managers return them from every
     program, so the programs a benchmark times are the ones whose
     logits a reference check reads (fetched only on request) and the
     pair counts ride home with the tokens.
@@ -353,9 +359,9 @@ def build_step_body(model, variables, window: int, sampled: bool):
             {"params": G._params(variables), "cache": cache},
             tok[None, None], decode=True, decode_position=pos,
             mutable=["cache", G.STATS])
-        pairs = G.stats_total(mut.get(G.STATS))
+        pairs = G.stats_rows(mut.get(G.STATS))      # a row a layer
         if pairs is None:
-            pairs = jnp.zeros((0,), jnp.int32)
+            pairs = jnp.zeros((0, 0), jnp.int32)
         return (G.extract_logits(out)[:, -1][0], pairs,   # [V]
                 mut["cache"])
 
@@ -381,6 +387,12 @@ def build_step_body(model, variables, window: int, sampled: bool):
             jax.vmap(one), stacked, toks, positions,
             *keys, *idxs, *shaping)
 
+        def counted(rows):
+            # One step's rows [S, layers, n], the lanes summed: the
+            # experts a LAYER's grouped matmul touches are the pool's.
+            return G.stats_total(rows.sum(axis=0)) if rows.size \
+                else jnp.zeros((0,), rows.dtype)
+
         def body(i, carry):
             cache, tok, pos, idx, outs, _, pairs = carry
             # How far this step's attention reads the full-length
@@ -392,14 +404,15 @@ def build_step_body(model, variables, window: int, sampled: bool):
                     cache, tok, pos, *keys, *idx, *shaping)
             return (cache, nxt, pos + 1, tuple(j + 1 for j in idx),
                     outs.at[i].set(nxt), logits,
-                    pairs + new_pairs.sum(axis=0))
+                    pairs + counted(new_pairs))
 
         cache, tok, _, _, outs, logits, pairs = jax.lax.fori_loop(
             0, steps, body,
             (stacked, toks, positions, tuple(idxs),
              jnp.zeros((window,) + toks.shape, jnp.int32),
              jnp.zeros(like[1].shape, like[1].dtype),
-             jnp.zeros(like[2].shape[1:], like[2].dtype)))
+             jnp.zeros(jax.eval_shape(counted, like[2]).shape,
+                       like[2].dtype)))
         extras = {"tok": tok, "logits": logits}
         if pairs.size:          # nothing sown: nothing to fetch
             extras["pairs"] = pairs
@@ -757,7 +770,7 @@ class SlotManager:
                 self.plane_reads.count(
                     int(state.positions.max()) + 1 + np.arange(window),
                     lanes=self.n_slots, cap=plane_cap, shared=True)
-                self.plane_reads.count_steps(window, self.n_slots)
+                self.plane_reads.count_steps(window, state.positions)
                 *host, extras = host
                 state.launched(window, extras["tok"])
             self.last_logits = extras.get("logits")  # stays on the device
@@ -933,8 +946,8 @@ class SlotKVManager(SlotManager):
     def kv_pool_bytes_by_kind(self) -> dict:
         """``kv_pool_bytes`` split by the kind of cache a leaf belongs
         to (``kv_cache.leaf_kinds``): ``window`` for a ring's leaves,
-        ``state`` for a recurrent layer's, ``full`` for everything
-        else."""
+        ``state`` for a recurrent layer's, ``latent`` for a latent
+        attention layer's, ``full`` for everything else."""
         out = dict.fromkeys(KINDS, 0)
         for pool in (self._stacked, self._draft_stacked):
             for _, leaf, kind in leaf_kinds(pool):

@@ -312,8 +312,45 @@ def test_pair_counters_count_prefill_and_decode(served):
     assert info["moe_pairs_routed_total"] == tokens * 2 * 4
     assert sum(info["moe_expert_pairs"]) == info["moe_pairs_held_total"]
     assert 0 < info["moe_pairs_held_total"] < info["moe_pairs_routed_total"]
+    # 4 held experts x 4 expert layers at most, a piece or a step.
+    runs = info["prefill_chunks_total"] + info["decode_steps_total"]
+    assert 0 < info["moe_experts_touched_total"] <= min(
+        info["moe_pairs_held_total"], 4 * 4 * runs)
+    assert "ptpu_serving_moe_experts_touched_total" in metrics
     assert "ptpu_serving_moe_pairs_held_total" in metrics
     assert 'ptpu_serving_kv_pool_bytes_by_kind{kind="window"}' in metrics
+
+
+@pytest.mark.parametrize("lanes", [1, 3], ids=["a_piece", "pool_lanes"])
+def test_experts_touched_are_counted_a_layer_over_all_lanes(tiny, lanes):
+    """The last entry of the sown total: (layer, held expert) with at
+    least one pair in ONE program run — a prefill piece, or a decode
+    step whose lanes' pairs are summed BEFORE they are counted (a
+    layer's grouped matmul runs once for the pool) — against the
+    layers' own vectors."""
+    model, variables, ids, _ = tiny
+    held = model.cfg.experts_held
+
+    def sown(tokens):
+        _, mut = model.apply(variables, tokens, mutable=[G.STATS])
+        return mut[G.STATS]
+
+    if lanes == 1:
+        layers = [np.asarray(v).reshape(-1, held + 1).sum(0)
+                  for v in jax.tree.leaves(sown(ids[:, :6]))]
+        got = G.prefill(model, variables, ids[:, :6], with_stats=True)[2]
+    else:
+        per_lane = [jax.tree.leaves(sown(ids[:, t:t + 1]))
+                    for t in range(lanes)]
+        layers = [sum(np.asarray(v).reshape(-1, held + 1).sum(0)
+                      for v in layer) for layer in zip(*per_lane)]
+        rows = jax.vmap(lambda t: G.stats_rows(sown(t[None, None])))(
+            ids[0, :lanes])
+        got = G.stats_total(rows.sum(axis=0))
+    want = sum(int(np.count_nonzero(v[:-1])) for v in layers)
+    assert len(layers) == 4 and 0 < want <= 4 * held
+    assert int(got[-1]) == want
+    assert np.array_equal(got[:-1], sum(layers))
 
 
 def test_plane_rows_count_chunks_to_their_extent_and_steps_whole(served):

@@ -489,7 +489,7 @@ def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
     32 slots: bfloat16 weights at rest (8.65 GB) beside the pool (3.49
     GB), every pool leaf — rings and the full plane — aliased to an
     output, nothing of a leaf's size copied or concatenated, the
-    grouped expert matmul there as the compiler's ragged-dot call."""
+    grouped expert matmul there as the Pallas kernel (ops/grouped_matmul.py)."""
     import re
 
     from polyaxon_tpu.serving.slots import SlotKVManager
@@ -526,7 +526,10 @@ def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
         < 15.75 * 2 ** 30
     lane = r"bf16\[%d,1,(4608|8192),8,128\]" % TRINITY_SLOTS
     assert not re.search(r"= %s\S* (copy|concatenate)\(" % lane, text)
-    assert "ragged-dot" in text
+    # The grouped matmuls took the Pallas kernel: XLA's own ragged-dot
+    # is a tpu_custom_call too, so the count alone would not tell.
+    assert text.count("tpu_custom_call") >= 3
+    assert "ragged-dot" not in text
     # The full layer's plane is its layer's own variable, read whole
     # in the pool's step (kv_cache.narrows): sliced under a conditional
     # the compiler converted the layout of all of it in every branch,
@@ -557,7 +560,10 @@ def test_trinity_prefill_chunk_compiles(one_chip, on_tpu, first):
     assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
     assert not re.search(
         r"= bf16\[1,(4608|5120),8,128\]\S* concatenate\(", text)
-    assert "ragged-dot" in text
+    # The grouped matmuls took the Pallas kernel: XLA's own ragged-dot
+    # is a tpu_custom_call too, so the count alone would not tell.
+    assert text.count("tpu_custom_call") >= 3
+    assert "ragged-dot" not in text
     # The full layer's plane is read as far as the chunk has written
     # it.  From position 0 that is known while tracing: the first of
     # the four widths, no conditional, no scores over 8 192 keys
@@ -669,3 +675,109 @@ def test_jamba_decode_window_keeps_state_and_planes_in_place(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 15.75 * 2 ** 30
     assert "tpu_custom_call" not in text
+
+
+# -- deepseek-v2-lite, stage 0: one latent plane a layer --------------------
+
+DSV2_SLOTS = 16         # perfbench/configs/deepseek-v2-lite.json
+DSV2_POSITIONS = 16896
+
+
+def _dsv2_shapes(one_chip):
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.models.registry import get_model
+
+    model = get_model("deepseek-v2-lite-stage0").make_model()
+    variables = _abstract(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32)), one_chip)
+    one = jax.eval_shape(lambda: G.init_cache(model, 1))
+    return model, variables, one
+
+
+def test_dsv2_decode_window_keeps_the_latent_planes_in_place(
+        one_chip, on_tpu, monkeypatch):
+    """The decode program as the slot manager builds it, 16 slots of
+    16 896 positions at the published widths: 8.02 GB of weights, a
+    pool of latent planes and nothing else — 576 numbers a position a
+    layer, resting in 640 lanes (five tiles of 128), no sublane padding
+    of a heads axis there is not — every leaf aliased to an output, the
+    absorbed path's temporaries small, the plane never expanded."""
+    import re
+
+    from polyaxon_tpu.models.kv_cache import leaf_kinds
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, one = _dsv2_shapes(one_chip)
+    pool = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((DSV2_SLOTS,) + l.shape,
+                                       l.dtype, sharding=one_chip), one)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, DSV2_SLOTS)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, True)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((DSV2_SLOTS,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32), vec(jnp.uint32, 2),
+                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+                vec(jnp.float32)]
+    compiled = fn.func.lower(*fn.args, pool, *operands).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    weights = sum(l.size * l.dtype.itemsize
+                  for l in jax.tree.leaves(variables))
+    assert 8.01e9 < weights < 8.03e9
+    kinds = {kind for _, _, kind in leaf_kinds(pool)}
+    assert kinds == {"latent"}
+    logical = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(pool))
+    a_position = 7 * 576 * 2                    # bytes, logical
+    assert logical == DSV2_SLOTS * (DSV2_POSITIONS * a_position + 7 * 4)
+    # ...and as it rests: 640 lanes a row, 8 960 B a position.
+    resting = DSV2_SLOTS * DSV2_POSITIONS * 7 * 640 * 2
+    assert resting <= mem.alias_size_in_bytes < resting + 1e6
+    assert mem.temp_size_in_bytes < 0.3 * 2 ** 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    # The absorbed path: no key or value a head over the plane's rows.
+    assert not re.search(r"= bf16\[(?:\d+,)*16896,16,(?:256|128)\]", text)
+    # The grouped matmuls took the Pallas kernel: XLA's own ragged-dot
+    # is a tpu_custom_call too, so the count alone would not tell.
+    assert text.count("tpu_custom_call") >= 3
+    assert "ragged-dot" not in text
+
+
+def test_dsv2_extend_piece_compiles(one_chip, on_tpu):
+    """A 512-token piece onto an existing cache (``--prefill-chunk
+    512``): ONE conditional a layer over the four widths a plane is
+    read to, the rows read expanded to keys and values (the widest
+    branch 16 896 x 16 x 256), scores over 16 896 keys outside that
+    branch nowhere, temporaries that leave room beside 8.02 GB of
+    weights and 2.42 GB of pool."""
+    import re
+
+    from polyaxon_tpu.models import generate as G
+
+    model, variables, one = _dsv2_shapes(one_chip)
+    toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(G.prefill_programs(model)[1]).lower(
+        variables, _abstract(one, one_chip), toks, pos).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(re.findall(r" conditional\(", text)) == 7
+    assert re.search(r"bf16\[(?:1,)?2112,16,256\]", text)
+    assert re.search(r"bf16\[(?:1,)?16896,16,256\]", text)
+    assert mem.temp_size_in_bytes < 1.0 * 2 ** 30
+    # One lane's cache out, in the device's default layout: 7 planes
+    # of 16 896 x 576, NOT padded to 640 lanes as the pinned row-major
+    # pool is; and a row of float32 logits.
+    lane = 7 * DSV2_POSITIONS * 576 * 2
+    assert lane <= mem.output_size_in_bytes < lane + 1e6
+    # The grouped matmuls took the Pallas kernel: XLA's own ragged-dot
+    # is a tpu_custom_call too, so the count alone would not tell.
+    assert text.count("tpu_custom_call") >= 3
+    assert "ragged-dot" not in text

@@ -22,6 +22,8 @@ import numpy as np
 import pytest
 
 from polyaxon_tpu.models.afmoe import AfmoeConfig, AfmoeModel
+from polyaxon_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                             DeepseekV2Model)
 from polyaxon_tpu.models.jamba import JambaConfig, JambaModel
 from polyaxon_tpu.models.registry import get_model
 from polyaxon_tpu.serving import DecodeEngine, SchedulerPolicy
@@ -35,14 +37,16 @@ def _built(kind):
     if kind == "gpt2-tiny":
         return get_model("gpt2-tiny").init_params(batch_size=1)
     cfg, cls = {"jamba-tiny": (JambaConfig, JambaModel),
-                "afmoe-tiny": (AfmoeConfig, AfmoeModel)}[kind]
+                "afmoe-tiny": (AfmoeConfig, AfmoeModel),
+                "deepseek-v2-tiny": (DeepseekV2Config,
+                                     DeepseekV2Model)}[kind]
     model = cls(dataclasses.replace(cfg.tiny(), dtype=jnp.float32))
     return model, model.init(jax.random.PRNGKey(0),
                              jnp.zeros((1, 4), jnp.int32))
 
 
 @pytest.fixture(scope="module", params=["gpt2-tiny", "jamba-tiny",
-                                        "afmoe-tiny"])
+                                        "afmoe-tiny", "deepseek-v2-tiny"])
 def any_model(request):
     return _built(request.param)
 

@@ -188,7 +188,8 @@ def test_counters_against_hand_counts(tiny, monkeypatch):
     kinds = info["kv_pool_bytes_by_kind"]
     # A slot: 3 layers x (h 4 x 64 f32 + tail 3 x 64 f32) of state,
     # K and V planes of 64 x 8 f32 and an index.
-    assert kinds == {"window": 0, "state": 3 * 3 * (4 + 3) * 64 * 4,
+    assert kinds == {"window": 0, "latent": 0,
+                     "state": 3 * 3 * (4 + 3) * 64 * 4,
                      "full": 3 * (2 * 64 * 8 * 4 + 4)}
     assert sum(kinds.values()) == info["kv_pool_bytes"]
     assert set(info["scan_routes"]) == {"pallas", "xla"}
