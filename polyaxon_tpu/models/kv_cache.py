@@ -18,6 +18,16 @@ latent row a position (models/deepseek_v2.py) where the others cache a
 key and a value a head: one plane ``[B, positions, width]``, no heads
 axis, no value plane.
 
+WHAT IS WRITTEN, WHERE AND WHEN.  An append writes its new rows into
+the plane and then reads it — except a call of ONE row a sequence (a
+decode step) into a stack that the layer loop carries
+(:func:`defers`): it writes nothing inside the loop, hands the
+attention the plane with the new row laid over its position, and
+leaves the row for ``scan_stack.LayerScanBody.run`` to write once for
+all layers after the loop (:func:`write_deferred`).  The cache holds
+the row when the apply returns either way; what differs is how many
+dependent writes a slot pool's step runs (PERF.md section 6, PR 36).
+
 A cache tree may also hold a recurrent layer's state, which has no
 position axis and goes through none of the helpers above
 (models/jamba.py declares it): :func:`leaf_kinds` tells the four
@@ -84,7 +94,9 @@ def _put_rows(var, layer, rows, start) -> None:
     """Write ``rows`` ([B, S, H, D], or [B, S, width] of a latent
     plane) at positions ``[start, start+S)`` of this layer's plane —
     on a carried stack an update of S rows in place, not of the
-    plane."""
+    plane.  Under a pool's vmap ``start`` differs by lane and the
+    update is a scatter, a dependent write a lane: a decode step on a
+    carried stack does not come here (``defers``)."""
     behind = (0,) * (rows.ndim - 2)
     if layer is None:
         var.value = jax.lax.dynamic_update_slice(
@@ -103,6 +115,76 @@ def _scatter_rows(var, layer, rows, slots) -> None:
         # Two advanced indices around a slice: their axis leads.
         var.value = var.value.at[layer, :, slots].set(
             jnp.moveaxis(rows, 1, 0))
+
+
+# -- one write a step ----------------------------------------------------------
+#
+# Under a slot pool's vmap every lane stands at its own index, so a
+# row written at ``[layer, :, index]`` is a scatter of one index a
+# lane, and the TPU runs a scatter as a loop of dependent writes: a
+# trip a lane, in every layer and leaf.  gpt2-medium's 24 layers x 2
+# leaves x 24 slots were 1 152 such writes a decode step, 3 of its 7
+# ms (PERF.md section 6, PR 36).  So a call that appends ONE row a
+# sequence to a carried stack (``defers``) does not write it inside the
+# layer loop: the attention is handed the plane with the new row laid
+# OVER its position, in value space (a select that fuses into the
+# attention's read of its operand; what the attention sees is bit for
+# bit what write-then-read gave it), the rows of all layers leave the
+# loop beside it (``deferred_rows``: the scan's ys, ``[layers, B, 1,
+# H, D]`` a leaf) and ``write_deferred`` puts each leaf's rows into the
+# stack ONCE, after the last layer: one scatter a leaf and step.
+
+_DEFER = threading.local()
+
+
+def defers(stacked: bool, rows: int) -> bool:
+    """Whether a call that appends ``rows`` rows (static) a sequence
+    writes them after the layer loop instead of inside it: one row,
+    into a ``stacked`` (carried) cache variable.  A call of more rows
+    (a prefill piece: one sequence, one in-place update and no loop; a
+    speculative verify) and a variable that is its layer's own (no
+    loop to defer past) write, then read.  One rule for the program
+    and for the host's count of its writes (``row_writes_a_step``)."""
+    return stacked and rows == 1
+
+
+@contextlib.contextmanager
+def deferred_rows():
+    """While TRACING one layer of a carried stack inside this scope,
+    the appends that :func:`defers` names leave their new rows in the
+    dict it yields — ``{module path: {"start": index, leaf name:
+    rows}}`` — instead of in the cache.  ``scan_stack.LayerScanBody.
+    carried`` opens it around a layer and returns the dict as the
+    layer scan's ys; :func:`write_deferred` takes the stacked dict."""
+    was = getattr(_DEFER, "rows", None)
+    _DEFER.rows = rows = {}
+    try:
+        yield rows
+    finally:
+        _DEFER.rows = was
+
+
+def write_deferred(stack, deferred) -> None:
+    """After the layer loop: write what its layers left in
+    :func:`deferred_rows` (stacked by the scan: rows ``[layers, B, 1,
+    ...]`` a leaf) into the cache variables under ``stack``, the module
+    the scan was lifted from — each leaf's rows of ALL layers in one
+    in-place update at ``[:, :, start]``.  ``start`` is the first
+    layer's index: the layers of a stack advance together."""
+    for path, rows in deferred.items():
+        below = path[len(stack.path):]
+        held = stack.variables["cache"]
+        for name in below:
+            held = held[name]
+        start = rows["start"][0]
+        written = {
+            leaf: jax.lax.dynamic_update_slice(
+                held[leaf], new, (0, 0, start) + (0,) * (new.ndim - 3))
+            for leaf, new in rows.items() if leaf != "start"}
+        for name in reversed(below):
+            written = {name: written}
+        for name, value in written.items():
+            stack.put_variable("cache", name, value)
 
 
 def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
@@ -457,6 +539,31 @@ def state_layers(cache) -> int:
                for path, _, _ in leaf_kinds(cache))
 
 
+def row_writes_a_step(cache) -> int:
+    """Row writes that ONE decode step issues into the position-keyed
+    leaves of one sequence's cache tree (planes, rings, int8 scales,
+    latent planes; an index or a ring's position table is no row): one
+    a leaf that is its layer's own, and for a stacked leaf (``[layers,
+    B, positions, ...]``, a carried stack) one a LAYER where the layers
+    write their own rows and ONE where the rows are written after the
+    loop — by :func:`defers`, the rule the program traces under.
+    Under a pool's vmap each is a scatter of a trip a lane."""
+    writes = 0
+    for path, leaf, kind in leaf_kinds(cache):
+        name = jax.tree_util.keystr(path[-1:])
+        if kind == "state" or not any(
+                row in name for row in
+                ("cached_key", "cached_value", LATENT_LEAF)):
+            continue
+        lead = leaf.shape[:-2 if LATENT_LEAF in name else -3]
+        stacked = len(lead) > 1         # [layers, B] ahead of the rows
+        if stacked and not (kind == "full" and defers(stacked, 1)):
+            writes += lead[0]           # every layer its own
+        else:
+            writes += 1
+    return writes
+
+
 def causal_pairs(start, length):
     """Query-key pairs of ``length`` queries at positions ``[start,
     start + length)``, each over the keys up to its own: ``length *
@@ -491,7 +598,10 @@ class PlaneReads:
     a slot, idle slots too); and ``rows_expanded``, the cached rows
     the former sent through the expansion (the static width read, a
     layer).  Engine stats ``latent_pairs_expanded_total`` /
-    ``latent_pairs_absorbed_total`` / ``latent_rows_expanded_total``."""
+    ``latent_pairs_absorbed_total`` / ``latent_rows_expanded_total``.
+
+    And of the rows the decode steps wrote (``row_writes``: steps x
+    :func:`row_writes_a_step`).  Engine stat ``kv_row_writes_total``."""
 
     def __init__(self):
         self.read = 0
@@ -505,6 +615,8 @@ class PlaneReads:
         self.pairs_expanded = 0
         self.pairs_absorbed = 0
         self.rows_expanded = 0
+        self.writes_a_step = 0
+        self.row_writes = 0
 
     def learn(self, cache) -> None:
         """The shape of one sequence's cache, from the first one seen
@@ -512,6 +624,7 @@ class PlaneReads:
         if self.planes is None:
             self.planes = full_planes(cache)
             self.state_layers = state_layers(cache)
+            self.writes_a_step = row_writes_a_step(cache)
             for (cap, _), n in full_planes(cache, "latent").items():
                 self.latent_planes += n
                 self.latent_cap = cap
@@ -538,6 +651,7 @@ class PlaneReads:
         """A decode window of ``steps`` steps over the slots that stand
         at ``positions`` (every lane of the pool, idle ones too)."""
         positions = np.asarray(positions, np.int64)
+        self.row_writes += steps * self.writes_a_step
         if self.state_layers:
             self.state_steps += steps * positions.size
         if self.latent_planes:
@@ -582,8 +696,9 @@ def _over_prefix(attend_rows, pos_q, cap: int, stacked: bool):
 def _append(mod, k, v, max_position, window, rotate, quantize, layer):
     """The append of :func:`append_kv_cache`.  Returns ``(read, cap,
     positions)``: ``read(n)`` is ``(keys, values, mask)`` over the
-    plane's first ``n`` rows (static; ``cap`` for the whole plane),
-    dequantised where stored int8 — what is read, not the plane."""
+    plane's first ``n`` rows (static; ``cap`` for the whole plane) as
+    they stand once this call's rows are in it, dequantised where
+    stored int8 — what is read, not the plane."""
     b, s, h, d = k.shape
     idx = mod.variable("cache", "cache_index",
                        lambda: jnp.array(0, jnp.int32))
@@ -591,13 +706,8 @@ def _append(mod, k, v, max_position, window, rotate, quantize, layer):
     pos_q = idx0 + jnp.arange(s)  # absolute positions of new rows
     if rotate is not None:
         k = rotate(pos_q, k)
-    if quantize:
-        store_dtype, out_dtype = jnp.int8, k.dtype
-        kq, k_scale = _quantize_chunk(k)
-        vq, v_scale = _quantize_chunk(v)
-    else:
-        store_dtype, out_dtype = k.dtype, k.dtype
-        kq, k_scale, vq, v_scale = k, None, v, None
+    out_dtype = k.dtype
+    store_dtype = jnp.int8 if quantize else out_dtype
     ck = mod.variable("cache", "cached_key", jnp.zeros,
                       (b, max_position, h, d), store_dtype)
     # An existing (possibly paged-view) cache keeps ITS width; only a
@@ -605,28 +715,65 @@ def _append(mod, k, v, max_position, window, rotate, quantize, layer):
     cap = ck.value.shape[-3]
     cv = mod.variable("cache", "cached_value", jnp.zeros,
                       (b, cap, h, d), store_dtype)
-    _put_rows(ck, layer, kq, idx0)
-    _put_rows(cv, layer, vq, idx0)
     if quantize:
         cks = mod.variable("cache", "cached_key_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
         cvs = mod.variable("cache", "cached_value_scale", jnp.zeros,
                            (b, cap, h, 1), jnp.bfloat16)
-        _put_rows(cks, layer, k_scale, idx0)
-        _put_rows(cvs, layer, v_scale, idx0)
+        new = dict(zip((ck, cks), _quantize_chunk(k)))
+        new.update(zip((cv, cvs), _quantize_chunk(v)))
+    else:
+        new = {ck: k, cv: v}
+    later = defers(layer is not None, s)
+    if later:
+        # Written once for all layers, after the loop (write_deferred).
+        _DEFER.rows[mod.path] = {
+            "start": idx0, **{var.name: rows for var, rows in new.items()}}
+    else:
+        for var, rows in new.items():
+            _put_rows(var, layer, rows, idx0)
     _store(idx, layer, idx0 + s)
 
+    def held(var, n: int):
+        """The first ``n`` rows of ``var``'s plane with this call's
+        rows in them: written above, or — deferred — the one new row
+        laid over its position as the in-place update will put it
+        (which clamps an index past the plane to its last row)."""
+        rows = _plane(var, layer, None if n == cap else n)
+        if not later:
+            return rows
+        # HOW the position is compared decides what the v5e compiler
+        # makes of the two reads (deviceless compile and chip, PERF.md
+        # section 6, PR 36; tests/test_chip_compile.py holds both).  The
+        # KEY's select compares an iota of the rows' own shape, made
+        # inside the fusion that reads them: the score fusion keeps
+        # the tiles it had without the select.  Over a predicate of
+        # ``[n]`` — a ``[slots, n]`` operand, positions in the lanes —
+        # the 512-row branch was tiled ``[2, 171]`` and ran 232 us a
+        # layer where 70 were.  The VALUE's select compares that
+        # ``[n]`` predicate: with an iota of the rows' shape there,
+        # or with ONE predicate under both selects, the compiler lays
+        # the stack out layers-major for the layer loop and copies
+        # the whole leaf, 2.4 GB of gpt2-medium's pool, in every step.
+        at = jnp.minimum(idx0, cap - 1)
+        if var.name.startswith("cached_key"):
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, rows.shape, rows.ndim - 3) == at
+        else:
+            here = (jnp.arange(n) == at)[None, :, None, None]
+        return jnp.where(here, new[var], rows)
+
     def read(n: int):
-        rows = None if n == cap else n
-        k_read, v_read = _plane(ck, layer, rows), _plane(cv, layer, rows)
+        k_read, v_read = held(ck, n), held(cv, n)
         if quantize:
             # Unwritten positions hold scale 0 -> dequantize to 0,
             # exactly like the unquantized zero-init cache (masked off
-            # anyway).
+            # anyway).  A deferred row is laid over the int8 plane and
+            # its scale, so what is attended is what will be stored.
             k_read = k_read.astype(out_dtype) \
-                * _plane(cks, layer, rows).astype(out_dtype)
+                * held(cks, n).astype(out_dtype)
             v_read = v_read.astype(out_dtype) \
-                * _plane(cvs, layer, rows).astype(out_dtype)
+                * held(cvs, n).astype(out_dtype)
         keys = jnp.arange(n)
         valid = keys[None, :] <= pos_q[:, None]  # [S, n]
         if window is not None:
@@ -657,8 +804,10 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
     ``cache_index`` to a smaller value leaves stale K/V entries past
     it, but they are masked BY ABSOLUTE POSITION, never trusted —
     entry slot ``j`` is admissible only to queries at positions
-    ``>= j``, appends always write ``[idx, idx + S)`` BEFORE the
-    chunk's queries read, and post-rollback appends are contiguous
+    ``>= j``, appends always put ``[idx, idx + S)`` in front of the
+    chunk's queries (written BEFORE they read, or — one row on a
+    carried stack — laid over the plane they read and written when the
+    layer loop ends), and post-rollback appends are contiguous
     from the rewound index, so every stale slot a query could admit
     has already been overwritten by the fresh chunk that contains
     that query.  Holds for any mix of chunk widths after the rewind
@@ -690,17 +839,28 @@ def append_kv_cache(mod, k, v, max_position: int, window=None,
     IN PLACE contract (``layer``): under ``scan_stack`` a decode
     apply over an existing cache hands every layer the WHOLE stacked
     cache — each variable ``[num_layers, ...]``, the layer scan's
-    carry — and ``layer``, its index.  The append then
-    writes only the S new rows (and scales) of each sequence at
-    ``[layer, :, idx:idx+S]`` and bumps ``cache_index[layer]``: an
-    update XLA performs in place on a loop carry, and in place on the
-    caller's buffer when the program donates it (serving/slots.py
-    does).  The layer's keys and values are read once, as the
-    attention's operand.  Nothing else of the stack is read or
-    written; no layer is sliced out and written back.  ``layer=None``
+    carry — and ``layer``, its index.  An append of S > 1 rows (a
+    prefill piece, a speculative verify) then writes only those rows
+    (and scales) of each sequence at ``[layer, :, idx:idx+S]`` and
+    bumps ``cache_index[layer]``: an update XLA performs in place on a
+    loop carry, and in place on the caller's buffer when the program
+    donates it (serving/slots.py does).  An append of ONE row (a
+    decode step) bumps the index and writes no row here
+    (:func:`defers`): the keys and values it returns are the plane's
+    with the new row — rotated, quantised: as it will be stored — laid
+    over position ``idx`` by a select that fuses into the attention's
+    read, bit for bit what write-then-read returned, and the row is
+    written after the layer loop with every other layer's, once a
+    leaf (:func:`write_deferred`): under a slot pool's vmap a write at
+    a lane's own index is a loop of one dependent write a lane, and
+    one a layer and leaf was 3 of gpt2-medium's 7 ms a step (PERF.md
+    section 6, PR 36).  Either way the layer's keys and values are
+    read once, as the attention's operand, nothing else of the stack
+    is read or written, no layer is sliced out and written back, and
+    the cache holds the rows when the apply returns.  ``layer=None``
     (an unrolled stack, T5's own scan, and the apply that CREATES the
     variables) is the same arithmetic on a variable that is the
-    layer's own.
+    layer's own: written, then read.
 
     WHAT IS READ: this function returns the layer's WHOLE plane
     (``max_position`` keys, most of them masked) for the caller to

@@ -20,6 +20,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import kv_cache
+
 
 def remat_policy(name: Optional[str]):
     return getattr(jax.checkpoint_policies, name) if name else None
@@ -36,10 +38,16 @@ class LayerScanBody(nn.Module):
 
     def carried(self, carry, layer):
         """One DECODE layer over the whole stacked cache: the block is
-        told which layer it is and writes its new rows into, and reads
-        its keys and values out of, the ``[num_layers, ...]`` cache
-        variables (kv_cache.append_kv_cache ``layer=``)."""
-        return self(carry, True, layer)
+        told which layer it is and reads its keys and values out of
+        the ``[num_layers, ...]`` cache variables (kv_cache.
+        append_kv_cache ``layer=``).  A call of several rows writes
+        them into the stack here; a call of ONE row a sequence (a
+        decode step) hands it back instead — ``(carry, rows)``, the
+        scan's ys — for ``run`` to write after the loop
+        (kv_cache.defers)."""
+        with kv_cache.deferred_rows() as rows:
+            carry, _ = self(carry, True, layer)
+        return carry, rows
 
     def run(self, carry, decode=None):
         """``(carry, decode?) -> (carry, None)`` through every layer.
@@ -47,18 +55,37 @@ class LayerScanBody(nn.Module):
         Decoding over an EXISTING cache carries the stacked cache
         through the layer loop: no layer's keys and values are sliced
         out of the stack and none is written back whole — a layer
-        writes the rows it appends and reads its own plane as an
-        operand of the attention.  With the stack as the scan's xs/ys
-        (the lifted ``__call__``) every decode step read and wrote the
-        entire cache once more, which was most of a serving step's
-        device time (PERF.md section 6, PR 28).  Training takes the
-        lifted ``__call__`` as before, and so does the first decode
-        apply that CREATES the cache variables (``generate.
-        init_cache``'s shape probe): a scan cannot carry variables
-        that do not exist yet."""
+        reads its own plane as an operand of the attention.  With the
+        stack as the scan's xs/ys (the lifted ``__call__``) every
+        decode step read and wrote the entire cache once more, which
+        was most of a serving step's device time (PERF.md section 6,
+        PR 28).
+
+        WHAT IS WRITTEN, WHERE AND WHEN.  A call of several rows a
+        sequence (a prefill piece, a speculative verify) writes them
+        inside the loop, each layer its own at ``[layer, :, index:
+        index+S]``: one in-place update a layer and leaf.  A call of
+        ONE row (a decode step) writes nothing inside the loop: each
+        layer attends over its plane with the new row laid over its
+        position, the rows leave the loop as its ys, and they are
+        written HERE, once a leaf for all layers, at ``[:, :, index]``
+        (kv_cache.write_deferred).  Under a slot pool's vmap the index
+        differs by lane, a write at it is a scatter, and the TPU runs a
+        scatter as one dependent write a lane: inside the loop that
+        was layers x leaves x slots writes a step — 3 of
+        gpt2-medium's 7 ms — after it leaves x slots (PERF.md
+        section 6, PR 36).  The cache holds the rows when the apply
+        returns, as it always did.
+
+        Training takes the lifted ``__call__`` as before, and so does
+        the first decode apply that CREATES the cache variables
+        (``generate.init_cache``'s shape probe): a scan cannot carry
+        variables that do not exist yet."""
         if decode and self.variables.get("cache"):
-            return self.carried(carry,
-                                jnp.arange(self.cfg.num_layers))
+            carry, rows = self.carried(carry,
+                                       jnp.arange(self.cfg.num_layers))
+            kv_cache.write_deferred(self, rows)
+            return carry, None
         return self(carry, decode or None)
 
 

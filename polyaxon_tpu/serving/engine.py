@@ -2909,6 +2909,9 @@ class DecodeEngine:
             # planes hold (kv_cache.PlaneReads).
             "kv_plane_rows_read_total": self.slots.plane_reads.read,
             "kv_plane_rows_held_total": self.slots.plane_reads.held,
+            # Row writes the decode steps issued into the pool's
+            # position-keyed leaves (kv_cache.row_writes_a_step).
+            "kv_row_writes_total": self.slots.plane_reads.row_writes,
             **self._moe_stats(),
             **self._ssm_stats(),
             **self._latent_stats(),

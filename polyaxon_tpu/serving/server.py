@@ -3196,6 +3196,7 @@ class ModelServer:
                     "prefill_chunks_total", "prefill_tokens_total",
                     "kv_plane_rows_read_total",
                     "kv_plane_rows_held_total",
+                    "kv_row_writes_total",
                     "completed_total",
                     "completed_greedy_total",
                     "completed_sampled_total",
@@ -3514,6 +3515,9 @@ class ModelServer:
                 "# TYPE ptpu_serving_kv_plane_rows_held_total counter",
                 f"ptpu_serving_kv_plane_rows_held_total "
                 f"{es['kv_plane_rows_held_total']}",
+                "# TYPE ptpu_serving_kv_row_writes_total counter",
+                f"ptpu_serving_kv_row_writes_total "
+                f"{es['kv_row_writes_total']}",
                 "# TYPE ptpu_serving_kv_pool_bytes_by_kind gauge",
                 *(f'ptpu_serving_kv_pool_bytes_by_kind{{kind="{k}"}} '
                   f"{v}" for k, v in

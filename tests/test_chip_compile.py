@@ -142,12 +142,12 @@ def test_gpt2_medium_train_step_compiles(topo, on_tpu, n_chips):
         < 15.75 * 2 ** 30
 
 
-def _serving_shapes(one_chip, served=True):
+def _serving_shapes(one_chip, served=True, slots=SLOTS):
     """gpt2-medium as ``ptpu serve`` holds it: the variables of
     ``spec.init_params(batch_size=1)`` at rest as the serving model
     declares them (serving/weights.py; ``served=False``: as they are
-    drawn, float32) and the default pool's stacked cache, as shapes on
-    the described chip."""
+    drawn, float32) and the stacked cache of a pool of ``slots`` (the
+    default's), as shapes on the described chip."""
     from polyaxon_tpu.models import generate as G
     from polyaxon_tpu.models.registry import get_model
     from polyaxon_tpu.serving.weights import (declared_tree,
@@ -161,7 +161,7 @@ def _serving_shapes(one_chip, served=True):
         model, jax.ShapeDtypeStruct((1, 1024), jnp.int32)), one_chip)
     one = jax.eval_shape(lambda: G.init_cache(model, 1))
     pool = jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct((SLOTS,) + l.shape, l.dtype,
+        lambda l: jax.ShapeDtypeStruct((slots,) + l.shape, l.dtype,
                                        sharding=one_chip), one)
     return model, variables, pool
 
@@ -194,6 +194,32 @@ def test_engine_decode_window_compiles(one_chip, on_tpu, sampled):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
 
 
+def _manager_window(one_chip, monkeypatch, slots, sampled):
+    """``(compiled, pool)``: the decode window as a slot manager of
+    ``slots`` builds it over gpt2-medium — the pool donated, pinned
+    row-major in and out — compiled for the described chip."""
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    model, variables, pool = _serving_shapes(one_chip, slots=slots)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: list(one_chip.device_set))
+    mgr = SlotKVManager(model, variables, slots)
+    mgr._cache_sh = mgr._pool_formats(pool)
+    fn = mgr._build_step(DECODE_WINDOW, sampled)
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((slots,) + tail, dtype,
+                                    sharding=one_chip)
+
+    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+                vec(jnp.int32)]
+    if sampled:
+        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
+                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    return fn.func.lower(*fn.args, pool, *operands).compile(), pool
+
+
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
 def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
@@ -206,26 +232,7 @@ def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
     converts all of it on entry and again on exit)."""
     import re
 
-    from polyaxon_tpu.serving.slots import SlotKVManager
-
-    model, variables, pool = _serving_shapes(one_chip)
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: list(one_chip.device_set))
-    mgr = SlotKVManager(model, variables, SLOTS)
-    mgr._cache_sh = mgr._pool_formats(pool)
-    fn = mgr._build_step(DECODE_WINDOW, sampled)
-
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32)]
-    if sampled:
-        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
-                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
-    compiled = fn.func.lower(*fn.args, pool, *operands).compile()
+    compiled, pool = _manager_window(one_chip, monkeypatch, SLOTS, sampled)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = sum(l.size * l.dtype.itemsize
@@ -240,8 +247,41 @@ def test_decode_window_updates_the_pool_in_place(one_chip, on_tpu,
     assert len(params) == 2 and all(row_major in l for l in params)
     assert not re.search(r"= %s\S* copy\(" % re.escape(kv), text)
     # one padded pool in the arguments, no second one among the
-    # temporaries
+    # temporaries (with ONE predicate under the key's and the value's
+    # overlay the compiler copied the whole value stack before the
+    # layer loop in every step: kv_cache._append)
     assert mem.temp_size_in_bytes < pool_bytes
+    # a step's rows are written after the layer loop, none inside it
+    # (kv_cache.defers): the loop that holds the attention's
+    # conditional makes nothing of a pool leaf's shape but what
+    # carries it
+    made = re.findall(r"= %s\S* ([a-z\-]+)\(" % re.escape(kv),
+                      _layer_loop(text))
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
+
+
+def test_decode_window_scores_in_whole_tiles(one_chip, on_tpu,
+                                             monkeypatch):
+    """`gpt2m-serve-closed`'s pool (24 slots): each fusion that scores
+    a step's queries against the first ``n`` rows of the key plane
+    walks those rows in tiles that divide ``n``, for every width short
+    of the whole plane.  Laid over the rows under a ``[slots, n]``
+    predicate the new row cost the 512-row branch a ``[2, 171]`` tile
+    and 232 us a layer where ``[3, 128]`` took 70 (kv_cache._append;
+    PERF.md section 6, PR 36)."""
+    import re
+
+    from polyaxon_tpu.models import kv_cache
+
+    compiled, pool = _manager_window(one_chip, monkeypatch, 24, True)
+    cap = jax.tree.leaves(pool)[-1].shape[-3]
+    tiles = {int(n): int(tile) for n, tile in re.findall(
+        r"= f32\[24,(\d+),16\]\S* fusion\(.*?bqhd,bkhd->bhqk/dot_general"
+        r".*?output_window_bounds\":\[\"\d+\",\"(\d+)\"",
+        compiled.as_text())}
+    narrow = kv_cache.prefix_widths(cap)[:-1]
+    assert set(narrow) <= set(tiles), tiles
+    assert all(n % tiles[n] == 0 for n in narrow), tiles
 
 
 def _computations(text):
@@ -250,6 +290,42 @@ def _computations(text):
 
     return {m.group(1): m.group(0) for m in re.finditer(
         r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}", text, re.M | re.S)}
+
+
+def _reach(comps, name, seen=None):
+    """``name`` and every computation it calls."""
+    import re
+
+    seen = set() if seen is None else seen
+    if name in seen or name not in comps:
+        return seen
+    seen.add(name)
+    for callee in re.findall(r"(?:calls|to_apply|body|condition|"
+                             r"true_computation|false_computation)="
+                             r"%?([\w.\-]+)", comps[name]):
+        _reach(comps, callee, seen)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                            comps[name]):
+        for callee in group.split(","):
+            _reach(comps, callee.strip().lstrip("%"), seen)
+    return seen
+
+
+def _layer_loop(text):
+    """The text of the ``while`` that walks the layers in a compiled
+    decode window — its body and everything the body calls.  Of the
+    loops that hold the attention's conditional over the prefix
+    widths it is the innermost."""
+    import re
+
+    comps = _computations(text)
+    holds = {b: _reach(comps, b) for b in re.findall(
+        r" while\(.*?body=%?([\w.\-]+)", text)}
+    holds = {b: names for b, names in holds.items()
+             if any("conditional(" in comps[c] for c in names)}
+    layer, = [b for b, names in holds.items()
+              if not any(o != b and o in names for o in holds)]
+    return "\n".join(comps[c] for c in sorted(holds[layer]))
 
 
 def _whole_plane_scores(text, dims):
@@ -267,17 +343,7 @@ def _whole_plane_scores(text, dims):
     assert branches
     wide = re.compile(r"= f32\[(?:\d+,)*%s(?:,\d+)*\]\S* " % dims)
 
-    def reach(name, seen):
-        """``name`` and every computation it calls."""
-        if name in seen or name not in comps:
-            return seen
-        seen.add(name)
-        for callee in re.findall(r"(?:calls|to_apply|body|condition)="
-                                 r"%?([\w.\-]+)", comps[name]):
-            reach(callee, seen)
-        return seen
-
-    inside = {b: reach(b, set()) for b in branches}
+    inside = {b: _reach(comps, b) for b in branches}
     owned = set().union(*inside.values())
     outside = [n for n, body in comps.items()
                if n not in owned and wide.search(body)]

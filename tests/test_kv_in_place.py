@@ -10,7 +10,10 @@ that fails AFTER it consumed the pool goes to recovery, one that fails
 before it is still retried in place; (e) a step reads the planes only
 as far as the pool's furthest position — the same tokens and logits as
 a step that reads them whole, and the rows the host counts are the rows
-the program took.
+the program took; (f) a decode step writes its rows ONCE, after the
+layer loop — the same tokens and logits, bit for bit, as the step that
+writes them inside it, and the writes the host counts are the writes
+the program makes.
 """
 
 import collections
@@ -34,6 +37,8 @@ from polyaxon_tpu.serving import (DecodeEngine, FaultPlan, RetryPolicy)
 from polyaxon_tpu.serving.paged import PagedSlotKVManager
 from polyaxon_tpu.serving.scheduler import SamplingSpec, SchedulerPolicy
 from polyaxon_tpu.serving.slots import SlotKVManager
+
+from test_chip_compile import _layer_loop
 
 SLOTS = 4
 # One prompt a slot, every slot at another position.
@@ -198,24 +203,40 @@ def test_decode_window_aliases_the_pool_and_moves_only_rows(
     # (a fusion of that shape is a row write's wrapper)
     kv = {l.shape for l in jax.tree.leaves(pool) if l.ndim >= 5}
     assert kv
+    made, movers = _made(text, kv)
+    assert not movers, movers
+    writes = made["scatter"] + made["dynamic-update-slice"]
+    n_kv = sum(l.ndim >= 5 for l in jax.tree.leaves(pool))
+    assert writes == n_kv and made["fusion"] <= writes, made
+    # exactly one write a K/V leaf, and none of them inside the loop
+    # that walks the layers: a decode step's rows are written after it
+    # (kv_cache.defers)
+    inside, _ = _made(_layer_loop(text), kv)
+    assert not (inside["scatter"] + inside["dynamic-update-slice"]
+                + inside["fusion"]), inside
+
+
+_MADE = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                   r"([a-z\-]+)\(")
+
+
+def _made(text, shapes):
+    """``(Counter of the opcodes that make a result of one of
+    ``shapes``, the lines of those that are no carrier and no row
+    write)`` over an HLO text."""
     made, movers = collections.Counter(), []
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
-                     r"([a-z\-]+)\(", line)
+        m = _MADE.match(line)
         if not m or not m.group(2):
             continue
-        shape = tuple(int(d) for d in m.group(2).split(","))
-        if shape not in kv:
+        if tuple(int(d) for d in m.group(2).split(",")) not in shapes:
             continue
         made[m.group(3)] += 1
         if m.group(3) not in (
                 "parameter", "get-tuple-element", "bitcast", "while",
                 "dynamic-update-slice", "scatter", "fusion"):
             movers.append(line.strip()[:160])
-    assert not movers, movers
-    writes = made["scatter"] + made["dynamic-update-slice"]
-    n_kv = sum(l.ndim >= 5 for l in jax.tree.leaves(pool))
-    assert writes == n_kv and made["fusion"] <= writes, made
+    return made, movers
 
 
 # -- (c) equal to a forward without any cache -------------------------------
@@ -587,6 +608,180 @@ def test_engine_counts_plane_rows_of_steps_and_chunks(small_model):
         st["prefill_chunks_total"] + 4 * st["decode_steps_total"])
 
 
+# -- (f) one write a step, after the layer loop -----------------------------
+
+
+@contextlib.contextmanager
+def _writes_in_the_loop():
+    """A program traced in here writes every new row inside the layer
+    loop and then reads the plane, as every call of several rows does:
+    the one rule (``kv_cache.defers``) says no."""
+    real = kv_cache.defers
+    kv_cache.defers = lambda stacked, rows: False
+    try:
+        yield
+    finally:
+        kv_cache.defers = real
+
+
+def _rotating_model():
+    """``llama-tiny`` cut to this file's sizes: keys are rotated at
+    their positions inside the append (``rotate``), two query heads
+    share a KV head."""
+    from polyaxon_tpu.models.llama import LlamaConfig, LlamaModel
+
+    cfg = dataclasses.replace(
+        LlamaConfig.tiny(), vocab_size=32, hidden_size=32,
+        intermediate_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
+        max_position=64, dtype=jnp.float32)
+    model = LlamaModel(cfg=cfg)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 4), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def write_twins():
+    """For a storage: the model, a manager whose programs write a
+    step's rows after the layer loop (the overlay: the attention is
+    handed the plane with the new row laid over its position) and one
+    whose programs write them inside it, then read."""
+    memo = {}
+
+    def get(storage):
+        if storage not in memo:
+            model, variables = _rotating_model() \
+                if storage == "rotate" else _model(int8=storage == "int8")
+            memo[storage] = model, variables, (
+                SlotKVManager(model, variables, SLOTS),
+                SlotKVManager(model, variables, SLOTS))
+        return memo[storage]
+    return get
+
+
+@pytest.mark.parametrize("window", [1, 8])
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("storage", ["plain", "int8", "rotate"])
+def test_rows_written_after_the_loop_read_as_rows_written_in_it(
+        write_twins, storage, sampled, window):
+    """The overlay changes nothing the attention sees: four streams at
+    four positions decode the same tokens AND the same logits, bit for
+    bit, whether a step's rows are written once after the layer loop
+    or inside it — and those tokens are ``generate``'s, solo, over the
+    whole plane."""
+    model, variables, pair = write_twins(storage)
+    want = []
+    for row, prompt in enumerate(PROMPTS):
+        ids = np.asarray([prompt], np.int32)
+        out = G.generate_positional(
+            model, variables, ids, max_new_tokens=13,
+            keys=jnp.asarray(_key(row))[None], **SAMP) if sampled \
+            else G.generate(model, variables, ids, max_new_tokens=13)
+        want.append(np.asarray(out)[0, len(prompt):].tolist())
+    windows = [1] * 4 if window == 1 else [8, 4]
+    got = []
+    for in_loop, mgr in enumerate(pair):
+        mgr.reset()
+        with _writes_in_the_loop() if in_loop \
+                else contextlib.nullcontext():
+            _fill(mgr, model, variables, sampled=sampled,
+                  first=[w[0] for w in want])
+            toks, logits = [], []
+            for w in windows:
+                toks.append(mgr.step(w, sampled, 8))
+                logits.append(np.asarray(mgr.last_logits))
+        got.append((np.concatenate(toks), logits))
+    (toks, logits), (toks_in, logits_in) = got
+    assert toks.tolist() == toks_in.tolist()
+    for after, inside in zip(logits, logits_in):
+        assert np.array_equal(after, inside)
+    assert toks.T.tolist() == [w[1:1 + sum(windows)] for w in want]
+
+
+def _tiny(name):
+    """A registry preset in float32, its variables, and one sequence's
+    prefilled cache."""
+    from polyaxon_tpu.models.registry import get_model
+
+    spec = get_model(name)
+    model = spec.make_model(dtype=jnp.float32)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    return model, variables
+
+
+def _row_writes(jaxpr, shapes) -> int:
+    """Equations of a traced program, its loops' and conditionals'
+    bodies included, that write rows into an array of one of
+    ``shapes``: a ``scatter`` (an update at a lane's own position under
+    the pool's vmap) or a ``dynamic_update_slice``."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scatter", "dynamic_update_slice") \
+                and eqn.outvars[0].aval.shape in shapes:
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _row_writes(sub, shapes)
+    return n
+
+
+# layers x leaves where every layer writes its own rows: afmoe-tiny's
+# five layers keep a ring or a plane, K and V each; jamba-tiny's one
+# attention layer (of four) keeps a plane; a carried stack writes its
+# two stacked leaves once each.
+ROW_WRITES = {"gpt2-tiny": 2, "llama-tiny": 2, "afmoe-tiny": 10,
+              "jamba-tiny": 2}
+
+
+@pytest.mark.parametrize("name", sorted(ROW_WRITES))
+def test_the_row_writes_counted_are_the_writes_the_program_makes(name):
+    """``kv_row_writes_total`` against the compiled window: a step's
+    count (``kv_cache.row_writes_a_step``, from one sequence's cache
+    and the rule the program traces under) is the number of
+    equations of the traced window that write rows into a pool leaf —
+    one a K/V leaf of a carried stack (the compiled program:
+    ``test_decode_window_aliases_the_pool_and_moves_only_rows``), one a
+    layer and leaf of an unrolled one — and the manager's count grows
+    by it every step."""
+    model, variables = _tiny(name)
+    mgr = SlotKVManager(model, variables, 2)
+    prompt = np.asarray([PROMPTS[1]], np.int32)
+    _, cache = G.prefill(model, variables, prompt)
+    mgr.insert(mgr.acquire(), cache, 1, prompt.shape[1])
+    assert mgr.plane_reads.writes_a_step == ROW_WRITES[name] \
+        == kv_cache.row_writes_a_step(cache)
+    mgr.step(2, False, 8)
+    mgr.step(3, False, 8)
+    assert mgr.plane_reads.row_writes == 5 * ROW_WRITES[name]
+    fn = mgr._step_fns[(8, False)]
+    operands = [jnp.asarray(8, jnp.int32)] + [
+        jnp.asarray(x) for x in mgr.state.operands("plain")]
+    pool = mgr.kv_pool()
+    rows = {leaf.shape for path, leaf, kind in kv_cache.leaf_kinds(pool)
+            if kind != "state" and leaf.ndim >= 4}
+    traced = jax.make_jaxpr(fn.func)(*fn.args, pool, *operands)
+    assert _row_writes(traced.jaxpr, rows) == ROW_WRITES[name]
+    with _writes_in_the_loop():     # what the rule decides, it counts
+        assert kv_cache.row_writes_a_step(cache) == (
+            4 if name in ("gpt2-tiny", "llama-tiny")  # 2 layers x 2
+            else ROW_WRITES[name])
+
+
+def test_engine_reports_row_writes(small_model):
+    model, variables = small_model
+    eng = _engine(model, variables)
+    try:
+        groups = [eng.submit(p, new, None, None, sampling=s)
+                  for p, new, s in _requests()]
+        for g in groups:
+            assert g.event.wait(timeout=120), "hung caller"
+        st = eng.stats()
+    finally:
+        eng.close()
+    # K and V of the carried stack, once a step
+    assert st["kv_row_writes_total"] == 2 * st["decode_steps_total"] > 0
+
+
 def _reader(name):
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "perfbench", "layer_metrics",
@@ -614,3 +809,19 @@ def test_kv_plane_read_pct_reader(info_open, info_close, want):
     ctx = types.SimpleNamespace(collected={"info_open": info_open,
                                            "info_close": info_close})
     assert _reader("kv_plane_read_pct")(ctx) == want
+
+
+@pytest.mark.parametrize("info_open,info_close,want", [
+    ({"kv_row_writes_total": 96, "decode_steps_total": 48},
+     {"kv_row_writes_total": 1096, "decode_steps_total": 548}, 2.0),
+    ({"kv_row_writes_total": 0, "decode_steps_total": 0},
+     {"kv_row_writes_total": 480, "decode_steps_total": 10}, 48.0),
+    # nothing stepped in the window; a program without the counter
+    ({"kv_row_writes_total": 6, "decode_steps_total": 3},
+     {"kv_row_writes_total": 6, "decode_steps_total": 3}, None),
+    ({"decode_steps_total": 1}, {"decode_steps_total": 90}, None),
+], ids=["after-the-loop", "a-layer-and-leaf", "idle", "parent"])
+def test_kv_row_writes_per_step_reader(info_open, info_close, want):
+    ctx = types.SimpleNamespace(collected={"info_open": info_open,
+                                           "info_close": info_close})
+    assert _reader("kv_row_writes_per_step")(ctx) == want
