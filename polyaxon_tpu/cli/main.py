@@ -747,7 +747,7 @@ def serve(model_name, host, port, checkpoint, int8_weights, int8_kv,
         jax.config.update("jax_platforms", "cpu")
     from polyaxon_tpu.config import enable_compilation_cache
 
-    enable_compilation_cache()
+    enable_compilation_cache(names_in_key=bool(profile_dir))
     from polyaxon_tpu.serving import (ModelServer,
                                       PrefixFetchPolicy,
                                       make_server)

@@ -48,7 +48,7 @@ def home_dir() -> str:
     return default_home()
 
 
-def enable_compilation_cache() -> str:
+def enable_compilation_cache(names_in_key: bool = False) -> str:
     """Turn on JAX's persistent compilation cache; return its directory.
 
     Every entry point that compiles (``train``, ``ptpu serve``, ``ptpu
@@ -62,11 +62,28 @@ def enable_compilation_cache() -> str:
     too).  Otherwise it is ``<checkout>/.jax_cache`` — never a path
     built from a temp name, a pid, the time or ``POLYAXON_TPU_HOME``:
     a directory that moves never hits.
+
+    ``names_in_key``: for a process that will take a device trace
+    (``train --profile-at``, ``ptpu serve --profile-dir``).  The cache's
+    key leaves a program's metadata out, so an executable read from it
+    carries the NAMES of whichever tree compiled it — name stacks,
+    files, lines — and a device trace shows those: the scopes of
+    ``spans.py`` as they stood then, or none (v5e, PERF.md section 6,
+    PR 37: a server's prefill programs read from a cache that the
+    parent commit had filled showed no scope while the decode program,
+    compiled in the process, showed them all).  A traced process
+    therefore keys the cache by the metadata too: it compiles what no
+    process of THIS tree has compiled for a trace before, and reads
+    back what one has.  Untraced processes keep the key they had.
     """
+    import jax
+
+    if names_in_key:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if cache_dir:
         return cache_dir
-    import jax
 
     cache_dir = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

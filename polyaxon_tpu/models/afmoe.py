@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from ..ops.rotary import apply_rotary
 from ..parallel.moe import (held_experts_ffn, held_pair_counts,
                             sigmoid_topk_route)
+from ..spans import scope
 from .generate import STATS
 from .kv_cache import append_ring_kv_cache, attend_kv_cache
 
@@ -162,16 +163,18 @@ def grouped_attention(q, k, v, allowed):
     """``q`` [B, S, Hq, D] over ``k``/``v`` [B, T, Hkv, D], the query
     heads of one KV head computed as a group against the KV head as it
     lies: nothing of K or V is repeated.  ``allowed`` broadcasts to
-    [B, 1, 1, S, T].  Scores and softmax in float32."""
+    [B, 1, 1, S, T].  Scores and softmax in float32.  Traced under
+    the scope ``ptpu_attend`` (spans.py)."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
-    q = q.reshape(b, s, hkv, hq // hkv, d)
-    scores = jnp.einsum("bsngd,btnd->bngst", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = jnp.where(allowed, scores / math.sqrt(d), -1e30)
-    p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bngst,btnd->bsngd", p, v)
-    return out.reshape(b, s, hq * d)
+    with scope("ptpu_attend"):
+        q = q.reshape(b, s, hkv, hq // hkv, d)
+        scores = jnp.einsum("bsngd,btnd->bngst", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.where(allowed, scores / math.sqrt(d), -1e30)
+        p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bngst,btnd->bsngd", p, v)
+        return out.reshape(b, s, hq * d)
 
 
 class AfmoeAttention(nn.Module):

@@ -85,6 +85,7 @@ from ..ops.attention import note_latent_route
 from ..ops.rotary import apply_rotary, yarn_inv_freq, yarn_mscale
 from ..parallel.moe import (held_experts_ffn, held_pair_counts,
                             softmax_topk_route)
+from ..spans import scope
 from .afmoe import SwiGLU
 from .generate import STATS
 from .kv_cache import attend_latent_cache
@@ -202,33 +203,40 @@ def expanded_attention(q_nope, q_pe, rows, w_kvb, allowed, cfg):
     ``rows`` [B, T, rank + rope], every row read EXPANDED through
     ``w_kvb`` [rank, H, nope + v] to its key and value a head.
     ``allowed`` broadcasts to [B, H, S, T].  Scores and softmax in
-    float32.  Returns [B, S, H * v]."""
+    float32.  Returns [B, S, H * v].  Traced under the scopes
+    ``ptpu_latent_expand`` (the expansion) and ``ptpu_attend`` (the
+    rest; spans.py)."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    kv = jnp.einsum("btr,rhd->bthd", rows[..., :r], w_kvb)
-    scores = jnp.einsum("bshd,bthd->bhst", q_nope, kv[..., :dn],
-                        preferred_element_type=F32) \
-        + jnp.einsum("bshd,btd->bhst", q_pe, rows[..., r:],
-                     preferred_element_type=F32)
-    p = _softmax(scores, allowed, cfg.softmax_scale, rows.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", p, kv[..., dn:])
-    return out.reshape(out.shape[:2] + (-1,))
+    with scope("ptpu_latent_expand"):
+        kv = jnp.einsum("btr,rhd->bthd", rows[..., :r], w_kvb)
+    with scope("ptpu_attend"):
+        scores = jnp.einsum("bshd,bthd->bhst", q_nope, kv[..., :dn],
+                            preferred_element_type=F32) \
+            + jnp.einsum("bshd,btd->bhst", q_pe, rows[..., r:],
+                         preferred_element_type=F32)
+        p = _softmax(scores, allowed, cfg.softmax_scale, rows.dtype)
+        out = jnp.einsum("bhst,bthd->bshd", p, kv[..., dn:])
+        return out.reshape(out.shape[:2] + (-1,))
 
 
 def absorbed_attention(q_nope, q_pe, rows, w_kvb, allowed, cfg):
     """The same attention with nothing of ``rows`` expanded: the key
     half of ``w_kvb`` folded into the query, the scores and the
     weighted sum over the rows as they lie (every head reads the same
-    ones), the value half applied to the one sum a head."""
+    ones), the value half applied to the one sum a head.  Traced
+    under the scope ``ptpu_attend`` (spans.py), the two folded
+    projections with it."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q = jnp.concatenate([
-        jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_pe],
-        axis=-1)                                # [B, S, H, rank + rope]
-    scores = jnp.einsum("bshw,btw->bhst", q, rows,
-                        preferred_element_type=F32)
-    p = _softmax(scores, allowed, cfg.softmax_scale, rows.dtype)
-    mixed = jnp.einsum("bhst,btr->bshr", p, rows[..., :r])
-    out = jnp.einsum("bshr,rhd->bshd", mixed, w_kvb[..., dn:])
-    return out.reshape(out.shape[:2] + (-1,))
+    with scope("ptpu_attend"):
+        q = jnp.concatenate([
+            jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_pe],
+            axis=-1)                            # [B, S, H, rank + rope]
+        scores = jnp.einsum("bshw,btw->bhst", q, rows,
+                            preferred_element_type=F32)
+        p = _softmax(scores, allowed, cfg.softmax_scale, rows.dtype)
+        mixed = jnp.einsum("bhst,btr->bshr", p, rows[..., :r])
+        out = jnp.einsum("bshr,rhd->bshd", mixed, w_kvb[..., dn:])
+        return out.reshape(out.shape[:2] + (-1,))
 
 
 def takes_absorbed(positions: int) -> bool:

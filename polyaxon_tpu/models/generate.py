@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.quant import dequantize_params
+from ..spans import scope
 from .kv_cache import leaf_kinds, read_extent
 
 
@@ -339,13 +340,15 @@ def _sample_positional_row(logits, base_key, index, temperature,
     ``temperature <= 0`` rows take argmax over the raw logits — the
     greedy lane, identical to the greedy decode programs.  Shaping
     runs in f32 (:func:`_shape_logits_positional`) so bf16 models
-    sample from the same grid the f32 solo reference uses."""
-    key = jax.random.fold_in(base_key, index)
-    l, greedy = _shape_logits_positional(logits, temperature, top_k,
-                                         top_p)
-    sampled = jax.random.categorical(key, l)
-    return jnp.where(greedy, jnp.argmax(logits, axis=-1),
-                     sampled).astype(jnp.int32)
+    sample from the same grid the f32 solo reference uses.  Traced
+    whole under the scope ``ptpu_sample`` (spans.py)."""
+    with scope("ptpu_sample"):
+        key = jax.random.fold_in(base_key, index)
+        l, greedy = _shape_logits_positional(logits, temperature, top_k,
+                                             top_p)
+        sampled = jax.random.categorical(key, l)
+        return jnp.where(greedy, jnp.argmax(logits, axis=-1),
+                         sampled).astype(jnp.int32)
 
 
 def _sample_positional(logits, keys, index, temperature, top_k, top_p):

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.selective_scan import selective_scan, selective_step, silu
+from ..spans import scope
 from .afmoe import SwiGLU, grouped_attention
 from .kv_cache import attend_kv_cache
 
@@ -213,15 +214,22 @@ class JambaMamba(nn.Module):
                     preferred_element_type=F32) + dt_bias)
         a = -jnp.exp(a_log)
         h0 = state.value if decode else jnp.zeros((b, n, di), F32)
+        # What the device does for the state is named (spans.py): a
+        # decode step's one-position update with the shift of the
+        # convolution's tail ``ptpu_state_step``, a piece's scan
+        # ``ptpu_scan`` (inside selective_scan) and its tail's store
+        # ``ptpu_kv_write``.
         if s == 1:
-            y, h = selective_step(u[:, 0], delta[:, 0], a, bmat[:, 0],
-                                  cmat[:, 0], d_skip, h0)
-            y = (y * silu(z[:, 0].astype(F32)))[:, None]
+            with scope("ptpu_state_step"):
+                y, h = selective_step(u[:, 0], delta[:, 0], a,
+                                      bmat[:, 0], cmat[:, 0], d_skip, h0)
+                y = (y * silu(z[:, 0].astype(F32)))[:, None]
         else:
             y, h = selective_scan(u, delta, a, bmat, cmat, d_skip, h0, z)
         if decode:
-            state.value = h
-            tail.value = seen[:, s:].astype(cfg.dtype)
+            with scope("ptpu_state_step" if s == 1 else "ptpu_kv_write"):
+                state.value = h
+                tail.value = seen[:, s:].astype(cfg.dtype)
         return _dense(cfg, cfg.hidden_size, "out_proj")(
             y.astype(cfg.dtype))
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.quant import symmetric_int8
+from ..spans import scope
 
 
 def _quantize_chunk(x):
@@ -86,8 +87,9 @@ def _plane(var, layer, rows=None, tail: int = 2):
 def _store(var, layer, value) -> None:
     """Replace this layer's whole plane (the small leaves: an index,
     a ring's position table)."""
-    var.value = value if layer is None \
-        else var.value.at[layer].set(value)
+    with scope("ptpu_kv_write"):
+        var.value = value if layer is None \
+            else var.value.at[layer].set(value)
 
 
 def _put_rows(var, layer, rows, start) -> None:
@@ -98,23 +100,25 @@ def _put_rows(var, layer, rows, start) -> None:
     update is a scatter, a dependent write a lane: a decode step on a
     carried stack does not come here (``defers``)."""
     behind = (0,) * (rows.ndim - 2)
-    if layer is None:
-        var.value = jax.lax.dynamic_update_slice(
-            var.value, rows, (0, start) + behind)
-    else:
-        var.value = jax.lax.dynamic_update_slice(
-            var.value, rows[None], (layer, 0, start) + behind)
+    with scope("ptpu_kv_write"):
+        if layer is None:
+            var.value = jax.lax.dynamic_update_slice(
+                var.value, rows, (0, start) + behind)
+        else:
+            var.value = jax.lax.dynamic_update_slice(
+                var.value, rows[None], (layer, 0, start) + behind)
 
 
 def _scatter_rows(var, layer, rows, slots) -> None:
     """Write ``rows`` ([B, n, H, D]) at the ring slots ``slots`` [n]
     (distinct) of this layer's plane."""
-    if layer is None:
-        var.value = var.value.at[:, slots].set(rows)
-    else:
-        # Two advanced indices around a slice: their axis leads.
-        var.value = var.value.at[layer, :, slots].set(
-            jnp.moveaxis(rows, 1, 0))
+    with scope("ptpu_kv_write"):
+        if layer is None:
+            var.value = var.value.at[:, slots].set(rows)
+        else:
+            # Two advanced indices around a slice: their axis leads.
+            var.value = var.value.at[layer, :, slots].set(
+                jnp.moveaxis(rows, 1, 0))
 
 
 # -- one write a step ----------------------------------------------------------
@@ -177,10 +181,12 @@ def write_deferred(stack, deferred) -> None:
         for name in below:
             held = held[name]
         start = rows["start"][0]
-        written = {
-            leaf: jax.lax.dynamic_update_slice(
-                held[leaf], new, (0, 0, start) + (0,) * (new.ndim - 3))
-            for leaf, new in rows.items() if leaf != "start"}
+        with scope("ptpu_kv_write"):
+            written = {
+                leaf: jax.lax.dynamic_update_slice(
+                    held[leaf], new,
+                    (0, 0, start) + (0,) * (new.ndim - 3))
+                for leaf, new in rows.items() if leaf != "start"}
         for name in reversed(below):
             written = {name: written}
         for name, value in written.items():
@@ -259,13 +265,15 @@ def append_ring_kv_cache(mod, k, v, window: int, rotate=None,
         kq, k_scale, vq, v_scale = k, None, v, None
 
     def ring():
-        """The ring's keys and values as they lie, dequantized."""
-        if not quantize:
-            return _plane(ck, layer), _plane(cv, layer)
-        return (_plane(ck, layer).astype(k.dtype)
-                * _plane(cks, layer).astype(k.dtype),
-                _plane(cv, layer).astype(k.dtype)
-                * _plane(cvs, layer).astype(k.dtype))
+        """The ring's keys and values as they lie, dequantized: the
+        first of the attention's read."""
+        with scope("ptpu_attend"):
+            if not quantize:
+                return _plane(ck, layer), _plane(cv, layer)
+            return (_plane(ck, layer).astype(k.dtype)
+                    * _plane(cks, layer).astype(k.dtype),
+                    _plane(cv, layer).astype(k.dtype)
+                    * _plane(cvs, layer).astype(k.dtype))
 
     def written(pos):
         """Which ring slots hold a position that was really written
@@ -679,18 +687,22 @@ def _over_prefix(attend_rows, pos_q, cap: int, stacked: bool):
     """``attend_rows(n)`` — the attention over a plane's first ``n``
     rows (static) — for the narrowest of :func:`prefix_widths` that the
     extent in scope allows; for ``cap`` where none is in scope, or
-    where this plane is not narrowed under it (:func:`narrows`)."""
-    scope = getattr(_READ, "scope", None)
-    widths = prefix_widths(cap)
-    if scope is None or len(widths) == 1 \
-            or not narrows(scope[1], stacked):
-        return attend_rows(cap)
-    extent = pos_q[-1] + 1 if scope[0] is OWN_INDEX else scope[0]
-    if not isinstance(extent, jax.Array):       # known while tracing
-        return attend_rows(int(prefix_width(extent, cap)))
-    return jax.lax.switch(
-        prefix_branch(jnp.asarray(extent, jnp.int32), cap),
-        [lambda n=n: attend_rows(n) for n in widths])
+    where this plane is not narrowed under it (:func:`narrows`).
+    Traced under the scope ``ptpu_attend`` (spans.py): the read of
+    the rows, scores, softmax and values, the width a conditional took
+    readable as ``branch_<i>_fun`` under it."""
+    with scope("ptpu_attend"):
+        reads = getattr(_READ, "scope", None)
+        widths = prefix_widths(cap)
+        if reads is None or len(widths) == 1 \
+                or not narrows(reads[1], stacked):
+            return attend_rows(cap)
+        extent = pos_q[-1] + 1 if reads[0] is OWN_INDEX else reads[0]
+        if not isinstance(extent, jax.Array):   # known while tracing
+            return attend_rows(int(prefix_width(extent, cap)))
+        return jax.lax.switch(
+            prefix_branch(jnp.asarray(extent, jnp.int32), cap),
+            [lambda n=n: attend_rows(n) for n in widths])
 
 
 def _append(mod, k, v, max_position, window, rotate, quantize, layer):

@@ -21,6 +21,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..spans import scope
+
 BIG_NEG = -1e30
 
 logger = logging.getLogger(__name__)
@@ -244,7 +246,17 @@ def dot_product_attention(
     [B, H, Sq, Sk] (T5-style relative position bias).  Routes through
     the fused-XLA path — the flash kernels and the sequence-parallel
     schedules take no bias operand (a bias-carrying flash BlockSpec is
-    future work), so biased attention stays local and unfused."""
+    future work), so biased attention stays local and unfused.
+
+    Whatever the route, the call's operations are traced under the
+    scope ``ptpu_attend`` (spans.py): in a device trace a flash kernel
+    reads ``.../ptpu_attend/pallas_call`` under ``jvp(`` or
+    ``transpose(``."""
+    with scope("ptpu_attend"):
+        return _routed(q, k, v, mask, causal, scale, window, bias)
+
+
+def _routed(q, k, v, mask, causal, scale, window, bias):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if window is not None:

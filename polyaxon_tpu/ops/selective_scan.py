@@ -43,6 +43,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from ..spans import scope
+
 F32 = jnp.float32
 SUBLANES, LANES = 8, 128
 CHANNEL_BLOCK = SUBLANES * LANES    # channels a kernel instance owns
@@ -210,10 +212,12 @@ def selective_scan(u, delta, A, B, C, D, h0, z=None):
     """``(y [B, L, D], h_L [B, N, D])`` of the recurrence in the
     module's docstring over one piece, from the carried-in state
     ``h0``; float32.  The one entry point: the kernel where
-    :func:`scan_eligible`, the ``lax.scan`` otherwise."""
+    :func:`scan_eligible`, the ``lax.scan`` otherwise; either way
+    traced under the scope ``ptpu_scan`` (spans.py)."""
     route = "pallas" if scan_eligible(u.shape[-1]) else "xla"
     with _ROUTES_LOCK:
         _ROUTES[route] += 1
     fn = selective_scan_pallas if route == "pallas" \
         else selective_scan_xla
-    return fn(u, delta, A, B, C, D, h0, z)
+    with scope("ptpu_scan"):
+        return fn(u, delta, A, B, C, D, h0, z)

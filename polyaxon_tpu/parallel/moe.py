@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.grouped_matmul import grouped_matmul
+from ..spans import scope
 from .mesh import active_batch_axes
 
 
@@ -153,15 +154,18 @@ def sigmoid_topk_route(x, router_w, select_bias, k: int, *,
     in: the top-k cut is a discontinuity, and a rounded score moves
     tokens between experts.  ``select_bias`` [E] enters the CHOICE only
     (``top_k(s + b)``); the weights are the chosen scores themselves,
-    normalised over the k (``+ 1e-20``) and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + select_bias.astype(jnp.float32), k)
-    w = jnp.take_along_axis(s, chosen, axis=-1)
-    if normalize:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), w * scale
+    normalised over the k (``+ 1e-20``) and scaled.  Traced under
+    the scope ``ptpu_route`` (spans.py)."""
+    with scope("ptpu_route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(
+            s + select_bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        if normalize:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return chosen.astype(jnp.int32), w * scale
 
 
 def softmax_topk_route(x, router_w, k: int, *, scale: float = 1.0):
@@ -170,12 +174,14 @@ def softmax_topk_route(x, router_w, k: int, *, scale: float = 1.0):
     [..., k] f32)``.  The scores are the softmax over ALL experts,
     float32 at full matmul precision as in :func:`sigmoid_topk_route`
     and for its reason; the weights are the k largest as they are
-    (they do not add up to 1) times ``scale``."""
-    g = jax.nn.softmax(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST), axis=-1)
-    w, chosen = jax.lax.top_k(g, k)
-    return chosen.astype(jnp.int32), w * scale
+    (they do not add up to 1) times ``scale``.  Traced under the
+    scope ``ptpu_route`` (spans.py)."""
+    with scope("ptpu_route"):
+        g = jax.nn.softmax(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        w, chosen = jax.lax.top_k(g, k)
+        return chosen.astype(jnp.int32), w * scale
 
 
 def held_pair_counts(chosen, num_held: int, expert_offset: int = 0):
@@ -250,6 +256,8 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
     return to their tokens under the routing weights.  The row count is
     the static ``T * k``, every pair's place should all of them fall
     here, so no held pair is ever dropped; no ``[T, d, f]`` copy of
-    weights a token is made."""
-    return _grouped_ffn(int(expert_offset))(
-        x, chosen, weights, w_gate, w_up, w_down)
+    weights a token is made.  Traced whole under the scope
+    ``ptpu_experts`` (spans.py)."""
+    with scope("ptpu_experts"):
+        return _grouped_ffn(int(expert_offset))(
+            x, chosen, weights, w_gate, w_up, w_down)

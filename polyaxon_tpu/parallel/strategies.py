@@ -27,6 +27,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..spans import scope
 from .constraints import ambient_mesh
 from .mesh import MeshSpec, build_mesh, data_sharding
 
@@ -288,10 +289,15 @@ class TrainStep:
             if isinstance(aux, dict) and "__new_vars__" in aux:
                 aux = dict(aux)
                 new_vars = aux.pop("__new_vars__")
-            updates, opt_state = optimizer.update(
-                grads, state["opt_state"], params)
-            params = jax.tree.map(
-                lambda p, u: (p + u).astype(p.dtype), params, updates)
+            # Forward and backward name themselves in a device trace
+            # (``jvp(`` and ``transpose(`` in the name stack); the
+            # update has no name but this one (spans.py).
+            with scope("ptpu_optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, state["opt_state"], params)
+                params = jax.tree.map(
+                    lambda p, u: (p + u).astype(p.dtype), params,
+                    updates)
             if new_vars is not None:
                 params = {**params, **new_vars}
             metrics = {"loss": loss,

@@ -132,7 +132,7 @@ import numpy as np
 from ..analysis.xprof import STEP_MARKER
 from ..models.kv_cache import (KINDS, PlaneReads, cache_kinds,
                                leaf_kinds, read_extent)
-from ..spans import span
+from ..spans import scope, span
 
 
 _KIND_WORDS = {"window": "window rings", "full": "full-length planes",
@@ -372,10 +372,14 @@ def build_step_body(model, variables, window: int, sampled: bool):
         it), else the shared position-keyed sampler with the slot's
         OWN (key, index, temperature, top_k, top_p); greedy co-tenants
         (temperature 0) take its argmax lane, producing the same
-        tokens the greedy body would."""
+        tokens the greedy body would.  Either way under the scope
+        ``ptpu_sample`` (spans.py; the sampler opens its own)."""
         logits, pairs, cache = logits_for(cache, tok, pos)
-        nxt = G._sample_positional_row(logits, *sampling) if sampled \
-            else jnp.argmax(logits).astype(jnp.int32)
+        if sampled:
+            nxt = G._sample_positional_row(logits, *sampling)
+        else:
+            with scope("ptpu_sample"):
+                nxt = jnp.argmax(logits).astype(jnp.int32)
         return nxt, logits, pairs, cache
 
     def step(stacked, steps, toks, fed, fresh, positions, *sampling):
@@ -1086,9 +1090,10 @@ class SlotKVManager(SlotManager):
                                "draft" if draft else "target")
 
         def _insert(stacked, one, idx):
-            return jax.tree.map(
-                lambda s, n: jax.lax.dynamic_update_index_in_dim(
-                    s, n.astype(s.dtype), idx, 0), stacked, one)
+            with scope("ptpu_kv_write"):
+                return jax.tree.map(
+                    lambda s, n: jax.lax.dynamic_update_index_in_dim(
+                        s, n.astype(s.dtype), idx, 0), stacked, one)
 
         sh = self._draft_cache_sh if draft else self._cache_sh
         fn = jax.jit(_insert, in_shardings=(sh, None, None),

@@ -1,6 +1,7 @@
-"""Named host sections on the profiler's clock.
+"""Named host sections on the profiler's clock, and named parts of the
+device's programs.
 
-One list of names, one helper.  ``span(name)`` enters a
+The host half: one list of names, one helper.  ``span(name)`` enters a
 ``jax.profiler.TraceAnnotation``: while a trace is being taken the
 section lies on the ``/host:CPU`` plane, on its thread's line, on the
 clock the device planes use, so an idle gap of the device can be given
@@ -15,6 +16,22 @@ The Python tracer is off unless asked for: it instruments every call
 on every thread, so a trace taken with it measures a slower host than
 the untraced run (the serving cell's device idled 7.4-11.1 % of a
 trace with it and 4.6-7.2 % without, PERF.md section 6).
+
+The device half of the same idea: a span names a host section, a SCOPE
+names a part of a device program.  ``scope(name)`` is
+``jax.named_scope`` for a name of ``SCOPE_NAMES``: it writes the name
+into the JAX name stack of every operation traced inside it, beside
+what Flax writes there for a module and JAX for ``jvp`` and
+``transpose``.  A device trace carries that stack with every
+operation (the ``tf_op`` of the event's metadata), and
+``perfbench/device_scopes.py`` splits a program's device time by it.
+A scope stands ONLY where the stack is silent otherwise: around the
+attention over a cache, the writes into a pool, the sampler, the
+expert layers, a recurrent state's step, the optimizer; never around
+a Flax module, which names itself.  It is metadata of the lowered
+program: no operation, no operand, nothing at run time, and the
+program's text without debug info is the same with and without it
+(tests/test_spans.py).
 """
 
 from __future__ import annotations
@@ -50,6 +67,33 @@ SPAN_NAMES = (
     "ptpu/commit",          # tokens out, eviction, _complete, tel.step
     "ptpu/board",           # the debug snapshot
 )
+
+
+# Closed, as SPAN_NAMES is: a scope used anywhere in the package is in
+# here (tests/test_spans.py), and perfbench/device_scopes.py maps each
+# to the part of a program it names.  No "/" in a name: it separates
+# the segments of the name stack.
+SCOPE_NAMES = (
+    "ptpu_attend",          # scores, softmax, values over the keys read
+    "ptpu_kv_write",        # a write into a leaf of a cache or a pool
+    "ptpu_latent_expand",   # latent rows through W_kvb to K, V a head
+    "ptpu_route",           # token-choice routing: scores and top-k
+    "ptpu_experts",         # the held experts: sort, grouped matmuls, sum
+    "ptpu_scan",            # the selective scan over a piece
+    "ptpu_state_step",      # a recurrent state's one-position update
+    "ptpu_sample",          # logits shaped and a token drawn, or arg-max
+    "ptpu_optimizer",       # optimizer.update and the parameters' update
+)
+
+
+def scope(name: str):
+    """``with scope(name): ...`` while TRACING: the operations traced
+    inside carry ``name`` in their name stack — see the module."""
+    import jax
+
+    if name not in SCOPE_NAMES:
+        raise ValueError(f"{name!r} is not in spans.SCOPE_NAMES")
+    return jax.named_scope(name)
 
 
 class span:

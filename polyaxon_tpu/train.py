@@ -340,7 +340,7 @@ def _main(argv=None) -> int:
     #     bench).
     from .config import enable_compilation_cache
 
-    enable_compilation_cache()
+    enable_compilation_cache(names_in_key=bool(args.profile_at))
 
     # 0c. the host's chips may still be held by a predecessor that was
     #     just stopped (chips.wait_for_chips): wait for it rather than

@@ -56,6 +56,34 @@ class TestCompilationCachePlacement:
                                                         ".jax_cache")
         assert got["min_s"] == 0.0
 
+    @pytest.mark.parametrize("placed", ["checkout", "env"])
+    def test_a_traced_process_keys_the_cache_by_names_too(self, tmp_path,
+                                                          placed):
+        """An executable read from the cache carries the names of the
+        tree that compiled it, and a device trace reads them
+        (spans.scope): a process that will take a trace keys the cache
+        by the metadata too, wherever the directory comes from; every
+        other process keeps the key it had."""
+        code = ("import json, jax;"
+                "from polyaxon_tpu.config import enable_compilation_cache"
+                " as on; name = "
+                "'jax_compilation_cache_include_metadata_in_key';"
+                "a = on(); b = getattr(jax.config, name);"
+                "c = on(names_in_key=True);"
+                "print(json.dumps([a, b, c, getattr(jax.config, name)]))")
+        base = {k: v for k, v in os.environ.items()
+                if k != "JAX_COMPILATION_CACHE_DIR"}
+        if placed == "env":
+            base["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                              capture_output=True, text=True, timeout=600,
+                              env=base)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        plain, before, traced, after = json.loads(
+            proc.stdout.strip().splitlines()[-1])
+        assert plain == traced      # the directory does not move
+        assert before is False and after is True
+
     def test_in_process_train_leaves_the_suite_cache_alone(self):
         """conftest places the suite's cache through the environment,
         so the helper — which every in-process ``train.main()`` calls —

@@ -2,13 +2,19 @@
 (polyaxon_tpu/spans.py): the closed list of names, the counters, what
 a CPU trace taken through ``start_trace`` holds after ``train.py``'s
 loop and after an engine tick, the Python tracer's switch, and the
-wait for chips a dying predecessor still holds (chips.py)."""
+wait for chips a dying predecessor still holds (chips.py).  And for
+the device half, the scopes: the closed list, where each stands in the
+lowered programs of the served architectures and of a training step,
+and that a scope is metadata alone (the program's text without debug
+info is the same without it)."""
 
 import ast
+import contextlib
 import errno
 import glob
 import json
 import os
+import sys
 import urllib.error
 import urllib.request
 
@@ -47,8 +53,10 @@ def _no_engine_left_running():
 # ---------------------------------------------------------------------------
 
 
-def _span_literals():
-    """``(file, name)`` of every ``span("...")`` call in the package."""
+def _call_literals(func: str):
+    """``(file, [names])`` of every ``<func>(...)`` call in the package:
+    the string constants of its first argument (one, or the arms of a
+    conditional)."""
     out = []
     for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
                           recursive=True):
@@ -56,12 +64,18 @@ def _span_literals():
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
             if isinstance(node, ast.Call) \
-                    and getattr(node.func, "id", None) == "span" \
-                    and node.args \
-                    and isinstance(node.args[0], ast.Constant):
-                out.append((os.path.relpath(path, PACKAGE),
-                            node.args[0].value))
+                    and getattr(node.func, "id", None) == func \
+                    and node.args:
+                out.append((os.path.relpath(path, PACKAGE), [
+                    n.value for n in ast.walk(node.args[0])
+                    if isinstance(n, ast.Constant)
+                    and isinstance(n.value, str)]))
     return out
+
+
+def _span_literals():
+    """``(file, name)`` of every ``span("...")`` call in the package."""
+    return [(f, names[0]) for f, names in _call_literals("span") if names]
 
 
 def test_span_names_closed_and_every_literal_listed():
@@ -134,6 +148,187 @@ def test_span_passes_an_exception_through_and_still_counts():
 
 
 # ---------------------------------------------------------------------------
+# the scopes: the list, and where each stands in the lowered programs
+# ---------------------------------------------------------------------------
+
+
+def test_scope_names_closed_and_every_literal_listed():
+    names = spans.SCOPE_NAMES
+    assert len(set(names)) == len(names)
+    assert all(n.startswith("ptpu_") and "/" not in n for n in names)
+    assert not set(names) & set(spans.SPAN_NAMES)
+    used = [(f, n) for f, ns in _call_literals("scope") for n in ns]
+    assert len(used) >= 20
+    assert [u for u in used if u[1] not in names] == []
+    assert {n for _, n in used} == set(names)       # every one is used
+    # every site goes through spans.scope
+    raw = []
+    for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            if "named_scope(" in f.read():
+                raw.append(os.path.relpath(path, PACKAGE))
+    assert raw == ["spans.py"]
+
+
+def test_scope_refuses_a_name_not_listed():
+    with spans.scope("ptpu_attend"):
+        pass
+    for name in ("ptpu_attention", "ptpu/decode", "attend"):
+        with pytest.raises(ValueError):
+            spans.scope(name)
+
+
+def _manager(model, variables):
+    """``(manager, cache)``: a slot manager of two slots over ``model``
+    with one prefilled, sampled stream (``cache``) inserted."""
+    import jax
+
+    from polyaxon_tpu.models import generate as G
+    from polyaxon_tpu.serving.slots import SlotKVManager
+
+    mgr = SlotKVManager(model, variables, 2)
+    _, cache = G.prefill(model, variables,
+                         np.asarray([[3, 1, 4, 1, 5]], np.int32))
+    mgr.insert(mgr.acquire(), cache, 1, 5, temperature=0.9, top_k=16,
+               base_key=np.asarray(jax.random.key_data(
+                   jax.random.PRNGKey(11)), np.uint32))
+    return mgr, cache
+
+
+def _decode_window(model, variables):
+    """The manager's sampled decode window, ready to lower."""
+    import jax.numpy as jnp
+
+    mgr, _ = _manager(model, variables)
+    mgr.step(2, True)               # a launch leaves the feedback token
+    operands = [jnp.asarray(2, jnp.int32)] + [
+        jnp.asarray(x) for x in mgr.state.operands("sampled")]
+
+    def lower():
+        fn = mgr._build_step(2, True)       # traced anew each time
+        return fn.func.lower(*fn.args, mgr.kv_pool(), *operands)
+    return lower
+
+
+def _extend_piece(model, variables):
+    """``jit_ptpu_extend``: a piece of four onto a prefilled cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from polyaxon_tpu.models import generate as G
+
+    _, cache = G.prefill(model, variables,
+                         np.asarray([[3, 1, 4, 1]], np.int32))
+    toks = jnp.asarray([[5, 9, 2, 6]], jnp.int32)
+    return lambda: jax.jit(G.prefill_programs(model, chunk=4)[1]).lower(
+        variables, cache, toks, 4)
+
+
+def _prefill_piece(model, variables):
+    """``jit_ptpu_prefill``: a prompt of four into a fresh cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from polyaxon_tpu.models import generate as G
+
+    toks = jnp.asarray([[3, 1, 4, 1]], jnp.int32)
+    return lambda: jax.jit(G.prefill_programs(model, chunk=4)[0]).lower(
+        variables, toks)
+
+
+def _insertion(model, variables):
+    import jax.numpy as jnp
+
+    mgr, cache = _manager(model, variables)
+    return lambda: mgr._build_insert(False).lower(
+        mgr.kv_pool(), cache, jnp.asarray(1, jnp.int32))
+
+
+def _train_step(name):
+    import jax
+    import optax
+
+    from polyaxon_tpu.parallel.mesh import MeshSpec, build_mesh
+    from polyaxon_tpu.parallel.strategies import make_train_step
+
+    spec = get_model(name)
+    model = spec.make_model()
+    batch = spec.make_batch(2)
+    mesh = build_mesh(MeshSpec(dp=1), devices=jax.devices()[:1])
+    rng = jax.random.PRNGKey(0)
+
+    def lower():
+        step = make_train_step(spec.loss_fn(model), optax.sgd(0.01),
+                               mesh=mesh, donate=False)
+        state = step.init_state(model.init(rng, batch["inputs"]))
+        return step._build().lower(state, batch, rng)
+    return lower
+
+
+WRITES, ATTEND, SAMPLE = "ptpu_kv_write", "ptpu_attend", "ptpu_sample"
+EXPERTS = ("ptpu_route", "ptpu_experts")
+# (model, program) -> the scopes the lowered program must hold.
+SCOPED_PROGRAMS = {
+    ("gpt2-tiny", "decode"): (ATTEND, WRITES, SAMPLE),
+    ("gpt2-tiny", "prefill"): (ATTEND, WRITES),
+    ("gpt2-tiny", "extend"): (ATTEND, WRITES),
+    ("gpt2-tiny", "insert"): (WRITES,),
+    ("afmoe-tiny", "decode"): (ATTEND, WRITES, SAMPLE) + EXPERTS,
+    ("afmoe-tiny", "extend"): (ATTEND, WRITES) + EXPERTS,
+    ("jamba-tiny", "decode"): (ATTEND, WRITES, SAMPLE,
+                               "ptpu_state_step"),
+    ("jamba-tiny", "extend"): (ATTEND, WRITES, "ptpu_scan"),
+    ("deepseek-v2-tiny", "decode"): (ATTEND, WRITES, SAMPLE) + EXPERTS,
+    ("deepseek-v2-tiny", "extend"): (ATTEND, WRITES,
+                                     "ptpu_latent_expand") + EXPERTS,
+    ("gpt2-tiny", "train"): (ATTEND, "ptpu_optimizer"),
+    ("bert-tiny", "train"): (ATTEND, "ptpu_optimizer"),
+}
+
+
+@pytest.mark.parametrize("name, program", list(SCOPED_PROGRAMS),
+                         ids=["-".join(k) for k in SCOPED_PROGRAMS])
+def test_lowered_program_holds_its_scopes_and_only_as_metadata(
+        name, program, monkeypatch):
+    """With debug info the lowered program names each scope the model
+    has on this path (and no other of the list's); without debug info
+    its text is the same, byte for byte, as with every scope a no-op:
+    a scope adds no operation, operand, shape or layout."""
+    if program == "train":
+        lower = _train_step(name)
+    else:
+        model, variables = get_model(name).init_params(batch_size=1)
+        lower = {"decode": _decode_window, "prefill": _prefill_piece,
+                 "extend": _extend_piece,
+                 "insert": _insertion}[program](model, variables)
+    lowered = lower()
+    named, plain = lowered.as_text(debug_info=True), lowered.as_text()
+    want = set(SCOPED_PROGRAMS[name, program])
+    held = {n for n in spans.SCOPE_NAMES if f"/{n}/" in named
+            or f"({n})/" in named}
+    assert held == want, (held ^ want)
+    assert not any(n in plain for n in spans.SCOPE_NAMES)
+    # the stack reads as the issue's table has it: the width a
+    # conditional took under the attention's scope, forward and
+    # backward around it in a training step
+    if (name, program) == ("gpt2-tiny", "decode"):
+        assert "ptpu_attend/cond/branch_1_fun/" in named
+    if program == "train":
+        assert "transpose(jvp(" in named and "/ptpu_attend/" in named
+    real = spans.scope
+    for mod in list(sys.modules.values()):
+        if getattr(mod, "__name__", "").startswith("polyaxon_tpu") \
+                and getattr(mod, "scope", None) is real:
+            monkeypatch.setattr(
+                mod, "scope", lambda name: contextlib.nullcontext())
+    bare = lower()
+    assert not any(n in bare.as_text(debug_info=True)
+                   for n in spans.SCOPE_NAMES)
+    assert bare.as_text() == plain
+
+
+# ---------------------------------------------------------------------------
 # what a trace holds
 # ---------------------------------------------------------------------------
 
@@ -187,14 +382,23 @@ def train_run(tmp_path, monkeypatch):
     from polyaxon_tpu.client import FileRunStore
     from polyaxon_tpu.train import main
 
+    import jax
+
     home = str(tmp_path / "home")
     monkeypatch.setenv("POLYAXON_TPU_HOME", home)
     monkeypatch.setenv("POLYAXON_TPU_NO_TPU", "1")
+    names = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, names)
     assert main(["--model", "mlp", "--cpu", "--steps", "4",
                  "--batch-size", "8", "--log-every", "2",
                  "--profile-at", "1", "--profile-steps", "3",
                  "--eval-every", "2", "--checkpoint-every", "2",
                  "--no-resume"]) == 0
+    # A process that takes a trace keys its compilation cache by the
+    # programs' names too (config.enable_compilation_cache): the
+    # switch is the process's, and this one runs other tests.
+    assert getattr(jax.config, names) is True
+    jax.config.update(names, was)
     store = FileRunStore(home)
     uuid = store.list_runs()[0]["uuid"]
     return store, uuid, os.path.join(store.artifacts_path(uuid), "traces")
