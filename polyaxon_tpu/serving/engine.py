@@ -2891,6 +2891,10 @@ class DecodeEngine:
             "decode_dispatches_ahead_total":
                 self.decode_dispatches_ahead_total,
             "decode_serial_reasons": dict(self.decode_serial_reasons),
+            # Host arrays the decode launches handed to the device:
+            # one a launch, every host operand packed
+            # (slots.LaunchPacking).
+            "decode_upload_arrays_total": self.slots.upload_arrays_total,
             # The KV pool updated in place (serving/slots.py):
             # programs that took the pool, those that consumed the
             # tree they were handed, the live pools' bytes (logical,

@@ -74,8 +74,9 @@ pad class of a dispatch, the dirty-page window, gather/scatter, spill
 and the wire format.  The slots' host state and the host half of a
 decode dispatch are ``slots.SlotManager``'s, shared with the fixed
 lanes: ``_program`` hands a launch the program for a key, the pools'
-names, the page tables with each slot's first dirty page (uploaded
-with the slots' arrays, inside ``device_s``) and the gathered view's
+names, the page tables with each slot's first dirty page (packed
+with the slots' arrays into the launch's one transfer, inside
+``device_s``) and the gathered view's
 width, and nothing else.  Tables and dirty pages are read off the
 slots' positions, which move at a LAUNCH, so a dispatch can be
 launched before the one ahead of it is collected wherever the tables
@@ -1123,10 +1124,13 @@ class PagedSlotKVManager(SlotManager):
         model = self.model
         metas, treedef = self._meta, self._treedef
         n_dirty = self._n_dirty(window)
+        packing = self._packing("sampled" if sampled else "plain",
+                                self._leading(P, n_dirty))
 
-        def step(variables, pool, tables, d0, toks, fed, fresh,
-                 positions, *extra):
+        def step(variables, pool, words, fed):
             # The weights are an ARGUMENT (jit_over), not a closure.
+            tables, d0, toks, fed, fresh, positions, *extra = \
+                packing.unpack(words, (fed,))
             body = build_step_body(model, variables, window, sampled)
             stacked = self._gather_tree(pool, metas, treedef,
                                         tables, positions)
@@ -1139,11 +1143,15 @@ class PagedSlotKVManager(SlotManager):
         if self.mesh is None:
             return jit_over(self.variables, step)
         rep = self.mesh.replicated
-        n_extra = 5 if sampled else 0
         in_sh = (self.mesh.shardings_of(self.variables),
-                 self._pool_sh) + (rep,) * (6 + n_extra)
+                 self._pool_sh, rep, rep)
         return jit_over(self.variables, step, in_shardings=in_sh,
                         out_shardings=(rep, rep, self._pool_sh))
+
+    def _leading(self, P: int, n_dirty: int):
+        """A launch's leading operands over ``P`` pages a slot: the
+        page tables and each slot's first dirty page."""
+        return self.page_tables[:, :P], self._dirty_start(P, n_dirty)
 
     def _program(self, window: int, sampled: bool,
                  cap: Optional[int], K: int):
@@ -1163,17 +1171,14 @@ class PagedSlotKVManager(SlotManager):
                                "insert()" if K
                                else "step() before any insert()")
         P = self._resident_pad()
-        tables = self.page_tables[:, :P]
         if K:
             return ((window, "spec", K, P),
                     lambda: self._build_spec_step(window, K, P),
                     ("_pool", "_draft_pool"),
-                    (tables, self._dirty_start(
-                        P, self._n_dirty(window * K + 1))))
+                    self._leading(P, self._n_dirty(window * K + 1)))
         return ((window, sampled, P),
                 lambda: self._build_step(window, sampled, P),
-                ("_pool",),
-                (tables, self._dirty_start(P, self._n_dirty(window))),
+                ("_pool",), self._leading(P, self._n_dirty(window)),
                 P * self.page_tokens)
 
     def _build_spec_step(self, window: int, K: int, P: int):
@@ -1184,9 +1189,11 @@ class PagedSlotKVManager(SlotManager):
         metas, treedef = self._meta, self._treedef
         d_metas, d_treedef = self._draft_meta, self._draft_treedef
         n_dirty = self._n_dirty(window * K + 1)
+        packing = self._packing("spec", self._leading(P, n_dirty))
 
-        def step(weights, t_pool, d_pool, tables, d0, toks, positions,
-                 idxs, keys, temps, tks, tps, sks):
+        def step(weights, t_pool, d_pool, words):
+            (tables, d0, toks, positions, idxs, keys, temps, tks, tps,
+             sks) = packing.unpack(words, ())
             body = build_spec_step_body(model, weights[0], draft,
                                         weights[1], window, K)
             t_stacked = self._gather_tree(t_pool, metas, treedef,
@@ -1206,7 +1213,7 @@ class PagedSlotKVManager(SlotManager):
             return jit_over(weights, step)
         rep = self.mesh.replicated
         in_sh = (self.mesh.shardings_of(weights), self._pool_sh,
-                 self._draft_pool_sh) + (rep,) * 10
+                 self._draft_pool_sh, rep)
         return jit_over(weights, step, in_shardings=in_sh,
                         out_shardings=(rep, rep, rep, self._pool_sh,
                                        self._draft_pool_sh))

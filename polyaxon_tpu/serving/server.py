@@ -3190,6 +3190,7 @@ class ModelServer:
                     "decode_dispatches_total",
                     "decode_dispatches_ahead_total",
                     "decode_serial_reasons",
+                    "decode_upload_arrays_total",
                     "kv_pool_dispatches_total",
                     "kv_pool_in_place_total", "kv_pool_bytes",
                     "kv_pool_rest_bytes",
@@ -3493,6 +3494,9 @@ class ModelServer:
                 *(f'ptpu_serving_decode_serial_reasons'
                   f'{{reason="{reason}"}} {n}' for reason, n in
                   sorted(es["decode_serial_reasons"].items())),
+                "# TYPE ptpu_serving_decode_upload_arrays_total counter",
+                f"ptpu_serving_decode_upload_arrays_total "
+                f"{es['decode_upload_arrays_total']}",
                 "# TYPE ptpu_serving_kv_pool_dispatches_total counter",
                 f"ptpu_serving_kv_pool_dispatches_total "
                 f"{es['kv_pool_dispatches_total']}",

@@ -32,7 +32,8 @@ THE HOST HALF OF A DECODE DISPATCH IS WRITTEN ONCE, for this manager
 and the paged one (serving/paged.py) and for every kind of step, and
 it is CUT IN TWO at the one place where the host waits:
 ``SlotManager._launch`` — program lookup and the recompile sentinel,
-upload, the program's call, rebinding the pool to its successor, the
+upload (every host operand in ONE packed array, ``LaunchPacking``),
+the program's call, rebinding the pool to its successor, the
 counters: everything up to the futures, which a ``Flight`` holds —
 and ``SlotManager._collect`` — the one ``device_get`` of a flight's
 tokens.  ``SlotManager._dispatch`` runs a launch and then a collect
@@ -202,7 +203,11 @@ class SlotState:
     are written slot by slot here and nowhere else, and a
     crash-recovery ``reset()`` builds them as construction does, so a
     field added here can never survive a supervised restart carrying
-    stale pre-crash state.  Engine thread only."""
+    stale pre-crash state.  A launch hands the ones its kind of step
+    takes (``operands``) to the device in ONE int32 buffer
+    (:class:`LaunchPacking`: packed in ``SlotManager._launch``,
+    unpacked first thing in every step program).  Engine thread
+    only."""
 
     def __init__(self, n_slots: int):
         self.n_slots = int(n_slots)
@@ -256,8 +261,10 @@ class SlotState:
         self.arm(slot, 0, 0, None, 0, 0.0, 0, 0.0, 0)
 
     def operands(self, kind: str):
-        """The arrays a ``kind`` of step uploads, in its program's
-        argument order (``build_step_body``, ``build_spec_step_body``)."""
+        """The arrays a ``kind`` of step takes, in its body's argument
+        order (``build_step_body``, ``build_spec_step_body``): the
+        host's travel packed (:class:`LaunchPacking`), ``fed`` is on
+        the device already."""
         if kind == "spec":
             return (self.tokens, self.positions, self.next_index,
                     self.keys, self.temps, self.top_ks, self.top_ps,
@@ -303,6 +310,96 @@ class SlotState:
             self.fresh[idle] = True
             self.positions[idle] = 0
             self.next_index[idle] = 0
+
+
+# -- one transfer a launch ---------------------------------------------------
+#
+# What a transfer to the device costs the host is paid per array, not
+# per byte: a sampled dispatch's nine arrays of a few dozen words took
+# 2.47 ms on a v5e host, the same words in one buffer 0.31 ms
+# (benchmarks/bench_upload.py; PERF.md section 6).  So a launch lays
+# every host operand's words into ONE int32 buffer and puts that, and
+# the program cuts it up again before anything else.
+#
+# How an operand's elements become int32 words, and come back inside
+# the program: "int" as they are, "bits" bit for bit (float32 NaN
+# payloads, -0.0 and uint32 keys survive), "bool" as 0/1.  An operand
+# of several axes travels column by column: the TPU compiler laid the
+# PRNG keys [slots, 2] out slot-minor as a parameter, and cut from
+# column order they keep that layout, so the sampler's fusions are
+# those it compiled when each operand was a parameter.
+_WORDS = {np.dtype(np.int32): "int", np.dtype(np.uint32): "bits",
+          np.dtype(np.float32): "bits", np.dtype(np.bool_): "bool"}
+
+
+def _on_host(a) -> bool:
+    return isinstance(a, (np.ndarray, np.generic))
+
+
+def _words(a) -> np.ndarray:
+    a = np.asarray(a)
+    how = _WORDS.get(a.dtype)
+    if how is None:
+        raise TypeError(f"a launch cannot pack {a.dtype} operands")
+    a = a.T.reshape(-1)
+    return a.astype(np.int32) if how == "bool" else a.view(np.int32)
+
+
+class LaunchPacking:
+    """Where each host operand of a launch lies in the ONE int32 buffer
+    the launch transfers, and the way back.
+
+    ``operands`` are a launch's arguments after its pools, in the
+    step body's order: the manager's leading ones (the step count;
+    paged, page tables and dirty starts), then
+    ``SlotState.operands(kind)``.  A numpy array or scalar travels in
+    the buffer, its words in that order; anything else (``fed``, on
+    the device already; None where it is not yet) is handed to the
+    program as it is.  The layout depends on shapes and dtypes alone,
+    so a program key has ONE: its program takes ``(weights, *pools,
+    words, *device)`` and calls :meth:`unpack` first."""
+
+    def __init__(self, operands):
+        fields, size = [], 0
+        for a in operands:
+            if _on_host(a):
+                n = _words(a).size
+                fields.append((size, n, np.shape(a), np.asarray(a).dtype))
+                size += n
+            else:
+                fields.append(None)
+        self.fields = tuple(fields)
+        self.size = size
+
+    @staticmethod
+    def pack(operands):
+        """``(words, device)``: the host operands' words in one FRESH
+        int32 buffer (the slots' arrays are written again before the
+        program may have read it), and the device operands in order."""
+        return (np.concatenate([_words(a) for a in operands
+                                if _on_host(a)]),
+                tuple(a for a in operands if not _on_host(a)))
+
+    def unpack(self, words, device):
+        """Inside the program: every operand as the step body takes
+        it, cut from ``words`` at static offsets and given back its
+        dtype, the device operands put back where they stood."""
+        import jax
+
+        device, out = iter(device), []
+        for field in self.fields:
+            if field is None:
+                out.append(next(device))
+                continue
+            at, n, shape, dtype = field
+            a = words[at:at + n].reshape(shape[::-1]).T
+            how = _WORDS[dtype]
+            if how == "bool":
+                a = a != 0
+            elif how == "bits":
+                a = jax.lax.bitcast_convert_type(a, dtype)
+            out.append(a)
+        return tuple(out)
 
 
 # -- step-program bodies (shared with the paged manager) --------------------
@@ -579,6 +676,9 @@ class SlotManager:
         # check).  Engine stats / /info, with ``kv_pool_bytes``.
         self.kv_pool_dispatches_total = 0
         self.kv_pool_in_place_total = 0
+        # Host arrays the decode launches transferred: one a launch,
+        # every host operand packed (``LaunchPacking``).
+        self.upload_arrays_total = 0
         # How far the attention read the full-length planes, against
         # what they hold, and what the state leaves went through
         # (kv_cache.PlaneReads): every decode step here, every
@@ -642,10 +742,17 @@ class SlotManager:
     def _fed_sharding(self):
         """Where the feedback token rests between two dispatches
         (None: uncommitted, on the default device): the first launch
-        (every slot fresh, ``fed`` never read) puts zeros there, the
+        (every slot fresh, ``fed`` never read) makes zeros there, the
         kind of array the step programs hand out, so that a program
-        sees ONE signature from its first call on."""
+        sees ONE signature from its first call on.  A launch's packed
+        host operands go there too."""
         return self.mesh.replicated if self.mesh is not None else None
+
+    def _packing(self, kind: str, leading=()) -> LaunchPacking:
+        """The layout of a ``kind`` of launch's packed operands, for a
+        manager whose leading operands are shaped as ``leading``: what
+        a step program unpacks (``_build_step``)."""
+        return LaunchPacking((*leading, *self.state.operands(kind)))
 
     def _exact(self):
         """Serving-exact trace context (no-op unmeshed) — wraps every
@@ -726,6 +833,9 @@ class SlotManager:
         rebound to their successors as soon as the program hands them
         back; its own ``leading`` operands; and the ``plane_cap`` its
         program's view of the planes has (None: the planes' own).
+        Every host operand, the manager's leading ones and the
+        slots', reaches the device in ONE transfer
+        (:class:`LaunchPacking`; the program unpacks it first).
         The slots' host state moves on HERE, by the window
         (``SlotState.launched``): the next launch needs nothing of
         this one's results but ``fed``, which stays on the device.
@@ -746,20 +856,20 @@ class SlotManager:
         window = stats["window"]
         with span("ptpu/upload", host_s):
             if state.fed is None:
-                state.fed = jax.device_put(
-                    np.zeros((self.n_slots,), np.int32),
-                    self._fed_sharding())
-            # A COPY of every host array: the slots' arrays are
-            # written in place between this launch and the program's
-            # run, and a backend may read the host buffer it was
-            # handed as late as that.
-            operands = [jnp.asarray(a.copy() if isinstance(a, np.ndarray)
-                                    else a) for a in
-                        (*leading, *state.operands(kind))]
+                # made on the device: no host array travels for it
+                state.fed = jnp.zeros((self.n_slots,), jnp.int32,
+                                      device=self._fed_sharding())
+            # ONE transfer (LaunchPacking): the buffer is fresh, so
+            # the slots' arrays may be written in place before the
+            # program runs, however late a backend reads it.
+            words, device = LaunchPacking.pack(
+                (*leading, *state.operands(kind)))
+            words = jax.device_put(words, self._fed_sharding())
+            self.upload_arrays_total += 1
         with span("ptpu/enqueue", host_s), self._compiling(new):
             held = [getattr(self, name) for name in pools]
             taken = [jax.tree.leaves(pool)[0] for pool in held]
-            out = fn(*held, *operands)
+            out = fn(*held, words, *device)
             for name, pool in zip(pools, out[-len(pools):]):
                 setattr(self, name, pool)
             self._count_dispatch(*taken)
@@ -1125,19 +1235,20 @@ class SlotKVManager(SlotManager):
         from ..models.generate import jit_over
 
         model = self.model
+        # steps, then the slots' operands (``_program``'s leading one)
+        packing = self._packing("sampled" if sampled else "plain",
+                                (np.int32(window),))
 
-        def program(variables, *operands):
+        def program(variables, stacked, words, fed):
             # The weights are an ARGUMENT (jit_over), not a closure.
-            return build_step_body(model, variables, window,
-                                   sampled)(*operands)
+            return build_step_body(model, variables, window, sampled)(
+                stacked, *packing.unpack(words, (fed,)))
 
         # The pool (argument 1: jit_over binds the weights as 0) is
         # DONATED and pinned to its formats in and out.  Meshed, the
-        # weights keep the shardings they were placed with, host
-        # operands (tokens/positions/sampling state) commit
-        # replicated, and token outputs gather back replicated.
-        # steps; tokens, fed, fresh, positions; the sampler's five
-        n_host = 10 if sampled else 5
+        # weights keep the shardings they were placed with, the
+        # packed host operands and ``fed`` commit replicated, and
+        # token outputs gather back replicated.
         rep, w_sh = None, None
         if self.mesh is not None:
             rep = self.mesh.replicated
@@ -1146,7 +1257,7 @@ class SlotKVManager(SlotManager):
         # (``_fed_sharding``: one signature a program).
         return jit_over(
             self.variables, program, donate_argnums=(1,),
-            in_shardings=(w_sh, self._cache_sh) + (rep,) * n_host,
+            in_shardings=(w_sh, self._cache_sh, rep, rep),
             out_shardings=(self._fed_sharding(),) * 2
             + (self._cache_sh,))
 
@@ -1195,11 +1306,12 @@ class SlotKVManager(SlotManager):
 
         model, draft = self.model, self.draft_model
         weights = (self.variables, self.draft_variables)
+        packing = self._packing("spec")
 
-        def program(weights, *operands):
+        def program(weights, t_stacked, d_stacked, words):
             return build_spec_step_body(
-                model, weights[0], draft, weights[1], window,
-                K)(*operands)
+                model, weights[0], draft, weights[1], window, K)(
+                t_stacked, d_stacked, *packing.unpack(words, ()))
 
         # Both pools (arguments 1 and 2) donated and pinned, as in
         # the plain step.
@@ -1210,5 +1322,5 @@ class SlotKVManager(SlotManager):
         pools = (self._cache_sh, self._draft_cache_sh)
         return jit_over(
             weights, program, donate_argnums=(1, 2),
-            in_shardings=(w_sh,) + pools + (rep,) * 8,
+            in_shardings=(w_sh,) + pools + (rep,),
             out_shardings=(rep, rep, rep) + pools)

@@ -410,17 +410,13 @@ class TestWeightsAreProgramArguments:
 
         from polyaxon_tpu.models import generate as G
         from polyaxon_tpu.serving.paged import PagedSlotKVManager
-        from polyaxon_tpu.serving.slots import SlotKVManager
+        from polyaxon_tpu.serving.slots import (LaunchPacking,
+                                                SlotKVManager)
 
         model, variables = tiny
         _, cache = G.prefill(model, variables,
                              np.zeros((1, 8), np.int32))
         n = 4
-        vec = np.zeros((n,), np.int32)
-        fresh = np.ones((n,), bool)     # tokens, fed, fresh, positions
-        extra = (np.zeros((n, 2), np.uint32), vec,
-                 np.zeros((n,), np.float32), vec,
-                 np.zeros((n,), np.float32)) if sampled else ()
         if paged:
             mgr = PagedSlotKVManager(
                 model, variables, n, page_tokens=16,
@@ -428,14 +424,16 @@ class TestWeightsAreProgramArguments:
             mgr.ensure_shaped(cache)
             P = 2
             fn = mgr._build_step(2, sampled, P)
-            operands = (mgr.kv_pool(), np.zeros((n, P), np.int32), vec,
-                        vec, vec, fresh, vec, *extra)
+            leading = mgr._leading(P, 1)
         else:
             mgr = SlotKVManager(model, variables, n)
             mgr._ensure_stacked(cache)
             fn = mgr._build_step(2, sampled)
-            operands = (mgr.kv_pool(), np.int32(2), vec, vec, fresh,
-                        vec, *extra)
+            leading = (np.int32(2),)
+        # the launch's packed host words, and the feedback token
+        words, _ = LaunchPacking.pack((*leading, *mgr.state.operands(
+            "sampled" if sampled else "plain")))
+        operands = (mgr.kv_pool(), words, np.zeros((n,), np.int32))
         assert not self._weights_inside(fn, *operands)
 
     @pytest.mark.parametrize("kind", ["engine-prefill", "engine-extend",

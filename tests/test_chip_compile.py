@@ -15,6 +15,7 @@ comes only from ``chip_smoke.py`` on the chip.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
@@ -194,6 +195,19 @@ def test_engine_decode_window_compiles(one_chip, on_tpu, sampled):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
 
 
+def _window_operands(mgr, one_chip, sampled):
+    """A slot manager's decode window's operands after its pool, as
+    shapes on the described chip: the launch's packed host words
+    (``slots.LaunchPacking``; the step count leads) and the feedback
+    token."""
+    packing = mgr._packing("sampled" if sampled else "plain",
+                           (np.int32(0),))
+    return [jax.ShapeDtypeStruct((packing.size,), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((mgr.n_slots,), jnp.int32,
+                                 sharding=one_chip)]
+
+
 def _manager_window(one_chip, monkeypatch, slots, sampled):
     """``(compiled, pool)``: the decode window as a slot manager of
     ``slots`` builds it over gpt2-medium — the pool donated, pinned
@@ -207,16 +221,7 @@ def _manager_window(one_chip, monkeypatch, slots, sampled):
     mgr._cache_sh = mgr._pool_formats(pool)
     fn = mgr._build_step(DECODE_WINDOW, sampled)
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((slots,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32)]
-    if sampled:
-        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
-                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    operands = _window_operands(mgr, one_chip, sampled)
     return fn.func.lower(*fn.args, pool, *operands).compile(), pool
 
 
@@ -445,16 +450,7 @@ def test_decode_window_reads_no_whole_plane_outside_the_widest_branch(
     mgr._cache_sh = mgr._pool_formats(pool)
     fn = mgr._build_step(DECODE_WINDOW, sampled)
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32)]
-    if sampled:
-        operands += [vec(jnp.uint32, 2), vec(jnp.int32),
-                     vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)]
+    operands = _window_operands(mgr, one_chip, sampled)
     text = fn.func.lower(*fn.args, pool, *operands).compile().as_text()
     conds = re.findall(r"branch_computations=\{([^}]*)\}", text)
     assert len(conds) == 1      # one layer body, one conditional
@@ -508,10 +504,6 @@ def test_served_programs_convert_no_weight(one_chip, on_tpu,
     monkeypatch.setattr(jax, "devices",
                         lambda *a: list(one_chip.device_set))
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
     def compiled_text(served):
         model, variables, pool = _serving_shapes(one_chip, served)
         if program == "prefill":
@@ -522,12 +514,7 @@ def test_served_programs_convert_no_weight(one_chip, on_tpu,
         mgr = SlotKVManager(model, variables, SLOTS)
         mgr._cache_sh = mgr._pool_formats(pool)
         fn = mgr._build_step(DECODE_WINDOW, True)
-        operands = [
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-            vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-            vec(jnp.int32), vec(jnp.uint32, 2),
-            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
-            vec(jnp.float32)]
+        operands = _window_operands(mgr, one_chip, True)
         return variables, fn.func.lower(
             *fn.args, pool, *operands).compile().as_text()
 
@@ -650,15 +637,7 @@ def test_trinity_decode_window_keeps_both_cache_kinds_in_place(
     mgr._cache_sh = mgr._pool_formats(pool)
     fn = mgr._build_step(DECODE_WINDOW, True)
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((TRINITY_SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32), vec(jnp.uint32, 2),
-                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
-                vec(jnp.float32)]
+    operands = _window_operands(mgr, one_chip, True)
     compiled = fn.func.lower(*fn.args, pool, *operands).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     weights = sum(l.size * l.dtype.itemsize
@@ -797,15 +776,7 @@ def test_jamba_decode_window_keeps_state_and_planes_in_place(
     mgr._cache_sh = mgr._pool_formats(pool)
     fn = mgr._build_step(DECODE_WINDOW, True)
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((JAMBA_SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32), vec(jnp.uint32, 2),
-                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
-                vec(jnp.float32)]
+    operands = _window_operands(mgr, one_chip, True)
     compiled = fn.func.lower(*fn.args, pool, *operands).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     by_kind = {}
@@ -864,15 +835,7 @@ def test_dsv2_decode_window_keeps_the_latent_planes_in_place(
     mgr._cache_sh = mgr._pool_formats(pool)
     fn = mgr._build_step(DECODE_WINDOW, True)
 
-    def vec(dtype, *tail):
-        return jax.ShapeDtypeStruct((DSV2_SLOTS,) + tail, dtype,
-                                    sharding=one_chip)
-
-    operands = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-                vec(jnp.int32), vec(jnp.uint32, 2),
-                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
-                vec(jnp.float32)]
+    operands = _window_operands(mgr, one_chip, True)
     compiled = fn.func.lower(*fn.args, pool, *operands).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     weights = sum(l.size * l.dtype.itemsize

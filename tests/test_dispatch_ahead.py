@@ -318,10 +318,15 @@ def test_counters_reach_info_and_metrics(tiny):
         assert info["decode_dispatches_total"] \
             == info["decode_dispatches_ahead_total"] \
             + sum(info["decode_serial_reasons"].values()) > 0
+        # one packed host array a launch (slots.LaunchPacking)
+        assert info["decode_upload_arrays_total"] \
+            == info["decode_dispatches_total"]
         text = ms.metrics_text()
         assert "ptpu_serving_decode_dispatches_total " in text
         assert "ptpu_serving_decode_dispatches_ahead_total " in text
         assert 'ptpu_serving_decode_serial_reasons{reason="first"}' in text
+        assert (f"ptpu_serving_decode_upload_arrays_total "
+                f"{info['decode_upload_arrays_total']}\n") in text
     finally:
         ms.close()
 
@@ -353,3 +358,24 @@ def test_dispatch_ahead_pct_reader(info_open, info_close, want):
     ctx = types.SimpleNamespace(collected={"info_open": info_open,
                                            "info_close": info_close})
     assert _reader("dispatch_ahead_pct")(ctx) == want
+
+
+@pytest.mark.parametrize("info_open,info_close,want", [
+    ({"decode_dispatches_total": 10, "decode_upload_arrays_total": 10},
+     {"decode_dispatches_total": 210, "decode_upload_arrays_total": 210},
+     1.0),
+    # a launch that put nine arrays, as before they were packed
+    ({"decode_dispatches_total": 4, "decode_upload_arrays_total": 36},
+     {"decode_dispatches_total": 6, "decode_upload_arrays_total": 54},
+     9.0),
+    # nothing dispatched in the window; a program without the counter
+    ({"decode_dispatches_total": 7, "decode_upload_arrays_total": 7},
+     {"decode_dispatches_total": 7, "decode_upload_arrays_total": 7},
+     None),
+    ({"decode_dispatches_total": 1}, {"decode_dispatches_total": 90},
+     None),
+], ids=["packed", "nine", "idle", "parent"])
+def test_upload_arrays_per_dispatch_reader(info_open, info_close, want):
+    ctx = types.SimpleNamespace(collected={"info_open": info_open,
+                                           "info_close": info_close})
+    assert _reader("upload_arrays_per_dispatch")(ctx) == want

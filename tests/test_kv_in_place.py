@@ -36,7 +36,7 @@ from polyaxon_tpu.models.gpt2 import GPT2Config, GPT2Model
 from polyaxon_tpu.serving import (DecodeEngine, FaultPlan, RetryPolicy)
 from polyaxon_tpu.serving.paged import PagedSlotKVManager
 from polyaxon_tpu.serving.scheduler import SamplingSpec, SchedulerPolicy
-from polyaxon_tpu.serving.slots import SlotKVManager
+from polyaxon_tpu.serving.slots import LaunchPacking, SlotKVManager
 
 from test_chip_compile import _layer_loop
 
@@ -180,9 +180,9 @@ def test_decode_window_aliases_the_pool_and_moves_only_rows(
     _fill(mgr, model, variables, sampled=sampled)
     mgr.step(8, sampled)
     fn = mgr._step_fns[(8, sampled)]        # partial(jitted, weights)
-    operands = [jnp.asarray(8, jnp.int32)] + [
-        jnp.asarray(x) for x in mgr.state.operands(
-            "sampled" if sampled else "plain")]
+    words, device = LaunchPacking.pack((np.int32(8), *mgr.state.operands(
+        "sampled" if sampled else "plain")))
+    operands = [jnp.asarray(words), *device]
     pool = mgr.kv_pool()
     text = fn.func.lower(*fn.args, pool, *operands).compile().as_text()
 
@@ -754,8 +754,9 @@ def test_the_row_writes_counted_are_the_writes_the_program_makes(name):
     mgr.step(3, False, 8)
     assert mgr.plane_reads.row_writes == 5 * ROW_WRITES[name]
     fn = mgr._step_fns[(8, False)]
-    operands = [jnp.asarray(8, jnp.int32)] + [
-        jnp.asarray(x) for x in mgr.state.operands("plain")]
+    words, device = LaunchPacking.pack(
+        (np.int32(8), *mgr.state.operands("plain")))
+    operands = [jnp.asarray(words), *device]
     pool = mgr.kv_pool()
     rows = {leaf.shape for path, leaf, kind in kv_cache.leaf_kinds(pool)
             if kind != "state" and leaf.ndim >= 4}

@@ -200,10 +200,13 @@ def _decode_window(model, variables):
     """The manager's sampled decode window, ready to lower."""
     import jax.numpy as jnp
 
+    from polyaxon_tpu.serving.slots import LaunchPacking
+
     mgr, _ = _manager(model, variables)
     mgr.step(2, True)               # a launch leaves the feedback token
-    operands = [jnp.asarray(2, jnp.int32)] + [
-        jnp.asarray(x) for x in mgr.state.operands("sampled")]
+    words, device = LaunchPacking.pack(
+        (np.int32(2), *mgr.state.operands("sampled")))
+    operands = [jnp.asarray(words), *device]
 
     def lower():
         fn = mgr._build_step(2, True)       # traced anew each time
