@@ -143,7 +143,9 @@ def _dt_bias_init(cfg: JambaConfig):
 
 
 class JambaAttention(nn.Module):
-    """Multi-query attention, no bias, no positional encoding."""
+    """Grouped-query attention (multi-query where there is one KV
+    head), no bias, no positional encoding; also the attention layer
+    of ``nemotron_h.py``."""
 
     cfg: JambaConfig
 

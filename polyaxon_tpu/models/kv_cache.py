@@ -666,7 +666,8 @@ class PlaneReads:
     And of what the ``state`` leaves went through, for a model that
     keeps any (``state_layers`` > 0): ``scan_tokens``, positions x
     state layers through the prefill scan (a piece of more than one
-    position: ``ops/selective_scan.selective_scan``), and
+    position: ``ops/selective_scan.selective_scan``, Mamba-2's
+    ``ops/ssd.ssd_scan``), and
     ``state_steps``, sequence-steps of the one-position update (a
     decode step a slot, idle slots too; a prefill piece of one
     position).  Engine stats ``ssm_scan_tokens_total`` /

@@ -26,6 +26,7 @@ from .jamba import JambaConfig, JambaModel
 from .llama import LlamaConfig, LlamaModel
 from .mlp import MLP
 from .moe_gpt import MoEGPTConfig, MoEGPTModel
+from .nemotron_h import NemotronHConfig, NemotronHModel
 from .resnet import ResNet, ResNet50
 from .t5 import T5Config, T5Model, shift_right
 from .vit import ViTConfig, ViTModel
@@ -539,6 +540,28 @@ _register(ModelSpec(
                           DeepseekV2Config.v2_lite_stage0()),
     make_batch=lambda b: _token_batch(
         b, 8, DeepseekV2Config.v2_lite_stage0().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=1,
+))
+
+
+_register(ModelSpec(
+    name="nemotron-h-tiny",
+    make_model=_cfg_model(NemotronHModel, NemotronHConfig.tiny()),
+    make_batch=lambda b: _token_batch(
+        b, 16, NemotronHConfig.tiny().vocab_size),
+    loss_fn=_lm_loss,
+    default_batch_size=8,
+))
+
+# Served only (perfbench cell nemotron3-serve-reason): stage 0 of
+# EP 8 x PP 4.
+_register(ModelSpec(
+    name="nemotron-3-nano-stage0-ep8",
+    make_model=_cfg_model(NemotronHModel,
+                          NemotronHConfig.nano_stage0_ep8()),
+    make_batch=lambda b: _token_batch(
+        b, 8, NemotronHConfig.nano_stage0_ep8().vocab_size),
     loss_fn=_lm_loss,
     default_batch_size=1,
 ))

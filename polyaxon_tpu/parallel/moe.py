@@ -7,7 +7,8 @@ dispatch/combine keeps everything MXU-shaped (no dynamic gathers — XLA
 and the TPU both prefer the one-hot matmul form).
 
 Beside it, the token-choice layer of the served sparse decoders
-(``models/afmoe.py``, ``models/deepseek_v2.py``):
+(``models/afmoe.py``, ``models/deepseek_v2.py``,
+``models/nemotron_h.py``):
 :func:`sigmoid_topk_route` and :func:`softmax_topk_route` score every
 token over ALL experts, and :func:`held_experts_ffn` computes the part
 of the routed sum that the experts HELD here give — one chip's share
@@ -194,16 +195,18 @@ def held_pair_counts(chosen, num_held: int, expert_offset: int = 0):
 
 
 @functools.lru_cache(maxsize=None)
-def _grouped_ffn(expert_offset: int):
-    """The grouped computation for one ``expert_offset``, flat over
-    tokens, with a batching rule that FLATTENS: a vmapped decode step
-    (serving/slots.py: one lane a slot, one token a lane) becomes ONE
-    grouped matmul over every slot's token, not a matmul a lane."""
+def _grouped_ffn(expert_offset: int, gated: bool):
+    """The grouped computation for one ``expert_offset`` and one form
+    of expert — ``gated``: SwiGLU, ``(w_gate, w_up, w_down)``; else
+    relu², ``(w_up, w_down)`` — flat over tokens, with a batching rule
+    that FLATTENS: a vmapped decode step (serving/slots.py: one lane a
+    slot, one token a lane) becomes ONE grouped matmul over every
+    slot's token, not a matmul a lane."""
 
     @jax.custom_batching.custom_vmap
-    def ffn(x, chosen, weights, w_gate, w_up, w_down):
+    def ffn(x, chosen, weights, *ws):
         t, k = chosen.shape
-        held_n = w_gate.shape[0]
+        held_n = ws[0].shape[0]
         local = chosen.reshape(-1) - expert_offset          # [T*k]
         held = (local >= 0) & (local < held_n)
         key = jnp.where(held, local, held_n)    # absent experts sort last
@@ -211,7 +214,12 @@ def _grouped_ffn(expert_offset: int):
         sizes = held_pair_counts(chosen, held_n, expert_offset)
         rows = x[order // k]                                # [T*k, d]
         dot = functools.partial(grouped_matmul, sizes=sizes)
-        h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
+        if gated:
+            w_gate, w_up, w_down = ws
+            h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
+        else:
+            w_up, w_down = ws
+            h = jnp.square(jax.nn.relu(dot(rows, w_up)))
         out = dot(h.astype(x.dtype), w_down)                # [T*k, d]
         # Rows past the last group belong to no held expert: whatever
         # the grouped matmul left there must not reach the sum.
@@ -238,7 +246,10 @@ def _grouped_ffn(expert_offset: int):
 
 def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
                      expert_offset: int = 0):
-    """The held experts' part of ``sum_e w_e SwiGLU_e(x)``.
+    """The held experts' part of ``sum_e w_e FFN_e(x)``: ``FFN_e`` the
+    SwiGLU ``W_down (silu(x W_gate) * x W_up)``, or, where ``w_gate``
+    is None, the ungated relu² ``W_down relu(x W_up)^2``
+    (``models/nemotron_h.py``).
 
     ``x`` [T, d]; ``chosen``/``weights`` [T, k] from a route above
     (ids over ALL experts); ``w_gate``,
@@ -258,6 +269,7 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, *,
     here, so no held pair is ever dropped; no ``[T, d, f]`` copy of
     weights a token is made.  Traced whole under the scope
     ``ptpu_experts`` (spans.py)."""
+    ws = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
     with scope("ptpu_experts"):
-        return _grouped_ffn(int(expert_offset))(
-            x, chosen, weights, w_gate, w_up, w_down)
+        return _grouped_ffn(int(expert_offset), w_gate is not None)(
+            x, chosen, weights, *ws)

@@ -180,6 +180,22 @@ def pool_refusal(models, *, paged: bool = False, meshed: bool = False,
     return None
 
 
+def _rests_as_pinned(shape, fmt) -> bool:
+    """Whether the device's default layout for a pool leaf of
+    ``shape`` on ``fmt``'s sharding is ``fmt``'s pinned one: where it
+    is not, an executable read back from the persistent compilation
+    cache would label the leaf wrongly (``config.fresh_compile``)."""
+    from jax.experimental.layout import Layout
+
+    sharding = fmt.sharding
+    device = min(sharding.device_set, key=lambda d: d.id)
+    shard = sharding.shard_shape(shape.shape)
+    default = Layout.from_pjrt_layout(
+        device.client.get_default_layout(shape.dtype, shard, device))
+    return tuple(default.major_to_minor) \
+        == tuple(fmt.layout.major_to_minor)
+
+
 class SlotState:
     """The host's half of the slot pool: which slots are free, and per
     slot what the next step program is fed — feedback token and
@@ -1130,15 +1146,20 @@ class SlotKVManager(SlotManager):
         # A prefilled cache rests in the device's default layout:
         # row-major on a CPU, and on a TPU for a plane whose heads fill
         # the lanes (folded, kv_cache.lane_fold); position-minor there
-        # for a head dimension under 128 lanes that does not fold.
-        self._pin_is_default = self._pin_is_default and all(
-            tuple(l.format.layout.major_to_minor) == tuple(range(l.ndim))
-            for l in jax.tree.leaves(template_cache)
-            if isinstance(l, jax.Array))
+        # for a head dimension under 128 lanes that does not fold.  A
+        # pool leaf's own default may differ from its lane's: on a TPU
+        # a [slots, 1, 3, width] convolution tail rests (1, 2, 0, 3)
+        # while its lane [1, 3, width] rests row-major.
         shapes = jax.tree.map(
             lambda l: jax.ShapeDtypeStruct(
                 (self.n_slots,) + l.shape, l.dtype), template_cache)
         fmt = self._pool_formats(shapes)
+        self._pin_is_default = self._pin_is_default and all(
+            tuple(l.format.layout.major_to_minor) == tuple(range(l.ndim))
+            for l in jax.tree.leaves(template_cache)
+            if isinstance(l, jax.Array)) and all(
+            _rests_as_pinned(l, f) for l, f in zip(
+                jax.tree.leaves(shapes), jax.tree.leaves(fmt)))
         with self._exact(), self._compiling(True):
             stacked = jax.jit(
                 lambda: jax.tree.map(

@@ -79,8 +79,11 @@ SCOPE_NAMES = (
     "ptpu_latent_expand",   # latent rows through W_kvb to K, V a head
     "ptpu_route",           # token-choice routing: scores and top-k
     "ptpu_experts",         # the held experts: sort, grouped matmuls, sum
-    "ptpu_scan",            # the selective scan over a piece
-    "ptpu_state_step",      # a recurrent state's one-position update
+    "ptpu_scan",            # a piece's scan: Mamba-1's selective scan,
+                            # Mamba-2's chunked (SSD) blocks
+    "ptpu_state_step",      # a recurrent state's one-position update:
+                            # Mamba-1's vector a channel, Mamba-2's
+                            # matrix a head
     "ptpu_sample",          # logits shaped and a token drawn, or arg-max
     "ptpu_optimizer",       # optimizer.update and the parameters' update
 )

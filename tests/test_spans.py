@@ -282,6 +282,8 @@ SCOPED_PROGRAMS = {
     ("jamba-tiny", "decode"): (ATTEND, WRITES, SAMPLE,
                                "ptpu_state_step"),
     ("jamba-tiny", "extend"): (ATTEND, WRITES, "ptpu_scan"),
+    ("nemotron-h-tiny", "extend"): (ATTEND, WRITES, "ptpu_scan")
+    + EXPERTS,
     ("deepseek-v2-tiny", "decode"): (ATTEND, WRITES, SAMPLE) + EXPERTS,
     ("deepseek-v2-tiny", "extend"): (ATTEND, WRITES,
                                      "ptpu_latent_expand") + EXPERTS,
